@@ -1,0 +1,660 @@
+"""chip_smoke.py — the quickest proof that the serving path starts on the chip.
+
+One process (a chip belongs to one process at a time) drives the system's
+main path once, through the entry points a user would call:
+
+- **serve**: a localcluster node with the REAL backends its config names
+  (``EngineBackend`` for ResNet-18 at its published width, batch 256,
+  bfloat16) over a 1,000-class JPEG corpus generated from a seed;
+  ``leader.predict()`` -> ``job.start`` -> ``job.predict`` -> the donating
+  ``run_paths_stream`` program, plus one direct ``job.predict`` RPC through
+  the plain program. Answers are checked against a float32 forward of the
+  same seed-initialised weights computed on the host CPU.
+- **generate**: the same node's ``lm_small`` generation plane at the config
+  defaults: overlapping ``leader.generate`` calls through GenRouter ->
+  GenerateWorker -> SlotScheduler -> GenerationEngine, greedy tokens checked
+  against a ``cache="contiguous", use_pallas=False`` engine.
+- **kernels**: every ``pl.pallas_call`` site compiled (never interpreted)
+  once at a serving/training shape and checked against its XLA reference.
+- **multichip**: on a host with several chips, the engine's dp mesh, per-
+  device memory, and the gang-sharded LM at full width.
+
+It refuses to run anywhere but a TPU: the first thing it does is read
+``jax.devices()``, and a CPU exits non-zero before any work. No flag or
+environment switch makes it pass there — ``tests/test_chip_smoke.py`` calls
+the phase functions below at a tiny size instead.
+
+Stdout carries two JSON lines. The first is the report, ``{"report":
+{versions, compile_cache, phases, census, decode, peak_hbm_bytes}}``; wall
+seconds in it are set-up times, not metrics, and the script claims no rate.
+The LAST line is the result and holds exactly ``{"ok": true, "device":
+{"platform": ..., "kind": ..., "count": ...}}``, the device as JAX reports
+it. A failed phase raises: there is neither line, and the exit code is
+non-zero.
+"""
+
+from __future__ import annotations
+
+import json
+import logging
+import sys
+import tempfile
+import threading
+import time
+from pathlib import Path
+
+# bf16 carries 8 significand bits. Tolerances for comparing the chip's bf16
+# answers with a float32 reference are fixed here from the dtype, before any
+# run: 8 ulps of the largest value in play.
+BF16_TOL = 8 * 2.0 ** -8
+
+
+def say(msg: str) -> None:
+    """Progress goes to stderr; stdout carries only the report and result lines."""
+    print(f"[chip_smoke] {msg}", file=sys.stderr, flush=True)
+
+
+# ---------------------------------------------------------------------------
+# serve: JPEG -> top-1 through the cluster
+# ---------------------------------------------------------------------------
+
+
+def start_cluster(tmp: Path, *, model: str, n_classes: int, image_size: int,
+                  gen_model: str, **overrides):
+    """One localcluster node serving ``model`` and ``gen_model`` with the
+    real, config-built backends, at the reference's 1 s / 3 s intervals
+    (``scale=5``: the harness's 5x-compressed timers falsely FAIL a member
+    whose compile holds the GIL). Returns (node list, synset ids, corpus
+    image directory)."""
+    from dmlc_tpu.cluster import localcluster
+    from dmlc_tpu.utils import corpus
+
+    data_dir, synset_path = corpus.generate(
+        tmp / "corpus", n_classes=n_classes, images_per_class=1,
+        size=image_size, seed=0,
+    )
+    nodes = localcluster.start_local_cluster(
+        tmp, n_nodes=1, n_leader_candidates=1,
+        backends=localcluster.CONFIGURED, scale=5.0,
+        job_models=[model], generate_models=[gen_model],
+        data_dir=str(data_dir), synset_path=synset_path,
+        **overrides,
+    )
+    return nodes, [f"n{i:08d}" for i in range(n_classes)], data_dir
+
+
+def reference_top1(model: str, paths, *, chunk: int = 64):
+    """float32 forward of the registry's seed-initialised weights (the seed
+    every engine inits from) on the HOST CPU device — off the chip.
+    Returns (top-1 index, top-1 softmax probability, near-tie mask): an
+    image is a near-tie when its top-2 logit margin is inside the bf16
+    tolerance, i.e. when a correct bf16 forward may legitimately disagree."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dmlc_tpu.models import get_model
+    from dmlc_tpu.ops import preprocess as pp
+
+    spec = get_model(model)
+    mean, std = pp.stats_for_model(model)
+    with jax.default_device(jax.devices("cpu")[0]):
+        module = spec.module(dtype=jnp.float32)
+        _, variables = spec.init_params(jax.random.PRNGKey(0), dtype=jnp.float32)
+        forward = jax.jit(lambda v, x: module.apply(v, x, train=False))
+        logits = []
+        for s in range(0, len(paths), chunk):
+            u8 = pp.load_batch(paths[s:s + chunk], size=spec.input_size)
+            x = (u8.astype(np.float32) / 255.0 - mean) / std
+            logits.append(np.asarray(forward(variables, x)))
+    logits = np.concatenate(logits).astype(np.float64)
+    top2 = np.sort(logits, axis=1)[:, -2:]
+    near_tie = (top2[:, 1] - top2[:, 0]) < BF16_TOL * np.abs(logits).max()
+    z = np.exp(logits - logits.max(axis=1, keepdims=True))
+    prob = z.max(axis=1) / z.sum(axis=1)
+    return logits.argmax(axis=1), prob, near_tie
+
+
+def _compare_top1(what: str, got_idx, got_prob, ref, rows) -> dict:
+    """Chip answers for corpus rows ``rows`` against the reference: top-1
+    index on every image that is not a near-tie, top-1 probability (which,
+    unlike the index under random weights, differs image to image) on all."""
+    import numpy as np
+
+    ref_idx, ref_prob, near_tie = (np.asarray(r)[rows] for r in ref)
+    got_idx = np.asarray(got_idx)
+    if got_idx.shape != ref_idx.shape:
+        raise AssertionError(f"{what}: {got_idx.shape} answers for {ref_idx.shape} images")
+    decided = ~near_tie
+    wrong = np.nonzero(decided & (got_idx != ref_idx))[0]
+    if wrong.size:
+        raise AssertionError(
+            f"{what}: top-1 differs from the float32 reference on "
+            f"{wrong.size}/{int(decided.sum())} decided images, first rows "
+            f"{wrong[:5].tolist()}: got {got_idx[wrong[:5]].tolist()} "
+            f"want {ref_idx[wrong[:5]].tolist()}"
+        )
+    out = {"compared": int(decided.sum()), "near_ties_skipped": int(near_tie.sum())}
+    if got_prob is not None:
+        got_prob = np.asarray(got_prob, np.float64)
+        if not np.isfinite(got_prob).all():
+            raise AssertionError(f"{what}: non-finite top-1 probabilities")
+        rel = np.abs(got_prob - ref_prob) / ref_prob
+        if rel.max() > BF16_TOL:
+            raise AssertionError(
+                f"{what}: top-1 probability off by {rel.max():.4f} relative "
+                f"(bound {BF16_TOL:.4f}) at row {int(rel.argmax())}"
+            )
+        out["prob_max_rel_err"] = round(float(rel.max()), 5)
+    return out
+
+
+def serve_phase(node, *, model: str, synsets, data_dir,
+                job_timeout_s: float = 600.0) -> dict:
+    """``predict`` the whole corpus through the scheduler, send one direct
+    ``job.predict`` of one full batch, and check what came back."""
+    import numpy as np
+
+    from dmlc_tpu.cluster.localcluster import wait_until
+    from dmlc_tpu.ops import preprocess as pp
+    from dmlc_tpu.utils import tracing
+
+    paths = [str(pp.class_image_path(data_dir, s)) for s in synsets]
+    ref = reference_top1(model, paths)
+    total = len(synsets)
+    direct_n = node.config.batch_size
+
+    # The job: leader.predict() -> job.start -> dispatch loop -> job.predict
+    # on the member -> (shards > batch_size) run_paths_stream. Traced, so
+    # "zero failed shards" is read off the repo's own spans.
+    tracer = tracing.tracer
+    was_enabled, tracer.enabled = tracer.enabled, True
+    seen = tracer.event_count
+    try:
+        node.predict()
+        wait_until(
+            lambda: node.jobs_report()[model]["finished"] == total,
+            timeout=job_timeout_s, interval=0.25, msg=f"{model} job to finish",
+        )
+    finally:
+        tracer.enabled = was_enabled
+    report = node.jobs_report()[model]
+    if report["last_error"]:
+        raise AssertionError(f"job reported an error: {report['last_error']}")
+    # Every dispatch span closed without an exception, and there are
+    # exactly as many as shards (a failed shard is dispatched again).
+    spans = tracer.events_wire(offset=seen)
+    failed = [e for e in spans if e["attrs"].get("error")
+              and e["name"].startswith(("scheduler/", "rpc/job.", "device/", "host/"))]
+    if failed:
+        raise AssertionError(f"failed spans on the serving path: {failed[:3]}")
+    dispatches = sum(1 for e in spans if e["name"] == "scheduler/dispatch")
+    want_shards = -(-total // node.config.dispatch_shard_size)
+    if dispatches != want_shards:
+        raise AssertionError(f"{dispatches} shard dispatches for {want_shards} shards")
+
+    # One direct RPC of <= batch_size synsets: the plain (non-donating) program.
+    rows = np.arange(direct_n)
+    reply = node.rpc.call(
+        node.self_member_addr, "job.predict",
+        {"model": model, "synsets": synsets[:direct_n]},
+        timeout=node.config.predict_deadline_s,
+    )
+    direct = _compare_top1("direct job.predict", reply["predictions"], None, ref, rows)
+
+    # The RPC surface returns indices only, and under random weights every
+    # image lands on the same class. The same two compiled programs, asked
+    # in-process for their probabilities, are checked image by image.
+    engine = node.worker.backends[model]._engine
+    plain = engine.run_paths(paths[:direct_n])
+    stream = engine.run_paths_stream(paths)
+    return {
+        "job": {"finished": report["finished"], "total": total,
+                "shards": dispatches, "failed_shards": 0,
+                "correct_vs_labels": report["correct"]},
+        "direct_rpc": direct,
+        "plain_program": _compare_top1(
+            "infer program", plain.top1_index, plain.top1_prob, ref, rows),
+        "stream_program": _compare_top1(
+            "stream program", stream.top1_index, stream.top1_prob, ref,
+            np.arange(total)),
+        "distinct_top1_classes": int(len(set(np.asarray(ref[0]).tolist()))),
+    }
+
+
+# ---------------------------------------------------------------------------
+# generate: overlapping streams through the router
+# ---------------------------------------------------------------------------
+
+
+def generate_phase(node, *, model: str, prompts, max_new) -> dict:
+    """Overlapping ``leader.generate`` calls (so slots join and leave a
+    running batch), each stream checked token for token against a
+    contiguous-cache, XLA-gather engine run of the same prompt."""
+    from dmlc_tpu.generate.engine import GenerationEngine
+
+    results: list = [None] * len(prompts)
+    errors: list = []
+    gate = threading.Barrier(len(prompts))
+
+    def one(i: int) -> None:
+        try:
+            gate.wait(timeout=30)
+            results[i] = node.generate(model, prompts[i], max_new_tokens=max_new[i])
+        except BaseException as e:  # re-raised on the main thread below
+            errors.append(e)
+
+    threads = [threading.Thread(target=one, args=(i,)) for i in range(len(prompts))]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+    if errors:
+        raise errors[0]
+    if any(t.is_alive() for t in threads):
+        raise AssertionError("a generate call did not return in 300 s")
+    if not all(r["routed"] for r in results):
+        raise AssertionError("a generate call bypassed the leader's GenRouter")
+
+    sched = node._gen_backends[model]._scheduler
+    cfg = node.config
+    ref = GenerationEngine(
+        model, cache="contiguous", use_pallas=False, max_slots=1,
+        max_prefill=cfg.gen_max_prefill,
+    )
+    for i, prompt in enumerate(prompts):
+        want = [ref.join(0, prompt)]
+        while len(want) < max_new[i]:
+            want.append(int(ref.step()[0]))
+        ref.release(0)
+        got = results[i]["tokens"]
+        if got != want:
+            raise AssertionError(
+                f"stream {i} (prompt of {len(prompt)}) diverged from the "
+                f"contiguous reference: got {got} want {want}"
+            )
+    summary = sched.summary()
+    serial_steps = sum(n - 1 for n in max_new)
+    if not summary["steps"] < serial_steps:
+        raise AssertionError(
+            f"{summary['steps']} decode steps for {serial_steps} decoded tokens: "
+            "the streams never shared a batch"
+        )
+    return {
+        "streams": len(prompts),
+        "tokens_checked": sum(max_new),
+        "decode_steps": summary["steps"],
+        "serial_steps": serial_steps,
+        "use_pallas": summary["use_pallas"],
+        "completions": summary["completions"],
+    }
+
+
+def lowered_step_text(engine) -> str:
+    """StableHLO of the engine's decode-step program, lowered from abstract
+    arguments (no live buffer is touched — the pools are donated)."""
+    import jax
+    import numpy as np
+
+    def abstract(tree):
+        return jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), tree)
+
+    return engine._step.lower(
+        abstract(engine._variables), abstract(engine._k_state),
+        abstract(engine._v_state), abstract(engine.last_tokens),
+        abstract(engine.lengths), abstract(engine.active),
+        abstract(engine.cache.page_table), abstract(engine.seeds),
+        abstract(engine.temps),
+    ).as_text()
+
+
+# ---------------------------------------------------------------------------
+# kernels: every pallas_call site, compiled, against its XLA reference
+# ---------------------------------------------------------------------------
+
+MOSAIC_CALL = "tpu_custom_call"
+
+#: Shapes the kernels phase compiles at: the serving batch for the two
+#: vision kernels, a training-grade bf16 Dh=128 attention on both sides of
+#: the resident/streamed K/V switch, lm_small's page geometry.
+KERNEL_SHAPES = {
+    "images": (256, 224, 224, 3),
+    "logits": (256, 1000),
+    "attn_heads": 8,
+    "attn_dh": 128,
+    "s_resident": 2048,
+    "s_streamed": 16384,
+    "sp_s_local": 1024,
+    "pages": (128, 16, 2, 64),   # [num_pages, page_size, heads, head_dim]
+    "page_table": (8, 16),       # [max_slots, max_pages_per_slot]
+}
+
+
+def _attention_ref(q, k, v, *, causal: bool, chunk: int = 2048):
+    """Dense attention in q-row chunks at full matmul precision: the XLA
+    reference for sequences whose [S, S] score matrix should not be built
+    whole."""
+    import jax
+    import jax.numpy as jnp
+
+    s = q.shape[2]
+    scale = q.shape[-1] ** -0.5
+    k32, v32 = k.astype(jnp.float32), v.astype(jnp.float32)
+    outs = []
+    with jax.default_matmul_precision("highest"):
+        for start in range(0, s, chunk):
+            qc = q[:, :, start:start + chunk].astype(jnp.float32) * scale
+            scores = jnp.einsum("bhqd,bhkd->bhqk", qc, k32)
+            if causal:
+                q_pos = start + jnp.arange(qc.shape[2])
+                mask = jnp.arange(s)[None, :] <= q_pos[:, None]
+                scores = jnp.where(mask[None, None], scores, -jnp.inf)
+            outs.append(jnp.einsum("bhqk,bhkd->bhqd", jax.nn.softmax(scores, -1), v32))
+    return jnp.concatenate(outs, axis=2)
+
+
+def _run_kernel(name: str, fn, args, ref_fn, tol: float, *, exact: bool = False) -> dict:
+    """Lower ``fn`` (the lowered text must hold the Mosaic custom call —
+    an interpreted kernel has none), compile, run, and compare every output
+    leaf with ``ref_fn`` by max error relative to the reference's scale."""
+    import jax
+    import numpy as np
+
+    jitted = jax.jit(fn)
+    t0 = time.perf_counter()
+    text = jitted.lower(*args).as_text()
+    got = jax.block_until_ready(jitted(*args))
+    seconds = time.perf_counter() - t0
+    want = jax.block_until_ready(jax.jit(ref_fn)(*args))
+    worst = 0.0
+    for g, w in zip(jax.tree_util.tree_leaves(got), jax.tree_util.tree_leaves(want)):
+        g, w = np.asarray(g, np.float64), np.asarray(w, np.float64)
+        if g.shape != w.shape:
+            raise AssertionError(f"{name}: shape {g.shape} != reference {w.shape}")
+        if not np.isfinite(g).all():
+            raise AssertionError(f"{name}: non-finite output")
+        if exact:
+            err = float((g != w).any())
+        else:
+            err = float(np.abs(g - w).max() / max(np.abs(w).max(), 1e-30))
+        worst = max(worst, err)
+    if worst > tol:
+        raise AssertionError(f"{name}: max relative error {worst:.3e} > {tol:.3e}")
+    return {"mosaic": MOSAIC_CALL in text, "rel_err": float(f"{worst:.3e}"),
+            "compile_and_run_s": round(seconds, 2)}
+
+
+def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
+    """Every ``pl.pallas_call`` site in ``ops/`` (and the two sequence-
+    parallel schedules built on them, over a mesh of all local devices).
+    Each kernel's failure is recorded with the compiler's message and the
+    rest still run — a bring-up wants the whole list — but the phase raises
+    if any failed."""
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_tpu.ops import pallas_kernels as pk
+    from dmlc_tpu.ops import preprocess as pp
+    from dmlc_tpu.ops.ragged_decode import gather_kv_pages
+    from dmlc_tpu.parallel.mesh import make_mesh
+    from dmlc_tpu.parallel.ring_attention import ring_flash_attention
+    from dmlc_tpu.parallel.ulysses import ulysses_attention
+
+    keys = iter(jax.random.split(jax.random.PRNGKey(7), 32))
+    h, dh = shapes["attn_heads"], shapes["attn_dh"]
+
+    def qkv(s, dtype=jnp.bfloat16, heads=h):
+        return tuple(jax.random.normal(next(keys), (1, heads, s, dh), dtype)
+                     for _ in range(3))
+
+    def causal_flash(q, k, v):
+        return pk.flash_attention(q, k, v, causal=True)
+
+    def causal_ref(q, k, v):
+        return _attention_ref(q, k, v, causal=True).astype(q.dtype)
+
+    def grads(attn):
+        return jax.grad(
+            lambda q, k, v: jnp.sum(attn(q, k, v).astype(jnp.float32) ** 2),
+            argnums=(0, 1, 2))
+
+    images = jax.random.randint(next(keys), shapes["images"], 0, 256, jnp.int32).astype(jnp.uint8)
+    logits = jax.random.normal(next(keys), shapes["logits"], jnp.float32) * 3.0
+    n_pages = shapes["pages"][0]
+    table = jax.random.randint(next(keys), shapes["page_table"], 0, n_pages, jnp.int32)
+    pages = jax.random.normal(next(keys), shapes["pages"], jnp.float32)
+    n = len(devices)
+    mesh = make_mesh({"sp": n}, devices=devices)
+    sp_args = qkv(shapes["sp_s_local"] * n)
+
+    cases = [
+        ("normalize_u8",
+         lambda u8: pk.normalize_u8(u8, pp.IMAGENET_MEAN, pp.IMAGENET_STD),
+         (images,),
+         lambda u8: (u8.astype(jnp.float32) / 255.0 - pp.IMAGENET_MEAN) / pp.IMAGENET_STD,
+         1e-5, {}),
+        ("softmax_top1", pk.softmax_top1, (logits,),
+         lambda x: (jnp.argmax(x, -1).astype(jnp.int32), jnp.max(jax.nn.softmax(x, -1), -1)),
+         1e-4, {}),
+        ("flash_fwd_resident", causal_flash, qkv(shapes["s_resident"]), causal_ref, BF16_TOL, {}),
+        ("flash_fwd_streamed", causal_flash, qkv(shapes["s_streamed"], heads=2),
+         causal_ref, BF16_TOL, {}),
+        ("flash_bwd", grads(causal_flash), qkv(shapes["s_resident"]),
+         grads(causal_ref), 2 * BF16_TOL, {}),
+        ("page_gather_f32", lambda p, t: gather_kv_pages(p, t, use_pallas=True),
+         (pages, table), lambda p, t: gather_kv_pages(p, t), 0.0, {"exact": True}),
+        ("page_gather_bf16", lambda p, t: gather_kv_pages(p, t, use_pallas=True),
+         (pages.astype(jnp.bfloat16), table), lambda p, t: gather_kv_pages(p, t),
+         0.0, {"exact": True}),
+        (f"ring_flash_sp{n}",
+         lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
+         sp_args, causal_ref, BF16_TOL, {}),
+        (f"ulysses_flash_sp{n}",
+         lambda q, k, v: ulysses_attention(q, k, v, mesh, causal=True, use_flash=True),
+         sp_args, causal_ref, BF16_TOL, {}),
+    ]
+    out: dict = {}
+    failures: list[str] = []
+    for name, fn, args, ref_fn, tol, kw in cases:
+        try:
+            out[name] = _run_kernel(name, fn, args, ref_fn, tol, **kw)
+            say(f"kernel {name}: {out[name]}")
+        except Exception as e:  # recorded, and the phase raises below
+            msg = f"{type(e).__name__}: {e}"
+            failures.append(f"{name}: {msg[-1500:]}")
+            say(f"kernel {name} FAILED: {msg[-4000:]}")
+    if failures:
+        raise AssertionError(
+            f"{len(failures)} kernel(s) failed:\n" + "\n".join(failures))
+    return out
+
+
+# ---------------------------------------------------------------------------
+# multichip: what one process sees on a host with several chips
+# ---------------------------------------------------------------------------
+
+
+def multichip_phase(node, model: str, gen_model: str, devices) -> dict:
+    """The engine's default mesh is dp over every chip and its output's
+    addressable shards sit on as many distinct devices; every device holds
+    resident bytes; the gang-sharded lm_wide at full width is
+    token-identical to the mesh-of-1 reference, in this process. Two known
+    one-device behaviours are reported as observed, not asserted (ROADMAP
+    D2): where the generation engine's arrays live, and which device the
+    node's HBM gauge reads."""
+    import jax
+    import jax.numpy as jnp
+
+    import __graft_entry__ as graft
+
+    n = len(devices)
+    engine = node.worker.backends[model]._engine
+    if dict(engine.mesh.shape) != {"dp": n}:
+        raise AssertionError(f"engine mesh is {dict(engine.mesh.shape)}, want dp={n}")
+    u8 = jnp.zeros((engine.batch_size, engine.input_size, engine.input_size, 3), jnp.uint8)
+    idx, _ = engine._forward(engine.variables, u8)
+    shard_devices = {s.device.id for s in idx.addressable_shards}
+    if len(shard_devices) != n:
+        raise AssertionError(f"output shards sit on devices {sorted(shard_devices)}, want {n}")
+    in_use = [d.memory_stats()["bytes_in_use"] for d in jax.local_devices()]
+    if not all(b > 0 for b in in_use):
+        raise AssertionError(f"a device holds no resident bytes: {in_use}")
+    graft._gang_smoke_body(n)
+    gen = node._gen_backends[gen_model]._scheduler.engine
+    gen_devices = {d.id for leaf in jax.tree_util.tree_leaves(
+        (gen._variables, gen._k_state, gen._v_state)) for d in leaf.devices()}
+    return {"mesh": {"dp": n}, "output_shard_devices": sorted(shard_devices),
+            "bytes_in_use_per_device": in_use, "gang_width": n,
+            "gang_token_identical": True,
+            "observed_gen_engine_devices": sorted(gen_devices),
+            "observed_hbm_gauge_bytes_in_use":
+                node.registry.snapshot()["gauges"]["hbm_bytes_in_use"]}
+
+
+# ---------------------------------------------------------------------------
+# the run
+# ---------------------------------------------------------------------------
+
+VISION_MODEL = "resnet18"
+GEN_MODEL = "lm_small"
+REQUIRED_LABELS = (
+    f"infer/{VISION_MODEL}", f"infer/{VISION_MODEL}/stream",
+    f"gen/{GEN_MODEL}/step", f"gen/{GEN_MODEL}/prefill",
+)
+
+
+def _versions() -> dict:
+    from importlib import metadata
+
+    return {name: metadata.version(name) for name in ("jax", "jaxlib", "libtpu")}
+
+
+def result_line(devices) -> str:
+    """The last line of stdout: exactly ``ok`` and ``device``, the device as
+    JAX reports it. Everything else the run learned is in the report line."""
+    device = {"platform": devices[0].platform, "kind": devices[0].device_kind,
+              "count": len(devices)}
+    return json.dumps({"ok": True, "device": device})
+
+
+def main() -> int:
+    import jax
+
+    devices = jax.devices()
+    platform = devices[0].platform
+    if platform != "tpu":
+        print(
+            f"chip_smoke: no TPU found — jax.devices() reports platform="
+            f"{platform!r} ({devices[0].device_kind!r} x{len(devices)}). This "
+            "script proves the serving path on the chip and will not run "
+            "anywhere else; the CPU check is tests/test_chip_smoke.py.",
+            file=sys.stderr,
+        )
+        return 1
+
+    logging.basicConfig(level=logging.WARNING, stream=sys.stderr)
+    import numpy as np
+
+    from dmlc_tpu import native
+    from dmlc_tpu.cluster.devicemon import CENSUS
+    from dmlc_tpu.cluster.localcluster import stop_local_cluster
+    from dmlc_tpu.ops import pallas_kernels
+    from dmlc_tpu.utils import compile_cache
+
+    device = {"platform": platform, "kind": devices[0].device_kind, "count": len(devices)}
+    say(f"device {device}")
+    if pallas_kernels.interpret_mode():
+        raise AssertionError("Pallas would run interpreted on this backend")
+    compile_cache.enable()
+    cache_entries_at_start = compile_cache.counters()["entries"]
+    phases: dict = {}
+
+    def run(name: str, fn, *args, **kw):
+        say(f"phase {name} ...")
+        t0 = time.perf_counter()
+        facts = fn(*args, **kw)
+        phases[name] = {"status": "ok", "wall_s": round(time.perf_counter() - t0, 1), **facts}
+        say(f"phase {name} ok in {phases[name]['wall_s']} s")
+
+    native_stale_at_start = native._stale()
+    nodes: list = []
+    with tempfile.TemporaryDirectory(prefix="chip_smoke-") as tmp:
+        try:
+            t0 = time.perf_counter()
+            nodes, synsets, data_dir = start_cluster(
+                Path(tmp), model=VISION_MODEL, n_classes=1000, image_size=256,
+                gen_model=GEN_MODEL, dispatch_shard_size=512,
+            )
+            node = nodes[0]
+            phases["start"] = {"status": "ok", "wall_s": round(time.perf_counter() - t0, 1)}
+            say(f"cluster up in {phases['start']['wall_s']} s")
+
+            # What the node says about itself over the RPC surface.
+            info = node.rpc.call(node.self_member_addr, "node.info", {}, timeout=10.0)
+            status = node.rpc.call(node.self_member_addr, "node.status", {}, timeout=10.0)
+            if info["platform"] != "tpu" or status["platform"] != "tpu":
+                raise AssertionError(f"node reports platform {info['platform']!r}")
+            if info["decode_backend"] != "native":
+                raise AssertionError(
+                    "the native decode library did not build on this host; "
+                    "the node serves through PIL")
+            if info["chips"] != len(devices):
+                raise AssertionError(f"node.info chips={info['chips']}, jax sees {len(devices)}")
+            if not status["generate"]["models"][GEN_MODEL]["use_pallas"]:
+                raise AssertionError("node.status: the engine serves the XLA gather on the chip")
+            engine = node._gen_backends[GEN_MODEL]._scheduler.engine
+            if MOSAIC_CALL not in lowered_step_text(engine):
+                raise AssertionError(
+                    f"gen/{GEN_MODEL}/step lowered without a Mosaic custom call")
+            # Eager warm-up compiled every program the node serves with.
+            warmed = CENSUS.snapshot()["labels"]
+            missing = [label for label in REQUIRED_LABELS if label not in warmed]
+            if missing:
+                raise AssertionError(f"census shows no compile for {missing} after start")
+
+            run("serve", serve_phase, node, model=VISION_MODEL, synsets=synsets,
+                data_dir=data_dir)
+            rng = np.random.default_rng(0)
+            lengths, max_new = [5, 17, 33, 48, 64], [32, 24, 16, 28, 20]
+            prompts = [rng.integers(0, 1024, n).tolist() for n in lengths]
+            run("generate", generate_phase, node, model=GEN_MODEL,
+                prompts=prompts, max_new=max_new)
+
+            run("kernels", kernels_phase, devices)
+            interpreted = [k for k, v in phases["kernels"].items()
+                           if isinstance(v, dict) and not v["mosaic"]]
+            if interpreted:
+                raise AssertionError(f"kernels lowered without Mosaic: {interpreted}")
+
+            if len(devices) > 1:
+                run("multichip", multichip_phase, node, VISION_MODEL, GEN_MODEL, devices)
+            else:
+                phases["multichip"] = {"status": "skipped", "why": "1 device"}
+        finally:
+            stop_local_cluster(nodes)
+            native.pool_shutdown()
+
+    census = CENSUS.snapshot()["labels"]
+    stats = [d.memory_stats() for d in jax.local_devices()]
+    report = {
+        "versions": _versions(),
+        # Hits with no entries at start are this run re-compiling HLO it
+        # already compiled (e.g. the stream program, identical to the plain).
+        "compile_cache": {"dir": compile_cache.cache_dir(),
+                          "entries_at_start": cache_entries_at_start,
+                          **compile_cache.counters()},
+        "phases": phases,
+        "census": {label: {"compiles": e["compiles"], "seconds": round(e["seconds"], 2)}
+                   for label, e in census.items()},
+        "decode": {"backend": info["decode_backend"],
+                   "built_this_run": bool(native_stale_at_start)},
+        "peak_hbm_bytes": [s["peak_bytes_in_use"] for s in stats],
+    }
+    print(json.dumps({"report": report}), flush=True)
+    print(result_line(devices), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -409,6 +409,10 @@ class Cli:
         if cmd == "status":
             s = n.status()
             out = [f"node {s['member']}  (believed leader: {s['leader']})"]
+            out.append(
+                f"  device: platform={s['platform'] or '(jax not loaded)'} "
+                f"kind={s['device_kind'] or '-'}"
+            )
             counters = {k: v for k, v in sorted(s["counters"].items()) if v}
             out.append(
                 "  counters: "

@@ -1,7 +1,8 @@
 """Distributed decode tier: fan raw JPEG bytes across members' idle lanes.
 
-Single-host ingest is decode-bound: one host CPU caps at ~2.7k img/s while
-a chip wants >30k (BENCH_r05.json — the ~400x gap ROADMAP item 2 names).
+Single-host ingest is decode-bound: host JPEG decode runs an order of
+magnitude below what a chip classifies (the gap ROADMAP S2 names; not
+measured on this installation).
 SDFS already scales storage with membership; this module does the same for
 JPEG decode. The unit of work is a contiguous *chunk* of raw encoded-image
 blobs shipped to a member's ``job.decode`` verb (scheduler/worker.py),

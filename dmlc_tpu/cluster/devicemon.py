@@ -24,8 +24,10 @@ learns about queue depths:
   the alert fraction.
 - **Live MFU** — each dispatch/gen-step reports (items, device-seconds);
   with the registry's analytic ``flops_per_item`` that becomes achieved
-  FLOP/s against the per-platform ``PEAK_FLOPS`` roofline, exported as
-  per-model ``mfu_<model>`` gauges and folded into CostProfiler lanes.
+  FLOP/s against the ``DEVICE_PEAKS`` roofline of this host's
+  ``device_kind``, exported as per-model ``mfu_<model>`` gauges (``None``
+  on a device kind the table does not list — a CPU has no roofline here)
+  and folded into CostProfiler lanes.
 
 The census is process-global (jax compiles are process-global); co-hosted
 nodes in the localcluster harness therefore share one census, exactly like
@@ -46,11 +48,39 @@ if TYPE_CHECKING:
 
 log = logging.getLogger(__name__)
 
-# Per-chip peak dense FLOP/s by jax platform (bf16). The TPU row is the
-# v5e MXU peak — the same roofline bench.py scores MFU against; the CPU
-# row is a nominal 1 TFLOP/s so MFU stays a meaningful (if generous) ratio
-# on the test mesh. Override per-node with config.devicemon_peak_flops.
-PEAK_FLOPS: dict[str, float] = {"tpu": 197e12, "cpu": 1e12}
+# Published per-chip peaks, keyed by jax ``device_kind`` — the ONE table
+# every utilization figure in the repo (these gauges, bench.py) divides by.
+# A kind that is not listed has no roofline: ``peak_flops()`` and the
+# ``mfu_*`` gauges read None rather than score against somebody else's
+# chip. Override per-node with config.devicemon_peak_flops.
+DEVICE_PEAKS: dict[str, dict[str, float]] = {
+    # Google Cloud documentation, "TPU v5e": 197 TFLOP/s bf16, 16 GB HBM
+    # at 819 GB/s. jax reports the chip as "TPU v5 lite".
+    "TPU v5 lite": {"flops_bf16": 197e12, "hbm_bytes_per_s": 819e9},
+}
+
+
+def device_identity() -> dict[str, Any] | None:
+    """``{"platform", "device_kind", "count"}`` of this process's local
+    devices as jax reports them, or None while jax is not loaded or the
+    backend cannot be read. Never raises, and never the import that loads
+    jax (node.py's autodetect rule); once jax is loaded this does
+    initialize the backend, like ``memory_stats``."""
+    import sys
+
+    jax = sys.modules.get("jax")
+    if jax is None:
+        return None
+    try:
+        devices = jax.local_devices()
+        return {
+            "platform": devices[0].platform,
+            "device_kind": devices[0].device_kind,
+            "count": len(devices),
+        }
+    except Exception:  # noqa: BLE001 - telemetry degrades to None, never raises
+        log.debug("device introspection failed", exc_info=True)
+        return None
 
 
 def pytree_nbytes(tree: Any) -> int:
@@ -281,7 +311,6 @@ class DeviceMonitor:
         self.hbm_alert_fraction = float(hbm_alert_fraction)
         self.mfu_window_s = float(mfu_window_s)
         self._peak_override = float(peak_flops)
-        self._peak: float | None = None  # resolved lazily (jax import)
         self.census = census if census is not None else CENSUS
         self.census.warmup_s = float(warmup_s)
         hook_jax_monitoring()
@@ -424,28 +453,18 @@ class DeviceMonitor:
 
     # ---- live MFU -------------------------------------------------------
 
-    def peak_flops(self) -> float:
+    def peak_flops(self) -> float | None:
         """The roofline this node scores against: the configured override,
-        else the per-platform table (unknown platforms score like CPU)."""
+        else the ``DEVICE_PEAKS`` row of the local ``device_kind``. None
+        while jax is not loaded, when the backend cannot be read, or for a
+        kind the table does not list (every CPU)."""
         if self._peak_override > 0:
             return self._peak_override
-        if self._peak is None:
-            import sys
-
-            jax = sys.modules.get("jax")
-            if jax is None:
-                # jax not loaded yet: report the CPU roofline WITHOUT
-                # caching, so a TPU node resolves correctly once its
-                # engines import jax.
-                return PEAK_FLOPS["cpu"]
-            platform = "cpu"
-            try:
-                platform = jax.default_backend()
-            except Exception:  # noqa: BLE001
-                log.debug("jax.default_backend() failed; scoring as cpu",
-                          exc_info=True)
-            self._peak = PEAK_FLOPS.get(platform, PEAK_FLOPS["cpu"])
-        return self._peak
+        ident = device_identity()
+        if ident is None:
+            return None
+        row = DEVICE_PEAKS.get(ident["device_kind"])
+        return row["flops_bf16"] if row is not None else None
 
     def _item_flops(self, model: str) -> float | None:
         if model not in self._flops_per_item:
@@ -483,9 +502,9 @@ class DeviceMonitor:
 
     def mfu(self, model: str) -> float | None:
         """Model FLOP/s Utilization over the sliding window: achieved
-        FLOP/s during device execution divided by the platform roofline.
-        None until the model has reported work (or has no analytic
-        flops_per_item)."""
+        FLOP/s during device execution divided by the device roofline.
+        None until the model has reported work, for a model with no
+        analytic flops_per_item, or on a device with no roofline."""
         now = self.clock()
         with self._lock:
             window = self._work.get(model)
@@ -499,7 +518,7 @@ class DeviceMonitor:
         if seconds <= 0:
             return None
         peak = self.peak_flops()
-        if peak <= 0:
+        if peak is None:
             return None
         return (flops / seconds) / peak
 
@@ -531,8 +550,9 @@ __all__ = [
     "CENSUS",
     "CensusedJit",
     "CompileCensus",
+    "DEVICE_PEAKS",
     "DeviceMonitor",
-    "PEAK_FLOPS",
+    "device_identity",
     "hook_jax_monitoring",
     "pytree_nbytes",
 ]

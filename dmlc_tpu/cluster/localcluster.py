@@ -37,6 +37,13 @@ def make_synsets(path: Path, n: int) -> Path:
     return path
 
 
+#: ``backends=CONFIGURED`` asks every node to build the REAL backends its
+#: config names (ClusterNode._build: EngineBackend / LmBackend /
+#: ExportedBackend) instead of the harness's echo fake — the shape
+#: chip_smoke.py drives the serving path in.
+CONFIGURED = "configured"
+
+
 def echo_backend(synsets):
     """Fake model: predicts the class encoded in the synset id (always
     right against make_synsets truth)."""
@@ -59,8 +66,9 @@ def start_local_cluster(
     ``backends`` is {model: PredictFn} shared by every node, OR a callable
     ``node_index -> {model: PredictFn}`` for per-node instances (needed
     when a test must prove EVERY member's backend changed — a shared
-    object would mask a one-member regression); default is the echo
-    backend for the configured job models. With ``join`` the fleet is
+    object would mask a one-member regression), OR ``CONFIGURED`` for the
+    real backends each node's config names; default is the echo backend
+    for the configured job models. With ``join`` the fleet is
     joined, converged, and the first leader promoted before returning.
 
     Returns the node list; caller owns shutdown (``stop_local_cluster``).
@@ -123,9 +131,12 @@ def _start_all(tmp, n_nodes, base, candidates, synset_path, overrides,
         )
         fields.update(overrides)  # caller overrides win over harness defaults
         cfg = ClusterConfig(**fields)
-        node_backends = backends(i) if callable(backends) else backends
-        if node_backends is None:
-            node_backends = {name: echo_backend for name in cfg.job_models}
+        if backends is CONFIGURED:
+            node_backends = None  # ClusterNode builds them from cfg
+        else:
+            node_backends = backends(i) if callable(backends) else backends
+            if node_backends is None:
+                node_backends = {name: echo_backend for name in cfg.job_models}
         node = ClusterNode(cfg, backends=node_backends)
         node.start()
         nodes.append(node)

@@ -285,7 +285,10 @@ class ClusterNode:
         # compile census + HBM gauges + live MFU on the SAME registry the
         # obs scrape exports, so the leader learns about recompiles and
         # memory pressure the way it learns about queue depths. The
-        # persistent-compile-cache counters join the scrape too.
+        # persistent compile cache is switched on here (idempotent; the
+        # directory is JAX_COMPILATION_CACHE_DIR's to place) so every entry
+        # point that builds a node — cli.main, localcluster, chip_smoke.py
+        # — starts warm, and its counters join the scrape.
         self.devicemon = DeviceMonitor(
             self.registry,
             flight=self.flight,
@@ -297,6 +300,7 @@ class ClusterNode:
             hbm_alert_fraction=config.devicemon_hbm_alert_fraction,
             peak_flops=config.devicemon_peak_flops,
         )
+        compile_cache.enable()
         compile_cache.export_metrics(self.registry)
 
         # --- L1 membership over UDP gossip -----------------------------
@@ -870,20 +874,35 @@ class ClusterNode:
         mb = self.mesh_bootstrap
         return None if mb is None else mb.group()
 
+    def _device_identity(self) -> dict:
+        """``platform`` / ``device_kind`` / device count of the backend
+        this node computes on, for ``node.info`` and ``status`` — a node
+        that came up on the CPU backend must be tellable from one on a TPU
+        over the RPC surface. Like the chip count, never *imports* jax:
+        None until the engines have loaded it."""
+        from dmlc_tpu.cluster.devicemon import device_identity
+
+        return device_identity() or {
+            "platform": None, "device_kind": None, "count": None,
+        }
+
     def _node_info(self, p: dict) -> dict:
         """Member RPC: this host's chip capacity, for the leader's
-        ICI-local weighted placement. Autodetect never *imports* jax — it
-        reads the count only when the engines already loaded it."""
+        ICI-local weighted placement, plus what it computes and decodes
+        with. Autodetect never *imports* jax — it reads the devices only
+        when the engines already loaded it."""
+        from dmlc_tpu import native
+
+        device = self._device_identity()
         chips = self.config.chips_per_host
         if chips <= 0:
-            import sys
-
-            jax = sys.modules.get("jax")
-            try:
-                chips = jax.local_device_count() if jax is not None else 1
-            except Exception:
-                chips = 1
-        info: dict = {"chips": int(chips)}
+            chips = device["count"] or 1
+        info: dict = {
+            "chips": int(chips),
+            "platform": device["platform"],
+            "device_kind": device["device_kind"],
+            "decode_backend": "native" if native.available() else "pil",
+        }
         # Idle decode lanes right now — the decode tier's capacity signal
         # for callers that poll node.info instead of the obs scrape.
         info["decode_lane_idle"] = int(self.worker.decode_lane_idle())
@@ -971,8 +990,12 @@ class ClusterNode:
         heartbeat threads into a false FAILED verdict."""
         if self.config.eager_load:
             from dmlc_tpu import native
+            from dmlc_tpu.cluster.rpc import RpcError
+            from dmlc_tpu.models.weights import not_published
 
-            native.ensure_built()  # compile off the hot path, before serving
+            # Built on THIS host off the hot path, before serving; a host
+            # with no toolchain serves through PIL and node.info says so.
+            native.ensure_built()
             for backend in [
                 *self.worker.backends.values(),
                 *self._gen_backends.values(),
@@ -981,13 +1004,18 @@ class ClusterNode:
                     continue
                 try:
                     backend.warmup()
-                except Exception:
-                    # Best-effort: an ExportedBackend on a FRESH cluster has
-                    # nothing to fetch yet (the artifact is published by the
-                    # running cluster's `export` verb) — it must not kill
-                    # bootstrap. The backend stays lazy and builds on the
-                    # first shard instead.
-                    log.exception("eager warmup failed; backend will build lazily")
+                except RpcError as e:
+                    # The ONE tolerated failure: an ExportedBackend on a
+                    # FRESH cluster has nothing to fetch yet (the artifact
+                    # is published by the running cluster's `export` verb)
+                    # — it stays lazy and builds on the first shard. Any
+                    # other failure (a kernel the compiler refuses, an OOM,
+                    # a corrupt blob) must stop the node here, not surface
+                    # later as per-shard errors on a member that joined.
+                    if not (isinstance(backend, ExportedBackend)
+                            and not_published(e)):
+                        raise
+                    log.info("eager warmup deferred: %s", e)
         self._spawn(self._membership_loop)
         self._spawn(self._probe_loop)
         if self.config.devicemon_poll_interval_s > 0:
@@ -1522,6 +1550,9 @@ class ClusterNode:
             "breakers": self.retry_policy.snapshot(),
             "flight_recorded": self.flight.to_wire()["recorded"],
         }
+        device = self._device_identity()
+        out["platform"] = device["platform"]
+        out["device_kind"] = device["device_kind"]
         if self.tenant_specs:
             out["tenants"] = {
                 name: {"priority": spec.priority, "share": spec.share}

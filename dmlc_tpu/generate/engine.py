@@ -346,6 +346,27 @@ class GenerationEngine:
 
     # ---- slot lifecycle (decode-thread only) -----------------------------
 
+    def warmup(self) -> None:
+        """Compile both programs NOW by running a one-token prompt through
+        prefill and one decode step in slot 0, then retiring it — so a
+        kernel the compiler refuses (or an OOM) fails here, at node start,
+        not inside the first request. Call before the engine serves. Leaves
+        no trace: slot, pages and counters are as before (the K/V it wrote
+        sits in freed pages, which the next prefill overwrites), and the
+        compile-inflated step is kept out of the MFU window."""
+        if self.active.any():
+            raise RuntimeError("warmup on an engine that is already serving")
+        counters = (self.steps, self.tokens_out, self._joins)
+        device_work, self.device_work = self.device_work, None
+        try:
+            self.join(0, [0])
+            self.ensure_capacity(0)
+            self.step()
+            self.release(0)
+        finally:
+            self.device_work = device_work
+        self.steps, self.tokens_out, self._joins = counters
+
     def free_slots(self) -> list[int]:
         return [s for s in range(self.max_slots) if not self.active[s]]
 

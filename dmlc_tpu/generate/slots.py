@@ -653,6 +653,10 @@ class SlotScheduler:
             "tok_s": round(self.tok_s(), 2),
             "slots_active": self.engine.slots_active,
             "pages_free": self.engine.pages_free,
+            # Which page gather serves: the Pallas kernel (compiled on a
+            # TPU) or the XLA take — on node.status so a caller can tell,
+            # and chip_smoke.py can refuse, the fallback.
+            "use_pallas": self.engine.use_pallas,
             "max_active": self.max_active,
             "page_budget": self.page_budget,
             **({"tenants": tenants} if tenants else {}),

@@ -54,9 +54,9 @@ log = logging.getLogger(__name__)
 
 
 class GenerationBackend:
-    """One servable LM: engine + slot scheduler, built lazily like
-    EngineBackend (JAX import + compile are heavy; nodes that never see a
-    generate request shouldn't pay)."""
+    """One servable LM: engine + slot scheduler, built AND compiled lazily
+    like EngineBackend (JAX import + compile are heavy; nodes that never
+    see a generate request shouldn't pay)."""
 
     def __init__(
         self,
@@ -118,6 +118,9 @@ class GenerationBackend:
                     use_pallas=self.use_pallas,
                     device_work=self.device_work,
                 )
+                # Before the decode thread exists: the engine's mutators
+                # are single-writer.
+                engine.warmup()
                 self._scheduler = SlotScheduler(
                     engine,
                     max_waiting=self.max_waiting,

@@ -3,7 +3,7 @@
 The registry's image models map name -> flax module + input geometry; this
 module does the same for causal LMs built on ``parallel.sp_transformer.
 SPTransformerLM`` — the architecture the lm_flash_train bench leg already
-trains at 130k tok/s (BENCH_r05). Registering here makes an LM a first-class
+trains. Registering here makes an LM a first-class
 registry citizen: the generation worker builds it by name, weights publish/
 hot-swap through the existing SDFS blob path (``models/<name>``), and
 ``weights.variables_template`` validates blobs against the same abstract

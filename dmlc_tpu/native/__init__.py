@@ -3,14 +3,21 @@
 ``decode_resize_batch`` is the high-throughput replacement for the PIL path
 in ops/preprocess.py — libjpeg DCT-domain downscaling + thread-pooled
 triangle resampling (PIL BILINEAR semantics), one call per shard. The
-library builds from native/ via make; when it is absent the callers fall
-back to PIL transparently, so nothing in the framework hard-requires the
-toolchain at runtime.
+library builds from native/ via make; on a host with no toolchain the
+callers fall back to PIL, and ``available()`` (surfaced as ``node.info``'s
+``decode_backend``) says which path is serving.
+
+The Makefile compiles with ``-march=native`` and the working tree travels
+between machines with its ignored files, so a library is only ever loaded
+when its sidecar stamp says it was built on THIS host's CPU from THESE
+sources (``_stamp``). File mtimes survive a copy and prove neither.
 """
 
 from __future__ import annotations
 
 import ctypes
+import functools
+import hashlib
 import logging
 import subprocess
 from pathlib import Path
@@ -20,6 +27,7 @@ import numpy as np
 log = logging.getLogger(__name__)
 
 _LIB_PATH = Path(__file__).parent / "libdmlc_native.so"
+_STAMP_PATH = _LIB_PATH.with_name(_LIB_PATH.name + ".stamp")
 _SRC_DIR = Path(__file__).parent.parent.parent / "native"
 # v2: persistent decode pool (dmlc_pool_size/dmlc_pool_shutdown) replacing
 # the spawn-and-join-per-call threading of v1.
@@ -37,7 +45,7 @@ def _load():
     global _lib, _load_failed
     if _lib is not None or _load_failed:
         return _lib
-    if not _LIB_PATH.exists():
+    if _stale():
         return None
     try:
         lib = ctypes.CDLL(str(_LIB_PATH))
@@ -64,36 +72,55 @@ def _load():
     return _lib
 
 
-def build() -> None:
-    """Compile the library (g++ via make). Raises on failure."""
-    global _lib, _load_failed
-    subprocess.run(
-        ["make", "-s"], cwd=_SRC_DIR, check=True, capture_output=True, text=True
-    )
-    _lib, _load_failed = None, False  # rebind on next use
+@functools.lru_cache(maxsize=None)
+def _stamp() -> str:
+    """What a library built here, now, is stamped with: a digest of the
+    sources that go into it and of this host's CPU code-generation surface
+    (the Makefile's ``-march=native`` target). Computed once per process:
+    ``_load`` asks on the serving path while PIL is serving."""
+    from dmlc_tpu.utils.compile_cache import machine_fingerprint
+
+    h = hashlib.sha256(machine_fingerprint().encode())
+    for src in (_SRC_DIR / "image_pipeline.cpp", _SRC_DIR / "Makefile"):
+        h.update(src.read_bytes())
+    return h.hexdigest()
 
 
 def _stale() -> bool:
-    """Is the .so missing or older than any native source? Checked in
-    Python so a prebuilt library on a toolchain-less host never spawns
-    make (and fresh libraries are never needlessly re-linked under a
+    """Is the .so missing, built from other sources, or built for another
+    CPU? A prebuilt library whose stamp matches never spawns make (and
+    fresh libraries are never needlessly re-linked under a
     concurrently-starting fleet)."""
-    if not _LIB_PATH.exists():
+    try:
+        return not _LIB_PATH.exists() or _STAMP_PATH.read_text() != _stamp()
+    except OSError:
         return True
-    so_mtime = _LIB_PATH.stat().st_mtime
-    sources = list(_SRC_DIR.glob("*.cpp")) + [_SRC_DIR / "Makefile"]
-    return any(s.exists() and s.stat().st_mtime > so_mtime for s in sources)
+
+
+def build() -> None:
+    """Compile the library (g++ via make) and stamp it. Raises on failure.
+    ``-B``: make's own mtime comparison is exactly what a copied tree
+    fools."""
+    global _lib, _load_failed
+    _STAMP_PATH.unlink(missing_ok=True)
+    subprocess.run(
+        ["make", "-s", "-B"], cwd=_SRC_DIR, check=True, capture_output=True, text=True
+    )
+    _STAMP_PATH.write_text(_stamp())
+    _lib, _load_failed = None, False  # rebind on next use
 
 
 def ensure_built() -> bool:
-    """Build if missing or source-stale (best effort) and report
-    availability. Call at node startup / bench setup — never from the
-    per-shard path."""
+    """Build on this host if the library is missing or stale and report
+    availability. A failed build (no toolchain) leaves PIL serving — loudly;
+    callers that require native check the return value. Call at node
+    startup / bench setup — never from the per-shard path."""
     if not _load_failed and _stale():
         try:
             build()
-        except Exception as e:
-            log.warning("native build failed (%s); PIL fallback stays active", e)
+        except (OSError, subprocess.CalledProcessError) as e:
+            detail = getattr(e, "stderr", "") or e
+            log.warning("native build failed (%s); decode serves through PIL", detail)
     return available()
 
 

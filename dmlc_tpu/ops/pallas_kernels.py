@@ -12,7 +12,8 @@ Layout notes (per /opt/skills/guides/pallas_guide.md): images are viewed as
 [rows, W*C] 2-D blocks so the lane dimension is dense; normalization is
 expressed as one fused multiply-add ``u8 * scale + bias`` with per-column
 vectors precomputed on the host (scale = 1/(255*std), bias = -mean/std).
-Off-TPU the kernels run in interpreter mode so tests stay hermetic.
+Off-TPU the kernels run in interpreter mode so tests stay hermetic;
+``interpret_mode()`` says which, so a measurement path can refuse it.
 """
 
 from __future__ import annotations
@@ -26,20 +27,20 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _interpret() -> bool:
+def interpret_mode() -> bool:
+    """True when this repo's ``pallas_call`` sites run in the Pallas
+    interpreter instead of compiling through Mosaic: whenever the default
+    backend is not a TPU (the hermetic CPU tests). Public because an
+    interpreted kernel still "passes" — a path that claims to have run on
+    the chip (chip_smoke.py) must check this is False."""
     return jax.default_backend() != "tpu"
 
 
 def _sds(shape, dtype, like):
-    """ShapeDtypeStruct inheriting ``like``'s varying-manual-axes set on
-    jax versions that track one (jax.typeof, >= 0.7); the plain struct on
-    older jax, whose ShapeDtypeStruct has no vma parameter."""
-    typeof = getattr(jax, "typeof", None)
-    if typeof is None:
-        return jax.ShapeDtypeStruct(shape, dtype)
-    return jax.ShapeDtypeStruct(
-        shape, dtype, vma=getattr(typeof(like), "vma", frozenset())
-    )
+    """ShapeDtypeStruct inheriting ``like``'s varying-manual-axes set, so a
+    kernel called under shard_map declares which mesh axes its output
+    varies over."""
+    return jax.ShapeDtypeStruct(shape, dtype, vma=jax.typeof(like).vma)
 
 
 # ---------------------------------------------------------------------------
@@ -68,7 +69,7 @@ def _normalize_call(u8_2d, scale_row, bias_row, out_dtype):
             pl.BlockSpec((1, cols), lambda i: (0, 0), memory_space=pltpu.VMEM),
         ],
         out_specs=pl.BlockSpec((block_rows, cols), lambda i: (i, 0), memory_space=pltpu.VMEM),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(u8_2d, scale_row, bias_row)
 
 
@@ -509,7 +510,7 @@ def _flash_forward(causal, scale, blk_q, blk_k, q, k, v):
                 pl.BlockSpec((1, blk_q, dh), lambda bh, iq: (bh, iq, 0), memory_space=pltpu.VMEM),
                 pl.BlockSpec((1, blk_q, 1), lambda bh, iq: (bh, iq, 0), memory_space=pltpu.VMEM),
             ),
-            interpret=_interpret(),
+            interpret=interpret_mode(),
         )(q3, k3, v3)
     else:
         out, lse = pl.pallas_call(
@@ -530,7 +531,7 @@ def _flash_forward(causal, scale, blk_q, blk_k, q, k, v):
                 pltpu.VMEM((blk_q, 1), jnp.float32),
                 pltpu.VMEM((blk_q, dh), jnp.float32),
             ],
-            interpret=_interpret(),
+            interpret=interpret_mode(),
         )(q3, k3, v3)
     return out.reshape(b, h, s, dh), lse
 
@@ -563,7 +564,7 @@ def _flash_backward(causal, scale, q, k, v, out, lse, do, delta=None):
         in_specs=[qspec, kspec, kspec, qspec, rowspec, rowspec],
         out_specs=qspec,
         scratch_shapes=[pltpu.VMEM((blk_q, dh), jnp.float32)],
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(q3, k3, v3, do3, lse, delta)
 
     # dK/dV grid: (bh, k-block, q-block) — q innermost so the scratch
@@ -584,7 +585,7 @@ def _flash_backward(causal, scale, q, k, v, out, lse, do, delta=None):
             pltpu.VMEM((blk_k, dh), jnp.float32),
             pltpu.VMEM((blk_k, dh), jnp.float32),
         ],
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(q3, k3, v3, do3, lse, delta)
     shape = (b, h, s, dh)
     return dq.reshape(shape), dk.reshape(shape), dv.reshape(shape)
@@ -610,7 +611,7 @@ def softmax_top1(logits):
             pl.BlockSpec((block_b, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
             pl.BlockSpec((block_b, 1), lambda i: (i, 0), memory_space=pltpu.VMEM),
         ),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(logits)
     return idx[:, 0], prob[:, 0]
 

@@ -33,7 +33,7 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-from dmlc_tpu.ops.pallas_kernels import _interpret
+from dmlc_tpu.ops.pallas_kernels import interpret_mode
 
 
 def _gather_pages_pallas(pages, flat_table):
@@ -62,7 +62,7 @@ def _gather_pages_pallas(pages, flat_table):
         copy_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_out, page_size, width), pages.dtype),
-        interpret=_interpret(),
+        interpret=interpret_mode(),
     )(flat_table, pages)
 
 

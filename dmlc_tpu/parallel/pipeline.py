@@ -22,8 +22,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-
-from dmlc_tpu.parallel.compat import axis_size, shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
@@ -43,7 +41,7 @@ def _pipeline_local(params, x, *, stage_fn, axis_name: str, n_micro: int):
     Returns [n_micro, mb, ...] outputs, valid on every device after the
     final broadcast (all devices return the last stage's results).
     """
-    n_stages = axis_size(axis_name)
+    n_stages = lax.axis_size(axis_name)
     stage = lax.axis_index(axis_name)
     params = jax.tree_util.tree_map(lambda a: a[0], params)  # drop stage axis
     mb_shape = x.shape[1:]
@@ -122,7 +120,7 @@ def pipeline_apply(
     fn = partial(
         _pipeline_local, stage_fn=stage_fn, axis_name=axis_name, n_micro=n_micro
     )
-    out = shard_map(
+    out = jax.shard_map(
         fn,
         mesh=mesh,
         in_specs=(param_specs, data_spec),

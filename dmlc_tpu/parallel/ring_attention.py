@@ -19,15 +19,13 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-
-from dmlc_tpu.parallel.compat import axis_size, shard_map
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
 
 def _ring_attention_local(q, k, v, *, axis_name: str, causal: bool, scale: float):
     """Per-device body. q/k/v: [B, H, S_local, Dh] (this device's sequence block)."""
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     s_local = q.shape[2]
     q32 = q.astype(jnp.float32) * scale
@@ -76,7 +74,7 @@ def ring_attention(
         scale = q.shape[-1] ** -0.5
     spec = P(None, None, axis_name, None)
     fn = partial(_ring_attention_local, axis_name=axis_name, causal=causal, scale=scale)
-    return shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+    return jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -113,7 +111,7 @@ def _merge_blocks(o32, lse, o_blk, lse_blk):
 def _ring_flash_fwd_impl(axis_name, causal, scale, q, k, v):
     from dmlc_tpu.ops.pallas_kernels import flash_attention_with_lse
 
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     q32 = q.astype(jnp.float32)
 
@@ -160,7 +158,7 @@ def _ring_flash_vjp_bwd(axis_name, causal, scale, res, do):
     from dmlc_tpu.ops.pallas_kernels import flash_attention_block_bwd
 
     q, k, v, out, lse = res
-    n = axis_size(axis_name)
+    n = lax.axis_size(axis_name)
     my_idx = lax.axis_index(axis_name)
     # Step-invariant softmax-jacobian row term, hoisted out of the ring:
     # each per-step block backward would otherwise recompute this full
@@ -228,7 +226,7 @@ def ring_flash_attention(
     # yet propagate varying-manual-axes through its internal dynamic_slice
     # index operands; on TPU the kernels lower natively and the flag only
     # skips the static check (jax-ml/jax suggested workaround).
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
     )(q, k, v)
 

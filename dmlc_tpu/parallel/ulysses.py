@@ -36,8 +36,6 @@ import jax
 from jax import lax
 from jax.sharding import Mesh, PartitionSpec as P
 
-from dmlc_tpu.parallel.compat import shard_map
-
 from dmlc_tpu.parallel.ring_attention import dense_attention
 
 
@@ -89,20 +87,21 @@ def ulysses_attention(
     if scale is None:
         scale = q.shape[-1] ** -0.5
     local_attn = None
+    check = True
     if use_flash:
-        from dmlc_tpu.ops.pallas_kernels import flash_attention
+        from dmlc_tpu.ops.pallas_kernels import flash_attention, interpret_mode
 
         local_attn = flash_attention
+        # check_vma off ONLY for the flash variant in INTERPRET mode
+        # (off-TPU): interpret-mode pallas_call's discharge mixes varying
+        # and unvarying operands inside dynamic_slice, which the vma checker
+        # rejects (jax suggests exactly this workaround). Compiled TPU runs
+        # and the dense variant keep full checking.
+        check = not interpret_mode()
     spec = P(None, None, axis_name, None)
     fn = partial(
         _ulysses_local, axis_name=axis_name, causal=causal, scale=scale, local_attn=local_attn
     )
-    # check_vma off ONLY for the flash variant in INTERPRET mode (off-TPU):
-    # interpret-mode pallas_call's discharge mixes varying and unvarying
-    # operands inside dynamic_slice, which the vma checker rejects (jax
-    # suggests exactly this workaround). Compiled TPU runs and the dense
-    # variant keep full checking.
-    check = not (use_flash and jax.default_backend() != "tpu")
-    return shard_map(
+    return jax.shard_map(
         fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=check
     )(q, k, v)

@@ -695,7 +695,13 @@ class LmBackend:
         from dmlc_tpu.parallel.mesh import make_mesh
 
         devs = self._resolve_devices()
-        width = max(1, min(width, len(devs)))
+        if not 1 <= width <= len(devs):
+            # Never a clamp: a gang of 4 that quietly serves at width 1 on
+            # a one-chip host reports a 4-chip number from one chip.
+            raise RpcError(
+                f"model {self.model_name!r} asked for a gang of {width} "
+                f"devices; this host has {len(devs)}"
+            )
         prog = self._programs.get(width)
         if prog is None:
             spec = get_model(self.model_name)
@@ -751,7 +757,11 @@ class LmBackend:
         keep — each rank's slice is an independent device execution over
         chip-sharded weights — so an empty slice just answers []."""
         with self._lock:
-            prog = self._program(self.gang_devices or world)
+            # Following the scheduler's world size means "as wide as the
+            # gang, on the chips this host has" — computed here, so
+            # _program only ever sees a width it can honor or refuse.
+            width = self.gang_devices or min(world, len(self._resolve_devices()))
+            prog = self._program(width)
             start, stop = gang_slice(len(synsets), rank, world)
             mine = list(synsets[start:stop])
             if not mine:

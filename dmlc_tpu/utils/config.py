@@ -256,8 +256,8 @@ class ClusterConfig:
     # hbm_high_watermark flight event fires when bytes_in_use crosses this
     # fraction of bytes_limit (re-arms below 0.9x the line).
     devicemon_hbm_alert_fraction: float = 0.9
-    # Per-chip peak FLOP/s override for MFU (0 = the per-platform table in
-    # devicemon.PEAK_FLOPS: v5e bf16 for tpu, nominal 1 TF for cpu).
+    # Per-chip peak FLOP/s override for MFU (0 = the device_kind-keyed
+    # table devicemon.DEVICE_PEAKS; a kind it does not list scores None).
     devicemon_peak_flops: float = 0.0
 
     # --- dynamic request micro-batching (scheduler/worker.DynamicBatcher) ---

@@ -15,8 +15,8 @@ from dmlc_tpu.cluster.devicemon import (
     CENSUS,
     CensusedJit,
     CompileCensus,
+    DEVICE_PEAKS,
     DeviceMonitor,
-    PEAK_FLOPS,
     pytree_nbytes,
 )
 from dmlc_tpu.cluster.flight import FlightRecorder
@@ -164,9 +164,12 @@ class TestGracefulCpu:
                 assert key in gauges
                 # ... and None: the CPU PJRT client has no memory_stats.
                 assert gauges[key] is None
-            # The census/roofline gauges still read real numbers.
+            # The census gauges still read real numbers ...
             assert gauges["jit_compiles"] == 0.0
-            assert gauges["device_peak_flops"] == PEAK_FLOPS["cpu"]
+            # ... but a CPU is not in the device_kind-keyed peak table, so
+            # it has no roofline: None like the hbm gauges, never a default.
+            assert "cpu" not in DEVICE_PEAKS
+            assert gauges["device_peak_flops"] is None
         finally:
             mon.close()
 
@@ -207,7 +210,7 @@ class TestGracefulCpu:
         try:
             summary = mon.summary()
             assert summary["hbm"]["bytes_in_use"] is None
-            assert summary["platform_peak_flops"] > 0
+            assert summary["platform_peak_flops"] is None
         finally:
             mon.close()
 
@@ -261,6 +264,28 @@ class TestMfuWindow:
             assert mon.mfu("fake") == pytest.approx(0.5)
             mon.device_work("fake", 5, 1.0)  # same rate: ratio unchanged
             assert mon.mfu("fake") == pytest.approx(0.5)
+        finally:
+            mon.close()
+
+    def test_no_roofline_without_a_table_row(self, monkeypatch):
+        """The peak table is keyed by device_kind with no default row: on
+        the CPU mesh the same work scores None, on a listed kind it scores
+        against that row, and the override still wins."""
+        from dmlc_tpu.cluster import devicemon
+
+        clock = VClock()
+        mon = DeviceMonitor(None, clock=clock, census=CompileCensus(clock))
+        mon._flops_per_item["fake"] = 10.0
+        try:
+            mon.device_work("fake", 5, 1.0)
+            assert mon.peak_flops() is None
+            assert mon.mfu("fake") is None
+            monkeypatch.setitem(
+                devicemon.DEVICE_PEAKS, "cpu",
+                {"flops_bf16": 200.0, "hbm_bytes_per_s": 1.0},
+            )
+            assert mon.peak_flops() == 200.0
+            assert mon.mfu("fake") == pytest.approx(0.25)
         finally:
             mon.close()
 
@@ -346,7 +371,7 @@ class TestCompileCacheCounters:
         from dmlc_tpu.utils import compile_cache as cc
 
         monkeypatch.setattr(cc, "_COUNTS", {"hits": 0, "misses": 0, "requests": 0})
-        monkeypatch.setattr(cc, "_CACHE_ROOT", tmp_path)
+        monkeypatch.setattr(cc, "cache_dir", lambda: str(tmp_path))
         monkeypatch.setattr(cc, "_BASELINE_ENTRIES", baseline)
         return cc
 
@@ -386,6 +411,74 @@ class TestCompileCacheCounters:
         assert gauges["jax_cache_misses"] == 0.0
         assert gauges["jax_cache_writes"] == 1.0
         assert gauges["jax_cache_entries"] == 1.0
+
+
+class TestCompileCachePlacement:
+    """The cache directory is the environment's to place: with
+    JAX_COMPILATION_CACHE_DIR set, enable() touches nothing and jax writes
+    there; unset, it is <repo>/.jax_cache[/cpu-<fingerprint>]."""
+
+    SCRIPT = (
+        "import jax, jax.numpy as jnp\n"
+        "from dmlc_tpu.utils import compile_cache as cc\n"
+        "cc.enable()\n"
+        "jax.config.update('jax_persistent_cache_min_compile_time_secs', 0)\n"
+        "jax.jit(lambda x: x * 2 + 1)(jnp.ones((4,))).block_until_ready()\n"
+        "print(jax.config.jax_compilation_cache_dir)\n"
+        "print(cc.cache_dir())\n"
+        "print(cc.counters()['writes'])\n"
+    )
+
+    def _run(self, env_overrides):
+        import os
+        import subprocess
+        import sys
+
+        env = {k: v for k, v in os.environ.items()
+               if k != "JAX_COMPILATION_CACHE_DIR"}
+        env.update(JAX_PLATFORMS="cpu", **env_overrides)
+        repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        env["PYTHONPATH"] = repo + os.pathsep + env.get("PYTHONPATH", "")
+        proc = subprocess.run(
+            [sys.executable, "-c", self.SCRIPT], env=env, cwd=repo,
+            capture_output=True, text=True, timeout=120, check=True,
+        )
+        return proc.stdout.strip().splitlines()[-3:]
+
+    def test_environment_variable_places_the_cache(self, tmp_path):
+        where = tmp_path / "placed"
+        configured, reported, writes = self._run(
+            {"JAX_COMPILATION_CACHE_DIR": str(where)}
+        )
+        assert configured == reported == str(where)  # nothing appended
+        assert int(writes) >= 1
+        assert any(where.iterdir())
+
+    def test_default_is_the_repo_cache_scoped_per_cpu(self):
+        from dmlc_tpu.utils import compile_cache as cc
+
+        configured, reported, _ = self._run({})
+        want = cc._REPO_ROOT / ".jax_cache" / f"cpu-{cc.machine_fingerprint()}"
+        assert configured == reported == str(want)
+
+    def test_cluster_node_build_enables_it(self, monkeypatch, tmp_path):
+        from dmlc_tpu.cluster.node import ClusterNode
+        from dmlc_tpu.utils import compile_cache as cc
+        from dmlc_tpu.utils.config import ClusterConfig
+
+        calls = []
+        monkeypatch.setattr(cc, "enable", lambda: calls.append(1))
+        node = ClusterNode(
+            ClusterConfig(
+                host="127.0.0.1", gossip_port=0, leader_port=0, member_port=0,
+                storage_dir=str(tmp_path / "storage"), job_models=[],
+            ),
+            backends={},
+        )
+        try:
+            assert calls == [1]
+        finally:
+            node.stop()
 
 
 class TestFleetScrape:

@@ -3,8 +3,6 @@ dp inference sharding, dp x tp train step, ring attention parity."""
 
 import jax
 import jax.numpy as jnp
-
-from dmlc_tpu.parallel.compat import shard_map
 import numpy as np
 import pytest
 from jax.sharding import PartitionSpec as P
@@ -228,7 +226,7 @@ def _sp_times_dp_check(local_fn, seed, h):
     ref = dense_attention(q, k, v)
     spec = P("dp", None, "sp", None)
     fn = partial(local_fn, axis_name="sp", causal=False, scale=q.shape[-1] ** -0.5)
-    got = shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
+    got = jax.shard_map(fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec)(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=1e-4)
 
 
@@ -360,7 +358,7 @@ class TestRingFlashAttention:
         ref = dense_attention(q, k, v)
         spec = P("dp", None, "sp", None)
         fn = _partial(_ring_flash, "sp", False, q.shape[-1] ** -0.5)
-        got = shard_map(
+        got = jax.shard_map(
             fn, mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec, check_vma=False
         )(q, k, v)
         np.testing.assert_allclose(np.asarray(got), np.asarray(ref), atol=2e-5, rtol=1e-4)

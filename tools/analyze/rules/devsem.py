@@ -12,7 +12,7 @@ the space BETWEEN the jit construction and its call sites:
   is a runtime error on hardware and silent garbage on some backends.
 - **A6** one jitted program, many call-site signatures: every distinct
   abstract signature is a separate XLA compilation (the 22 s first-hit
-  problem, BENCH_r02). The rule takes a census of per-call-site signature
+  problem). The rule takes a census of per-call-site signature
   descriptors and flags programs whose family is unbounded (shape derived
   from a loop variable or ``len(arg)``) or larger than K, plus unhashable
   static arguments and traced parameters that drive Python control flow.
