@@ -24,18 +24,18 @@ arguments through. The pieces (docs/OBSERVABILITY.md):
   the wire — so a whole request tree is either kept or dropped together
   and the merged fleet timeline never shows half a request.
 
-IDs come from ``os.urandom`` (not the process-global ``random`` state, so
-sans-IO determinism of the simulator is untouched — trace ids are labels,
-never control flow).
+IDs are one 64-bit ``os.urandom`` draw per process plus a counter (never
+the process-global ``random`` state, so sans-IO determinism of the
+simulator is untouched — trace ids are labels, never control flow): one
+system call per process, not one or two per span.
 """
 
 from __future__ import annotations
 
 import contextvars
+import itertools
 import os
-from contextlib import contextmanager
 from dataclasses import dataclass
-from typing import Iterator
 
 
 @dataclass(frozen=True)
@@ -50,9 +50,27 @@ class TraceContext:
     sampled: bool = True
 
 
+_MASK64 = (1 << 64) - 1
+# Odd, so k -> k * _STRIDE is a bijection on 64 bits: ids of one process
+# never repeat, and two processes' runs only meet if their random bases do.
+_STRIDE = 0x9E3779B97F4A7C15
+_id_base = 0
+_id_counter = itertools.count(1)  # next() is atomic under the interpreter lock
+
+
+def _reseed_ids() -> None:
+    global _id_base
+    _id_base = int.from_bytes(os.urandom(8), "big")
+
+
+_reseed_ids()
+os.register_at_fork(after_in_child=_reseed_ids)  # a forked child must not replay its parent's ids
+
+
 def new_id() -> str:
-    """A 64-bit random hex id (8 bytes — the Perfetto/W3C span-id width)."""
-    return os.urandom(8).hex()
+    """A 64-bit hex id (16 characters — the Perfetto/W3C span-id width),
+    unique in this process: the process's random base plus a strided counter."""
+    return "%016x" % ((_id_base + next(_id_counter) * _STRIDE) & _MASK64)
 
 
 _current: contextvars.ContextVar[TraceContext | None] = contextvars.ContextVar(
@@ -65,18 +83,34 @@ def current() -> TraceContext | None:
     return _current.get()
 
 
-@contextmanager
-def bind(ctx: TraceContext | None) -> Iterator[TraceContext | None]:
+def enter(ctx: TraceContext | None) -> contextvars.Token:
+    """Make ``ctx`` ambient until ``leave(token)``: ``bind`` without the
+    block, for a caller that is itself a context manager (``Tracer.span``)."""
+    return _current.set(ctx)
+
+
+def leave(token: contextvars.Token) -> None:
+    _current.reset(token)
+
+
+class bind:
     """Make ``ctx`` ambient for the dynamic extent of the block. Binding
     ``None`` *clears* any inherited context — the RPC server does exactly
     that for frames that carried no ``t`` field, so the sim fabric (which
     dispatches on the caller's stack) has the same propagation semantics as
     the TCP fabric (which crosses a process boundary)."""
-    token = _current.set(ctx)
-    try:
-        yield ctx
-    finally:
-        _current.reset(token)
+
+    __slots__ = ("_ctx", "_token")
+
+    def __init__(self, ctx: TraceContext | None) -> None:
+        self._ctx = ctx
+
+    def __enter__(self) -> TraceContext | None:
+        self._token = enter(self._ctx)
+        return self._ctx
+
+    def __exit__(self, *exc: object) -> None:
+        leave(self._token)
 
 
 def child(parent: TraceContext | None = None, sampled: bool | None = None) -> TraceContext:

@@ -47,6 +47,7 @@ from typing import Any
 import numpy as np
 
 from dmlc_tpu.generate.kvcache import SCRATCH_PAGE, PagedKVCache
+from dmlc_tpu.utils.tracing import tracer
 
 
 # ---------------------------------------------------------------------------
@@ -413,7 +414,10 @@ class GenerationEngine:
             jnp.float32(temperature),
         )
         self._set_state(k_state, v_state)
-        first = int(nxt)
+        # The one call of join that blocks on the device; what is left of
+        # the caller's gen/prefill span is the host's part.
+        with tracer.span("gen/prefill_sync", cpu=True):
+            first = int(nxt)
         self.lengths[slot] = prompt.size
         self.active[slot] = True
         self.temps[slot] = float(temperature)
@@ -459,11 +463,16 @@ class GenerationEngine:
         )
         if self.return_logits:
             k_state, v_state, nxt, logits = out
-            self.last_logits = np.asarray(logits)
         else:
             k_state, v_state, nxt = out
         self._set_state(k_state, v_state)
-        tokens = np.asarray(nxt)
+        # The one place step blocks on the device; what is left of the
+        # caller's gen/step span is the host's part (uploads, dispatch,
+        # bookkeeping).
+        with tracer.span("gen/step_sync", cpu=True):
+            if self.return_logits:
+                self.last_logits = np.asarray(logits)
+            tokens = np.asarray(nxt)
         n_active = int(self.active.sum())
         self.lengths[self.active] += 1
         self.last_tokens[self.active] = tokens[self.active]

@@ -34,7 +34,13 @@ Tracing: every decode step runs under a ``gen/step`` span bound to the
 OLDEST resident slot's submit-time trace context, so a request's timeline
 shows the steps that produced its tokens parented under its
 ``rpc/job.generate`` span (trace smoke asserts this); ``gen/prefill`` spans
-bind the joining request's own context.
+bind the joining request's own context, and so does its ``gen/wait`` (submit
+to the start of the prefill). The decode thread feeds the device, so its time
+is TILED by leaf spans (docs/OBSERVABILITY.md §1): ``gen/idle`` (waiting for
+work), ``gen/admit`` (admission bookkeeping either side of a prefill),
+``gen/prefill``, ``gen/retire`` (the resident sweep, page growth),
+``gen/step``, ``gen/deliver`` (token pushes, exits) — an idle gap of the chip
+always has an owner on this thread.
 """
 
 from __future__ import annotations
@@ -178,7 +184,7 @@ class _Slot:
     __slots__ = (
         "stream", "prompt", "max_new_tokens", "temperature", "eos_id",
         "deadline", "trace_ctx", "pages", "emitted", "slot", "submitted_t",
-        "tenant", "seed",
+        "tenant", "seed", "wait_t0",
     )
 
     def __init__(self, stream: GenStream, prompt: list[int],
@@ -199,6 +205,9 @@ class _Slot:
         self.submitted_t = submitted_t
         self.tenant = tenant
         self.seed = seed
+        # Submit instant on the TRACER's clock (gen/wait is a span, so it
+        # lives on the timebase spans live on, not the injectable clock).
+        self.wait_t0 = tracer.now() if tracer.enabled else None
 
 
 class SlotScheduler:
@@ -415,8 +424,10 @@ class SlotScheduler:
     def _loop_body(self) -> None:
         while True:
             with self._cv:
-                while not self._pending and not self._resident and not self._closed:
-                    self._cv.wait()
+                if not self._pending and not self._resident and not self._closed:
+                    with tracer.span("gen/idle", cpu=True):
+                        while not self._pending and not self._resident and not self._closed:
+                            self._cv.wait()
                 if self._closed:
                     drained = self._pending
                     self._pending = []
@@ -456,30 +467,15 @@ class SlotScheduler:
         request invisible to that count during its prefill would let a
         third request slip past a full slot table."""
         while True:
-            free = self.engine.free_slots()
-            with self._cv:
-                if not self._pending or not free:
-                    return
-                req = self._pending[0]
-            if req.deadline is not None and req.deadline.expired():
-                # Expired while waiting: a prefill now would be dead work.
-                self._unpend(req)
-                self.engine.release_reservation(req.pages)
-                self._ledger_release(req)
-                req.stream.finish("deadline: expired before a slot freed")
-                continue
-            if req.stream.cancelled:
-                # Cancelled while waiting (router migrated it away, or the
-                # client gave up): a prefill now would be dead work.
-                self._unpend(req)
-                self.engine.release_reservation(req.pages)
-                self._ledger_release(req)
-                req.stream.finish("cancelled: before a slot freed")
-                continue
-            req.slot = free[0]
+            with tracer.span("gen/admit", cpu=True):
+                req = self._next_admissible()
+            if req is None:
+                return
             try:
                 with tracectx.bind(req.trace_ctx):
-                    with tracer.span("gen/prefill", slot=req.slot,
+                    if req.wait_t0 is not None:
+                        tracer.record("gen/wait", max(0.0, tracer.now() - req.wait_t0))
+                    with tracer.span("gen/prefill", cpu=True, slot=req.slot,
                                      prompt=len(req.prompt)):
                         first = self.engine.join(
                             req.slot, req.prompt,
@@ -492,31 +488,63 @@ class SlotScheduler:
                 # are: bound to the slot (join got past bind) or still the
                 # submit-time reservation.
                 log.exception("prefill failed for %s", req.stream.request_id)
-                self._unpend(req)
-                if (self.engine.cache_mode == "paged"
-                        and not self.engine.cache.slot_pages(req.slot)):
-                    self.engine.release_reservation(req.pages)
-                self.engine.release(req.slot)
-                self._ledger_release(req)
-                req.stream.finish(f"{type(e).__name__}: {e}")
+                with tracer.span("gen/admit", cpu=True):
+                    self._unpend(req)
+                    if (self.engine.cache_mode == "paged"
+                            and not self.engine.cache.slot_pages(req.slot)):
+                        self.engine.release_reservation(req.pages)
+                    self.engine.release(req.slot)
+                    self._ledger_release(req)
+                    req.stream.finish(f"{type(e).__name__}: {e}")
                 continue
-            req.pages = []  # ownership moved to the cache's slot binding
+            with tracer.span("gen/admit", cpu=True):
+                self._seat(req)
+            with tracer.span("gen/deliver", cpu=True):
+                self._deliver(req, first)
+                if req.eos_id is not None and first == req.eos_id:
+                    self._exit(req, "eos")
+
+    def _next_admissible(self) -> _Slot | None:
+        """The head waiting request with a free slot assigned, or None when
+        nobody waits or no slot is free. A request that expired or was
+        cancelled while waiting (the router migrated it away, or the client
+        gave up) is finished here: a prefill now would be dead work."""
+        while True:
+            free = self.engine.free_slots()
             with self._cv:
-                self._pending.remove(req)
-                self._resident.append(req)
-            if self.flight is not None:
-                # ``step`` stamps WHEN in the batch's life the slot joined:
-                # admits at step > 0 are the continuous-batching evidence
-                # (a request entered a batch already mid-decode).
-                self.flight.note(
-                    "slot_admit", slot=req.slot, prompt=len(req.prompt),
-                    step=self.engine.steps, request=req.stream.request_id,
-                    pages=len(self.engine.cache.slot_pages(req.slot))
-                    if self.engine.cache_mode == "paged" else 0,
-                )
-            self._deliver(req, first)
-            if req.eos_id is not None and first == req.eos_id:
-                self._exit(req, "eos")
+                if not self._pending or not free:
+                    return None
+                req = self._pending[0]
+            if req.deadline is not None and req.deadline.expired():
+                self._drop_waiting(req, "deadline: expired before a slot freed")
+            elif req.stream.cancelled:
+                self._drop_waiting(req, "cancelled: before a slot freed")
+            else:
+                req.slot = free[0]
+                return req
+
+    def _drop_waiting(self, req: _Slot, error: str) -> None:
+        self._unpend(req)
+        self.engine.release_reservation(req.pages)
+        self._ledger_release(req)
+        req.stream.finish(error)
+
+    def _seat(self, req: _Slot) -> None:
+        """A prefilled request becomes a resident."""
+        req.pages = []  # ownership moved to the cache's slot binding
+        with self._cv:
+            self._pending.remove(req)
+            self._resident.append(req)
+        if self.flight is not None:
+            # ``step`` stamps WHEN in the batch's life the slot joined:
+            # admits at step > 0 are the continuous-batching evidence
+            # (a request entered a batch already mid-decode).
+            self.flight.note(
+                "slot_admit", slot=req.slot, prompt=len(req.prompt),
+                step=self.engine.steps, request=req.stream.request_id,
+                pages=len(self.engine.cache.slot_pages(req.slot))
+                if self.engine.cache_mode == "paged" else 0,
+            )
 
     def _unpend(self, req: _Slot) -> None:
         with self._cv:
@@ -560,6 +588,36 @@ class SlotScheduler:
     def _retire_and_step(self) -> None:
         # Between-step housekeeping: expired deadlines out, page growth
         # secured, THEN one fixed-shape step for whoever remains.
+        with tracer.span("gen/retire", cpu=True):
+            self._retire()
+        if not self._resident:
+            return
+        oldest = min(self._resident, key=lambda r: r.submitted_t)
+        attrs = {"slots": len(self._resident)}
+        if tracer.enabled:
+            # Pages handed out (bound to slots + reserved by waiting
+            # requests) against the tokens that sit in them, measured where
+            # the pages are handed out. A resident's cache holds its prompt
+            # and all but the newest of the tokens it was sent.
+            attrs["pages_bound"] = self._page_total - self.engine.pages_free
+            attrs["tokens_resident"] = sum(
+                len(r.prompt) + r.emitted - 1 for r in self._resident)
+        t0 = self.clock()
+        with tracectx.bind(oldest.trace_ctx):
+            with tracer.span("gen/step", cpu=True, **attrs):
+                tokens = self.engine.step()
+        elapsed = max(0.0, self.clock() - t0)
+        with tracer.span("gen/deliver", cpu=True):
+            self.step_stats.record(elapsed)
+            if self.profile is not None:
+                self.profile(elapsed)
+            for req in list(self._resident):
+                tok = int(tokens[req.slot])
+                self._deliver(req, tok)
+                if req.eos_id is not None and tok == req.eos_id:
+                    self._exit(req, "eos")
+
+    def _retire(self) -> None:
         for req in list(self._resident):
             if req not in self._resident:
                 continue  # already evicted as another slot's page victim
@@ -587,22 +645,6 @@ class SlotScheduler:
                         self.engine.ensure_capacity(req.slot)
                     except PagePoolExhausted as e2:
                         self._evict(req, e2)
-        if not self._resident:
-            return
-        oldest = min(self._resident, key=lambda r: r.submitted_t)
-        t0 = self.clock()
-        with tracectx.bind(oldest.trace_ctx):
-            with tracer.span("gen/step", slots=len(self._resident)):
-                tokens = self.engine.step()
-        elapsed = max(0.0, self.clock() - t0)
-        self.step_stats.record(elapsed)
-        if self.profile is not None:
-            self.profile(elapsed)
-        for req in list(self._resident):
-            tok = int(tokens[req.slot])
-            self._deliver(req, tok)
-            if req.eos_id is not None and tok == req.eos_id:
-                self._exit(req, "eos")
 
     def _deliver(self, req: _Slot, token: int) -> None:
         req.emitted += 1

@@ -18,6 +18,7 @@ from __future__ import annotations
 
 import collections
 import concurrent.futures
+import contextvars
 import os
 import threading
 import time
@@ -59,6 +60,18 @@ def _stage_pool() -> concurrent.futures.ThreadPoolExecutor:
                 thread_name_prefix="ingest-decode",
             )
         return _STAGE_POOL
+
+
+def _submit_in(ctx: contextvars.Context | None, pool: concurrent.futures.Executor,
+               fn, *args) -> concurrent.futures.Future:
+    """``pool.submit`` that runs ``fn`` in a copy of ``ctx``: a pool thread
+    has an empty context of its own, so a span opened there (``host/decode``)
+    would be the root of a fresh trace instead of a child of the span the
+    shard's thread had open when it took ``ctx``. ``None`` (the tracer is
+    off) submits bare."""
+    if ctx is None:
+        return pool.submit(fn, *args)
+    return pool.submit(ctx.copy().run, fn, *args)  # a Context runs one thread at a time
 
 
 #: Stage names exported by InferenceEngine.ingest_summary(), in pipeline
@@ -275,21 +288,25 @@ class InferenceEngine:
         if n > self.batch_size:
             raise ValueError(f"batch {n} exceeds engine batch_size {self.batch_size}")
         if n < self.batch_size:  # pad to the one compiled shape
+            t0 = time.perf_counter()
             pad = np.zeros((self.batch_size - n, *batch_u8.shape[1:]), batch_u8.dtype)
             batch_u8 = np.concatenate([batch_u8, pad])
+            tracer.record("ingest/stage", time.perf_counter() - t0,
+                          model=self.spec.name, batch=int(n))
         t0 = time.perf_counter()
         out = self._forward(self.variables, batch_u8)
         out = jax.block_until_ready(out)
         dt = time.perf_counter() - t0
-        self._stats.record(dt)
         tracer.record("device/forward", dt, model=self.spec.name, batch=int(n))
+        self._stats.record(dt)
         if self.device_work is not None:
             self.device_work(self.spec.name, int(n), dt)
-        if self.spec.classifier:
-            idx, top = (np.asarray(o) for o in out)
-            return BatchResult(idx[:n], top[:n], None, dt)
-        emb = np.asarray(out)[:n]
-        return BatchResult(np.zeros(n, np.int32), np.zeros(n, np.float32), emb, dt)
+        with tracer.span("ingest/collect", cpu=True):
+            if self.spec.classifier:
+                idx, top = (np.asarray(o) for o in out)
+                return BatchResult(idx[:n], top[:n], None, dt)
+            emb = np.asarray(out)[:n]
+            return BatchResult(np.zeros(n, np.int32), np.zeros(n, np.float32), emb, dt)
 
     def run_batch_global(self, local_u8: np.ndarray) -> BatchResult:
         """Multi-host SPMD inference: every process calls this with its OWN
@@ -409,16 +426,29 @@ class InferenceEngine:
                     (self.batch_size - len(chunk), *batch.shape[1:]), batch.dtype
                 )
                 batch = np.concatenate([batch, pad])
-            self._record_stage("decode", time.perf_counter() - t0, batch=len(chunk))
+            # Statistic only: the interval is the ``host/decode`` span above.
+            with self._ingest_lock:
+                self._ingest["decode"].record(time.perf_counter() - t0)
             return len(chunk), batch
 
         t_all = time.perf_counter()
         outs: list[tuple[int, Any]] = []
         futs: collections.deque = collections.deque()
         next_i = 0
-        while next_i < len(starts) and len(futs) < prefetch:
-            futs.append(pool.submit(decode, starts[next_i]))
-            next_i += 1
+        # Decodes run under the span open HERE (the shard's engine/run), not
+        # under whichever leaf span happens to be open when one is submitted.
+        shard_ctx = contextvars.copy_context() if tracer.enabled else None
+
+        def submit_decodes() -> None:
+            # A span of its own: a pool thread that starts decoding may take
+            # the interpreter from this one before submit() returns.
+            nonlocal next_i
+            with tracer.span("ingest/decode_submit", cpu=True):
+                while next_i < len(starts) and len(futs) < prefetch:
+                    futs.append(_submit_in(shard_ctx, pool, decode, starts[next_i]))
+                    next_i += 1
+
+        submit_decodes()
         staged: collections.deque = collections.deque()
         inflight: collections.deque = collections.deque()
         for _ in starts:
@@ -427,10 +457,11 @@ class InferenceEngine:
             # decode already finished, so the next dispatch finds its input
             # device-resident.
             while futs and len(staged) < 2 and (not staged or futs[0].done()):
-                n, batch = futs.popleft().result()
+                fut = futs.popleft()
+                with tracer.span("ingest/decode_wait", cpu=True, ready=fut.done()):
+                    n, batch = fut.result()
                 if next_i < len(starts):
-                    futs.append(pool.submit(decode, starts[next_i]))
-                    next_i += 1
+                    submit_decodes()
                 t0 = time.perf_counter()
                 buf = jax.device_put(batch, self._data_sharding)
                 self._record_stage("stage", time.perf_counter() - t0, batch=int(n))
@@ -454,14 +485,15 @@ class InferenceEngine:
             # signal that the host, not the chip, is the bottleneck).
             self.device_work(self.spec.name, len(paths), total_dt)
 
-        if self.spec.classifier:
-            idx = np.concatenate([np.asarray(o[0])[:n] for n, o in outs])
-            top = np.concatenate([np.asarray(o[1])[:n] for n, o in outs])
-            return BatchResult(idx, top, None, total_dt)
-        emb = np.concatenate([np.asarray(o)[:n] for n, o in outs])
-        return BatchResult(
-            np.zeros(len(emb), np.int32), np.zeros(len(emb), np.float32), emb, total_dt
-        )
+        with tracer.span("ingest/collect", cpu=True):
+            if self.spec.classifier:
+                idx = np.concatenate([np.asarray(o[0])[:n] for n, o in outs])
+                top = np.concatenate([np.asarray(o[1])[:n] for n, o in outs])
+                return BatchResult(idx, top, None, total_dt)
+            emb = np.concatenate([np.asarray(o)[:n] for n, o in outs])
+            return BatchResult(
+                np.zeros(len(emb), np.int32), np.zeros(len(emb), np.float32), emb, total_dt
+            )
 
     def _materialize(self, n: int, out):
         """Block on one in-flight device result. The recorded span is the
@@ -474,17 +506,21 @@ class InferenceEngine:
         # dmlc-lint: disable=A7 -- designed sync: _materialize IS the stream pipeline's two-behind backpressure barrier, and the wait is measured and exported as device/sync_wait rather than hidden
         out = jax.block_until_ready(out)
         dt = time.perf_counter() - t0
+        # The leaf record ends where it is made: before the statistics' lock.
+        tracer.record("device/sync_wait", dt, model=self.spec.name, batch=int(n))
         with self._ingest_lock:
             self._ingest["sync"].record(dt)
-        tracer.record("device/sync_wait", dt, model=self.spec.name, batch=int(n))
         return n, out
 
     # ---- ingest pipeline observability ---------------------------------
 
     def _record_stage(self, stage: str, dt: float, **attrs) -> None:
+        """A feeding-thread stage (``stage``, ``dispatch``) that ended just
+        now: a leaf span, recorded before the statistics' lock so that it
+        ends where the work ended."""
+        tracer.record(f"ingest/{stage}", dt, model=self.spec.name, **attrs)
         with self._ingest_lock:
             self._ingest[stage].record(dt)
-        tracer.record(f"ingest/{stage}", dt, model=self.spec.name, **attrs)
 
     def ingest_summary(self) -> dict[str, dict[str, float]]:
         """Per-stage pipeline counters since construction (or the last

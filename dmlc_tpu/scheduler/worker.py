@@ -518,26 +518,35 @@ class EngineBackend:
         return self._engine
 
     def __call__(self, synsets: Sequence[str]) -> list[int]:
+        # Spans (docs/OBSERVABILITY.md §1): this thread feeds the device, so
+        # between taking the lock and the reply every instant lies under one
+        # leaf span — an idle chip always has an owner here.
+        t_wait = time.perf_counter()
         # dmlc-lint: disable=A2 -- the engine lock serializes shards per engine BY DESIGN (the reference's model mutex, services.rs:493); the future wait it reaches in run_paths_stream is the decode/execute pipeline INSIDE one shard, not a foreign dependency
         with self._lock:
-            engine = self._ensure_engine()
-            paths = _resolve_paths(self.image_source, self.data_dir, synsets)
-            if len(paths) <= self.batch_size:
-                result = engine.run_paths(paths)
-            else:
-                # Multi-batch shard: decode batch i+1 while the device runs
-                # batch i (SURVEY §7 hard part b). With the fleet decode
-                # tier wired, that prefetch decode fans out across peers'
-                # idle decode lanes instead of only the local stage pool.
-                result = engine.run_paths_stream(
-                    paths,
-                    decode_source=(
-                        self.decode_tier.decode_paths
-                        if self.decode_tier is not None
-                        else None
-                    ),
-                )
-            return [int(x) for x in result.top1_index]
+            tracer.record("engine/lock_wait", time.perf_counter() - t_wait)
+            with tracer.span("engine/run", cpu=True, n=len(synsets),
+                             batches=-(-len(synsets) // self.batch_size)):
+                engine = self._ensure_engine()
+                with tracer.span("engine/resolve_paths", cpu=True, n=len(synsets)):
+                    paths = _resolve_paths(self.image_source, self.data_dir, synsets)
+                if len(paths) <= self.batch_size:
+                    result = engine.run_paths(paths)
+                else:
+                    # Multi-batch shard: decode batch i+1 while the device runs
+                    # batch i (SURVEY §7 hard part b). With the fleet decode
+                    # tier wired, that prefetch decode fans out across peers'
+                    # idle decode lanes instead of only the local stage pool.
+                    result = engine.run_paths_stream(
+                        paths,
+                        decode_source=(
+                            self.decode_tier.decode_paths
+                            if self.decode_tier is not None
+                            else None
+                        ),
+                    )
+                with tracer.span("engine/collect", cpu=True):
+                    return [int(x) for x in result.top1_index]
 
     def decode_gang(self, synsets: Sequence[str], rank: int, world: int) -> bool:
         """Decode this rank's slice of an UPCOMING gang shard into the
@@ -553,7 +562,9 @@ class EngineBackend:
             if engine is None:
                 # First touch only; afterwards the reference read above is
                 # lock-free so a running collective cannot block prefetch.
+                t_wait = time.perf_counter()
                 with self._lock:
+                    tracer.record("engine/lock_wait", time.perf_counter() - t_wait)
                     engine = self._ensure_engine()
             start, stop = gang_slice(len(synsets), rank, world)
             mine = tuple(synsets[start:stop])
@@ -592,7 +603,9 @@ class EngineBackend:
 
         from dmlc_tpu.ops import preprocess as pp
 
+        t_wait = time.perf_counter()
         with self._lock:
+            tracer.record("engine/lock_wait", time.perf_counter() - t_wait)
             engine = self._ensure_engine()
             size = engine.input_size
             deferred: Exception | None = None

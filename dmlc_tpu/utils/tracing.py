@@ -83,6 +83,63 @@ def lane(name: str | None) -> Iterator[None]:
         _lane.reset(token)
 
 
+class _NullSpan:
+    """What ``Tracer.span`` hands out while the tracer is off."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> None:
+        return None
+
+    def __exit__(self, *exc: object) -> None:
+        return None
+
+
+_NULL_SPAN = _NullSpan()
+
+
+class _Span:
+    """One open span of an enabled tracer: binds a child trace context on
+    entry, records itself on exit (a plain class: two nested generator
+    context managers cost more than the record they wrapped)."""
+
+    __slots__ = ("_tracer", "_name", "_attrs", "_cpu", "_ctx", "_token", "_start", "_cpu0")
+
+    def __init__(self, owner: "Tracer", name: str, attrs: dict, cpu: bool) -> None:
+        self._tracer = owner
+        self._name = name
+        self._attrs = attrs
+        self._cpu = cpu
+
+    def __enter__(self) -> None:
+        parent = tracectx.current()
+        if parent is None:
+            self._ctx = tracectx.child(sampled=self._tracer._decide_root())
+        else:
+            self._ctx = tracectx.child(parent)
+        self._token = tracectx.enter(self._ctx)
+        if self._cpu:
+            self._cpu0 = time.thread_time()
+        self._start = time.perf_counter()
+
+    def __exit__(self, exc_type, exc, tb) -> None:
+        dur = time.perf_counter() - self._start
+        attrs, ctx, owner = self._attrs, self._ctx, self._tracer
+        if self._cpu:
+            attrs["cpu_s"] = time.thread_time() - self._cpu0
+        tracectx.leave(self._token)
+        if exc_type is not None:
+            attrs["error"] = exc_type.__name__
+            if not ctx.sampled:
+                attrs["forced"] = "error"
+        rec = SpanRecord(
+            self._name, self._start - owner._t0, dur, threading.get_ident(), attrs,
+            trace_id=ctx.trace_id, span_id=ctx.span_id,
+            parent_id=ctx.parent_id, lane=_lane.get(),
+        )
+        owner._store(rec, ctx.sampled, forced=exc_type is not None)
+
+
 class Tracer:
     """Span collector. Disabled by default; enabling costs one branch per
     span entry. Bounded: keeps aggregates forever, raw events up to
@@ -130,48 +187,21 @@ class Tracer:
         this so the leader-side merge can align per-node timelines."""
         return time.perf_counter() - self._t0
 
-    @contextmanager
-    def span(self, name: str, **attrs):
+    def span(self, name: str, cpu: bool = False, **attrs):
+        """A context manager that records the block as one span under the
+        ambient trace context. ``cpu=True`` also stores ``cpu_s``, the
+        calling thread's CPU seconds inside the block (``time.thread_time``),
+        in the span's attrs: on a span that blocks on neither the device nor
+        a lock, wall minus CPU is time the thread waited for the interpreter.
+        Disabled, this is one branch and a shared no-op object."""
         if not self.enabled:
-            yield
-            return
-        if tracectx.current() is None:
-            ctx = tracectx.child(sampled=self._decide_root())
-        else:
-            ctx = tracectx.child()
-        start = time.perf_counter()
-        error: BaseException | None = None
-        try:
-            with tracectx.bind(ctx):
-                yield
-        except BaseException as e:
-            error = e
-            raise
-        finally:
-            dur = time.perf_counter() - start
-            if error is not None:
-                attrs = dict(attrs, error=type(error).__name__)
-                if not ctx.sampled:
-                    attrs["forced"] = "error"
-            rec = SpanRecord(
-                name, start - self._t0, dur, threading.get_ident(), attrs,
-                trace_id=ctx.trace_id, span_id=ctx.span_id,
-                parent_id=ctx.parent_id, lane=_lane.get(),
-            )
-            with self._lock:
-                self._aggregates.setdefault(name, LatencyStats()).record(dur)
-                # Forced sampling: a span that raised is stored even when
-                # the head decision said drop — every enclosing span of the
-                # failing request sees the same exception on unwind, so the
-                # whole local chain survives into the merged trace.
-                if ctx.sampled or error is not None:
-                    if error is not None and not ctx.sampled:
-                        self._forced_records += 1
-                    self._append_locked(rec)
+            return _NULL_SPAN
+        return _Span(self, name, attrs, cpu)
 
     def record(self, name: str, duration_s: float, **attrs) -> None:
         """Record an externally-timed duration (e.g. device execution) as a
-        leaf span under the ambient trace context."""
+        leaf span under the ambient trace context. The interval is taken to
+        END at the call, so call it right after the work, before any lock."""
         if not self.enabled:
             return
         ctx = tracectx.child()
@@ -181,9 +211,21 @@ class Tracer:
             trace_id=ctx.trace_id, span_id=ctx.span_id,
             parent_id=ctx.parent_id, lane=_lane.get(),
         )
+        self._store(rec, ctx.sampled, forced=False)
+
+    def _store(self, rec: SpanRecord, sampled: bool, forced: bool) -> None:
         with self._lock:
-            self._aggregates.setdefault(name, LatencyStats()).record(duration_s)
-            if ctx.sampled:
+            agg = self._aggregates.get(rec.name)
+            if agg is None:
+                agg = self._aggregates[rec.name] = LatencyStats()
+            agg.record(rec.duration_s)
+            # Forced sampling: a span that raised is stored even when the
+            # head decision said drop — every enclosing span of the failing
+            # request sees the same exception on unwind, so the whole local
+            # chain survives into the merged trace.
+            if sampled or forced:
+                if forced and not sampled:
+                    self._forced_records += 1
                 self._append_locked(rec)
 
     def _append_locked(self, rec: SpanRecord) -> None:

@@ -1056,8 +1056,16 @@ def test_analyzer_runtime_budget():
     """A1-A9 over the whole tree stays inside the 4s interactive budget
     (pure AST, no imports — docs/ANALYZE.md). Raised from 3s with the
     session-router tier (scheduler/genrouter.py), same as 2s -> 3s when
-    A9 landed: the budget tracks tree size, the analyzer stays pure-AST."""
+    A9 landed: the budget tracks tree size, the analyzer stays pure-AST.
+    Timed on this process's CPU clock, best of two walks: the walk is
+    single-threaded, and wall time under five other xdist workers measured
+    the machine's load, not the analyzer (it failed the driver's run at
+    PR 24 that way)."""
     import time
-    t0 = time.monotonic()
-    run_rules(REPO / "dmlc_tpu")
-    assert time.monotonic() - t0 < 4.0
+
+    def walk() -> float:
+        t0 = time.process_time()
+        run_rules(REPO / "dmlc_tpu")
+        return time.process_time() - t0
+
+    assert min(walk(), walk()) < 4.0

@@ -103,6 +103,30 @@ class TestCriticalPath:
         assert got == pytest.approx({"r": 2, "fwd": 5, "d": 1, "dec": 2})
         assert sum(got.values()) == pytest.approx(10.0)
 
+    def test_engine_holding_thread_children_leave_the_handler_no_self_time(self):
+        # A member's job.predict as the feeding-thread spans tile it
+        # (docs/OBSERVABILITY.md §1): the wait at the engine lock, then
+        # engine/run tiled by its leaves; host/decode runs on the stage
+        # pool under engine/run, concurrent with the thread's decode_wait.
+        spans = [
+            mk("rpc/job.predict", 0, 10, "r", model="m", lane="n1"),
+            mk("engine/lock_wait", 0, 6, "w", parent="r", lane="n1"),
+            mk("engine/run", 6, 10, "run", parent="r", lane="n1"),
+            mk("engine/resolve_paths", 6, 6.5, "res", parent="run", lane="n1"),
+            mk("ingest/decode_wait", 6.5, 8, "dw", parent="run", lane="n1"),
+            mk("host/decode", 6.5, 7.9, "dec", parent="run", lane="n1"),
+            mk("ingest/stage", 8, 9, "st", parent="run", lane="n1"),
+            mk("device/sync_wait", 9, 10, "sync", parent="run", lane="n1"),
+        ]
+        path = critical_path(spans)
+        got = charged_by_span(path)
+        # The thread's own wait blocks the tail the pool's decode overlaps:
+        # decode is concurrent shadow, the handler and engine/run keep nothing.
+        assert got == pytest.approx(
+            {"w": 6, "res": 0.5, "dw": 1.5, "st": 1, "sync": 1})
+        assert path.total_s == pytest.approx(10.0)
+        assert stage_of("engine/lock_wait") == "engine/lock_wait"  # its own label
+
     def test_child_overhanging_parent_is_clamped(self):
         # A child recorded past its parent's end (clock skew / late flush)
         # must not push shares past 1.0.

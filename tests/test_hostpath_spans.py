@@ -1,0 +1,479 @@
+"""Spans on the threads that feed the device (docs/OBSERVABILITY.md §1,
+"Feeding threads"): ``job.predict``'s engine-holding thread and the
+generation loop are TILED by leaf spans, work handed to a pool keeps its
+shard's trace, the counters ride ``gen/step``, and a disabled tracer costs
+nothing — at CPU size, on the tiny models.
+
+Each scenario runs once (module fixtures) and every property is a case of
+its own.
+"""
+
+import contextlib
+import random
+import re
+import sys
+import threading
+import time
+
+import numpy as np
+import pytest
+
+pytest.importorskip("jax")
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from dmlc_tpu.cluster import tracectx  # noqa: E402
+from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
+from dmlc_tpu.generate.slots import SlotScheduler  # noqa: E402
+from dmlc_tpu.models.registry import get_model  # noqa: E402
+from dmlc_tpu.ops import preprocess as pp  # noqa: E402
+from dmlc_tpu.scheduler.worker import EngineBackend  # noqa: E402
+from dmlc_tpu.utils import corpus  # noqa: E402
+from dmlc_tpu.utils.tracing import Tracer, tracer  # noqa: E402
+from tiny_model import N_CLASSES  # noqa: E402,F401  (registers "tinynet")
+
+BATCH = 8  # the CPU test mesh is dp=8
+N_IMAGES = 32  # 4 batches a shard: the stream path
+
+#: What the engine-holding thread does, one leaf span each (they tile engine/run).
+PREDICT_LEAVES = ("engine/resolve_paths", "ingest/decode_submit", "ingest/decode_wait", "ingest/stage",
+                  "ingest/dispatch", "device/sync_wait", "ingest/collect",
+                  "engine/collect")
+#: The decode thread's top-level spans (they tile first admission .. last exit).
+LOOP_TOP = ("gen/idle", "gen/admit", "gen/prefill", "gen/retire", "gen/step", "gen/deliver")
+
+
+def wire(events):
+    return [dict(e, t0=e["start"], t1=e["start"] + e["dur"]) for e in events]
+
+
+#: perf_counter read on two cores of a virtual machine can differ by
+#: microseconds: what "at the same instant" is allowed to mean below.
+CLOCK_SLACK = 1e-4
+
+
+def covered(spans, t0, t1):
+    """(seconds of [t0, t1] under ``spans``, seconds two of them overlap)."""
+    total = overlap = 0.0
+    end = t0
+    for s in sorted(spans, key=lambda s: s["t0"]):
+        a, b = max(s["t0"], t0), min(s["t1"], t1)
+        if b <= a:
+            continue
+        if a < end:
+            overlap += min(b, end) - a
+        total += max(0.0, b - max(a, end))
+        end = max(end, b)
+    return total, overlap
+
+
+@contextlib.contextmanager
+def traced_scenario():
+    """The process-global tracer, on and empty, for one scenario; off and
+    empty afterwards. The interpreter's switch interval is cut to 0.1 ms:
+    a feeding thread that loses the interpreter BETWEEN two spans (to a
+    decode thread, to the test runner's own threads) otherwise stays out for
+    5 ms, which at CPU size is the tiling margin itself."""
+    was, cap, interval = tracer.enabled, tracer.max_events, sys.getswitchinterval()
+    tracer.reset()
+    tracer.max_events = 500_000
+    tracer.enabled = True
+    sys.setswitchinterval(1e-4)
+    try:
+        yield tracer
+    finally:
+        sys.setswitchinterval(interval)
+        tracer.enabled, tracer.max_events = was, cap
+        tracer.reset()
+
+
+@pytest.fixture
+def tracing_on():
+    with traced_scenario() as t:
+        yield t
+
+
+# ---------------------------------------------------------------------------
+# job.predict: two shards contend for one EngineBackend on the stream path
+# ---------------------------------------------------------------------------
+
+
+@pytest.fixture(scope="module")
+def backend(tmp_path_factory):
+    root = tmp_path_factory.mktemp("hostpath_corpus")
+    data_dir, _ = corpus.generate(root, n_classes=N_IMAGES, images_per_class=1, size=32)
+    synsets = sorted(d.name for d in data_dir.iterdir())
+    be = EngineBackend("tinynet", data_dir, batch_size=BATCH)
+    be.warmup()
+    be(synsets)  # first use builds the stage pool and its threads: not a shard's cost
+    return be, synsets
+
+
+@pytest.fixture(scope="module")
+def predict_spans(backend):
+    """Spans of two concurrent shards; decode sleeps 50 ms a batch (the GIL
+    is released), so a shard's wall is waits the spans must own."""
+    be, synsets = backend
+    real_load = pp.load_batch
+
+    def slow_load(paths, **kw):
+        time.sleep(0.05)
+        return real_load(paths, **kw)
+
+    gate = threading.Barrier(2)
+    errors = []
+
+    def shard(i):
+        try:
+            gate.wait(timeout=30)
+            with tracer.span("test/shard", i=i):
+                assert len(be(synsets)) == len(synsets)
+        except BaseException as e:  # surfaced by the fixture below
+            errors.append(e)
+
+    threads = [threading.Thread(target=shard, args=(i,)) for i in range(2)]
+    pp.load_batch = slow_load
+    try:
+        with traced_scenario():
+            for t in threads:
+                t.start()
+            for t in threads:
+                t.join(timeout=120)
+            assert not any(t.is_alive() for t in threads)
+            spans = wire(tracer.events_wire())
+            summary = be._engine.ingest_summary()
+    finally:
+        pp.load_batch = real_load
+    assert not errors, errors
+    roots = [s for s in spans if s["name"] == "test/shard"]
+    assert len(roots) == 2
+    by_trace = {r["trace"]: [s for s in spans if s["trace"] == r["trace"]] for r in roots}
+    return {"all": spans, "by_trace": by_trace, "ingest_summary": summary}
+
+
+@pytest.mark.parametrize("name", ("engine/lock_wait", "engine/run", "host/decode") + PREDICT_LEAVES)
+def test_predict_span_in_every_shard(predict_spans, name):
+    for spans in predict_spans["by_trace"].values():
+        assert any(s["name"] == name for s in spans), (name, sorted({s["name"] for s in spans}))
+
+
+def test_predict_lock_wait_sees_the_other_shard(predict_spans):
+    """One shard takes the lock at once; the other waits out the first's whole hold."""
+    waits, runs = [], []
+    for spans in predict_spans["by_trace"].values():
+        waits += [s["dur"] for s in spans if s["name"] == "engine/lock_wait"]
+        runs += [s for s in spans if s["name"] == "engine/run"]
+    assert len(waits) == 2 and len(runs) == 2
+    first, second = sorted(runs, key=lambda s: s["t0"])
+    assert second["t0"] >= first["t1"] - CLOCK_SLACK    # the lock serialises the holds
+    assert max(waits) >= 0.9 * first["dur"]
+    assert min(waits) < 0.5 * first["dur"]
+
+
+def test_predict_leaves_tile_engine_run(predict_spans):
+    """On the thread that holds the engine, the leaf spans cover >= 95% of
+    each engine/run and no two of them overlap."""
+    for spans in predict_spans["by_trace"].values():
+        run = next(s for s in spans if s["name"] == "engine/run")
+        leaves = [s for s in spans if s["name"] in PREDICT_LEAVES and s["tid"] == run["tid"]]
+        total, overlap = covered(leaves, run["t0"], run["t1"])
+        assert overlap < CLOCK_SLACK
+        assert total >= 0.95 * run["dur"], (total, run["dur"])
+        assert all(run["t0"] - CLOCK_SLACK <= s["t0"] and s["t1"] <= run["t1"] + CLOCK_SLACK
+                   for s in leaves)
+
+
+def test_predict_decode_keeps_the_shards_trace(predict_spans):
+    """host/decode runs on the stage pool and is still the shard's: its
+    trace id, engine/run as parent, another thread."""
+    for spans in predict_spans["by_trace"].values():
+        run = next(s for s in spans if s["name"] == "engine/run")
+        decodes = [s for s in spans if s["name"] == "host/decode"]
+        assert len(decodes) == N_IMAGES // BATCH
+        assert all(d["parent"] == run["span"] and d["tid"] != run["tid"] for d in decodes)
+    # and none is left a root of its own trace
+    assert not [s for s in predict_spans["all"]
+                if s["name"] == "host/decode" and s["parent"] is None]
+
+
+def test_predict_parent_edges(predict_spans):
+    for spans in predict_spans["by_trace"].values():
+        root = next(s for s in spans if s["name"] == "test/shard")
+        run = next(s for s in spans if s["name"] == "engine/run")
+        wait = next(s for s in spans if s["name"] == "engine/lock_wait")
+        assert run["parent"] == root["span"] and wait["parent"] == root["span"]
+        assert wait["t1"] <= run["t0"] + CLOCK_SLACK
+        assert run["attrs"]["n"] == N_IMAGES and run["attrs"]["batches"] == N_IMAGES // BATCH
+        for s in spans:
+            if s["name"] in PREDICT_LEAVES:
+                assert s["parent"] == run["span"], s["name"]
+        waits = [s for s in spans if s["name"] == "ingest/decode_wait"]
+        assert len(waits) == N_IMAGES // BATCH
+        assert all(isinstance(w["attrs"]["ready"], bool) for w in waits)
+
+
+def test_predict_cpu_time_on_engine_spans(predict_spans):
+    """cpu_s rides the engine-holding spans; a hold that mostly waits for
+    decode burns less CPU than wall."""
+    for spans in predict_spans["by_trace"].values():
+        run = next(s for s in spans if s["name"] == "engine/run")
+        assert 0.0 <= run["attrs"]["cpu_s"] < run["dur"]
+        for s in spans:
+            if s["name"] in ("engine/resolve_paths", "ingest/decode_wait",
+                             "ingest/collect", "engine/collect"):
+                assert s["attrs"]["cpu_s"] >= 0.0
+
+
+def test_ingest_decode_is_a_statistic_not_a_span(predict_spans):
+    """The decode interval is host/decode; ingest_summary still counts it."""
+    assert not [s for s in predict_spans["all"] if s["name"] == "ingest/decode"]
+    assert predict_spans["ingest_summary"]["decode"]["count"] >= 2 * N_IMAGES // BATCH
+
+
+def test_one_batch_shard_is_not_dark(backend, tracing_on, monkeypatch):
+    """A shard of one batch takes run_paths: decode, forward, collect."""
+    be, synsets = backend
+    real_load = pp.load_batch
+    monkeypatch.setattr(pp, "load_batch",
+                        lambda paths, **kw: (time.sleep(0.05), real_load(paths, **kw))[1])
+    with tracer.span("test/shard"):
+        be(synsets[:BATCH - 1])
+    spans = wire(tracer.events_wire())
+    run = next(s for s in spans if s["name"] == "engine/run")
+    leaves = [s for s in spans if s["parent"] == run["span"]]
+    assert {s["name"] for s in leaves} == {
+        "engine/resolve_paths", "host/decode", "ingest/stage", "device/forward",
+        "ingest/collect", "engine/collect"}
+    total, overlap = covered(leaves, run["t0"], run["t1"])
+    assert overlap < CLOCK_SLACK and total >= 0.9 * run["dur"]
+
+
+# ---------------------------------------------------------------------------
+# the generation loop: a SlotScheduler over the tiny engine
+# ---------------------------------------------------------------------------
+
+SPEC = get_model("lm_small")
+N_REQUESTS = 6
+
+
+@pytest.fixture(scope="module")
+def lm_engine():
+    _, variables = SPEC.init_params(jax.random.PRNGKey(0), dtype=jnp.float32)
+    eng = GenerationEngine("lm_small", variables=variables, max_slots=4, page_size=8,
+                           num_pages=64, max_prefill=16)
+    eng.warmup()
+    return eng
+
+
+@pytest.fixture(scope="module")
+def gen_spans(lm_engine):
+    """Six requests staged before the decode thread starts (four slots, so
+    two wait holding their reservations), each submitted under a root span
+    of its own; the engine's own state is written down inside every step."""
+    eng = lm_engine
+    rng = np.random.default_rng(7)
+    reqs = [(rng.integers(0, SPEC.num_outputs, size=int(rng.integers(3, 12))).tolist(),
+             int(rng.integers(3, 9))) for _ in range(N_REQUESTS)]
+    state_at_step = []
+    real_step = eng.step
+
+    def step():
+        alloc = eng.cache.allocator
+        state_at_step.append((alloc.pages_total - alloc.pages_free,
+                              int(eng.lengths[eng.active].sum()), int(eng.active.sum())))
+        time.sleep(0.01)  # a step the size of the tracer's own cost would test the tracer
+        return real_step()
+
+    eng.step = step
+    try:
+        with traced_scenario():
+            sched = SlotScheduler(eng, max_waiting=N_REQUESTS, autostart=False)
+            streams = []
+            for i, (prompt, n) in enumerate(reqs):
+                with tracer.span("test/request", i=i):
+                    streams.append(sched.submit(prompt, max_new_tokens=n))
+            sched.start()
+            outs = [s.result(timeout=120) for s in streams]
+            sched.stop()
+            spans = wire(tracer.events_wire())
+    finally:
+        del eng.step
+    assert [len(o) for o in outs] == [n for _, n in reqs]
+    return {"all": spans, "reqs": reqs, "state_at_step": state_at_step}
+
+
+def named(spans, name):
+    return [s for s in spans["all"] if s["name"] == name]
+
+
+def test_gen_wait_once_per_request_under_its_trace(gen_spans):
+    roots = {r["attrs"]["i"]: r for r in named(gen_spans, "test/request")}
+    waits = named(gen_spans, "gen/wait")
+    assert len(roots) == N_REQUESTS and len(waits) == N_REQUESTS
+    assert sorted(w["trace"] for w in waits) == sorted(r["trace"] for r in roots.values())
+    prefill_of = {p["trace"]: p for p in named(gen_spans, "gen/prefill")}
+    for w in waits:
+        root = next(r for r in roots.values() if r["trace"] == w["trace"])
+        assert w["parent"] == root["span"]
+        # from submit (inside the root span) to the start of this request's prefill
+        assert root["t0"] - CLOCK_SLACK <= w["t0"] <= root["t1"] + CLOCK_SLACK
+        assert abs(w["t1"] - prefill_of[w["trace"]]["t0"]) < 0.005
+    # the two that found no slot waited for an exit: longer than any of the first four
+    by_len = sorted(w["dur"] for w in waits)
+    assert by_len[-2] > by_len[3]
+
+
+def test_loop_thread_spans_tile_admission_to_exit(gen_spans):
+    """From the first admission to the last exit the decode thread's
+    top-level spans cover >= 95% and never overlap."""
+    loop_tid = named(gen_spans, "gen/step")[0]["tid"]
+    top = [s for s in gen_spans["all"] if s["name"] in LOOP_TOP and s["tid"] == loop_tid]
+    assert {s["name"] for s in top} >= set(LOOP_TOP) - {"gen/idle"}
+    t0 = min(s["t0"] for s in top if s["name"] == "gen/admit")
+    t1 = max(s["t1"] for s in top if s["name"] == "gen/deliver")
+    total, overlap = covered([s for s in top if s["name"] != "gen/idle"], t0, t1)
+    assert overlap < CLOCK_SLACK
+    assert total >= 0.95 * (t1 - t0), (total, t1 - t0)
+
+
+@pytest.mark.parametrize("child,parent", [("gen/step_sync", "gen/step"),
+                                          ("gen/prefill_sync", "gen/prefill")])
+def test_sync_span_is_the_child_that_blocks(gen_spans, child, parent):
+    parents = {s["span"]: s for s in named(gen_spans, parent)}
+    kids = named(gen_spans, child)
+    assert len(kids) == len(parents) > 0
+    for k in kids:
+        p = parents[k["parent"]]
+        assert p["t0"] - CLOCK_SLACK <= k["t0"] and k["t1"] <= p["t1"] + CLOCK_SLACK
+        assert k["trace"] == p["trace"]
+
+
+def test_gen_step_carries_the_allocators_and_engines_state(gen_spans):
+    steps = sorted(named(gen_spans, "gen/step"), key=lambda s: s["t0"])
+    seen = [(s["attrs"]["pages_bound"], s["attrs"]["tokens_resident"], s["attrs"]["slots"])
+            for s in steps]
+    assert seen == gen_spans["state_at_step"]
+    # while two requests waited, their reserved pages were counted as bound
+    assert max(p for p, _, _ in seen) > 0 and all(p * 8 >= t for p, t, _ in seen)
+
+
+@pytest.mark.parametrize("name", LOOP_TOP[1:] + ("gen/step_sync", "gen/prefill_sync"))
+def test_loop_spans_carry_cpu_time(gen_spans, name):
+    spans = named(gen_spans, name)
+    assert spans and all(0.0 <= s["attrs"]["cpu_s"] for s in spans)
+
+
+def test_gen_idle_is_the_wait_for_work(lm_engine, tracing_on):
+    """A loop with nobody to serve sits in gen/idle, burning no CPU, until a submit wakes it."""
+    sched = SlotScheduler(lm_engine, max_waiting=2)
+    try:
+        time.sleep(0.05)
+        assert len(sched.submit([1, 2, 3], max_new_tokens=2).result(timeout=60)) == 2
+    finally:
+        sched.stop()
+    spans = wire(tracer.events_wire())
+    idle = [s for s in spans if s["name"] == "gen/idle"]
+    first_admit = min(s["t0"] for s in spans if s["name"] == "gen/prefill")
+    woke = [s for s in idle if s["t1"] <= first_admit]
+    assert len(woke) == 1 and woke[0]["dur"] >= 0.04 and woke[0]["attrs"]["cpu_s"] < 0.02
+
+
+def test_gen_step_binds_the_oldest_residents_trace(gen_spans):
+    request_traces = {r["trace"] for r in named(gen_spans, "test/request")}
+    assert all(s["trace"] in request_traces for s in named(gen_spans, "gen/step"))
+    assert all(s["parent"] is None for s in named(gen_spans, "gen/retire"))
+
+
+# ---------------------------------------------------------------------------
+# disabled: nothing recorded, no thread clock read, no context copied
+# ---------------------------------------------------------------------------
+
+
+@pytest.mark.parametrize("path", ("predict", "generate"))
+def test_disabled_tracer_records_nothing_and_reads_no_thread_clock(
+        path, backend, lm_engine, monkeypatch):
+    def boom():
+        raise AssertionError("time.thread_time read with the tracer off")
+
+    def no_copy():
+        raise AssertionError("a context was copied with the tracer off")
+
+    assert not tracer.enabled
+    tracer.reset()
+    monkeypatch.setattr(time, "thread_time", boom)
+    if path == "predict":
+        from dmlc_tpu.parallel import inference
+
+        monkeypatch.setattr(inference.contextvars, "copy_context", no_copy)
+        be, synsets = backend
+        assert len(be(synsets)) == len(synsets)
+    else:
+        sched = SlotScheduler(lm_engine, max_waiting=2)
+        try:
+            assert len(sched.submit([1, 2, 3], max_new_tokens=3).result(timeout=60)) == 3
+        finally:
+            sched.stop()
+    assert tracer.event_count == 0 and tracer.summary() == {}
+
+
+# ---------------------------------------------------------------------------
+# the tracer itself: ids, cpu=True
+# ---------------------------------------------------------------------------
+
+
+def test_span_ids_16_hex_unique_across_threads():
+    """100,000 spans from two threads: every span id and trace id is 16 hex
+    characters and no id repeats."""
+    t = Tracer(max_events=200_000)
+    t.enabled = True
+
+    def burn():
+        for _ in range(50_000):
+            with t.span("x"):
+                pass
+
+    threads = [threading.Thread(target=burn) for _ in range(2)]
+    for th in threads:
+        th.start()
+    for th in threads:
+        th.join(timeout=120)
+    assert not any(th.is_alive() for th in threads)
+    events = t.events_wire()
+    assert len(events) == 100_000
+    ids = [e["span"] for e in events] + [e["trace"] for e in events]
+    assert len(set(ids)) == 200_000
+    hex16 = re.compile(r"[0-9a-f]{16}\Z")
+    assert all(hex16.match(i) for i in ids)
+    assert t.summary()["x"]["count"] == 100_000
+
+
+def test_ids_leave_the_global_random_state_alone():
+    random.seed(1234)
+    want = random.random()
+    random.seed(1234)
+    ids = {tracectx.new_id() for _ in range(1000)}
+    assert random.random() == want and len(ids) == 1000
+
+
+@pytest.mark.parametrize("cpu", (True, False))
+def test_cpu_attr_only_when_asked(cpu):
+    t = Tracer()
+    t.enabled = True
+    with t.span("sleeps", cpu=cpu, n=1):
+        time.sleep(0.05)
+    (e,) = t.events_wire()
+    assert e["attrs"]["n"] == 1
+    if cpu:
+        assert 0.0 <= e["attrs"]["cpu_s"] < 0.04 <= e["dur"]
+    else:
+        assert "cpu_s" not in e["attrs"]
+
+
+def test_span_records_error_and_reraises():
+    t = Tracer()
+    t.enabled = True
+    with pytest.raises(KeyError):
+        with t.span("fails", cpu=True):
+            raise KeyError("x")
+    (e,) = t.events_wire()
+    assert e["attrs"]["error"] == "KeyError" and tracectx.current() is None

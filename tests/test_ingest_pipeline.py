@@ -120,6 +120,36 @@ def test_ingest_metrics_attribute_stages(corpus_paths):
     assert engine.ingest_summary()["decode"]["count"] == 0
 
 
+def test_stream_spans_one_per_stage_per_batch(corpus_paths):
+    """The tracer's view of the same three batches: the feeding thread's
+    leaf spans once a batch, the decode interval as ``host/decode`` alone
+    (``ingest/decode`` was a second record of it), one collect."""
+    from dmlc_tpu.parallel.inference import InferenceEngine
+    from dmlc_tpu.utils.tracing import tracer
+
+    engine = InferenceEngine("tinynet", batch_size=8, seed=6)
+    engine.warmup()
+    tracer.reset()
+    tracer.enabled = True
+    try:
+        with tracer.span("shard"):
+            engine.run_paths_stream(corpus_paths[:17])
+        events = tracer.events_wire()
+    finally:
+        tracer.enabled = False
+        tracer.reset()
+    count = {}
+    for e in events:
+        count[e["name"]] = count.get(e["name"], 0) + 1
+    root = next(e for e in events if e["name"] == "shard")
+    assert all(e["parent"] == root["span"] for e in events if e is not root)
+    assert count == {"shard": 1, "host/decode": 3, "ingest/decode_wait": 3, "ingest/stage": 3,
+                     "ingest/dispatch": 3, "device/sync_wait": 3, "ingest/collect": 1,
+                     "ingest/decode_submit": count["ingest/decode_submit"]}
+    assert 1 <= count["ingest/decode_submit"] <= 3  # the first fill, then a top-up while batches remain
+    assert engine.ingest_summary()["decode"]["count"] == 3  # the statistic stays
+
+
 def test_stream_partial_final_batch_padding(corpus_paths):
     """Direct pin on the tail-batch path: corpus sizes that are NOT a
     multiple of batch_size (including < one batch) are padded to the one

@@ -100,6 +100,43 @@ def test_nested_hops_share_one_trace_with_parent_links():
     assert spans["rpc/sdfs.fetch"]["lane"] == "storage"
 
 
+def test_member_predict_children_and_pool_work_stay_in_the_callers_trace(tmp_path):
+    """Below rpc/job.predict on a real EngineBackend (docs/OBSERVABILITY.md
+    §1, feeding threads): the wait at the engine lock and engine/run are the
+    handler's children, and the decode handed to the stage pool keeps the
+    caller's trace, engine/run as parent, and the member's lane — it used
+    to be the root of a trace of its own, with no lane."""
+    from dmlc_tpu.scheduler.worker import EngineBackend, PredictWorker
+    from dmlc_tpu.utils import corpus
+    import tiny_model  # noqa: F401  (registers "tinynet")
+
+    data_dir, _ = corpus.generate(tmp_path, n_classes=16, images_per_class=1, size=32)
+    synsets = sorted(d.name for d in data_dir.iterdir())
+    backend = EngineBackend("tinynet", data_dir, batch_size=8)
+    backend.warmup()
+    net = SimRpcNetwork()
+    net.serve("member", PredictWorker({"tinynet": backend}).methods())
+    tracer.enabled = True
+    with tracer.span("scheduler/dispatch"):
+        reply = net.client("leader").call(
+            "member", "job.predict", {"model": "tinynet", "synsets": synsets}, timeout=60.0)
+    assert len(reply["predictions"]) == len(synsets)
+    events = tracer.events_wire()
+    assert len({e["trace"] for e in events}) == 1
+    one = {e["name"]: e for e in events}
+    rpc, run = one["rpc/job.predict"], one["engine/run"]
+    assert rpc["parent"] == one["scheduler/dispatch"]["span"]
+    assert one["engine/lock_wait"]["parent"] == rpc["span"] and run["parent"] == rpc["span"]
+    for leaf in ("engine/resolve_paths", "ingest/decode_submit", "ingest/decode_wait",
+                 "ingest/stage", "ingest/dispatch", "device/sync_wait", "ingest/collect",
+                 "engine/collect"):
+        assert one[leaf]["parent"] == run["span"] and one[leaf]["lane"] == "member", leaf
+    decodes = [e for e in events if e["name"] == "host/decode"]
+    assert len(decodes) == 2  # 16 images, batches of 8: the stream path
+    for d in decodes:
+        assert d["parent"] == run["span"] and d["lane"] == "member" and d["tid"] != run["tid"]
+
+
 def test_every_frame_carries_the_same_trace_id():
     net = SimRpcNetwork()
     make_chain(net)

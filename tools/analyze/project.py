@@ -39,6 +39,8 @@ work: a synthetic package in tmp_path analyzes exactly like ``dmlc_tpu``.
 from __future__ import annotations
 
 import ast
+import functools
+from collections import deque
 from dataclasses import dataclass, field
 from pathlib import Path
 
@@ -49,6 +51,7 @@ from tools.lint.rules.locks import _lock_name as lock_display_name
 MAX_DEPTH = 16  # call-graph traversal bound (protects against pathological fan-out)
 
 
+@functools.lru_cache(maxsize=None)  # _unique_class_by_snake asks for every class name, per attribute
 def snake_case(name: str) -> str:
     out: list[str] = []
     for i, ch in enumerate(name):
@@ -123,6 +126,27 @@ _LOCK_CTORS = {
 }
 
 
+def child_nodes(node: ast.AST) -> list:
+    """``ast.iter_child_nodes`` as a list kept on the node: the rules walk
+    the same function bodies thousands of times and the tree never changes
+    after parsing, so the generic field scan is paid once per node."""
+    try:
+        return node._dmlc_children  # type: ignore[attr-defined]
+    except AttributeError:
+        kids = node._dmlc_children = list(ast.iter_child_nodes(node))  # type: ignore[attr-defined]
+        return kids
+
+
+def walk(node: ast.AST):
+    """``ast.walk`` over ``child_nodes``: every node under ``node``, itself
+    included, breadth first as ``ast.walk`` yields them."""
+    todo = deque([node])
+    while todo:
+        node = todo.popleft()
+        todo.extend(child_nodes(node))
+        yield node
+
+
 def iter_calls(stmts):
     """Every ast.Call under ``stmts`` without descending into nested
     function/lambda bodies (they run later — L1's convention)."""
@@ -133,7 +157,7 @@ def iter_calls(stmts):
             continue
         if isinstance(node, ast.Call):
             yield node
-        stack.extend(ast.iter_child_nodes(node))
+        stack.extend(child_nodes(node))
 
 
 def iter_withs(stmts):
@@ -145,7 +169,7 @@ def iter_withs(stmts):
             continue
         if isinstance(node, ast.With):
             yield node
-        stack.extend(ast.iter_child_nodes(node))
+        stack.extend(child_nodes(node))
 
 
 class Project:
@@ -235,7 +259,7 @@ class Project:
         class's own methods."""
         for method in ci.methods.values():
             annos = self._param_annotations(method)
-            for node in ast.walk(method.node):
+            for node in walk(method.node):
                 if isinstance(node, ast.AnnAssign) and self._is_self_attr(node.target):
                     attr = node.target.attr
                     hinted = self._class_from_annotation(node.annotation, ci.module)
@@ -416,7 +440,7 @@ class Project:
         """
         if fd.local_env is None:
             env: dict[str, str] = {}
-            for node in ast.walk(fd.node):
+            for node in walk(fd.node):
                 if not (isinstance(node, ast.Assign) and len(node.targets) == 1
                         and isinstance(node.targets[0], ast.Name)):
                     continue
@@ -451,7 +475,7 @@ class Project:
             return None
         seen.add(fd.qname)
         classes: set[str] = set()
-        for node in ast.walk(fd.node):
+        for node in walk(fd.node):
             if not isinstance(node, ast.Return) or node.value is None:
                 continue
             if isinstance(node.value, ast.Attribute) and self._is_self_attr(node.value) and fd.cls:
@@ -593,7 +617,7 @@ class Project:
         for mod in self.modules.values():
             for fd in self._all_funcs(mod):
                 in_methods_fn = fd.name == "methods"
-                for node in ast.walk(fd.node):
+                for node in walk(fd.node):
                     if isinstance(node, ast.Call):
                         callee = mod.imports.resolve(dotted_name(node.func))
                         is_tm = callee is not None and callee.split(".")[-1] == "traced_methods"
@@ -739,14 +763,14 @@ def _own_returns(fn_node) -> list[ast.Return]:
             if node.value is not None:
                 out.append(node)
             continue
-        stack.extend(ast.iter_child_nodes(node))
+        stack.extend(child_nodes(node))
     return out
 
 
 def nested_defs(fn_node) -> dict[str, ast.FunctionDef]:
     """Name -> def for functions nested (at any depth) inside ``fn_node``."""
     out: dict[str, ast.FunctionDef] = {}
-    for node in ast.walk(fn_node):
+    for node in walk(fn_node):
         if node is fn_node:
             continue
         if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)):
@@ -822,7 +846,7 @@ class DeviceModel:
                 self._jit_kwargs(dec if isinstance(dec, ast.Call) else None, w)
                 self.wrappers.append(w)
                 self._local[(fd.qname, name)] = w
-        for node in ast.walk(fd.node):
+        for node in walk(fd.node):
             if not isinstance(node, ast.Assign) or len(node.targets) != 1:
                 continue
             target, value = node.targets[0], node.value
@@ -1035,7 +1059,7 @@ class DeviceModel:
         if md is not None:
             return md
         if isinstance(expr, ast.Name):
-            for node in ast.walk(ctx.node):
+            for node in walk(ctx.node):
                 if (isinstance(node, ast.Assign) and len(node.targets) == 1
                         and isinstance(node.targets[0], ast.Name)
                         and node.targets[0].id == expr.id):

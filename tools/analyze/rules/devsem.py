@@ -47,6 +47,7 @@ import ast
 
 from tools.analyze.core import Analysis, Finding
 from tools.analyze.project import (
+    walk,
     FuncDef,
     JitWrapper,
     Project,
@@ -62,7 +63,7 @@ _INTERPROC_DEPTH = 6
 # ---- shared AST plumbing -------------------------------------------------
 
 def _contains(stmt, target) -> bool:
-    return any(n is target for n in ast.walk(stmt))
+    return any(n is target for n in walk(stmt))
 
 
 def _sub_bodies(stmt):
@@ -412,21 +413,21 @@ def _loop_vars(ctx: FuncDef, call: ast.Call) -> set[str]:
     out: set[str] = set()
     for _, _, stmt in _stmt_path(ctx.node.body, call):
         if isinstance(stmt, (ast.For, ast.AsyncFor)):
-            for n in ast.walk(stmt.target):
+            for n in walk(stmt.target):
                 if isinstance(n, ast.Name):
                     out.add(n.id)
-    for node in ast.walk(ctx.node):
+    for node in walk(ctx.node):
         if isinstance(node, (ast.ListComp, ast.SetComp, ast.GeneratorExp,
                              ast.DictComp)) and _contains(node, call):
             for gen in node.generators:
-                for n in ast.walk(gen.target):
+                for n in walk(gen.target):
                     if isinstance(n, ast.Name):
                         out.add(n.id)
     return out
 
 
 def _shape_vary_reason(expr, params: set[str], loop_vars: set[str]) -> str | None:
-    for node in ast.walk(expr):
+    for node in walk(expr):
         if isinstance(node, ast.Name) and node.id in loop_vars:
             return f"shape derives from loop variable {node.id!r}"
         if (isinstance(node, ast.Call) and isinstance(node.func, ast.Name)
@@ -514,7 +515,7 @@ class _A6:
                        "unhashable literal at a static_argnums position "
                        "(TypeError at dispatch, or a cache miss per call)")
             return f"s:{_fp(arg)}"
-        for node in ast.walk(arg):
+        for node in walk(arg):
             if isinstance(node, ast.Name) and node.id in loops:
                 self._flag(analysis, w, ctx, call, arg,
                            f"static argument varies with loop variable "
@@ -581,7 +582,7 @@ class _A6:
         }
         if not traced:
             return
-        for node in ast.walk(w.fn_node):
+        for node in walk(w.fn_node):
             tests: list = []
             if isinstance(node, (ast.If, ast.While)):
                 tests.append(node.test)
@@ -688,7 +689,7 @@ class _A7:
     @staticmethod
     def _jit_result_names(ctx: FuncDef, dm) -> set[str]:
         out: set[str] = set()
-        for node in ast.walk(ctx.node):
+        for node in walk(ctx.node):
             if not (isinstance(node, ast.Assign)
                     and isinstance(node.value, ast.Call)):
                 continue
@@ -727,13 +728,13 @@ class _A7:
         forces the device->host sync inside the control decision."""
         if not results:
             return
-        for node in ast.walk(ast.Module(body=list(stmts), type_ignores=[])):
+        for node in walk(ast.Module(body=list(stmts), type_ignores=[])):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef,
                                  ast.Lambda)):
                 continue
             if not isinstance(node, (ast.If, ast.While)):
                 continue
-            for sub in ast.walk(node.test):
+            for sub in walk(node.test):
                 if (isinstance(sub, ast.Subscript)
                         and isinstance(sub.value, ast.Name)
                         and sub.value.id in results):
@@ -785,7 +786,7 @@ class _A8:
         for mod in project.modules.values():
             shard_calls = []
             for fd in project._all_funcs(mod):
-                for node in ast.walk(fd.node):
+                for node in walk(fd.node):
                     if not isinstance(node, ast.Call):
                         continue
                     name = mod.imports.resolve_node(node.func) or ""
@@ -851,7 +852,7 @@ class _A8:
 
     @staticmethod
     def _immediate_call(fd: FuncDef, inner: ast.Call) -> ast.Call | None:
-        for node in ast.walk(fd.node):
+        for node in walk(fd.node):
             if isinstance(node, ast.Call) and node.func is inner:
                 return node
         return None
@@ -886,7 +887,7 @@ class _A8:
         """The single assignment to ``name`` in this function, else None
         (two bindings = not statically certain, stay silent)."""
         found = None
-        for node in ast.walk(fd.node):
+        for node in walk(fd.node):
             if (isinstance(node, ast.Assign) and len(node.targets) == 1
                     and isinstance(node.targets[0], ast.Name)
                     and node.targets[0].id == name):
@@ -1019,7 +1020,7 @@ class _A8:
     def _check_collectives(self, analysis, dm, mod, shard_calls) -> None:
         for fd in self._mod_funcs(mod):
             encl = self._enclosing_defs(fd.node)
-            for node in ast.walk(fd.node):
+            for node in walk(fd.node):
                 if not isinstance(node, ast.Call):
                     continue
                 name = mod.imports.resolve_node(node.func) or ""

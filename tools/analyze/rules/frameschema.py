@@ -33,7 +33,7 @@ import ast
 from dataclasses import dataclass, field
 
 from tools.analyze.core import Analysis, Finding
-from tools.analyze.project import ModuleInfo, iter_calls
+from tools.analyze.project import ModuleInfo, iter_calls, walk
 
 _FRAME_VARS = {"frame", "req", "reply", "err", "request", "response"}
 _PACK, _UNPACK = "_send_frame", "_recv_frame"
@@ -152,7 +152,7 @@ class _A4:
                                       "produce", key, _value_type(v)))
 
         # Pass 1: find tracked frame variables + inline _send_frame dicts.
-        for node in ast.walk(fn):
+        for node in walk(fn):
             if isinstance(node, (ast.FunctionDef, ast.AsyncFunctionDef)) and node is not fn:
                 continue  # nested defs collected via their own FuncDef pass
             if isinstance(node, ast.Call):
@@ -185,7 +185,7 @@ class _A4:
                 elif self._is_unpack(value):
                     tracked.update(names)
         # Pass 2: field accesses/stores on tracked vars.
-        for node in ast.walk(fn):
+        for node in walk(fn):
             if isinstance(node, ast.Subscript) and isinstance(node.value, ast.Name):
                 if node.value.id not in tracked:
                     continue

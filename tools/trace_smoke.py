@@ -11,7 +11,9 @@ asserts the committed contract:
 - spans from >= 2 distinct node lanes (pids) share one trace_id,
 - no child span starts before its parent after alignment,
 - the generate request produced ``gen/step`` spans PARENTED into its
-  ``rpc/job.generate`` trace (docs/GENERATE.md's tracing contract),
+  ``rpc/job.generate`` trace (docs/GENERATE.md's tracing contract), each
+  with its ``gen/step_sync`` child, and a ``gen/wait`` beside every
+  ``gen/prefill`` (the feeding-thread spans, docs/OBSERVABILITY.md §1),
 - the leader's fleet scrape surfaces the device-plane gauges
   (docs/OBSERVABILITY.md §8): compile census with real compiles counted,
   per-model ``mfu_*`` gauges, and the ``hbm_*`` keys (None-valued on CPU,
@@ -252,6 +254,36 @@ def main() -> int:
         print(
             f"trace smoke FAILED: {len(orphans)}/{len(gen_steps)} gen/step "
             "span(s) not parented into a rpc/job.generate trace",
+            file=sys.stderr,
+        )
+        return 1
+    # Feeding-thread contract (docs/OBSERVABILITY.md §1): the blocking read
+    # of every step is a gen/step_sync CHILD of its gen/step, every prefill
+    # has its gen/prefill_sync, and every generate trace that reached a
+    # prefill shows how long the request waited for it (gen/wait).
+    for child, parent in (("gen/step_sync", "gen/step"),
+                          ("gen/prefill_sync", "gen/prefill")):
+        parents = [e for e in events if e["name"] == parent]
+        kids: dict[str, int] = {}
+        for e in events:
+            if e["name"] == child:  # the engine's warm-up syncs too, under no step
+                kids[e["args"].get("parent")] = kids.get(e["args"].get("parent"), 0) + 1
+        childless = [e for e in parents if kids.get(e["args"]["span"]) != 1]
+        if not parents or childless:
+            print(
+                f"trace smoke FAILED: of {len(parents)} {parent} span(s) "
+                f"{len(childless)} lack their one {child} child",
+                file=sys.stderr,
+            )
+            return 1
+    prefill_traces = {e["args"].get("trace") for e in events
+                      if e["name"] == "gen/prefill"}
+    wait_traces = {e["args"].get("trace") for e in events
+                   if e["name"] == "gen/wait"}
+    if not prefill_traces or prefill_traces - wait_traces:
+        print(
+            "trace smoke FAILED: gen/prefill without a gen/wait in the same "
+            f"trace: {sorted(t for t in prefill_traces - wait_traces if t)}",
             file=sys.stderr,
         )
         return 1
