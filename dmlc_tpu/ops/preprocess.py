@@ -21,6 +21,7 @@ from __future__ import annotations
 import concurrent.futures
 import os
 import threading
+import time
 from pathlib import Path
 from typing import Iterable, Sequence
 
@@ -73,14 +74,92 @@ def load_synset_words(path: str | Path) -> list[tuple[str, str]]:
     return out
 
 
+def _first_file(d: str) -> str:
+    """Name of the first regular file (by name) in directory ``d``: one
+    ``scandir`` pass, file type from the dirent, no ``Path`` per entry."""
+    with os.scandir(d) as entries:
+        first = min((e.name for e in entries if e.is_file()), default=None)
+    if first is None:
+        raise FileNotFoundError(f"no images under {d}")
+    return first
+
+
 def class_image_path(data_dir: str | Path, synset: str) -> Path:
     """First image in the per-class fixture directory
-    (reference: src/services.rs:485-490 picks the first dir entry)."""
+    (reference: src/services.rs:485-490 picks the first dir entry).
+
+    The plain definition: it lists the directory at every call and remembers
+    nothing. A serving shard goes through :func:`class_image_paths`."""
     d = Path(data_dir) / synset
-    files = sorted(p for p in d.iterdir() if p.is_file())
-    if not files:
-        raise FileNotFoundError(f"no images under {d}")
-    return files[0]
+    return d / _first_file(str(d))
+
+
+# ---- memoised class-directory lookup ---------------------------------------
+# (data_dir, synset) -> (the class directory's st_mtime_ns when it was
+# listed, its first file). One entry per class directory ever asked for
+# (1,000 for ImageNet), never evicted. A plain dict: reads and writes are
+# atomic under the interpreter lock and two threads that miss on one key
+# write the same value, so the hit path takes no lock.
+_CLASS_PATHS: dict[tuple[str, str], tuple[int, Path]] = {}
+
+#: A directory changed this recently is answered but not remembered: a
+#: second change within the file system's timestamp granule (a scheduler
+#: tick on ext4/tmpfs, 1-2 s on older ones) would leave st_mtime_ns where
+#: it was, and the memo would then serve a stale first file.
+_MTIME_SETTLE_NS = 2_000_000_000
+
+
+def _checked_class_path(root: str, fd: int, synset: str, settled_before: int) -> tuple[Path, bool]:
+    """One class directory's first file, and whether it had to be listed."""
+    try:
+        mtime = os.stat(synset, dir_fd=fd).st_mtime_ns
+    except FileNotFoundError as e:  # name the whole path, as the plain lookup does
+        raise FileNotFoundError(e.errno, e.strerror, os.path.join(root, synset)) from None
+    key = (root, synset)
+    hit = _CLASS_PATHS.get(key)
+    if hit is not None and hit[0] == mtime:
+        return hit[1], False
+    # The stat came BEFORE this listing: a change that lands between the two
+    # leaves the remembered mtime behind the directory's, and the next call
+    # lists again.
+    d = os.path.join(root, synset)
+    path = Path(d, _first_file(d))
+    if mtime < settled_before:
+        _CLASS_PATHS[key] = (mtime, path)
+    else:
+        _CLASS_PATHS.pop(key, None)
+    return path, True
+
+
+def class_image_paths(data_dir: str | Path, synsets: Iterable[str]) -> tuple[list[Path], int]:
+    """``[class_image_path(data_dir, s) for s in synsets]`` and the number
+    of class directories that had to be listed from disk for it.
+
+    A class directory's first file is remembered with the directory's
+    ``st_mtime_ns`` and served again while that stands. Adding, removing or
+    renaming an entry moves the directory's mtime, so the next call lists it
+    again: the answer is ``class_image_path``'s at every call, and a missing
+    or empty directory raises the same ``FileNotFoundError``. A call checks
+    each distinct directory once, by one ``os.stat`` relative to ``data_dir``
+    held open (on a network file system a stat by full path revalidates
+    every component: 90 us against 30). Safe from any number of threads."""
+    root = str(data_dir)
+    settled_before = time.time_ns() - _MTIME_SETTLE_NS  # read before any stat below
+    checked: dict[str, Path] = {}  # this call's answers
+    paths: list[Path] = []
+    misses = 0
+    fd = os.open(root, os.O_RDONLY | os.O_DIRECTORY)
+    try:
+        for synset in synsets:
+            path = checked.get(synset)
+            if path is None:
+                path, listed = _checked_class_path(root, fd, synset, settled_before)
+                checked[synset] = path
+                misses += listed
+            paths.append(path)
+    finally:
+        os.close(fd)
+    return paths, misses
 
 
 def decode_resize(path: str | Path, size: int = 224) -> np.ndarray:

@@ -282,14 +282,26 @@ class DynamicBatcher:
             return out
 
 
-def _resolve_paths(image_source, data_dir: Path, synsets: Sequence[str]) -> list[Path]:
+def _resolve_paths(image_source, data_dir: Path, synsets: Sequence[str],
+                   span=None) -> list[Path]:
     """Synsets -> local image paths: through the SDFS-backed source when
-    wired, else the local fixture-corpus layout (shared by both backends)."""
+    wired, else the local fixture-corpus layout (shared by both backends).
+
+    The local branch is ``pp.class_image_paths``: each class directory's
+    first file is remembered and revalidated by the directory's mtime, so a
+    shard costs one ``os.stat`` per distinct class directory and not a
+    listing per image, and answers what ``pp.class_image_path`` would at
+    every call. ``span`` (the caller's open ``engine/resolve_paths``) is
+    told ``misses``, the class directories this shard had to list from
+    disk; an image source bypasses the memo and sets nothing."""
     from dmlc_tpu.ops import preprocess as pp
 
     if image_source is not None:
         return list(image_source(synsets))
-    return [pp.class_image_path(data_dir, s) for s in synsets]
+    paths, misses = pp.class_image_paths(data_dir, synsets)
+    if span is not None:
+        span.set(misses=misses)
+    return paths
 
 
 class PredictWorker:
@@ -528,8 +540,8 @@ class EngineBackend:
             with tracer.span("engine/run", cpu=True, n=len(synsets),
                              batches=-(-len(synsets) // self.batch_size)):
                 engine = self._ensure_engine()
-                with tracer.span("engine/resolve_paths", cpu=True, n=len(synsets)):
-                    paths = _resolve_paths(self.image_source, self.data_dir, synsets)
+                with tracer.span("engine/resolve_paths", cpu=True, n=len(synsets)) as span:
+                    paths = _resolve_paths(self.image_source, self.data_dir, synsets, span)
                 if len(paths) <= self.batch_size:
                     result = engine.run_paths(paths)
                 else:

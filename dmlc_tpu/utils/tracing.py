@@ -88,10 +88,13 @@ class _NullSpan:
 
     __slots__ = ()
 
-    def __enter__(self) -> None:
-        return None
+    def __enter__(self) -> "_NullSpan":
+        return self
 
     def __exit__(self, *exc: object) -> None:
+        return None
+
+    def set(self, **attrs) -> None:
         return None
 
 
@@ -111,7 +114,7 @@ class _Span:
         self._attrs = attrs
         self._cpu = cpu
 
-    def __enter__(self) -> None:
+    def __enter__(self) -> "_Span":
         parent = tracectx.current()
         if parent is None:
             self._ctx = tracectx.child(sampled=self._tracer._decide_root())
@@ -121,6 +124,11 @@ class _Span:
         if self._cpu:
             self._cpu0 = time.thread_time()
         self._start = time.perf_counter()
+        return self
+
+    def set(self, **attrs) -> None:
+        """Attributes only known once the block has run (a count of what it did)."""
+        self._attrs.update(attrs)
 
     def __exit__(self, exc_type, exc, tb) -> None:
         dur = time.perf_counter() - self._start

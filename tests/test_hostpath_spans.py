@@ -9,6 +9,7 @@ its own.
 """
 
 import contextlib
+import os
 import random
 import re
 import sys
@@ -27,6 +28,7 @@ from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
 from dmlc_tpu.generate.slots import SlotScheduler  # noqa: E402
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 from dmlc_tpu.ops import preprocess as pp  # noqa: E402
+from dmlc_tpu.scheduler import worker as worker_mod  # noqa: E402
 from dmlc_tpu.scheduler.worker import EngineBackend  # noqa: E402
 from dmlc_tpu.utils import corpus  # noqa: E402
 from dmlc_tpu.utils.tracing import Tracer, tracer  # noqa: E402
@@ -98,10 +100,19 @@ def tracing_on():
 # ---------------------------------------------------------------------------
 
 
+def settled_corpus(root):
+    """A corpus at rest: class directories dated an hour back (one changed
+    in the last two seconds is looked up on disk, never remembered)."""
+    data_dir, _ = corpus.generate(root, n_classes=N_IMAGES, images_per_class=1, size=32)
+    then = time.time() - 3600.0
+    for d in data_dir.iterdir():
+        os.utime(d, (then, then))
+    return data_dir
+
+
 @pytest.fixture(scope="module")
 def backend(tmp_path_factory):
-    root = tmp_path_factory.mktemp("hostpath_corpus")
-    data_dir, _ = corpus.generate(root, n_classes=N_IMAGES, images_per_class=1, size=32)
+    data_dir = settled_corpus(tmp_path_factory.mktemp("hostpath_corpus"))
     synsets = sorted(d.name for d in data_dir.iterdir())
     be = EngineBackend("tinynet", data_dir, batch_size=BATCH)
     be.warmup()
@@ -210,6 +221,57 @@ def test_predict_parent_edges(predict_spans):
         waits = [s for s in spans if s["name"] == "ingest/decode_wait"]
         assert len(waits) == N_IMAGES // BATCH
         assert all(isinstance(w["attrs"]["ready"], bool) for w in waits)
+
+
+def test_predict_resolve_paths_counts_its_misses(predict_spans):
+    """engine/resolve_paths opens under engine/run on every shard with n and
+    misses; the fixture's first shard listed every directory, so these two
+    list none."""
+    for spans in predict_spans["by_trace"].values():
+        run = next(s for s in spans if s["name"] == "engine/run")
+        (resolve,) = [s for s in spans if s["name"] == "engine/resolve_paths"]
+        assert resolve["parent"] == run["span"] and resolve["tid"] == run["tid"]
+        assert resolve["attrs"]["n"] == N_IMAGES and resolve["attrs"]["misses"] == 0
+
+
+@pytest.mark.parametrize("shard,misses", [(0, N_IMAGES), (1, 0), (2, 0)])
+def test_resolve_paths_lists_a_directory_once(backend, tracing_on, tmp_path, monkeypatch,
+                                              shard, misses):
+    """Over a corpus not seen before, the first shard lists every class
+    directory (twice-asked synsets once) and later shards list none."""
+    be, synsets = backend
+    monkeypatch.setattr(be, "data_dir", settled_corpus(tmp_path))
+    for _ in range(shard + 1):
+        tracer.reset()
+        with tracer.span("test/shard"):
+            be(synsets + synsets[:BATCH])
+    spans = wire(tracer.events_wire())
+    run = next(s for s in spans if s["name"] == "engine/run")
+    (resolve,) = [s for s in spans if s["name"] == "engine/resolve_paths"]
+    assert resolve["parent"] == run["span"]
+    assert resolve["attrs"]["n"] == N_IMAGES + BATCH and resolve["attrs"]["misses"] == misses
+
+
+def test_resolve_paths_with_an_image_source_never_touches_the_memo(backend, monkeypatch):
+    be, synsets = backend
+    local = pp.class_image_paths(be.data_dir, synsets)[0]
+
+    def boom(*a, **kw):
+        raise AssertionError("the local lookup ran beside an image source")
+
+    monkeypatch.setattr(pp, "class_image_paths", boom)
+    monkeypatch.setattr(pp, "class_image_path", boom)
+    remembered = dict(pp._CLASS_PATHS)
+    asked = []
+
+    def source(names):
+        asked.append(list(names))
+        return iter(local)
+
+    span = tracer.span("engine/resolve_paths")  # the disabled tracer's shared no-op span
+    assert worker_mod._resolve_paths(source, "/nowhere", synsets, span) == local
+    assert worker_mod._resolve_paths(source, "/nowhere", synsets) == local
+    assert asked == [synsets, synsets] and pp._CLASS_PATHS == remembered
 
 
 def test_predict_cpu_time_on_engine_spans(predict_spans):
@@ -467,6 +529,16 @@ def test_cpu_attr_only_when_asked(cpu):
         assert 0.0 <= e["attrs"]["cpu_s"] < 0.04 <= e["dur"]
     else:
         assert "cpu_s" not in e["attrs"]
+
+
+@pytest.mark.parametrize("enabled", (True, False))
+def test_span_set_adds_what_the_block_counted(enabled):
+    t = Tracer()
+    t.enabled = enabled
+    with t.span("x", n=3) as span:
+        span.set(misses=2)
+    events = t.events_wire()
+    assert [e["attrs"] for e in events] == ([{"n": 3, "misses": 2}] if enabled else [])
 
 
 def test_span_records_error_and_reraises():
