@@ -302,7 +302,8 @@ def lowered_step_text(engine) -> str:
 
     return engine._step.lower(
         abstract(engine._variables), abstract(engine._k_state),
-        abstract(engine._v_state), abstract(engine.last_tokens),
+        abstract(engine._v_state), abstract(engine._r_state),
+        abstract(engine.last_tokens),
         abstract(engine.lengths), abstract(engine.active),
         abstract(engine.cache.page_table), abstract(engine.seeds),
         abstract(engine.temps),

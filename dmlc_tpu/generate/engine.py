@@ -31,13 +31,22 @@ to its unkilled reference (docs/GENERATE.md §Migration): re-prefilling
 the identical random sequence at the identical position, so the
 continuation equals the uninterrupted run token for token.
 
-The forward math mirrors ``parallel.sp_transformer.SPTransformerLM``
-parameter-for-parameter (same trees, flax LayerNorm/Dense/gelu semantics,
-dense_attention's f32 score discipline), so decode logits match the full-
-sequence ``lm.apply`` within float tolerance — the paged-KV correctness
-pin. ``cache="contiguous"`` swaps the paged gather for a dense per-slot
-cache with identical math: the parity reference for the paged path, and
-the baseline the 2x continuous-batching pin measures against.
+A model FAMILY owns its math; the engine owns batching, pages, state slots
+and sampling. ``spec.decode_family(dtype)`` hands the engine an object with
+two pure functions over explicit state, ``prefill`` (a padded prompt) and
+``decode`` (one token per slot), the size of its KV (``kv_layers``,
+``kv_heads``, ``head_dim``: only layers that HAVE K/V take pages) and the
+shapes of whatever state it keeps beside the pages (``state_shapes``: a
+state-space layer's conv window and SSM state; none for the GPT-2 family).
+Inside a traced program the family reaches the cache through two calls the
+engine provides, ``kv.write_prefill`` and ``kv.write_attend``; it never sees
+pages, tables or slots. ``models/lm.TransformerFamily`` is
+``SPTransformerLM`` parameter-for-parameter (decode logits match the full-
+sequence ``lm.apply`` within float tolerance: the paged-KV correctness
+pin); ``models/nemotron_h.NemotronHFamily`` is the hybrid stack.
+``cache="contiguous"`` swaps the paged gather for a dense per-slot cache
+with identical math: the parity reference for the paged path, and the
+baseline the 2x continuous-batching pin measures against.
 """
 
 from __future__ import annotations
@@ -46,32 +55,82 @@ from typing import Any
 
 import numpy as np
 
-from dmlc_tpu.generate.kvcache import SCRATCH_PAGE, PagedKVCache
+from dmlc_tpu.generate.kvcache import SCRATCH_PAGE, PagedKVCache, SlotState
 from dmlc_tpu.utils.tracing import tracer
 
 
-# ---------------------------------------------------------------------------
-# flax-parity primitives (pure functions over the module's param tree)
-# ---------------------------------------------------------------------------
+class _StepKV:
+    """The cache as a family's ``decode`` sees it inside the traced step:
+    ``write_attend`` appends this step's K/V at position ``lengths[s]`` and
+    attends over ``lengths[s] + 1`` cached positions."""
+
+    def __init__(self, engine: "GenerationEngine", k_state: Any, v_state: Any,
+                 lengths: Any, active: Any, page_table: Any) -> None:
+        import jax.numpy as jnp
+
+        self.k_state, self.v_state = k_state, v_state
+        self._paged = engine.cache_mode == "paged"
+        self._use_pallas = engine.use_pallas
+        self._lengths, self._page_table = lengths, page_table
+        if self._paged:
+            # Destination of this step's K/V: the page covering position
+            # ``lengths[s]`` — inactive rows write into scratch page 0.
+            page_size = engine.cache.page_size
+            page_idx = jnp.take_along_axis(
+                page_table, (lengths // page_size)[:, None], axis=1
+            )[:, 0]
+            self._dest_page = jnp.where(active, page_idx, SCRATCH_PAGE)
+            self._dest_off = lengths % page_size
+        self._kv_lengths = jnp.maximum(lengths + 1, 1)
+        self._batch = jnp.arange(lengths.shape[0])
+
+    def write_attend(self, layer: int, q: Any, k: Any, v: Any) -> Any:
+        """q: [B, H, Dh]; k, v: [B, KV, Dh] (H a multiple of KV) -> [B, H, Dh]."""
+        from dmlc_tpu.ops.ragged_decode import gather_kv_pages, ragged_decode_attention
+
+        if self._paged:
+            self.k_state = self.k_state.at[layer, self._dest_page, self._dest_off].set(k)
+            self.v_state = self.v_state.at[layer, self._dest_page, self._dest_off].set(v)
+            ks = gather_kv_pages(self.k_state[layer], self._page_table,
+                                 use_pallas=self._use_pallas)
+            vs = gather_kv_pages(self.v_state[layer], self._page_table,
+                                 use_pallas=self._use_pallas)
+        else:
+            self.k_state = self.k_state.at[layer, self._batch, self._lengths].set(k)
+            self.v_state = self.v_state.at[layer, self._batch, self._lengths].set(v)
+            ks, vs = self.k_state[layer], self.v_state[layer]  # [B, S_max, KV, Dh]
+        return ragged_decode_attention(q, ks, vs, self._kv_lengths)
 
 
-def _layer_norm(x: Any, p: Any) -> Any:
-    # flax.linen.LayerNorm semantics: population moments over the last
-    # axis, epsilon 1e-6, learned scale + bias.
-    import jax.numpy as jnp
+class _PrefillKV:
+    """The cache as a family's ``prefill`` sees it: ``write_prefill`` puts
+    the K/V of the real positions into the slot's pages (padding lands on
+    the scratch page)."""
 
-    mean = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
-    return (x - mean) / jnp.sqrt(var + 1e-6) * p["scale"] + p["bias"]
+    def __init__(self, engine: "GenerationEngine", k_state: Any, v_state: Any,
+                 length: Any, dest: Any) -> None:
+        import jax.numpy as jnp
 
+        self.k_state, self.v_state = k_state, v_state
+        self._paged = engine.cache_mode == "paged"
+        self._dest = dest
+        self._s_pad = engine.max_prefill
+        if self._paged:
+            page_size = engine.cache.page_size
+            seq = jnp.arange(self._s_pad)
+            self._dest_page = jnp.where(seq < length, dest[seq // page_size], SCRATCH_PAGE)
+            self._dest_off = seq % page_size
 
-def _dense(x: Any, p: Any) -> Any:
-    return x @ p["kernel"] + p["bias"]
-
-
-def _split_heads(x: Any, num_heads: int) -> Any:
-    # [..., D] -> [..., H, Dh]
-    return x.reshape(*x.shape[:-1], num_heads, x.shape[-1] // num_heads)
+    def write_prefill(self, layer: int, k: Any, v: Any) -> None:
+        """k, v: [S, KV, Dh] of the padded prompt."""
+        if self._paged:
+            self.k_state = self.k_state.at[layer, self._dest_page, self._dest_off].set(k)
+            self.v_state = self.v_state.at[layer, self._dest_page, self._dest_off].set(v)
+        else:
+            # Positions past ``length`` are scratch rows the ragged mask
+            # never exposes; later decode steps overwrite them.
+            self.k_state = self.k_state.at[layer, self._dest, :self._s_pad].set(k)
+            self.v_state = self.v_state.at[layer, self._dest, :self._s_pad].set(v)
 
 
 class GenerationEngine:
@@ -119,7 +178,7 @@ class GenerationEngine:
         self.spec = spec
         self.model_name = spec.name
         self.dtype = dtype if dtype is not None else jnp.float32
-        module = spec.module(dtype=self.dtype)
+        self.family = spec.decode_family(self.dtype)
         if variables is None:
             # Seed init: generation is servable with no published weights,
             # exactly like the predict path before `train`.
@@ -127,12 +186,12 @@ class GenerationEngine:
                 jax.random.PRNGKey(0), dtype=self.dtype, batch_size=1
             )
         self._variables = jax.device_put(variables)
-        self.vocab = int(module.vocab)
-        self.num_layers = int(module.num_layers)
-        self.num_heads = int(module.num_heads)
-        self.hidden = int(module.hidden)
-        self.head_dim = self.hidden // self.num_heads
-        self.max_len = int(module.max_len)
+        self.vocab = int(self.family.vocab)
+        self.max_len = int(self.family.max_len)
+        # Only the model's attention layers have K/V: layers without take no pages.
+        self.kv_layers = int(self.family.kv_layers)
+        self.kv_heads = int(self.family.kv_heads)
+        self.head_dim = int(self.family.head_dim)
         self.max_slots = int(max_slots)
         self.max_prefill = min(int(max_prefill), self.max_len)
         if use_pallas is None:
@@ -144,10 +203,10 @@ class GenerationEngine:
         max_pages_per_slot = -(-self.max_len // int(page_size))
         if cache == "paged":
             self.cache = PagedKVCache(
-                num_layers=self.num_layers,
+                num_layers=self.kv_layers,
                 num_pages=num_pages,
                 page_size=page_size,
-                num_heads=self.num_heads,
+                num_heads=self.kv_heads,
                 head_dim=self.head_dim,
                 max_slots=self.max_slots,
                 max_pages_per_slot=max_pages_per_slot,
@@ -160,11 +219,19 @@ class GenerationEngine:
             self.cache = None
             self.max_tokens = self.max_len
             shape = (
-                self.num_layers, self.max_slots, self.max_tokens,
-                self.num_heads, self.head_dim,
+                self.kv_layers, self.max_slots, self.max_tokens,
+                self.kv_heads, self.head_dim,
             )
             self._k_state = jnp.zeros(shape, self.dtype)
             self._v_state = jnp.zeros(shape, self.dtype)
+        # The second kind of per-slot state (kvcache.SlotState): fixed-size
+        # rows a step rewrites; empty for a family that has none. Donated
+        # through both programs like the pools.
+        self.state = SlotState(self.family.state_shapes(self.max_slots), self.max_slots)
+        # What the last step / prefill did, for the caller's span
+        # (``gen/step`` / ``gen/prefill``): counts fetched with the tokens.
+        self.step_attrs: dict[str, Any] = {}
+        self.prefill_attrs: dict[str, Any] = {}
 
         # Host-side slot registers (fixed batch shape).
         self.lengths = np.zeros(self.max_slots, np.int32)
@@ -193,131 +260,45 @@ class GenerationEngine:
             f"gen/{self.model_name}/prefill", self._build_prefill()
         )
 
-    # ---- forward math ---------------------------------------------------
-
-    def _params(self, variables: Any) -> Any:
-        return variables["params"]
-
-    def _attend(self, q: Any, k_state: Any, v_state: Any, layer: int,
-                page_table: Any, kv_lengths: Any, slots: Any = None) -> Any:
-        """Per-layer decode attention: paged gather + ragged mask, or the
-        contiguous per-slot view. q: [B, H, Dh] -> [B, H, Dh]."""
-        from dmlc_tpu.ops.ragged_decode import (
-            gather_kv_pages,
-            ragged_decode_attention,
-        )
-
-        if self.cache_mode == "paged":
-            k = gather_kv_pages(k_state[layer], page_table, use_pallas=self.use_pallas)
-            v = gather_kv_pages(v_state[layer], page_table, use_pallas=self.use_pallas)
-        else:
-            k, v = k_state[layer], v_state[layer]  # [B, S_max, H, Dh]
-        return ragged_decode_attention(q, k, v, kv_lengths)
+    # ---- the two programs ------------------------------------------------
 
     def _build_step(self) -> Any:
         import jax
-        import jax.numpy as jnp
 
-        num_heads = self.num_heads
-        page_size = self.cache.page_size if self.cache_mode == "paged" else 0
-        num_layers = self.num_layers
+        family = self.family
         return_logits = self.return_logits
 
-        def step(variables: Any, k_state: Any, v_state: Any, tokens: Any,
-                 lengths: Any, active: Any, page_table: Any, seeds: Any,
-                 temps: Any) -> Any:
-            p = self._params(variables)
-            pos = jnp.minimum(lengths, self.max_len - 1)
-            x = p["embed"]["embedding"][tokens] + p["pos_embed"]["embedding"][pos]
-            x = x.astype(self.dtype)
-            if self.cache_mode == "paged":
-                # Destination of this step's K/V: the page covering position
-                # ``lengths[s]`` — inactive rows write into scratch page 0.
-                page_idx = jnp.take_along_axis(
-                    page_table, (lengths // page_size)[:, None], axis=1
-                )[:, 0]
-                dest_page = jnp.where(active, page_idx, SCRATCH_PAGE)
-                dest_off = lengths % page_size
-            kv_lengths = jnp.maximum(lengths + 1, 1)
-            batch = jnp.arange(tokens.shape[0])
-            for layer in range(num_layers):
-                blk = p[f"block{layer}"]
-                h = _layer_norm(x, blk["ln1"])
-                q = _split_heads(_dense(h, blk["attn"]["query"]), num_heads)
-                k = _split_heads(_dense(h, blk["attn"]["key"]), num_heads)
-                v = _split_heads(_dense(h, blk["attn"]["value"]), num_heads)
-                if self.cache_mode == "paged":
-                    k_state = k_state.at[layer, dest_page, dest_off].set(k)
-                    v_state = v_state.at[layer, dest_page, dest_off].set(v)
-                else:
-                    k_state = k_state.at[layer, batch, lengths].set(k)
-                    v_state = v_state.at[layer, batch, lengths].set(v)
-                att = self._attend(q, k_state, v_state, layer, page_table, kv_lengths)
-                x = x + _dense(att.reshape(att.shape[0], -1), blk["attn"]["out"])
-                h2 = _layer_norm(x, blk["ln2"])
-                h2 = jax.nn.gelu(_dense(h2, blk["mlp_in"]))
-                x = x + _dense(h2, blk["mlp_out"])
-            x = _layer_norm(x, p["ln_f"])
-            logits = _dense(x, p["head"]).astype(jnp.float32)  # [B, V]
+        def step(variables: Any, k_state: Any, v_state: Any, r_state: Any,
+                 tokens: Any, lengths: Any, active: Any, page_table: Any,
+                 seeds: Any, temps: Any) -> Any:
+            kv = _StepKV(self, k_state, v_state, lengths, active, page_table)
+            logits, r_state, aux = family.decode(
+                variables["params"], tokens, lengths, active, kv, r_state)
             # The token sampled here lands at sequence position ``lengths``
             # (pre-increment) — the position the key must be folded on.
             nxt = _sample(logits, seeds, lengths, temps)
             if return_logits:
-                return k_state, v_state, nxt, logits
-            return k_state, v_state, nxt
+                return kv.k_state, kv.v_state, r_state, nxt, aux, logits
+            return kv.k_state, kv.v_state, r_state, nxt, aux
 
-        return jax.jit(step, donate_argnums=(1, 2))
+        return jax.jit(step, donate_argnums=(1, 2, 3))
 
     def _build_prefill(self) -> Any:
         import jax
         import jax.numpy as jnp
 
-        from dmlc_tpu.parallel.ring_attention import dense_attention
-
-        num_heads = self.num_heads
-        num_layers = self.num_layers
-        page_size = self.cache.page_size if self.cache_mode == "paged" else 0
-        s_pad = self.max_prefill
+        family = self.family
 
         def prefill(variables: Any, tokens: Any, length: Any, k_state: Any,
-                    v_state: Any, dest: Any, seed: Any, temp: Any) -> Any:
+                    v_state: Any, r_state: Any, dest: Any, slot: Any, seed: Any,
+                    temp: Any) -> Any:
             """tokens: [1, s_pad]; length: [] int32 (real prompt length);
             dest: page row [max_pages_per_slot] (paged) or slot index []
-            (contiguous)."""
-            p = self._params(variables)
-            x = p["embed"]["embedding"][tokens] + p["pos_embed"]["embedding"][
-                jnp.arange(s_pad)
-            ][None, :]
-            x = x.astype(self.dtype)
-            seq = jnp.arange(s_pad)
-            if self.cache_mode == "paged":
-                dest_page = jnp.where(seq < length, dest[seq // page_size], SCRATCH_PAGE)
-                dest_off = seq % page_size
-            for layer in range(num_layers):
-                blk = p[f"block{layer}"]
-                h = _layer_norm(x, blk["ln1"])
-                q = _split_heads(_dense(h, blk["attn"]["query"]), num_heads)
-                k = _split_heads(_dense(h, blk["attn"]["key"]), num_heads)
-                v = _split_heads(_dense(h, blk["attn"]["value"]), num_heads)
-                if self.cache_mode == "paged":
-                    k_state = k_state.at[layer, dest_page, dest_off].set(k[0])
-                    v_state = v_state.at[layer, dest_page, dest_off].set(v[0])
-                else:
-                    # Positions past ``length`` are scratch rows the ragged
-                    # mask never exposes; later decode steps overwrite them.
-                    k_state = k_state.at[layer, dest, :s_pad].set(k[0])
-                    v_state = v_state.at[layer, dest, :s_pad].set(v[0])
-                qh = q.transpose(0, 2, 1, 3)  # [1, H, S, Dh]
-                att = dense_attention(
-                    qh, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), causal=True
-                ).transpose(0, 2, 1, 3)
-                x = x + _dense(att.reshape(1, s_pad, -1), blk["attn"]["out"])
-                h2 = _layer_norm(x, blk["ln2"])
-                h2 = jax.nn.gelu(_dense(h2, blk["mlp_in"]))
-                x = x + _dense(h2, blk["mlp_out"])
-            x = _layer_norm(x, p["ln_f"])
-            logits = _dense(x, p["head"]).astype(jnp.float32)  # [1, S, V]
-            last = jnp.take(logits[0], length - 1, axis=0)     # [V]
+            (contiguous); slot: [] int32, the row of the recurrent state
+            this prompt overwrites."""
+            kv = _PrefillKV(self, k_state, v_state, length, dest)
+            last, r_state, aux = family.prefill(
+                variables["params"], tokens, length, slot, kv, r_state)
             # First sampled token comes from position ``length - 1`` — the
             # same position a resumed prefill of prompt+prefix re-samples.
             nxt = _sample(
@@ -326,9 +307,9 @@ class GenerationEngine:
                 jnp.reshape(length - 1, (1,)),
                 temp[None],
             )[0]
-            return k_state, v_state, nxt, last
+            return kv.k_state, kv.v_state, r_state, nxt, last, aux
 
-        return jax.jit(prefill, donate_argnums=(3, 4))
+        return jax.jit(prefill, donate_argnums=(3, 4, 5))
 
     # ---- admission (thread-safe) ----------------------------------------
 
@@ -353,7 +334,8 @@ class GenerationEngine:
         kernel the compiler refuses (or an OOM) fails here, at node start,
         not inside the first request. Call before the engine serves. Leaves
         no trace: slot, pages and counters are as before (the K/V it wrote
-        sits in freed pages, which the next prefill overwrites), and the
+        sits in freed pages and the recurrent state in a free slot's rows,
+        both of which the next prefill overwrites), and the
         compile-inflated step is kept out of the MFU window."""
         if self.active.any():
             raise RuntimeError("warmup on an engine that is already serving")
@@ -378,8 +360,6 @@ class GenerationEngine:
         ``seed`` keys the position-seeded sampling RNG; passing the same
         seed with ``prompt + delivered_prefix`` resumes a migrated stream
         token-identically (module docstring)."""
-        import jax.numpy as jnp
-
         prompt = np.asarray(prompt, np.int32)
         if prompt.ndim != 1 or prompt.size == 0:
             raise ValueError("prompt must be a non-empty 1-D token sequence")
@@ -394,30 +374,34 @@ class GenerationEngine:
             if pages is None:
                 pages = self.reserve(prompt.size)
             self.cache.bind(slot, pages)
-            dest = jnp.asarray(self.cache.page_table[slot], jnp.int32)
+            dest = np.asarray(self.cache.page_table[slot], np.int32)
         else:
-            dest = jnp.int32(slot)
+            dest = np.int32(slot)
         padded = np.zeros(self.max_prefill, np.int32)
         padded[: prompt.size] = prompt
         if seed is None:
             seed = (self._base_seed * 1_000_003 + self._joins) % (1 << 31)
         self._joins += 1
         seed = int(seed) & 0xFFFFFFFF
-        k_state, v_state, nxt, last = self._prefill(
+        k_state, v_state, r_state, nxt, last, aux = self._prefill(
             self._variables,
-            jnp.asarray(padded[None]),
-            jnp.int32(prompt.size),
+            padded[None],
+            np.int32(prompt.size),
             self._k_state,
             self._v_state,
+            self._r_state,
             dest,
-            jnp.uint32(seed),
-            jnp.float32(temperature),
+            np.int32(slot),
+            np.uint32(seed),
+            np.float32(temperature),
         )
-        self._set_state(k_state, v_state)
+        self._set_state(k_state, v_state, r_state)
         # The one call of join that blocks on the device; what is left of
         # the caller's gen/prefill span is the host's part.
         with tracer.span("gen/prefill_sync", cpu=True):
             first = int(nxt)
+            aux = {name: np.asarray(a) for name, a in aux.items()}
+        self.prefill_attrs = self.family.work_attrs(aux, int(prompt.size))
         self.lengths[slot] = prompt.size
         self.active[slot] = True
         self.temps[slot] = float(temperature)
@@ -442,38 +426,39 @@ class GenerationEngine:
         active rows meaningful). Host state advances for active slots."""
         import time
 
-        import jax.numpy as jnp
-
         t0 = time.perf_counter()
         table = (
-            jnp.asarray(self.cache.page_table)
+            self.cache.page_table
             if self.cache_mode == "paged"
-            else jnp.zeros((self.max_slots, 1), jnp.int32)
+            else np.zeros((self.max_slots, 1), np.int32)
         )
         out = self._step(
             self._variables,
             self._k_state,
             self._v_state,
-            jnp.asarray(self.last_tokens),
-            jnp.asarray(self.lengths),
-            jnp.asarray(self.active),
+            self._r_state,
+            self.last_tokens,
+            self.lengths,
+            self.active,
             table,
-            jnp.asarray(self.seeds),
-            jnp.asarray(self.temps),
+            self.seeds,
+            self.temps,
         )
-        if self.return_logits:
-            k_state, v_state, nxt, logits = out
-        else:
-            k_state, v_state, nxt = out
-        self._set_state(k_state, v_state)
+        k_state, v_state, r_state, nxt, aux = out[:5]
+        self._set_state(k_state, v_state, r_state)
         # The one place step blocks on the device; what is left of the
         # caller's gen/step span is the host's part (uploads, dispatch,
         # bookkeeping).
         with tracer.span("gen/step_sync", cpu=True):
             if self.return_logits:
-                self.last_logits = np.asarray(logits)
+                self.last_logits = np.asarray(out[5])
             tokens = np.asarray(nxt)
+            aux = {name: np.asarray(a) for name, a in aux.items()}
         n_active = int(self.active.sum())
+        self.step_attrs = self.family.work_attrs(aux, n_active)
+        if self.state.nbytes:
+            self.step_attrs.update(
+                state_slots=n_active, state_bytes=n_active * self.state.bytes_per_slot)
         self.lengths[self.active] += 1
         self.last_tokens[self.active] = tokens[self.active]
         self.steps += 1
@@ -486,7 +471,8 @@ class GenerationEngine:
 
     def release(self, slot: int) -> list[int]:
         """Slot exit: recycle its pages, reset its registers. Returns the
-        freed page ids."""
+        freed page ids. The slot's recurrent state stays where it is, with
+        no device work: the next ``join`` of this slot overwrites it whole."""
         self.active[slot] = False
         self.lengths[slot] = 0
         self.temps[slot] = 0.0
@@ -496,9 +482,10 @@ class GenerationEngine:
             return self.cache.release(slot)
         return []
 
-    def _set_state(self, k_state: Any, v_state: Any) -> None:
+    def _set_state(self, k_state: Any, v_state: Any, r_state: Any) -> None:
         self._k_state = k_state
         self._v_state = v_state
+        self.state.arrays = r_state
         if self.cache_mode == "paged":
             self.cache.k_pages = k_state
             self.cache.v_pages = v_state
@@ -510,19 +497,30 @@ class GenerationEngine:
         return int(self.active.sum())
 
     @property
+    def _r_state(self) -> Any:
+        return self.state.arrays
+
+    @property
+    def state_bytes_active(self) -> int:
+        """Recurrent state held by the active slots (0 for a family with none)."""
+        return self.slots_active * self.state.bytes_per_slot
+
+    @property
     def pages_free(self) -> int:
         return self.cache.pages_free if self.cache_mode == "paged" else 0
 
     def resident_bytes(self) -> int:
         """Analytic device residency of this engine: weights pytree + both
-        KV pools (paged or contiguous) — the per-model attribution behind
-        the ``resident_bytes_<model>`` gauge (docs/OBSERVABILITY.md §8)."""
+        KV pools (paged or contiguous) + the recurrent state of every slot —
+        the per-model attribution behind the ``resident_bytes_<model>``
+        gauge (docs/OBSERVABILITY.md §8)."""
         from dmlc_tpu.cluster.devicemon import pytree_nbytes
 
         return (
             pytree_nbytes(self._variables)
             + pytree_nbytes(self._k_state)
             + pytree_nbytes(self._v_state)
+            + self.state.nbytes
         )
 
     def jit_cache_sizes(self) -> dict[str, int]:

@@ -24,6 +24,11 @@ Layout (docs/GENERATE.md):
   Released rows are reset to scratch so a stale table can never reach a
   recycled page.
 
+Beside the pages a model may carry a second kind of per-slot state
+(``SlotState``): arrays of FIXED size per slot that every step rewrites, such
+as a state-space layer's conv window and SSM state. It takes no pages and
+needs no allocator: slot ``s`` owns row ``s`` of every array.
+
 The allocator is a plain LIFO free list under a lock: page exhaustion
 raises the typed ``PagePoolExhausted``, which the slot scheduler converts
 into a typed ``Overloaded`` shed at admission (docs/OVERLOAD.md) — the
@@ -218,3 +223,23 @@ class PagedKVCache:
     @property
     def pages_free(self) -> int:
         return self.allocator.pages_free
+
+
+class SlotState:
+    """Slot-indexed recurrent state beside the pages: a pytree of device
+    arrays whose leading axis is the slot (one array per layer that has
+    such state, so a step updates each in place). ``join`` overwrites a
+    slot's rows whole, so ``release`` does no device work and a reused slot
+    cannot see its predecessor. Built once per engine, like the pools."""
+
+    def __init__(self, shapes: Any, max_slots: int) -> None:
+        import jax
+        import jax.numpy as jnp
+
+        is_leaf = lambda x: isinstance(x, tuple) and len(x) == 2 and isinstance(x[0], tuple)
+        self.max_slots = int(max_slots)
+        self.arrays = jax.tree_util.tree_map(
+            lambda sd: jnp.zeros(sd[0], sd[1]), shapes, is_leaf=is_leaf)
+        leaves = jax.tree_util.tree_leaves(self.arrays)
+        self.nbytes = sum(int(a.size) * a.dtype.itemsize for a in leaves)
+        self.bytes_per_slot = self.nbytes // self.max_slots if leaves else 0

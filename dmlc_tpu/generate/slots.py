@@ -279,6 +279,9 @@ class SlotScheduler:
             registry.gauge(f"{name}_slots_active", lambda: self.engine.slots_active)
             registry.gauge(f"{name}_pages_free", lambda: self.engine.pages_free)
             registry.gauge(f"{name}_tok_s", self.tok_s)
+            if engine.state.nbytes:
+                registry.gauge(f"gen_state_bytes_{engine.model_name}",
+                               lambda: self.engine.state_bytes_active)
         self._thread = threading.Thread(
             target=self._loop, name=f"gen-{name}", daemon=True
         )
@@ -476,12 +479,14 @@ class SlotScheduler:
                     if req.wait_t0 is not None:
                         tracer.record("gen/wait", max(0.0, tracer.now() - req.wait_t0))
                     with tracer.span("gen/prefill", cpu=True, slot=req.slot,
-                                     prompt=len(req.prompt)):
+                                     prompt=len(req.prompt),
+                                     prompt_tokens=len(req.prompt)) as span:
                         first = self.engine.join(
                             req.slot, req.prompt,
                             temperature=req.temperature, pages=req.pages,
                             seed=req.seed,
                         )
+                        self._count_work(span, self.engine.prefill_attrs)
             except Exception as e:
                 # A bad request (or a prefill failure) fails ITS stream,
                 # never the resident batch. Pages go back wherever they
@@ -604,8 +609,9 @@ class SlotScheduler:
                 len(r.prompt) + r.emitted - 1 for r in self._resident)
         t0 = self.clock()
         with tracectx.bind(oldest.trace_ctx):
-            with tracer.span("gen/step", cpu=True, **attrs):
+            with tracer.span("gen/step", cpu=True, **attrs) as span:
                 tokens = self.engine.step()
+                self._count_work(span, self.engine.step_attrs)
         elapsed = max(0.0, self.clock() - t0)
         with tracer.span("gen/deliver", cpu=True):
             self.step_stats.record(elapsed)
@@ -616,6 +622,18 @@ class SlotScheduler:
                 self._deliver(req, tok)
                 if req.eos_id is not None and tok == req.eos_id:
                     self._exit(req, "eos")
+
+    def _count_work(self, span: Any, attrs: dict) -> None:
+        """What the engine says its last program run did (expert pairs,
+        recurrent state; nothing for a model that has neither): onto the
+        caller's span, and the pairs into the node's counters."""
+        if not attrs:
+            return
+        span.set(**attrs)
+        if self.metrics is not None and "expert_pairs" in attrs:
+            model = self.engine.model_name
+            self.metrics.inc(f"gen_expert_pairs_{model}", attrs["expert_pairs"])
+            self.metrics.inc(f"gen_expert_pairs_absent_{model}", attrs["expert_pairs_absent"])
 
     def _retire(self) -> None:
         for req in list(self._resident):

@@ -67,3 +67,109 @@ def lm_small(dtype=jnp.float32):
 
 LM_SMALL_VOCAB = 1024
 LM_SMALL_MAX_LEN = 256
+
+
+# ---------------------------------------------------------------------------
+# The GPT-2 family behind the generation engine's seam
+# ---------------------------------------------------------------------------
+#
+# ``generate/engine.py`` owns batching, pages, slots and sampling; a model
+# family owns its math as two pure functions over explicit state, *prefill
+# over a padded prompt* and *one decode step*. This is ``SPTransformerLM``'s
+# math parameter-for-parameter (same trees, flax LayerNorm/Dense/gelu
+# semantics, dense_attention's f32 score discipline), so decode logits match
+# the full-sequence ``lm.apply`` within float tolerance.
+
+
+def _layer_norm(x, p):
+    # flax.linen.LayerNorm semantics: population moments over the last
+    # axis, epsilon 1e-6, learned scale + bias.
+    mean = jnp.mean(x, axis=-1, keepdims=True)
+    var = jnp.mean(jnp.square(x - mean), axis=-1, keepdims=True)
+    return (x - mean) / jnp.sqrt(var + 1e-6) * p["scale"] + p["bias"]
+
+
+def _dense(x, p):
+    return x @ p["kernel"] + p["bias"]
+
+
+def _split_heads(x, num_heads: int):
+    # [..., D] -> [..., H, Dh]
+    return x.reshape(*x.shape[:-1], num_heads, x.shape[-1] // num_heads)
+
+
+class TransformerFamily:
+    """``SPTransformerLM`` (learned positions, MHA, LayerNorm, GELU) as the
+    engine sees it: every layer has K/V, and there is no state beside the
+    pages."""
+
+    def __init__(self, module, dtype) -> None:
+        self.dtype = dtype
+        self.vocab = int(module.vocab)
+        self.max_len = int(module.max_len)
+        self.num_layers = int(module.num_layers)
+        self.num_heads = int(module.num_heads)
+        self.kv_layers = self.num_layers
+        self.kv_heads = self.num_heads
+        self.head_dim = int(module.hidden) // self.num_heads
+
+    def state_shapes(self, max_slots: int) -> dict:
+        return {}
+
+    def work_attrs(self, aux: dict, rows: int) -> dict:
+        return {}
+
+    def prefill(self, params, tokens, length, slot, kv, state):
+        """tokens [1, S] padded at the end -> (logits at ``length - 1`` [V]
+        float32, state, aux). Exact because padding sits at the END under a
+        causal mask: no real position can attend to it."""
+        import jax
+
+        from dmlc_tpu.parallel.ring_attention import dense_attention
+
+        del slot
+        s_pad = tokens.shape[1]
+        x = params["embed"]["embedding"][tokens] + params["pos_embed"]["embedding"][
+            jnp.arange(s_pad)
+        ][None, :]
+        x = x.astype(self.dtype)
+        for layer in range(self.num_layers):
+            blk = params[f"block{layer}"]
+            h = _layer_norm(x, blk["ln1"])
+            q = _split_heads(_dense(h, blk["attn"]["query"]), self.num_heads)
+            k = _split_heads(_dense(h, blk["attn"]["key"]), self.num_heads)
+            v = _split_heads(_dense(h, blk["attn"]["value"]), self.num_heads)
+            kv.write_prefill(layer, k[0], v[0])
+            qh = q.transpose(0, 2, 1, 3)  # [1, H, S, Dh]
+            att = dense_attention(
+                qh, k.transpose(0, 2, 1, 3), v.transpose(0, 2, 1, 3), causal=True
+            ).transpose(0, 2, 1, 3)
+            x = x + _dense(att.reshape(1, s_pad, -1), blk["attn"]["out"])
+            h2 = _layer_norm(x, blk["ln2"])
+            h2 = jax.nn.gelu(_dense(h2, blk["mlp_in"]))
+            x = x + _dense(h2, blk["mlp_out"])
+        x = _layer_norm(x, params["ln_f"])
+        logits = _dense(x, params["head"]).astype(jnp.float32)  # [1, S, V]
+        return jnp.take(logits[0], length - 1, axis=0), state, {}
+
+    def decode(self, params, tokens, lengths, active, kv, state):
+        """tokens [B] -> (logits [B, V] float32, state, aux)."""
+        import jax
+
+        del active
+        pos = jnp.minimum(lengths, self.max_len - 1)
+        x = params["embed"]["embedding"][tokens] + params["pos_embed"]["embedding"][pos]
+        x = x.astype(self.dtype)
+        for layer in range(self.num_layers):
+            blk = params[f"block{layer}"]
+            h = _layer_norm(x, blk["ln1"])
+            q = _split_heads(_dense(h, blk["attn"]["query"]), self.num_heads)
+            k = _split_heads(_dense(h, blk["attn"]["key"]), self.num_heads)
+            v = _split_heads(_dense(h, blk["attn"]["value"]), self.num_heads)
+            att = kv.write_attend(layer, q, k, v)
+            x = x + _dense(att.reshape(att.shape[0], -1), blk["attn"]["out"])
+            h2 = _layer_norm(x, blk["ln2"])
+            h2 = jax.nn.gelu(_dense(h2, blk["mlp_in"]))
+            x = x + _dense(h2, blk["mlp_out"])
+        x = _layer_norm(x, params["ln_f"])
+        return _dense(x, params["head"]).astype(jnp.float32), state, {}

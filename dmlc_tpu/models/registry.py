@@ -50,6 +50,10 @@ class ModelSpec:
     # None => fully replicated (the CNN families). num_heads bounds tp.
     partition_rules: tuple[tuple[str, Any], ...] | None = None
     num_heads: int | None = None
+    # kind="lm": (dtype) -> the family object the generation engine serves
+    # through (prefill + decode over explicit state). None => the GPT-2
+    # family over ``build``'s SPTransformerLM.
+    family: Callable[[Any], Any] | None = None
 
     def module(self, dtype=jnp.bfloat16):
         if self.kind == "lm":
@@ -57,6 +61,14 @@ class ModelSpec:
         if self.classifier:
             return self.build(num_classes=self.num_outputs, dtype=dtype)
         return self.build(dtype=dtype)
+
+    def decode_family(self, dtype: Any) -> Any:
+        """The model's math as the generation engine calls it."""
+        if self.family is not None:
+            return self.family(dtype)
+        from dmlc_tpu.models.lm import TransformerFamily
+
+        return TransformerFamily(self.module(dtype=dtype), dtype)
 
     def init_params(self, rng, dtype=jnp.bfloat16, batch_size: int = 1):
         model = self.module(dtype=dtype)
@@ -270,3 +282,10 @@ for _spec in [
     ),
 ]:
     register(_spec)
+
+# The Nemotron-H family's CPU-test preset (Mamba-2 + GQA + LatentMoE layers
+# in one stack); real sizes are registered by whoever serves them
+# (``models/nemotron_h.register_nemotron_h``).
+from dmlc_tpu.models.nemotron_h import NEMOTRON_H_TINY, register_nemotron_h  # noqa: E402
+
+register_nemotron_h("nemotron_h_tiny", NEMOTRON_H_TINY)

@@ -90,7 +90,8 @@ def ragged_decode_attention(q, k, v, kv_lengths, *, scale: float | None = None):
     """One decode step of attention over ragged per-slot lengths.
 
     ``q``: [B, H, Dh] (the single new position per slot); ``k``/``v``:
-    [B, S_max, H, Dh] padded cache views; ``kv_lengths``: int32 [B] — slot
+    [B, S_max, KV, Dh] padded cache views (KV = H, or a divisor of H for
+    grouped-query attention); ``kv_lengths``: int32 [B] — slot
     b attends positions [0, kv_lengths[b]). Scores and softmax run in f32
     (dense_attention's discipline); output is cast back to q's dtype.
     Callers guarantee kv_lengths >= 1 for every row (inactive slots carry a
@@ -99,6 +100,9 @@ def ragged_decode_attention(q, k, v, kv_lengths, *, scale: float | None = None):
     if scale is None:
         scale = q.shape[-1] ** -0.5
     s_max = k.shape[1]
+    heads, kv_heads = q.shape[1], k.shape[2]
+    if heads != kv_heads:
+        return _grouped_decode_attention(q, k, v, kv_lengths, scale)
     scores = jnp.einsum(
         "bhd,bshd->bhs",
         q.astype(jnp.float32) * scale,
@@ -109,6 +113,21 @@ def ragged_decode_attention(q, k, v, kv_lengths, *, scale: float | None = None):
     probs = jax.nn.softmax(scores, axis=-1)
     out = jnp.einsum("bhs,bshd->bhd", probs, v.astype(jnp.float32))
     return out.astype(q.dtype)
+
+
+def _grouped_decode_attention(q, k, v, kv_lengths, scale):
+    """Grouped-query form: ``q`` [B, H, Dh] against ``k``/``v`` [B, S, KV, Dh]
+    with H a multiple of KV; query head ``i`` reads KV head ``i // (H / KV)``.
+    The query heads are folded onto their KV head, so K and V are read once
+    and never copied H / KV times."""
+    b, heads, dh = q.shape
+    kv_heads = k.shape[2]
+    qg = (q.astype(jnp.float32) * scale).reshape(b, kv_heads, heads // kv_heads, dh)
+    scores = jnp.einsum("bkgd,bskd->bkgs", qg, k.astype(jnp.float32))
+    mask = jnp.arange(k.shape[1])[None, None, None, :] < kv_lengths[:, None, None, None]
+    probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
+    return out.reshape(b, heads, dh).astype(q.dtype)
 
 
 def paged_decode_attention(
