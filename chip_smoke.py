@@ -13,7 +13,10 @@ main path once, through the entry points a user would call:
 - **generate**: the same node's ``lm_small`` generation plane at the config
   defaults: overlapping ``leader.generate`` calls through GenRouter ->
   GenerateWorker -> SlotScheduler -> GenerationEngine, greedy tokens checked
-  against a ``cache="contiguous", use_pallas=False`` engine.
+  against a ``cache="contiguous", use_pallas=False`` engine; then the step
+  and the prefill compiled at gpt2-large's cache geometry from abstract
+  arguments, which must alias both KV pools and keep their temporaries under
+  the size of one pool (``pool_memory``).
 - **kernels**: every ``pl.pallas_call`` site compiled (never interpreted)
   once at a serving/training shape and checked against its XLA reference.
 - **multichip**: on a host with several chips, the engine's dp mesh, per-
@@ -290,24 +293,109 @@ def generate_phase(node, *, model: str, prompts, max_new) -> dict:
     }
 
 
-def lowered_step_text(engine) -> str:
-    """StableHLO of the engine's decode-step program, lowered from abstract
-    arguments (no live buffer is touched — the pools are donated)."""
+def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -> dict:
+    """``{"step": args, "prefill": args}``: the shapes of what the engine
+    hands its two programs (no live buffer is touched — the pools are
+    donated). ``variables`` and ``pool`` stand in where the engine was built
+    without weights or over a smaller pool than the one to compile for;
+    ``sharding`` places every argument (a described device, off the chip)."""
     import jax
     import numpy as np
 
     def abstract(tree):
         return jax.tree_util.tree_map(
-            lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype), tree)
+            lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype, sharding=sharding), tree)
 
-    return engine._step.lower(
-        abstract(engine._variables), abstract(engine._k_state),
-        abstract(engine._v_state), abstract(engine._r_state),
-        abstract(engine.last_tokens),
-        abstract(engine.lengths), abstract(engine.active),
-        abstract(engine.cache.page_table), abstract(engine.seeds),
-        abstract(engine.temps),
-    ).as_text()
+    def scalar(dtype):
+        return jax.ShapeDtypeStruct((), dtype, sharding=sharding)
+
+    variables = abstract(engine._variables if variables is None else variables)
+    k_state = abstract(engine._k_state if pool is None else pool)
+    v_state = abstract(engine._v_state if pool is None else pool)
+    r_state, table = abstract(engine._r_state), engine.cache.page_table
+    return {
+        "step": (variables, k_state, v_state, r_state, abstract(engine.last_tokens),
+                 abstract(engine.lengths), abstract(engine.active), abstract(table),
+                 abstract(engine.seeds), abstract(engine.temps)),
+        "prefill": (variables, abstract(np.zeros((1, engine.max_prefill), np.int32)),
+                    scalar(np.int32), k_state, v_state, r_state, abstract(table[0]),
+                    scalar(np.int32), scalar(np.uint32), scalar(np.float32)),
+    }
+
+
+def lowered_step_text(engine) -> str:
+    """StableHLO of the engine's decode-step program."""
+    return engine._step.lower(*abstract_program_args(engine)["step"]).as_text()
+
+
+#: gpt2-large's decoder and cache as its benchmark cell runs them
+#: (benchmark/configs/gpt2-large.json): 36 layers of 20 heads x 64, 1,024
+#: pages of 16 tokens, 24 slots, prompts padded to 640.
+POOL_GEOMETRY = {
+    "layers": 36, "heads": 20, "hidden": 1280, "vocab": 50257, "max_len": 1024,
+    "max_slots": 24, "page_size": 16, "num_pages": 1024, "max_prefill": 640,
+}
+
+
+def pool_memory(geometry: dict, *, sharding=None, use_pallas: bool | None = None) -> dict:
+    """Compile the generation engine's step and prefill at ``geometry`` from
+    abstract arguments (no weight is drawn and no pool of that size is
+    allocated: the programs take the pool's size from the pool they are
+    handed) and read each program's ``memory_analysis()``. Both must alias
+    the two donated pools to their outputs and keep temporaries under the
+    size of ONE pool: a layout the compiler re-lays around the writes shows
+    as temporaries of several pools (PERF.md, PR 24 finding 2). A check of
+    the chip's compiler (or of a described chip's, tests/test_tpu_compile.py):
+    the CPU backend widens a bfloat16 pool around a scatter and would fail it."""
+    import math
+
+    import jax
+    import jax.numpy as jnp
+
+    from dmlc_tpu.generate.engine import GenerationEngine
+    from dmlc_tpu.models import registry
+    from dmlc_tpu.parallel.sp_transformer import SPTransformerLM
+
+    g = geometry
+
+    def build(dtype=jnp.float32):
+        return SPTransformerLM(
+            vocab=g["vocab"], num_layers=g["layers"], num_heads=g["heads"],
+            hidden=g["hidden"], mlp_dim=4 * g["hidden"], max_len=g["max_len"],
+            schedule="dense", dtype=dtype)
+
+    spec = registry.ModelSpec("pool_geometry_lm", build, g["max_len"], g["vocab"],
+                              classifier=False, kind="lm")
+    registry.register(spec)
+    dtype = jnp.bfloat16
+    engine = GenerationEngine(
+        spec.name, variables={}, dtype=dtype, max_slots=g["max_slots"],
+        page_size=g["page_size"], num_pages=2, max_prefill=g["max_prefill"],
+        use_pallas=use_pallas)
+    variables = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, dtype),
+        jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=dtype)[1]))
+    pool = jax.ShapeDtypeStruct(
+        (g["layers"] * g["num_pages"], g["page_size"], g["hidden"]), dtype)
+    pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
+    args = abstract_program_args(engine, variables=variables, pool=pool, sharding=sharding)
+    out: dict = {"pool_bytes": pool_bytes}
+    for name, program in (("step", engine._step), ("prefill", engine._prefill)):
+        compiled = program.lower(*args[name]).compile()
+        memory = compiled.memory_analysis()
+        out[name] = {"temp_bytes": int(memory.temp_size_in_bytes),
+                     "alias_bytes": int(memory.alias_size_in_bytes),
+                     "mosaic": MOSAIC_CALL in compiled.as_text()}
+        say(f"pool_memory {name}: {out[name]} (one pool {pool_bytes})")
+        if memory.alias_size_in_bytes < 2 * pool_bytes:
+            raise AssertionError(
+                f"gen {name} at {g}: {memory.alias_size_in_bytes} bytes aliased, "
+                f"two pools are {2 * pool_bytes}: a donated pool is not updated in place")
+        if memory.temp_size_in_bytes >= pool_bytes:
+            raise AssertionError(
+                f"gen {name} at {g}: temporaries of {memory.temp_size_in_bytes} bytes "
+                f"reach one pool ({pool_bytes}): the program copies a pool")
+    return out
 
 
 # ---------------------------------------------------------------------------
@@ -327,7 +415,8 @@ KERNEL_SHAPES = {
     "s_resident": 2048,
     "s_streamed": 16384,
     "sp_s_local": 1024,
-    "pages": (128, 16, 2, 64),   # [num_pages, page_size, heads, head_dim]
+    "pages": (128, 16, 2 * 64),  # [rows, page_size, kv_heads * head_dim]
+    "kv_heads": 2,
     "page_table": (8, 16),       # [max_slots, max_pages_per_slot]
 }
 
@@ -422,7 +511,7 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
 
     images = jax.random.randint(next(keys), shapes["images"], 0, 256, jnp.int32).astype(jnp.uint8)
     logits = jax.random.normal(next(keys), shapes["logits"], jnp.float32) * 3.0
-    n_pages = shapes["pages"][0]
+    n_pages, kv_heads = shapes["pages"][0], shapes["kv_heads"]
     table = jax.random.randint(next(keys), shapes["page_table"], 0, n_pages, jnp.int32)
     pages = jax.random.normal(next(keys), shapes["pages"], jnp.float32)
     n = len(devices)
@@ -443,10 +532,10 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
          causal_ref, BF16_TOL, {}),
         ("flash_bwd", grads(causal_flash), qkv(shapes["s_resident"]),
          grads(causal_ref), 2 * BF16_TOL, {}),
-        ("page_gather_f32", lambda p, t: gather_kv_pages(p, t, use_pallas=True),
-         (pages, table), lambda p, t: gather_kv_pages(p, t), 0.0, {"exact": True}),
-        ("page_gather_bf16", lambda p, t: gather_kv_pages(p, t, use_pallas=True),
-         (pages.astype(jnp.bfloat16), table), lambda p, t: gather_kv_pages(p, t),
+        ("page_gather_f32", lambda p, t: gather_kv_pages(p, t, kv_heads, use_pallas=True),
+         (pages, table), lambda p, t: gather_kv_pages(p, t, kv_heads), 0.0, {"exact": True}),
+        ("page_gather_bf16", lambda p, t: gather_kv_pages(p, t, kv_heads, use_pallas=True),
+         (pages.astype(jnp.bfloat16), table), lambda p, t: gather_kv_pages(p, t, kv_heads),
          0.0, {"exact": True}),
         (f"ring_flash_sp{n}",
          lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
@@ -619,8 +708,9 @@ def main() -> int:
             rng = np.random.default_rng(0)
             lengths, max_new = [5, 17, 33, 48, 64], [32, 24, 16, 28, 20]
             prompts = [rng.integers(0, 1024, n).tolist() for n in lengths]
-            run("generate", generate_phase, node, model=GEN_MODEL,
-                prompts=prompts, max_new=max_new)
+            run("generate", lambda: {
+                **generate_phase(node, model=GEN_MODEL, prompts=prompts, max_new=max_new),
+                "pool_memory": pool_memory(POOL_GEOMETRY)})
 
             run("kernels", kernels_phase, devices)
             interpreted = [k for k, v in phases["kernels"].items()

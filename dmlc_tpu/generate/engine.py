@@ -10,16 +10,23 @@ Two jitted programs, both built ONCE in ``__init__`` (never per request —
 lint J2's regression class):
 
 - ``_prefill``: one slot's padded prompt ([1, max_prefill]) through the
-  full causal forward; K/V for real positions are scattered into the
-  slot's pages (padding lands on the scratch page), and the last real
-  position's logits seed the first sampled token. Exact because padding
-  sits at the END under a causal mask: no real position can attend to it.
+  full causal forward; its K/V go into the slot's pages a whole page at a
+  time (a page of padding alone lands on the scratch page), and the last
+  real position's logits seed the first sampled token. Exact because
+  padding sits at the END under a causal mask: no real position can attend
+  to it.
 - ``_step``: one token per slot ([max_slots]) — embed + per-layer
   (write K/V into pages at position ``lengths[s]``, ragged paged attention
   over ``lengths[s]+1`` cached positions, MLP) + head + sampling (greedy
-  at temperature 0, categorical otherwise, per-slot temperature). The
-  page pools are DONATED through both programs, so exactly one generation
-  of the cache exists in device memory.
+  at temperature 0, categorical otherwise, per-slot temperature).
+
+The page pools are DONATED through both programs and live in the one layout
+both write and the gather reads (``[kv_layers * num_pages, page_size,
+kv_heads * head_dim]``, generate/kvcache.py): each program writes its rows
+into the donated buffers and aliases them to its outputs, so exactly one
+generation of the cache exists in device memory, no program keeps a
+pool-sized temporary, and a call costs what it writes and gathers whatever
+the pool's size (both take it from the pool they are handed).
 
 Sampling is **per-slot position-seeded**: the categorical draw for the
 token at sequence position ``p`` of a request seeded ``s`` uses the key
@@ -71,6 +78,7 @@ class _StepKV:
         self.k_state, self.v_state = k_state, v_state
         self._paged = engine.cache_mode == "paged"
         self._use_pallas = engine.use_pallas
+        self._kv_heads = engine.kv_heads
         self._lengths, self._page_table = lengths, page_table
         if self._paged:
             # Destination of this step's K/V: the page covering position
@@ -79,6 +87,7 @@ class _StepKV:
             page_idx = jnp.take_along_axis(
                 page_table, (lengths // page_size)[:, None], axis=1
             )[:, 0]
+            self._num_pages = k_state.shape[0] // engine.kv_layers
             self._dest_page = jnp.where(active, page_idx, SCRATCH_PAGE)
             self._dest_off = lengths % page_size
         self._kv_lengths = jnp.maximum(lengths + 1, 1)
@@ -89,12 +98,16 @@ class _StepKV:
         from dmlc_tpu.ops.ragged_decode import gather_kv_pages, ragged_decode_attention
 
         if self._paged:
-            self.k_state = self.k_state.at[layer, self._dest_page, self._dest_off].set(k)
-            self.v_state = self.v_state.at[layer, self._dest_page, self._dest_off].set(v)
-            ks = gather_kv_pages(self.k_state[layer], self._page_table,
-                                 use_pallas=self._use_pallas)
-            vs = gather_kv_pages(self.v_state[layer], self._page_table,
-                                 use_pallas=self._use_pallas)
+            # One row per slot into the donated pool, then the gather reads
+            # the pool where it lives: no layer is cut out of it.
+            first = layer * self._num_pages
+            dest = (first + self._dest_page, self._dest_off)
+            self.k_state = self.k_state.at[dest].set(k.reshape(k.shape[0], -1))
+            self.v_state = self.v_state.at[dest].set(v.reshape(v.shape[0], -1))
+            ks, vs = (
+                gather_kv_pages(pool, self._page_table, self._kv_heads,
+                                first_row=first, use_pallas=self._use_pallas)
+                for pool in (self.k_state, self.v_state))
         else:
             self.k_state = self.k_state.at[layer, self._batch, self._lengths].set(k)
             self.v_state = self.v_state.at[layer, self._batch, self._lengths].set(v)
@@ -104,8 +117,10 @@ class _StepKV:
 
 class _PrefillKV:
     """The cache as a family's ``prefill`` sees it: ``write_prefill`` puts
-    the K/V of the real positions into the slot's pages (padding lands on
-    the scratch page)."""
+    the K/V of the padded prompt into the slot's pages, a whole page at a
+    time: a page with no real position lands on the scratch page, and the
+    padding rows of the prompt's last page are rows the ragged mask never
+    exposes and later decode steps overwrite."""
 
     def __init__(self, engine: "GenerationEngine", k_state: Any, v_state: Any,
                  length: Any, dest: Any) -> None:
@@ -116,16 +131,27 @@ class _PrefillKV:
         self._dest = dest
         self._s_pad = engine.max_prefill
         if self._paged:
-            page_size = engine.cache.page_size
-            seq = jnp.arange(self._s_pad)
-            self._dest_page = jnp.where(seq < length, dest[seq // page_size], SCRATCH_PAGE)
-            self._dest_off = seq % page_size
+            self._page_size = page_size = engine.cache.page_size
+            self._num_pages = k_state.shape[0] // engine.kv_layers
+            first_pos = jnp.arange(-(-self._s_pad // page_size)) * page_size
+            self._dest_page = jnp.where(
+                first_pos < length, dest[: first_pos.shape[0]], SCRATCH_PAGE)
+
+    def _pages(self, x: Any) -> Any:
+        """[S, KV, Dh] -> [pages, page_size, KV * Dh], the last page padded."""
+        import jax.numpy as jnp
+
+        n_pages = self._dest_page.shape[0]
+        x = x.reshape(self._s_pad, -1)
+        x = jnp.pad(x, ((0, n_pages * self._page_size - self._s_pad), (0, 0)))
+        return x.reshape(n_pages, self._page_size, -1)
 
     def write_prefill(self, layer: int, k: Any, v: Any) -> None:
         """k, v: [S, KV, Dh] of the padded prompt."""
         if self._paged:
-            self.k_state = self.k_state.at[layer, self._dest_page, self._dest_off].set(k)
-            self.v_state = self.v_state.at[layer, self._dest_page, self._dest_off].set(v)
+            dest = layer * self._num_pages + self._dest_page
+            self.k_state = self.k_state.at[dest].set(self._pages(k))
+            self.v_state = self.v_state.at[dest].set(self._pages(v))
         else:
             # Positions past ``length`` are scratch rows the ragged mask
             # never exposes; later decode steps overwrite them.

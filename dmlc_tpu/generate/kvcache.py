@@ -10,10 +10,17 @@ is a FLEET of pages shared by whatever mix of requests is resident.
 
 Layout (docs/GENERATE.md):
 
-- ``k_pages`` / ``v_pages``: [num_layers, num_pages, page_size, H, Dh]
-  device arrays. One page id spans EVERY layer — allocating a page grants
-  page_size token positions in all layers at once, so there is one
-  allocator and one table, not num_layers of each.
+- ``k_pages`` / ``v_pages``: [num_layers * num_pages, page_size, H * Dh]
+  device arrays: layer ``l``'s page ``p`` is row ``l * num_pages + p``, and
+  a page is ``page_size`` rows of every KV head side by side. That is the
+  one layout both programs write and the gather reads, so the donated
+  pools are updated in place: heads folded into the last axis fill the
+  chip's tiles (a trailing [H, Dh] such as 20 x 64 pads to 32 x 128, and
+  the compiler then re-lays the WHOLE pool around every write), and layers
+  folded into the page axis mean no layer is ever sliced out. One page id
+  spans EVERY layer — allocating a page grants page_size token positions
+  in all layers at once, so there is one allocator and one table, not
+  num_layers of each.
 - **page 0 is the reserved scratch page**: never allocated, the write/read
   target for inactive batch rows (the decode step runs at a fixed batch
   shape; rows with no request must still index something). Garbage lands
@@ -155,10 +162,11 @@ class PagedKVCache:
         self.max_pages_per_slot = int(max_pages_per_slot)
         self.dtype = dtype if dtype is not None else jnp.float32
         self.allocator = PageAllocator(num_pages, page_size)
-        shape = (num_layers, num_pages, page_size, num_heads, head_dim)
-        # The pools live on the engine's device; the jitted step donates
-        # and replaces them every call, so exactly one generation of the
-        # pool exists at a time.
+        shape = (self.num_layers * int(num_pages), self.page_size,
+                 int(num_heads) * int(head_dim))
+        # The pools live on the engine's device; both jitted programs donate
+        # them and write the new rows into the same buffers, so exactly one
+        # generation of the pool exists at a time.
         self.k_pages = jnp.zeros(shape, self.dtype)
         self.v_pages = jnp.zeros(shape, self.dtype)
         # Host-owned table/lengths; rows default to the scratch page.

@@ -7,16 +7,20 @@ fixed-size pages drawn from a shared pool, stitched into a per-slot sequence
 by an int32 page table — so slots join/leave the running batch without
 copying or fragmenting HBM.
 
-Two paths behind the repo's kernel-fallback pattern (ops/pallas_kernels.py):
+Two paths behind the repo's kernel-fallback pattern (ops/pallas_kernels.py),
+both over the pool as it lives in device memory, ``[rows, page_size, KV * Dh]``
+with every layer's pages in the one row axis (generate/kvcache.py):
 
-- ``gather_kv_pages`` XLA path — ``jnp.take`` over the page axis; what the
+- ``gather_kv_pages`` XLA path — ``jnp.take`` over the row axis; what the
   engine runs off-TPU and the parity reference everywhere.
-- ``gather_kv_pages`` Pallas path — a page-gather kernel using scalar
-  prefetch (``PrefetchScalarGridSpec``): the page table is prefetched to
-  SMEM and drives the BlockSpec index map, so each grid cell DMAs exactly
-  one page from the pool into its contiguous output slot — the gather is
-  pure data movement with no gather-scatter HLO. Interpreter mode off-TPU
-  keeps tests hermetic (same seam as the flash kernels).
+- ``gather_kv_pages`` Pallas path — a page-gather kernel that never stages
+  the pool: pool and result are left where the compiler keeps them
+  (``pl.ANY``: the pool in HBM), the row ids are prefetched to SMEM, and the
+  body issues one DMA per page, pool to result, with a window of them in
+  flight — the gather is pure data movement with no
+  gather-scatter HLO, no staged copy of a layer's pool, and a cost of the
+  pages it moves whatever the pool's size. Interpreter mode off-TPU keeps
+  tests hermetic (same seam as the flash kernels).
 
 ``ragged_decode_attention`` is the mask-based attention itself: scores are
 computed against the full padded [B, S_max] cache view and positions at or
@@ -35,55 +39,72 @@ from jax.experimental.pallas import tpu as pltpu
 
 from dmlc_tpu.ops.pallas_kernels import interpret_mode
 
+#: Page DMAs the gather kernel keeps in flight (one shared semaphore).
+_DMA_WINDOW = 32
 
-def _gather_pages_pallas(pages, flat_table):
-    """[N, P, D] pages gathered by a flat page-id vector -> [len, P, D].
 
-    One grid cell per output page: the prefetched table entry picks which
-    pool page the cell's input block maps to, the output block is the
-    cell's own slot — the kernel body is a straight block copy.
+def _gather_pages_pallas(pool, rows):
+    """[R, P, W] pool gathered by a flat row-id vector -> [len, P, W].
+
+    Every copy moves one page, so they share a DMA semaphore: a wait takes
+    one page's worth of it, whichever copy finished.
     """
-    n_out = flat_table.shape[0]
-    _, page_size, width = pages.shape
+    n_out = rows.shape[0]
+    _, page_size, width = pool.shape
+    window = min(_DMA_WINDOW, n_out)
 
-    def copy_kernel(table_ref, page_ref, out_ref):
-        del table_ref  # consumed by the index maps, not the body
-        out_ref[...] = page_ref[...]
+    def gather_kernel(rows_ref, pool_ref, out_ref, sem):
+        def page_copy(j):
+            return pltpu.make_async_copy(pool_ref.at[rows_ref[j]], out_ref.at[j], sem)
+
+        def issue(j, carry):
+            @pl.when(j >= window)
+            def _():
+                page_copy(j - window).wait()
+
+            page_copy(j).start()
+            return carry
+
+        def drain(j, carry):
+            page_copy(j).wait()
+            return carry
+
+        jax.lax.fori_loop(0, n_out, issue, 0)
+        jax.lax.fori_loop(n_out - window, n_out, drain, 0)
 
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=1,
-        grid=(n_out,),
-        in_specs=[
-            pl.BlockSpec((1, page_size, width), lambda j, table: (table[j], 0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, page_size, width), lambda j, table: (j, 0, 0)),
+        grid=(),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.SemaphoreType.DMA(())],
     )
     return pl.pallas_call(
-        copy_kernel,
+        gather_kernel,
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_out, page_size, width), pages.dtype),
+        out_shape=jax.ShapeDtypeStruct((n_out, page_size, width), pool.dtype),
         interpret=interpret_mode(),
-    )(flat_table, pages)
+    )(rows, pool)
 
 
-def gather_kv_pages(pages, page_table, *, use_pallas: bool = False):
+def gather_kv_pages(pool, page_table, kv_heads: int, *, first_row=0, use_pallas: bool = False):
     """Assemble the per-slot contiguous cache view from the shared pool.
 
-    ``pages``: [num_pages, page_size, H, Dh] (one layer's K or V pool);
+    ``pool``: [rows, page_size, KV * Dh] (K or V, every layer's pages);
     ``page_table``: int32 [B, max_pages] — row b's sequence is the
     concatenation of its pages in table order (unused entries point at the
-    reserved scratch page 0 and are masked out by the attention lengths).
-    Returns [B, max_pages * page_size, H, Dh].
+    reserved scratch page 0 and are masked out by the attention lengths);
+    ``first_row``: the pool row of this layer's page 0.
+    Returns [B, max_pages * page_size, KV, Dh].
     """
     b, max_pages = page_table.shape
-    _, page_size, heads, head_dim = pages.shape
+    _, page_size, width = pool.shape
+    rows = page_table.reshape(b * max_pages).astype(jnp.int32) + first_row
     if use_pallas:
-        flat = page_table.reshape(b * max_pages).astype(jnp.int32)
-        wide = pages.reshape(pages.shape[0], page_size, heads * head_dim)
-        out = _gather_pages_pallas(wide, flat)
-        return out.reshape(b, max_pages * page_size, heads, head_dim)
-    out = jnp.take(pages, page_table.reshape(-1), axis=0)
-    return out.reshape(b, max_pages * page_size, heads, head_dim)
+        out = _gather_pages_pallas(pool, rows)
+    else:
+        out = jnp.take(pool, rows, axis=0)
+    return out.reshape(b, max_pages * page_size, kv_heads, width // kv_heads)
 
 
 def ragged_decode_attention(q, k, v, kv_lengths, *, scale: float | None = None):
@@ -128,13 +149,3 @@ def _grouped_decode_attention(q, k, v, kv_lengths, scale):
     probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
     out = jnp.einsum("bkgs,bskd->bkgd", probs, v.astype(jnp.float32))
     return out.reshape(b, heads, dh).astype(q.dtype)
-
-
-def paged_decode_attention(
-    q, k_pages, v_pages, page_table, kv_lengths,
-    *, scale: float | None = None, use_pallas: bool = False,
-):
-    """Gather + ragged attention in one call: the engine's per-layer step."""
-    k = gather_kv_pages(k_pages, page_table, use_pallas=use_pallas)
-    v = gather_kv_pages(v_pages, page_table, use_pallas=use_pallas)
-    return ragged_decode_attention(q, k, v, kv_lengths, scale=scale)

@@ -241,15 +241,36 @@ class TestPageGatherKernel:
 
         rng = np.random.default_rng(0)
         pages = jnp.asarray(
-            rng.standard_normal((10, 4, 2, 8)).astype(np.float32)
+            rng.standard_normal((10, 4, 2 * 8)).astype(np.float32)
         )
         table = jnp.asarray(
             rng.integers(0, 10, size=(3, 5)).astype(np.int32)
         )
-        ref = gather_kv_pages(pages, table, use_pallas=False)
-        out = gather_kv_pages(pages, table, use_pallas=True)
+        ref = gather_kv_pages(pages, table, 2, use_pallas=False)
+        out = gather_kv_pages(pages, table, 2, use_pallas=True)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
         assert out.shape == (3, 20, 2, 8)
+
+    @pytest.mark.parametrize("layer", [0, 2])
+    @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
+    def test_gathers_agree_on_the_folded_pool(self, layer, dtype):
+        """Both gathers read layer ``layer`` of a pool that holds three, by a
+        table that repeats pages and points at scratch; KV heads unfold from
+        the row (2 x 24 = 48 wide: no multiple of a tile)."""
+        from dmlc_tpu.ops.ragged_decode import gather_kv_pages
+
+        num_pages, page_size, kv_heads, head_dim = 6, 4, 2, 24
+        rng = np.random.default_rng(1)
+        pool = jnp.asarray(rng.standard_normal(
+            (3 * num_pages, page_size, kv_heads * head_dim)).astype(np.float32)).astype(dtype)
+        table = jnp.asarray([[5, 5, SCRATCH_PAGE, 1], [SCRATCH_PAGE] * 4, [3, 1, 5, 3]], jnp.int32)
+        want = np.asarray(pool, np.float32).reshape(3, num_pages, page_size, kv_heads, head_dim)[
+            layer][np.asarray(table)].reshape(3, 4 * page_size, kv_heads, head_dim)
+        for use_pallas in (False, True):
+            got = gather_kv_pages(pool, table, kv_heads, first_row=layer * num_pages,
+                                  use_pallas=use_pallas)
+            assert got.dtype == dtype
+            np.testing.assert_array_equal(np.asarray(got, np.float32), want)
 
     def test_ragged_mask_excludes_beyond_length(self):
         from dmlc_tpu.ops.ragged_decode import ragged_decode_attention

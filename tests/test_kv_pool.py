@@ -1,0 +1,208 @@
+"""The KV pools as both programs share them (generate/kvcache.py layout):
+``[kv_layers * num_pages, page_size, kv_heads * head_dim]``, written in place
+by the step and the prefill and read where they live by the gather.
+
+- a prefill followed by steps gives the contiguous cache's logits at any
+  (heads, KV heads, head width), grouped-query and rows that fill no tile
+  included, through either gather;
+- a prefill touches the slot's page run and the scratch page, nothing else;
+- the names the benchmark reads (``engine.cache.k_pages``, ``engine._k_state``)
+  answer, and deleting them frees every pool buffer;
+- a step and a prefill consume the pool they are handed (one generation).
+
+Counts and values only; nothing here is a speed.
+"""
+
+import numpy as np
+import pytest
+
+pytest.importorskip("jax")
+import jax  # noqa: E402
+import jax.numpy as jnp  # noqa: E402
+
+from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
+from dmlc_tpu.generate.kvcache import SCRATCH_PAGE  # noqa: E402
+from dmlc_tpu.models import registry  # noqa: E402
+
+VOCAB, MAX_LEN, LAYERS, PAGE = 40, 32, 2, 4
+
+
+class GroupedQueryFamily:
+    """A two-layer attention-only decoder with ``heads`` query heads on
+    ``kv_heads`` K/V heads: the least family that exercises the engine's
+    cache seam (``kv.write_prefill`` / ``kv.write_attend``) at any widths."""
+
+    def __init__(self, dtype, heads: int, kv_heads: int, head_dim: int) -> None:
+        self.dtype = dtype
+        self.vocab, self.max_len = VOCAB, MAX_LEN
+        self.heads, self.kv_layers = heads, LAYERS
+        self.kv_heads, self.head_dim = kv_heads, head_dim
+        self.width = heads * head_dim
+
+    def params(self, seed: int) -> dict:
+        rng = np.random.default_rng(seed)
+        kv_width = self.kv_heads * self.head_dim
+
+        def draw(*shape):
+            return jnp.asarray(rng.standard_normal(shape) / np.sqrt(shape[0]), self.dtype)
+
+        layers = [{"wq": draw(self.width, self.width), "wk": draw(self.width, kv_width),
+                   "wv": draw(self.width, kv_width), "wo": draw(self.width, self.width)}
+                  for _ in range(LAYERS)]
+        return {"embed": draw(VOCAB, self.width) * 6.0, "pos": draw(MAX_LEN, self.width) * 6.0,
+                "head": draw(self.width, VOCAB), "layers": layers}
+
+    def state_shapes(self, max_slots: int) -> dict:
+        return {}
+
+    def work_attrs(self, aux: dict, rows: int) -> dict:
+        return {}
+
+    def _qkv(self, p, x):
+        rows = x.shape[0]
+        return ((x @ p["wq"]).reshape(rows, self.heads, self.head_dim),
+                (x @ p["wk"]).reshape(rows, self.kv_heads, self.head_dim),
+                (x @ p["wv"]).reshape(rows, self.kv_heads, self.head_dim))
+
+    def prefill(self, params, tokens, length, slot, kv, state):
+        del slot
+        s_pad = tokens.shape[1]
+        x = params["embed"][tokens[0]] + params["pos"][:s_pad]
+        causal = jnp.tril(jnp.ones((s_pad, s_pad), bool))
+        group = self.heads // self.kv_heads
+        for layer, p in enumerate(params["layers"]):
+            q, k, v = self._qkv(p, x)
+            kv.write_prefill(layer, k, v)
+            scores = jnp.einsum("shd,thd->hst", q, jnp.repeat(k, group, axis=1))
+            scores = jnp.where(causal[None], scores * self.head_dim ** -0.5, -jnp.inf)
+            att = jnp.einsum("hst,thd->shd", jax.nn.softmax(scores, -1),
+                             jnp.repeat(v, group, axis=1))
+            x = x + att.reshape(s_pad, -1) @ p["wo"]
+        logits = (x @ params["head"]).astype(jnp.float32)
+        return jnp.take(logits, length - 1, axis=0), state, {}
+
+    def decode(self, params, tokens, lengths, active, kv, state):
+        del active
+        x = params["embed"][tokens] + params["pos"][jnp.minimum(lengths, MAX_LEN - 1)]
+        for layer, p in enumerate(params["layers"]):
+            q, k, v = self._qkv(p, x)
+            att = kv.write_attend(layer, q, k, v)
+            x = x + att.reshape(att.shape[0], -1) @ p["wo"]
+        return (x @ params["head"]).astype(jnp.float32), state, {}
+
+
+@pytest.fixture
+def family_engine():
+    """``make(heads, kv_heads, head_dim, **engine_kw)`` -> an engine over a
+    ``GroupedQueryFamily`` registered for this test only."""
+    names: list[str] = []
+
+    def make(heads, kv_heads, head_dim, **kw):
+        name = f"gqa_{heads}_{kv_heads}_{head_dim}"
+        if name not in names:
+            registry.register(registry.ModelSpec(
+                name, None, MAX_LEN, VOCAB, classifier=False, kind="lm",
+                family=lambda dtype: GroupedQueryFamily(dtype, heads, kv_heads, head_dim)))
+            names.append(name)
+        dtype = kw.get("dtype", jnp.float32)
+        params = GroupedQueryFamily(dtype, heads, kv_heads, head_dim).params(seed=5)
+        kw = {"max_slots": 3, "page_size": PAGE, "num_pages": 24, "max_prefill": 10,
+              "return_logits": True, **kw}
+        return GenerationEngine(name, variables={"params": params}, **kw)
+
+    yield make
+    for name in names:
+        registry._REGISTRY.pop(name, None)
+
+
+def run(engine, prompts, n_steps):
+    """Join every prompt, then ``n_steps`` greedy steps: the logits of all."""
+    for slot, prompt in enumerate(prompts):
+        engine.join(slot, prompt)
+    out = []
+    for _ in range(n_steps):
+        for slot in range(len(prompts)):
+            engine.ensure_capacity(slot)
+        engine.step()
+        out.append(np.array(engine.last_logits[: len(prompts)]))
+    return np.stack(out)
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["take", "pallas"])
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [
+    (4, 4, 8),     # multi-head, a 32-wide row
+    (4, 2, 16),    # grouped-query
+    (6, 2, 24),    # grouped-query, a 48-wide row
+    (2, 1, 64),    # one KV head
+    (5, 5, 40),    # 200 wide: over one tile, no multiple of 128
+])
+def test_paged_pool_gives_the_contiguous_caches_logits(
+        family_engine, heads, kv_heads, head_dim, use_pallas):
+    rng = np.random.default_rng(heads * 100 + head_dim)
+    # 7 and 10 tokens: the second prompt fills its padded prefill, both cross
+    # a page boundary within six steps.
+    prompts = [rng.integers(0, VOCAB, n).astype(np.int32) for n in (7, 10)]
+    paged = family_engine(heads, kv_heads, head_dim, use_pallas=use_pallas)
+    contiguous = family_engine(heads, kv_heads, head_dim, cache="contiguous")
+    assert paged.cache.k_pages.shape == (LAYERS * 24, PAGE, kv_heads * head_dim)
+    got, want = run(paged, prompts, 6), run(contiguous, prompts, 6)
+    np.testing.assert_allclose(got, want, atol=2e-5, rtol=1e-5)
+    assert np.abs(want).max() > 0.1  # the logits say something
+
+
+@pytest.mark.parametrize("length", [1, PAGE, PAGE + 3, 10])
+def test_prefill_writes_the_slots_run_and_scratch_only(family_engine, length):
+    engine = family_engine(4, 2, 16)
+    num_pages = engine.cache.allocator.num_pages
+    sentinel = 7.0
+    stranger = engine.reserve(5)  # pages another request holds, LIFO-adjacent
+    for name in ("_k_state", "_v_state"):
+        setattr(engine, name, jnp.full_like(getattr(engine, name), sentinel))
+    engine.join(1, np.arange(length, dtype=np.int32) % VOCAB)
+    run_pages = engine.cache.slot_pages(1)
+    assert len(run_pages) == -(-(length + 1) // PAGE) and not set(run_pages) & set(stranger)
+    written = -(-length // PAGE)  # pages that hold a real position
+    for pool in (engine._k_state, engine._v_state):
+        pool = np.asarray(pool).reshape(LAYERS, num_pages, PAGE, -1)
+        untouched = [p for p in range(num_pages)
+                     if p != SCRATCH_PAGE and p not in run_pages[:written]]
+        assert (pool[:, untouched] == sentinel).all()
+        rows = pool[:, run_pages[:written]].reshape(LAYERS, written * PAGE, -1)
+        assert (rows[:, :length] != sentinel).all()
+
+
+def test_pool_names_answer_and_deleting_them_frees_every_buffer(family_engine):
+    engine = family_engine(4, 2, 16, dtype=jnp.bfloat16)
+    engine.join(0, np.arange(5, dtype=np.int32))
+    engine.step()
+    assert engine.cache.k_pages.dtype == jnp.bfloat16 == engine.cache.v_pages.dtype
+    assert engine.cache.k_pages.shape == engine.cache.v_pages.shape == (LAYERS * 24, PAGE, 32)
+    def live_pools():  # this test's engine is the only bfloat16 one
+        return [a for a in jax.live_arrays()
+                if a.shape == (LAYERS * 24, PAGE, 32) and a.dtype == jnp.bfloat16]
+
+    assert len(live_pools()) == 2  # one generation of K and of V, no stray copy
+    for name in ("_k_state", "_v_state"):  # as benchlib.system.free_pools does
+        pool = getattr(engine, name)
+        assert not pool.is_deleted()
+        pool.delete()
+    assert engine.cache.k_pages.is_deleted() and engine.cache.v_pages.is_deleted()
+    assert not live_pools()
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_each_program_consumes_the_pool_it_is_handed(family_engine, program):
+    probe = jnp.zeros((8,))
+    jax.jit(lambda x: x + 1, donate_argnums=0)(probe)
+    if not probe.is_deleted():
+        pytest.skip("this backend does not honour donation")
+    engine = family_engine(4, 2, 16)
+    engine.join(0, np.arange(6, dtype=np.int32))
+    handed = (engine._k_state, engine._v_state)
+    if program == "step":
+        engine.step()
+    else:
+        engine.join(1, np.arange(3, dtype=np.int32))
+    assert all(pool.is_deleted() for pool in handed)
+    assert not engine._k_state.is_deleted() and not engine._v_state.is_deleted()
+    assert engine.cache.k_pages is engine._k_state and engine.cache.v_pages is engine._v_state

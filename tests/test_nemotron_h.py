@@ -246,8 +246,8 @@ class TestStateSlots:
         assert engine.state.bytes_per_slot == per_slot
         weights_and_pools = before - engine.state.nbytes
         assert engine.state.nbytes == 4 * per_slot and weights_and_pools > 0
-        # only the attention layer takes pages: 1 layer, 2 KV heads
-        assert engine.cache.k_pages.shape == (1, 64, 8, 2, 16)
+        # only the attention layer takes pages: 1 layer x 64 pages, rows of 2 KV heads x 16
+        assert engine.cache.k_pages.shape == (64, 8, 32)
 
     def test_one_jit_entry_across_joins_and_releases(self, variables):
         engine = make_engine(variables)
