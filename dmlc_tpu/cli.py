@@ -116,10 +116,6 @@ Commands (reference: README.md:10-23):
                                         a member; residents finish within the
                                         deadline or migrate (docs/OPERATIONS.md)
   undrain <member>                      reopen a drained member for admission
-  export <model>                        publish the model's StableHLO executable
-  export-bundle <model> <dir>           write the native PJRT host bundle
-                                        (program.mlir + weights + manifests;
-                                        served by native/pjrt_host, no Python)
   mesh-join                             join the fleet-wide jax.distributed mesh
   jobs                                  job status, accuracy, latency percentiles
   assign                                per-job member assignment table
@@ -331,52 +327,6 @@ class Cli:
             return (
                 f"{r['member']}: admission reopened"
                 if r.get("was") else f"{r['member']}: was not draining"
-            )
-        if cmd == "export":
-            if len(args) != 1:
-                return "usage: export <model_name>"
-            from dmlc_tpu.models import export as export_lib
-
-            v = export_lib.publish_executable(
-                n.sdfs, args[0], batch_size=n.config.batch_size
-            )
-            return f"exported {args[0]} -> {export_lib.sdfs_executable_name(args[0])} v{v}"
-        if cmd == "export-bundle":
-            if len(args) != 2:
-                return "usage: export-bundle <model_name> <out_dir>"
-            from pathlib import Path
-
-            from dmlc_tpu.models import weights as weights_lib
-            from dmlc_tpu.models.pjrt_bundle import export_bundle
-
-            # Bundle the cluster's PUBLISHED weights when they exist (the
-            # same blob the Python serving path trains/hot-swaps from);
-            # random init only for clusters that never published any.
-            variables, source = None, "random-init (no published weights)"
-            blob = None
-            sdfs = getattr(n, "sdfs", None)  # standalone/tool contexts: no cluster
-            if sdfs is not None:
-                try:
-                    _, blob = sdfs.get_bytes(weights_lib.sdfs_weights_name(args[0]))
-                except RpcError as e:
-                    # Only NOT-FOUND means "never published"; a corrupt
-                    # blob, wrong-model magic, or transient replica failure
-                    # must surface, not silently bundle random weights
-                    # under a false label (same consent rule as
-                    # ExportedBackend).
-                    if not weights_lib.not_published(e):
-                        raise
-            if blob is not None:
-                _, variables = weights_lib.weights_from_bytes(blob, expect_model=args[0])
-                source = "published SDFS weights"
-            info = export_bundle(
-                args[0], n.config.batch_size, Path(args[1]), variables=variables
-            )
-            return (
-                f"bundle for {info['model']} (batch {info['batch']}, "
-                f"{info['weight_args']} weight files, {source}) -> {args[1]}; "
-                f"serve with: native/pjrt_host serve <plugin.so> {args[1]} "
-                f"--dir <jpegs> (or one-shot: pjrt_host run)"
             )
         if cmd == "mesh-join":
             info = n.join_global_mesh()

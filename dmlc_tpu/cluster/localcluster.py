@@ -38,9 +38,9 @@ def make_synsets(path: Path, n: int) -> Path:
 
 
 #: ``backends=CONFIGURED`` asks every node to build the REAL backends its
-#: config names (ClusterNode._build: EngineBackend / LmBackend /
-#: ExportedBackend) instead of the harness's echo fake — the shape
-#: chip_smoke.py drives the serving path in.
+#: config names (cluster/node.py build_backends: EngineBackend / LmBackend)
+#: instead of the harness's echo fake — the shape chip_smoke.py drives the
+#: serving path in.
 CONFIGURED = "configured"
 
 

@@ -54,7 +54,6 @@ from dmlc_tpu.scheduler.placement import PlacementAdvisor, SloEvaluator, SloObje
 from dmlc_tpu.scheduler.worker import (
     DynamicBatcher,
     EngineBackend,
-    ExportedBackend,
     LmBackend,
     ModelLoader,
     PredictWorker,
@@ -101,6 +100,29 @@ def _model_kind(name: str) -> str:
         return get_model(name).kind
     except Exception:  # noqa: BLE001 - wiring must not die on a bad name
         return "image"
+
+
+def build_backends(config: ClusterConfig, device_work) -> dict:
+    """The one place that decides which backend serves a job model: a
+    registry ``kind="lm"`` model through ``LmBackend`` (the gang-aware
+    sharded path, docs/SHARDING.md), every other name through
+    ``EngineBackend``."""
+    backends: dict = {}
+    for name in config.job_models:
+        if _model_kind(name) == "lm":
+            backends[name] = LmBackend(
+                name,
+                gang_devices=config.lm_gang_devices,
+                prompt_len=config.lm_prompt_len,
+                hbm_budget_bytes=config.lm_hbm_budget_bytes,
+                device_work=device_work,
+            )
+        else:
+            backends[name] = EngineBackend(
+                name, config.data_dir, batch_size=config.batch_size,
+                device_work=device_work,
+            )
+    return backends
 
 
 def _gen_resident(backend) -> int | None:
@@ -321,33 +343,7 @@ class ClusterNode:
             gate=self.transfer_gate,
         )
         if backends is None:
-            backends = {}
-            for name in config.job_models:
-                if _model_kind(name) == "lm":
-                    # kind="lm" jobs serve through the gang-aware sharded
-                    # path regardless of the image-serving deployment shape:
-                    # the compiled program IS the artifact (docs/SHARDING.md).
-                    backends[name] = LmBackend(
-                        name,
-                        gang_devices=config.lm_gang_devices,
-                        prompt_len=config.lm_prompt_len,
-                        hbm_budget_bytes=config.lm_hbm_budget_bytes,
-                        device_work=self.devicemon.device_work,
-                    )
-                elif config.serve_from_executable:
-                    # sdfs is wired in below once the client exists (the
-                    # member server needs the backends first); the backend is
-                    # lazy, so nothing touches sdfs until warmup/first shard.
-                    # No batch size here: the serving batch is the published
-                    # artifact's, fixed at export time.
-                    backends[name] = ExportedBackend(
-                        name, config.data_dir, sdfs=None
-                    )
-                else:
-                    backends[name] = EngineBackend(
-                        name, config.data_dir, batch_size=config.batch_size,
-                        device_work=self.devicemon.device_work,
-                    )
+            backends = build_backends(config, self.devicemon.device_work)
         self.worker = PredictWorker(backends, gate=self.predict_gate)
         # Per-model device accounting: resident_bytes_<model> (None until
         # the lazy engine builds) + mfu_<model> gauges. Registered against
@@ -472,9 +468,6 @@ class ClusterNode:
             transfer_timeout_s=config.transfer_deadline_s,
             retry_policy=self.retry_policy,
         )
-        for backend in self.worker.backends.values():
-            if isinstance(backend, ExportedBackend) and backend.sdfs is None:
-                backend.sdfs = self.sdfs
 
         # BASELINE "SDFS shard" config: members with no local corpus resolve
         # class images through the replicated store, cached on local disk.
@@ -517,11 +510,11 @@ class ClusterNode:
                     backend.decode_tier = self.decode_tier
 
         # Dynamic request micro-batching, wrapped LAST so the wiring above
-        # (sdfs / image_source assignment) still hits the raw backends. With
-        # a deadline configured, concurrent small `job.predict` RPCs
-        # coalesce into device-shaped batches (scheduler/worker.py); gang
-        # verbs pass through the wrapper untouched.
-        self._batchers: list[DynamicBatcher] = []
+        # (image_source / decode_tier assignment) still hits the raw
+        # backends. With a deadline configured, concurrent small
+        # `job.predict` RPCs coalesce into device-shaped batches
+        # (scheduler/worker.py); gang verbs pass through the wrapper untouched.
+        self._batchers: dict[str, DynamicBatcher] = {}
         if config.microbatch_wait_s > 0:
             for name, backend in list(self.worker.backends.items()):
                 wrapped = DynamicBatcher(
@@ -538,7 +531,7 @@ class ClusterNode:
                     tenants=self.tenant_specs,
                 )
                 self.worker.backends[name] = wrapped
-                self._batchers.append(wrapped)
+                self._batchers[name] = wrapped
                 self.registry.gauge(
                     f"microbatch_queue_{name}", lambda b=wrapped: len(b._queue)
                 )
@@ -911,9 +904,7 @@ class ClusterNode:
             # (docs/INGEST.md) ride the same member-info RPC the leader
             # already polls for capacity.
             info["microbatch"] = {
-                name: b.summary()
-                for name, b in self.worker.backends.items()
-                if isinstance(b, DynamicBatcher)
+                name: b.summary() for name, b in self._batchers.items()
             }
         return info
 
@@ -990,32 +981,19 @@ class ClusterNode:
         heartbeat threads into a false FAILED verdict."""
         if self.config.eager_load:
             from dmlc_tpu import native
-            from dmlc_tpu.cluster.rpc import RpcError
-            from dmlc_tpu.models.weights import not_published
 
             # Built on THIS host off the hot path, before serving; a host
             # with no toolchain serves through PIL and node.info says so.
             native.ensure_built()
+            # A warm-up that raises (a kernel the compiler refuses, an OOM,
+            # a corrupt blob) stops the node here, rather than surfacing
+            # later as per-shard errors on a member that joined.
             for backend in [
                 *self.worker.backends.values(),
                 *self._gen_backends.values(),
             ]:
-                if not hasattr(backend, "warmup"):
-                    continue
-                try:
+                if hasattr(backend, "warmup"):
                     backend.warmup()
-                except RpcError as e:
-                    # The ONE tolerated failure: an ExportedBackend on a
-                    # FRESH cluster has nothing to fetch yet (the artifact
-                    # is published by the running cluster's `export` verb)
-                    # — it stays lazy and builds on the first shard. Any
-                    # other failure (a kernel the compiler refuses, an OOM,
-                    # a corrupt blob) must stop the node here, not surface
-                    # later as per-shard errors on a member that joined.
-                    if not (isinstance(backend, ExportedBackend)
-                            and not_published(e)):
-                        raise
-                    log.info("eager warmup deferred: %s", e)
         self._spawn(self._membership_loop)
         self._spawn(self._probe_loop)
         if self.config.devicemon_poll_interval_s > 0:
@@ -1075,7 +1053,7 @@ class ClusterNode:
 
     def stop(self) -> None:
         self._stop.set()
-        for b in self._batchers:
+        for b in self._batchers.values():
             b.stop(timeout_s=2.0)
         for gb in self._gen_backends.values():
             gb.stop(timeout_s=2.0)
@@ -1562,9 +1540,7 @@ class ClusterNode:
             out["autoscaler"] = self.autoscaler.status()
         if self._batchers:
             out["microbatch"] = {
-                name: b.summary()
-                for name, b in self.worker.backends.items()
-                if isinstance(b, DynamicBatcher)
+                name: b.summary() for name, b in self._batchers.items()
             }
         if self.generate_worker is not None:
             out["generate"] = self.generate_worker.summary()

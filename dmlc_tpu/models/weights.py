@@ -34,15 +34,6 @@ from dmlc_tpu.models.registry import get_model
 MAGIC = b"DMLCWTS1"
 
 
-def not_published(err: Exception) -> bool:
-    """True when an SDFS error means the blob was never published (vs a
-    corrupt blob or transient replica failure, which callers must surface).
-    The one place the leader's not-found message text is interpreted —
-    RPC errors travel as message strings, so cli.py and worker.py share
-    this predicate instead of each matching the magic substring."""
-    return "not in SDFS" in str(err)
-
-
 def sdfs_weights_name(model_name: str) -> str:
     """Canonical SDFS name for a model's weights blob (the `train` payload)."""
     return f"models/{model_name}"

@@ -11,8 +11,7 @@ are clamped to replication, so the same table serves a 1-chip replica, a
 
 This generalizes the hardcoded Megatron walk in ``mesh.param_spec`` (kept as
 the engine-internal fallback for models that declare no table) and is what
-``models/export.py`` uses to export sharded executables and what the serving
-gang path (``scheduler/worker.LmBackend``) runs at predict time.
+the serving gang path (``scheduler/worker.LmBackend``) runs at predict time.
 
 Rule-table hygiene is checked twice: statically by analyzer rule A8
 (tools/analyze/rules/devsem.py — bad regexes, rules shadowed by an earlier

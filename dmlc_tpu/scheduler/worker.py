@@ -817,125 +817,6 @@ class LmBackend:
         )
 
 
-class ExportedBackend:
-    """Serve shards from the SDFS-distributed StableHLO artifact + weights —
-    NO model source code on the serving path. This is the deployed form of
-    the native-serving contract (models/export.py): everything a member
-    needs to answer ``job.predict`` is two SDFS files, ``executables/<m>``
-    and ``models/<m>``. Weights absent from SDFS fall back to the registry's
-    random init (exactly EngineBackend's behavior before `train`), and
-    `train` hot-swaps them through ``load_variables`` like any backend.
-    """
-
-    def __init__(
-        self,
-        model_name: str,
-        data_dir: str | Path,
-        sdfs,
-        image_source=None,
-    ):
-        self.model_name = model_name
-        self.data_dir = Path(data_dir)
-        self.sdfs = sdfs
-        self.image_source = image_source
-        self._server = None
-        self._lock = threading.Lock()
-        # Persistent decode-ahead worker for the shard pipeline below —
-        # created once here, never per shard (lint H1: no per-call pools on
-        # hot paths; the old code built a ThreadPoolExecutor every __call__).
-        self._decoder = concurrent.futures.ThreadPoolExecutor(
-            max_workers=1, thread_name_prefix="export-decode"
-        )
-
-    def warmup(self) -> None:
-        # dmlc-lint: disable=A2 -- one-time lazy init: the SDFS artifact/weights fetch MUST happen under the lock so shards arriving before the artifact is resident block instead of double-fetching (same invariant as the in-file L1 suppression inside _ensure_server)
-        with self._lock:
-            self._ensure_server()
-
-    def _ensure_server(self):
-        if self._server is None:
-            import jax
-            import numpy as np
-
-            from dmlc_tpu.cluster.rpc import RpcUnreachable
-            from dmlc_tpu.models import export as export_lib
-            from dmlc_tpu.models import weights as weights_lib
-            from dmlc_tpu.models.registry import get_model
-
-            spec = get_model(self.model_name)
-            version, exported = export_lib.fetch_executable(self.sdfs, self.model_name)
-            # The artifact's input shape is FIXED at export: serving batch
-            # and input size come from IT, never from node config — an
-            # artifact exported at another size must not shape-mismatch.
-            u8_avals = [
-                a for a in exported.in_avals if str(a.dtype) == "uint8" and len(a.shape) == 4
-            ]
-            if not u8_avals:
-                raise RpcError(
-                    f"executable for {self.model_name!r} has no uint8 NHWC "
-                    "input — not a serving artifact this backend can drive"
-                )
-            u8_aval = u8_avals[0]
-            artifact_batch = int(u8_aval.shape[0])
-            try:
-                # dmlc-lint: disable=L1 -- one-time lazy init: shards arriving before the artifact is resident MUST block here; after first load the fetch never runs again
-                _, blob = self.sdfs.get_bytes(weights_lib.sdfs_weights_name(self.model_name))
-                # Validation errors (corrupt/mismatched blob) PROPAGATE —
-                # weights.py's contract is fail-at-load, never serve them.
-                _, variables = weights_lib.weights_from_bytes(blob, expect_model=self.model_name)
-                log.info("%s: artifact v%d + SDFS weights", self.model_name, version)
-            except RpcUnreachable:
-                raise  # transient (failover mid-fetch): retry the shard, not random-init
-            except RpcError as e:
-                if not weights_lib.not_published(e):
-                    raise  # any refusal other than not-published is not consent
-                _, variables = spec.init_params(jax.random.PRNGKey(0), dtype=jax.numpy.float32)
-                variables = jax.tree_util.tree_map(np.asarray, variables)
-                log.info("%s: artifact v%d, weights not published yet — random init", self.model_name, version)
-            self._server = export_lib.ExportedServer(
-                exported, variables, artifact_batch, classifier=spec.classifier
-            )
-            self._serve_batch = artifact_batch
-            self._input_size = int(u8_aval.shape[1])
-        return self._server
-
-    @hot_path
-    def __call__(self, synsets: Sequence[str]) -> list[int]:
-        from dmlc_tpu.ops import preprocess as pp
-
-        if not synsets:
-            return []
-        # dmlc-lint: disable=A2 -- the backend lock serializes shards per artifact by design (reference's model mutex), and first-shard lazy init must block later shards on the one SDFS fetch (see _ensure_server's L1 justification)
-        with self._lock:
-            server = self._ensure_server()
-            chunk_size = self._serve_batch
-            paths = _resolve_paths(self.image_source, self.data_dir, synsets)
-            starts = list(range(0, len(paths), chunk_size))
-            preds: list[int] = []
-            # Decode chunk i+1 while the artifact executes chunk i (the same
-            # overlap EngineBackend gets from run_paths_stream), on the
-            # PERSISTENT self._decoder — never a per-shard pool (lint H1).
-            decode = lambda s: pp.load_batch(
-                paths[s : s + chunk_size], size=self._input_size
-            )
-            fut = self._decoder.submit(decode, starts[0])
-            for i, s in enumerate(starts):
-                # dmlc-lint: disable=L1 -- the backend lock serializes shards per artifact by design (reference's model mutex); the wait is the decode/execute pipeline inside one shard
-                batch = fut.result()
-                if i + 1 < len(starts):
-                    fut = self._decoder.submit(decode, starts[i + 1])
-                idx, _ = server(batch)
-                preds.extend(int(x) for x in idx)
-            return preds
-
-    def load_variables(self, variables) -> None:
-        """The `train` verb's hot-swap: same validated tree the engine path
-        takes, handed to the artifact executor."""
-        # dmlc-lint: disable=A2 -- hot-swap must not interleave with a running shard, so it takes the same serializing lock; the SDFS fetch it can reach is the one-time lazy init (see _ensure_server)
-        with self._lock:
-            self._ensure_server().variables = variables
-
-
 class ModelLoader:
     """Member RPC surface for hot-loading distributed weights.
 

@@ -300,11 +300,6 @@ class ClusterConfig:
     # reference's eager model load, src/services.rs:513-524). Lazy loading
     # risks compile-time GIL holds starving the heartbeat threads.
     eager_load: bool = True
-    # Serve shards from the SDFS-distributed StableHLO artifact
-    # (executables/<model>, published with the `export` verb) instead of
-    # building the model from source — the native-serving deployment shape
-    # (models/export.py): members need only the artifact + weights blobs.
-    serve_from_executable: bool = False
     # --- fleet decode tier (cluster/decodetier.py, docs/INGEST.md) ---
     # Ship raw JPEG bytes to peers' job.decode verbs so ingest decode
     # scales with membership instead of capping at one host's cores.

@@ -100,6 +100,44 @@ def test_full_stack_through_cli(cluster3, tmp_path):
     assert "usage" in cli.run_command("put onlyonearg")
 
 
+@pytest.mark.parametrize("line", ["export resnet18", "export-bundle resnet18 /tmp/b"])
+def test_retired_verbs_are_unknown_commands(line):
+    """The exported-executable path went in PR 29; its verbs answer as any
+    unknown verb does (no node is touched: the dispatcher falls through)."""
+    verb = line.split()[0]
+    assert Cli(node=None).run_command(line) == f"unknown command {verb!r} (try: help)"
+
+
+def test_help_lists_no_retired_verb():
+    first_words = {ln.split()[0] for ln in Cli(node=None).run_command("help").splitlines() if ln.strip()}
+    assert {"predict", "train", "mesh-join"} <= first_words
+    assert not {"export", "export-bundle"} & first_words
+
+
+@pytest.mark.parametrize("fault", ["rpc_not_found", "compiler"])
+def test_a_warmup_that_raises_stops_the_node(tmp_path, fault):
+    """No backend's warm-up failure is tolerated, whatever its class or
+    message (until PR 29 one backend's "not in SDFS" was)."""
+    from dmlc_tpu.cluster.rpc import RpcError
+
+    error = {
+        "rpc_not_found": RpcError("executables/resnet18 not in SDFS"),
+        "compiler": RuntimeError("Mosaic failed to compile"),
+    }[fault]
+
+    class Backend:
+        def __call__(self, synsets):
+            return [0 for _ in synsets]
+
+        def warmup(self):
+            raise error
+
+    with pytest.raises(type(error), match=str(error)):
+        start_local_cluster(
+            tmp_path, n_nodes=1, backends={"resnet18": Backend(), "alexnet": Backend()}
+        )
+
+
 def test_authenticated_cluster_end_to_end(tmp_path):
     """A fleet sharing auth_key converges, replicates, and serves jobs with
     every gossip datagram and RPC frame HMAC-tagged — and an unkeyed caller
