@@ -12,8 +12,9 @@ main path once, through the entry points a user would call:
   same seed-initialised weights computed on the host CPU.
 - **generate**: the same node's ``lm_small`` generation plane at the config
   defaults: overlapping ``leader.generate`` calls through GenRouter ->
-  GenerateWorker -> SlotScheduler -> GenerationEngine, greedy tokens checked
-  against a ``cache="contiguous", use_pallas=False`` engine; then the step
+  GenerateWorker -> SlotScheduler -> GenerationEngine, every greedy token
+  checked against a ``cache="contiguous", use_pallas=False`` engine fed the
+  served prefix (its best token, or a bfloat16 near-tie of it); then the step
   and the prefill compiled at gpt2-large's cache geometry from abstract
   arguments, which must alias both KV pools and keep their temporaries under
   the size of one pool (``pool_memory``).
@@ -232,8 +233,10 @@ def serve_phase(node, *, model: str, synsets, data_dir,
 
 def generate_phase(node, *, model: str, prompts, max_new) -> dict:
     """Overlapping ``leader.generate`` calls (so slots join and leave a
-    running batch), each stream checked token for token against a
-    contiguous-cache, XLA-gather engine run of the same prompt."""
+    running batch), every served token checked against a contiguous-cache,
+    XLA-attention engine that is fed the served prefix."""
+    import numpy as np
+
     from dmlc_tpu.generate.engine import GenerationEngine
 
     results: list = [None] * len(prompts)
@@ -263,19 +266,34 @@ def generate_phase(node, *, model: str, prompts, max_new) -> dict:
     cfg = node.config
     ref = GenerationEngine(
         model, cache="contiguous", use_pallas=False, max_slots=1,
-        max_prefill=cfg.gen_max_prefill,
+        max_prefill=cfg.gen_max_prefill, return_logits=True,
     )
+    # The served engine's attention is a fused kernel with its own order of
+    # float32 sums, and the model's matmuls round their inputs to bfloat16 on
+    # the chip: a served token is right if it is the reference's best token
+    # GIVEN THE SERVED PREFIX, or within a bfloat16 near-tie of it.
+    worst_gap, same = 0.0, 0
     for i, prompt in enumerate(prompts):
-        want = [ref.join(0, prompt)]
-        while len(want) < max_new[i]:
-            want.append(int(ref.step()[0]))
-        ref.release(0)
         got = results[i]["tokens"]
-        if got != want:
+        first = ref.join(0, prompt)  # the prefill is one program on both sides
+        if len(got) != max_new[i] or got[0] != first:
             raise AssertionError(
-                f"stream {i} (prompt of {len(prompt)}) diverged from the "
-                f"contiguous reference: got {got} want {want}"
-            )
+                f"stream {i} (prompt of {len(prompt)}): {len(got)} tokens starting "
+                f"{got[:1]}, want {max_new[i]} starting [{first}]")
+        same += 1
+        for j in range(1, len(got)):
+            ref.last_tokens[0] = got[j - 1]
+            ref.step()
+            logits = ref.last_logits[0]
+            gap = float(logits.max() - logits[got[j]]) / float(np.abs(logits).max())
+            if gap > BF16_TOL:
+                raise AssertionError(
+                    f"stream {i} (prompt of {len(prompt)}) token {j}: served {got[j]}, "
+                    f"the contiguous reference prefers {int(logits.argmax())} by "
+                    f"{gap:.3e} of its largest logit (near-tie bound {BF16_TOL:.3e})")
+            worst_gap = max(worst_gap, gap)
+            same += gap == 0.0
+        ref.release(0)
     summary = sched.summary()
     serial_steps = sum(n - 1 for n in max_new)
     if not summary["steps"] < serial_steps:
@@ -286,6 +304,8 @@ def generate_phase(node, *, model: str, prompts, max_new) -> dict:
     return {
         "streams": len(prompts),
         "tokens_checked": sum(max_new),
+        "tokens_best_of_reference": same,
+        "worst_near_tie": float(f"{worst_gap:.3e}"),
         "decode_steps": summary["steps"],
         "serial_steps": serial_steps,
         "use_pallas": summary["use_pallas"],
@@ -406,7 +426,9 @@ MOSAIC_CALL = "tpu_custom_call"
 
 #: Shapes the kernels phase compiles at: the serving batch for the two
 #: vision kernels, a training-grade bf16 Dh=128 attention on both sides of
-#: the resident/streamed K/V switch, lm_small's page geometry.
+#: the resident/streamed K/V switch, and the fused decode attention at the
+#: two forms the cells run: gpt2-large's heads (20 x 64, multi-head) and
+#: nemotron3-super's (32 query heads on 2 KV heads of 128).
 KERNEL_SHAPES = {
     "images": (256, 224, 224, 3),
     "logits": (256, 1000),
@@ -415,9 +437,10 @@ KERNEL_SHAPES = {
     "s_resident": 2048,
     "s_streamed": 16384,
     "sp_s_local": 1024,
-    "pages": (128, 16, 2 * 64),  # [rows, page_size, kv_heads * head_dim]
-    "kv_heads": 2,
-    "page_table": (8, 16),       # [max_slots, max_pages_per_slot]
+    "paged_mha": (20, 20, 64),   # (heads, kv_heads, head_dim)
+    "paged_gqa": (32, 2, 128),
+    "paged_slots": 24,
+    "paged_table": 64,           # pages a slot's table names, 16 tokens each
 }
 
 
@@ -444,7 +467,7 @@ def _attention_ref(q, k, v, *, causal: bool, chunk: int = 2048):
     return jnp.concatenate(outs, axis=2)
 
 
-def _run_kernel(name: str, fn, args, ref_fn, tol: float, *, exact: bool = False) -> dict:
+def _run_kernel(name: str, fn, args, ref_fn, tol: float) -> dict:
     """Lower ``fn`` (the lowered text must hold the Mosaic custom call —
     an interpreted kernel has none), compile, run, and compare every output
     leaf with ``ref_fn`` by max error relative to the reference's scale."""
@@ -464,10 +487,7 @@ def _run_kernel(name: str, fn, args, ref_fn, tol: float, *, exact: bool = False)
             raise AssertionError(f"{name}: shape {g.shape} != reference {w.shape}")
         if not np.isfinite(g).all():
             raise AssertionError(f"{name}: non-finite output")
-        if exact:
-            err = float((g != w).any())
-        else:
-            err = float(np.abs(g - w).max() / max(np.abs(w).max(), 1e-30))
+        err = float(np.abs(g - w).max() / max(np.abs(w).max(), 1e-30))
         worst = max(worst, err)
     if worst > tol:
         raise AssertionError(f"{name}: max relative error {worst:.3e} > {tol:.3e}")
@@ -486,7 +506,11 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
 
     from dmlc_tpu.ops import pallas_kernels as pk
     from dmlc_tpu.ops import preprocess as pp
-    from dmlc_tpu.ops.ragged_decode import gather_kv_pages
+    from dmlc_tpu.ops.ragged_decode import (
+        gather_kv_pages,
+        paged_decode_attention,
+        ragged_decode_attention,
+    )
     from dmlc_tpu.parallel.mesh import make_mesh
     from dmlc_tpu.parallel.ring_attention import ring_flash_attention
     from dmlc_tpu.parallel.ulysses import ulysses_attention
@@ -511,9 +535,34 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
 
     images = jax.random.randint(next(keys), shapes["images"], 0, 256, jnp.int32).astype(jnp.uint8)
     logits = jax.random.normal(next(keys), shapes["logits"], jnp.float32) * 3.0
-    n_pages, kv_heads = shapes["pages"][0], shapes["kv_heads"]
-    table = jax.random.randint(next(keys), shapes["page_table"], 0, n_pages, jnp.int32)
-    pages = jax.random.normal(next(keys), shapes["pages"], jnp.float32)
+
+    def paged_case(heads, kv_heads, head_dim, page_size=16):
+        """bfloat16 pools of two layers (the second is read), float32 queries
+        so that the float32 sums show in the result; lengths of 1, a page,
+        a page + 3, a full table, and whatever the key draws."""
+        slots, per_slot = shapes["paged_slots"], shapes["paged_table"]
+        n_pages = slots * per_slot + 1
+        pools = [jax.random.normal(next(keys), (2 * n_pages, page_size, kv_heads * head_dim),
+                                   jnp.bfloat16) for _ in range(2)]
+        table = 1 + jax.random.permutation(next(keys), n_pages - 1)[: slots * per_slot]
+        lengths = jax.random.randint(next(keys), (slots,), 1, per_slot * page_size + 1)
+        lengths = lengths.at[:4].set(
+            jnp.asarray([1, page_size, page_size + 3, per_slot * page_size]))
+        q = jax.random.normal(next(keys), (slots, heads, head_dim), jnp.float32)
+        args = (q, *pools, table.reshape(slots, per_slot).astype(jnp.int32), lengths)
+
+        def fused(q, k_pool, v_pool, table, lengths):
+            return paged_decode_attention(q, k_pool, v_pool, table, lengths,
+                                          first_row=n_pages, kv_heads=kv_heads)
+
+        def reference(q, k_pool, v_pool, table, lengths):
+            ks, vs = (gather_kv_pages(pool, table, kv_heads, first_row=n_pages)
+                      for pool in (k_pool, v_pool))
+            with jax.default_matmul_precision("highest"):
+                return ragged_decode_attention(q, ks, vs, lengths)
+
+        return fused, args, reference, 1e-4
+
     n = len(devices)
     mesh = make_mesh({"sp": n}, devices=devices)
     sp_args = qkv(shapes["sp_s_local"] * n)
@@ -523,32 +572,29 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
          lambda u8: pk.normalize_u8(u8, pp.IMAGENET_MEAN, pp.IMAGENET_STD),
          (images,),
          lambda u8: (u8.astype(jnp.float32) / 255.0 - pp.IMAGENET_MEAN) / pp.IMAGENET_STD,
-         1e-5, {}),
+         1e-5),
         ("softmax_top1", pk.softmax_top1, (logits,),
          lambda x: (jnp.argmax(x, -1).astype(jnp.int32), jnp.max(jax.nn.softmax(x, -1), -1)),
-         1e-4, {}),
-        ("flash_fwd_resident", causal_flash, qkv(shapes["s_resident"]), causal_ref, BF16_TOL, {}),
+         1e-4),
+        ("flash_fwd_resident", causal_flash, qkv(shapes["s_resident"]), causal_ref, BF16_TOL),
         ("flash_fwd_streamed", causal_flash, qkv(shapes["s_streamed"], heads=2),
-         causal_ref, BF16_TOL, {}),
+         causal_ref, BF16_TOL),
         ("flash_bwd", grads(causal_flash), qkv(shapes["s_resident"]),
-         grads(causal_ref), 2 * BF16_TOL, {}),
-        ("page_gather_f32", lambda p, t: gather_kv_pages(p, t, kv_heads, use_pallas=True),
-         (pages, table), lambda p, t: gather_kv_pages(p, t, kv_heads), 0.0, {"exact": True}),
-        ("page_gather_bf16", lambda p, t: gather_kv_pages(p, t, kv_heads, use_pallas=True),
-         (pages.astype(jnp.bfloat16), table), lambda p, t: gather_kv_pages(p, t, kv_heads),
-         0.0, {"exact": True}),
+         grads(causal_ref), 2 * BF16_TOL),
+        ("paged_attention_mha_bf16", *paged_case(*shapes["paged_mha"])),
+        ("paged_attention_gqa_bf16", *paged_case(*shapes["paged_gqa"])),
         (f"ring_flash_sp{n}",
          lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
-         sp_args, causal_ref, BF16_TOL, {}),
+         sp_args, causal_ref, BF16_TOL),
         (f"ulysses_flash_sp{n}",
          lambda q, k, v: ulysses_attention(q, k, v, mesh, causal=True, use_flash=True),
-         sp_args, causal_ref, BF16_TOL, {}),
+         sp_args, causal_ref, BF16_TOL),
     ]
     out: dict = {}
     failures: list[str] = []
-    for name, fn, args, ref_fn, tol, kw in cases:
+    for name, fn, args, ref_fn, tol in cases:
         try:
-            out[name] = _run_kernel(name, fn, args, ref_fn, tol, **kw)
+            out[name] = _run_kernel(name, fn, args, ref_fn, tol)
             say(f"kernel {name}: {out[name]}")
         except Exception as e:  # recorded, and the phase raises below
             msg = f"{type(e).__name__}: {e}"
@@ -692,7 +738,7 @@ def main() -> int:
             if info["chips"] != len(devices):
                 raise AssertionError(f"node.info chips={info['chips']}, jax sees {len(devices)}")
             if not status["generate"]["models"][GEN_MODEL]["use_pallas"]:
-                raise AssertionError("node.status: the engine serves the XLA gather on the chip")
+                raise AssertionError("node.status: the engine serves the XLA attention on the chip")
             engine = node._gen_backends[GEN_MODEL]._scheduler.engine
             if MOSAIC_CALL not in lowered_step_text(engine):
                 raise AssertionError(

@@ -95,18 +95,25 @@ class _StepKV:
 
     def write_attend(self, layer: int, q: Any, k: Any, v: Any) -> Any:
         """q: [B, H, Dh]; k, v: [B, KV, Dh] (H a multiple of KV) -> [B, H, Dh]."""
-        from dmlc_tpu.ops.ragged_decode import gather_kv_pages, ragged_decode_attention
+        from dmlc_tpu.ops.ragged_decode import (
+            gather_kv_pages,
+            paged_decode_attention,
+            ragged_decode_attention,
+        )
 
         if self._paged:
-            # One row per slot into the donated pool, then the gather reads
+            # One row per slot into the donated pool, then the attention reads
             # the pool where it lives: no layer is cut out of it.
             first = layer * self._num_pages
             dest = (first + self._dest_page, self._dest_off)
             self.k_state = self.k_state.at[dest].set(k.reshape(k.shape[0], -1))
             self.v_state = self.v_state.at[dest].set(v.reshape(v.shape[0], -1))
+            if self._use_pallas:
+                return paged_decode_attention(
+                    q, self.k_state, self.v_state, self._page_table, self._kv_lengths,
+                    first_row=first, kv_heads=self._kv_heads)
             ks, vs = (
-                gather_kv_pages(pool, self._page_table, self._kv_heads,
-                                first_row=first, use_pallas=self._use_pallas)
+                gather_kv_pages(pool, self._page_table, self._kv_heads, first_row=first)
                 for pool in (self.k_state, self.v_state))
         else:
             self.k_state = self.k_state.at[layer, self._batch, self._lengths].set(k)

@@ -713,9 +713,9 @@ class SlotScheduler:
             "tok_s": round(self.tok_s(), 2),
             "slots_active": self.engine.slots_active,
             "pages_free": self.engine.pages_free,
-            # Which page gather serves: the Pallas kernel (compiled on a
-            # TPU) or the XLA take — on node.status so a caller can tell,
-            # and chip_smoke.py can refuse, the fallback.
+            # Which decode attention serves: the fused Pallas kernel (compiled
+            # on a TPU) or the XLA take + mask — on node.status so a caller
+            # can tell, and chip_smoke.py can refuse, the fallback.
             "use_pallas": self.engine.use_pallas,
             "max_active": self.max_active,
             "page_budget": self.page_budget,

@@ -58,6 +58,9 @@ def test_serve_and_generate_phases_at_tiny_size(tmp_path):
             node, model="lm_small", prompts=prompts, max_new=[8, 6, 5],
         )
         assert generated["tokens_checked"] == 19
+        # One attention on both sides here: every token is the reference's best.
+        assert generated["tokens_best_of_reference"] == 19
+        assert generated["worst_near_tie"] == 0.0
         assert generated["decode_steps"] < generated["serial_steps"]
         # Off the TPU the engine serves the XLA gather, and says so.
         assert generated["use_pallas"] is False
@@ -73,6 +76,7 @@ def test_kernels_phase_at_tiny_shapes():
     shapes = dict(
         chip_smoke.KERNEL_SHAPES, images=(4, 32, 32, 3), logits=(16, 40),
         attn_heads=2, attn_dh=16, s_resident=128, s_streamed=256, sp_s_local=64,
+        paged_mha=(4, 4, 8), paged_gqa=(4, 2, 16), paged_slots=5, paged_table=3,
     )
     out = chip_smoke.kernels_phase(jax.devices()[:2], shapes)
     assert len(out) == 9

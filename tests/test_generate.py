@@ -231,30 +231,16 @@ class TestSampling:
 
 
 # ---------------------------------------------------------------------------
-# pallas page-gather kernel (interpret mode off-TPU)
+# the gathered view and the masked attention (the fused kernel's reference;
+# the kernel itself is pinned against them in tests/test_kv_pool.py)
 # ---------------------------------------------------------------------------
 
 
-class TestPageGatherKernel:
-    def test_pallas_gather_matches_xla(self):
-        from dmlc_tpu.ops.ragged_decode import gather_kv_pages
-
-        rng = np.random.default_rng(0)
-        pages = jnp.asarray(
-            rng.standard_normal((10, 4, 2 * 8)).astype(np.float32)
-        )
-        table = jnp.asarray(
-            rng.integers(0, 10, size=(3, 5)).astype(np.int32)
-        )
-        ref = gather_kv_pages(pages, table, 2, use_pallas=False)
-        out = gather_kv_pages(pages, table, 2, use_pallas=True)
-        np.testing.assert_allclose(np.asarray(out), np.asarray(ref))
-        assert out.shape == (3, 20, 2, 8)
-
+class TestGatheredViewAttention:
     @pytest.mark.parametrize("layer", [0, 2])
     @pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16])
     def test_gathers_agree_on_the_folded_pool(self, layer, dtype):
-        """Both gathers read layer ``layer`` of a pool that holds three, by a
+        """The gather reads layer ``layer`` of a pool that holds three, by a
         table that repeats pages and points at scratch; KV heads unfold from
         the row (2 x 24 = 48 wide: no multiple of a tile)."""
         from dmlc_tpu.ops.ragged_decode import gather_kv_pages
@@ -266,11 +252,9 @@ class TestPageGatherKernel:
         table = jnp.asarray([[5, 5, SCRATCH_PAGE, 1], [SCRATCH_PAGE] * 4, [3, 1, 5, 3]], jnp.int32)
         want = np.asarray(pool, np.float32).reshape(3, num_pages, page_size, kv_heads, head_dim)[
             layer][np.asarray(table)].reshape(3, 4 * page_size, kv_heads, head_dim)
-        for use_pallas in (False, True):
-            got = gather_kv_pages(pool, table, kv_heads, first_row=layer * num_pages,
-                                  use_pallas=use_pallas)
-            assert got.dtype == dtype
-            np.testing.assert_array_equal(np.asarray(got, np.float32), want)
+        got = gather_kv_pages(pool, table, kv_heads, first_row=layer * num_pages)
+        assert got.dtype == dtype
+        np.testing.assert_array_equal(np.asarray(got, np.float32), want)
 
     def test_ragged_mask_excludes_beyond_length(self):
         from dmlc_tpu.ops.ragged_decode import ragged_decode_attention

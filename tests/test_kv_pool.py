@@ -1,10 +1,13 @@
 """The KV pools as both programs share them (generate/kvcache.py layout):
 ``[kv_layers * num_pages, page_size, kv_heads * head_dim]``, written in place
-by the step and the prefill and read where they live by the gather.
+by the step and the prefill and read where they live by the attention.
 
 - a prefill followed by steps gives the contiguous cache's logits at any
   (heads, KV heads, head width), grouped-query and rows that fill no tile
-  included, through either gather;
+  included, through the gathered view and through the fused kernel;
+- the fused kernel (``ops/ragged_decode.paged_decode_attention``) gives what
+  the gathered view and the masked attention give, reads no page a slot does
+  not hold and nothing past a slot's length;
 - a prefill touches the slot's page run and the scratch page, nothing else;
 - the names the benchmark reads (``engine.cache.k_pages``, ``engine._k_state``)
   answer, and deleting them frees every pool buffer;
@@ -23,6 +26,7 @@ import jax.numpy as jnp  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
 from dmlc_tpu.generate.kvcache import SCRATCH_PAGE  # noqa: E402
 from dmlc_tpu.models import registry  # noqa: E402
+from dmlc_tpu.ops import ragged_decode  # noqa: E402
 
 VOCAB, MAX_LEN, LAYERS, PAGE = 40, 32, 2, 4
 
@@ -128,14 +132,17 @@ def run(engine, prompts, n_steps):
     return np.stack(out)
 
 
-@pytest.mark.parametrize("use_pallas", [False, True], ids=["take", "pallas"])
-@pytest.mark.parametrize("heads,kv_heads,head_dim", [
+GEOMETRIES = pytest.mark.parametrize("heads,kv_heads,head_dim", [
     (4, 4, 8),     # multi-head, a 32-wide row
     (4, 2, 16),    # grouped-query
     (6, 2, 24),    # grouped-query, a 48-wide row
     (2, 1, 64),    # one KV head
     (5, 5, 40),    # 200 wide: over one tile, no multiple of 128
 ])
+
+
+@pytest.mark.parametrize("use_pallas", [False, True], ids=["take", "pallas"])
+@GEOMETRIES
 def test_paged_pool_gives_the_contiguous_caches_logits(
         family_engine, heads, kv_heads, head_dim, use_pallas):
     rng = np.random.default_rng(heads * 100 + head_dim)
@@ -206,3 +213,104 @@ def test_each_program_consumes_the_pool_it_is_handed(family_engine, program):
     assert all(pool.is_deleted() for pool in handed)
     assert not engine._k_state.is_deleted() and not engine._v_state.is_deleted()
     assert engine.cache.k_pages is engine._k_state and engine.cache.v_pages is engine._v_state
+
+
+# ---------------------------------------------------------------------------
+# the fused kernel against the gathered view + the masked attention
+# ---------------------------------------------------------------------------
+
+FUSED_PAGES = 6  # a slot's table: 24 positions
+
+
+def pool_case(heads, kv_heads, head_dim, lengths, *, dtype=jnp.float32, seed=0):
+    """(q, k_pool, v_pool, table, lengths, num_pages): two layers of pages, a
+    slot's pages scattered over its layer, unused table entries on scratch."""
+    rng = np.random.default_rng(seed)
+    slots, width = len(lengths), kv_heads * head_dim
+    num_pages = slots * FUSED_PAGES + 1
+    k_pool, v_pool = (jnp.asarray(rng.standard_normal(
+        (LAYERS * num_pages, PAGE, width)), jnp.float32).astype(dtype) for _ in range(2))
+    free = iter(rng.permutation(np.arange(1, num_pages)))
+    table = np.full((slots, FUSED_PAGES), SCRATCH_PAGE, np.int32)
+    for slot, length in enumerate(lengths):
+        held = -(-length // PAGE)
+        table[slot, :held] = [next(free) for _ in range(held)]
+    q = jnp.asarray(rng.standard_normal((slots, heads, head_dim)), jnp.float32).astype(dtype)
+    return q, k_pool, v_pool, jnp.asarray(table), jnp.asarray(lengths, jnp.int32), num_pages
+
+
+def fused(q, k_pool, v_pool, table, lengths, first_row, kv_heads):
+    return np.asarray(ragged_decode.paged_decode_attention(
+        q, k_pool, v_pool, table, lengths, first_row=first_row, kv_heads=kv_heads), np.float32)
+
+
+def gathered(q, k_pool, v_pool, table, lengths, first_row, kv_heads):
+    ks, vs = (ragged_decode.gather_kv_pages(pool, table, kv_heads, first_row=first_row)
+              for pool in (k_pool, v_pool))
+    return np.asarray(ragged_decode.ragged_decode_attention(q, ks, vs, lengths), np.float32)
+
+
+@pytest.fixture(params=[256, 2 * PAGE], ids=["one_chunk", "chunks_of_two_pages"])
+def chunked(request, monkeypatch):
+    monkeypatch.setattr(ragged_decode, "_CHUNK_TOKENS", request.param)
+
+
+@GEOMETRIES
+def test_fused_attention_is_the_gathered_views(chunked, heads, kv_heads, head_dim):
+    # 1, exactly a page, a page + 3, a slot at its full table; the second layer.
+    *case, num_pages = pool_case(heads, kv_heads, head_dim,
+                                 [1, PAGE, PAGE + 3, FUSED_PAGES * PAGE])
+    got = fused(*case, num_pages, kv_heads)
+    want = gathered(*case, num_pages, kv_heads)
+    assert got.shape == (4, heads, head_dim)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
+    assert np.abs(want).max() > 0.1
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [(4, 4, 8), (4, 2, 16)],
+                         ids=["multi_head", "grouped_query"])
+def test_fused_attention_on_bfloat16_pools_keeps_float32_sums(chunked, heads, kv_heads, head_dim):
+    """bfloat16 K, V and q as the cells store them: the kernel's scores,
+    softmax and weighted sum are float32, so what differs from the float32
+    einsums of the reference is the order of the sums (and the result's own
+    rounding to bfloat16: half a unit in its last place)."""
+    *case, _ = pool_case(heads, kv_heads, head_dim, [3, 2 * PAGE + 1, FUSED_PAGES * PAGE],
+                         dtype=jnp.bfloat16)
+    got, want = fused(*case, 0, kv_heads), gathered(*case, 0, kv_heads)
+    np.testing.assert_allclose(got, want, atol=1e-6, rtol=2 ** -8)
+
+
+@GEOMETRIES
+def test_fused_attention_reads_nothing_a_slot_does_not_hold(chunked, heads, kv_heads, head_dim):
+    """Every page no slot holds (scratch among them) and the tail of every
+    slot's last page filled with NaN: the output is finite and unchanged."""
+    q, k_pool, v_pool, table, lengths, num_pages = pool_case(
+        heads, kv_heads, head_dim, [1, PAGE + 3, 3 * PAGE])
+    clean = fused(q, k_pool, v_pool, table, lengths, 0, kv_heads)
+    poison = np.ones((LAYERS * num_pages, PAGE), bool)
+    for slot, length in enumerate(np.asarray(lengths)):
+        for j in range(-(-length // PAGE)):
+            poison[int(table[slot, j]), :min(PAGE, length - j * PAGE)] = False
+    poison = jnp.asarray(poison)[:, :, None]
+    poisoned = fused(q, jnp.where(poison, jnp.nan, k_pool), jnp.where(poison, jnp.nan, v_pool),
+                     table, lengths, 0, kv_heads)
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(poisoned, clean)
+
+
+@pytest.mark.parametrize("heads,kv_heads,head_dim", [(4, 4, 8), (6, 2, 24)],
+                         ids=["multi_head", "grouped_query"])
+def test_fused_attention_with_inactive_slots_beside_full_ones(chunked, heads, kv_heads, head_dim):
+    """An inactive slot is a row of scratch-page entries and length 1 (the
+    engine's ``kv_lengths``): it attends the scratch page's first position,
+    so its row is that position's V, and its neighbours are not disturbed."""
+    full = FUSED_PAGES * PAGE
+    q, k_pool, v_pool, table, lengths, num_pages = pool_case(
+        heads, kv_heads, head_dim, [full, 1, full, 1])
+    table = table.at[jnp.asarray([1, 3])].set(SCRATCH_PAGE)
+    got = fused(q, k_pool, v_pool, table, lengths, num_pages, kv_heads)
+    np.testing.assert_allclose(
+        got, gathered(q, k_pool, v_pool, table, lengths, num_pages, kv_heads),
+        atol=2e-6, rtol=1e-5)
+    scratch_v = np.asarray(v_pool[num_pages + SCRATCH_PAGE, 0]).reshape(kv_heads, head_dim)
+    np.testing.assert_allclose(got[1], np.repeat(scratch_v, heads // kv_heads, axis=0), atol=1e-6)

@@ -40,7 +40,7 @@ def test_step_and_prefill_update_the_pools_in_place(one_chip, monkeypatch):
     from dmlc_tpu.models import registry
     from dmlc_tpu.ops import ragged_decode
 
-    # The gather must lower through Mosaic, as it does on the chip.
+    # The attention kernel must lower through Mosaic, as it does on the chip.
     monkeypatch.setattr(ragged_decode, "interpret_mode", lambda: False)
     # A compile for a described chip is written to the persistent cache and
     # cannot be read back without the chip: keep it out.
@@ -59,3 +59,8 @@ def test_step_and_prefill_update_the_pools_in_place(one_chip, monkeypatch):
         assert memory[program]["alias_bytes"] >= 2 * pool
         assert memory[program]["temp_bytes"] < pool
     assert memory["step"]["mosaic"] and not memory["prefill"]["mosaic"]
+    # The attention reads the pool's pages and keeps no padded view of them:
+    # the step's temporaries stay under ONE float32 unfolding of such a view
+    # (the two gathered bfloat16 views alone were that much).
+    g = GEOMETRY
+    assert memory["step"]["temp_bytes"] < g["max_slots"] * g["max_len"] * g["hidden"] * 4
