@@ -427,8 +427,9 @@ MOSAIC_CALL = "tpu_custom_call"
 #: Shapes the kernels phase compiles at: the serving batch for the two
 #: vision kernels, a training-grade bf16 Dh=128 attention on both sides of
 #: the resident/streamed K/V switch, and the fused decode attention at the
-#: two forms the cells run: gpt2-large's heads (20 x 64, multi-head) and
-#: nemotron3-super's (32 query heads on 2 KV heads of 128).
+#: forms the cells run: gpt2-large's heads (20 x 64, multi-head),
+#: nemotron3-super's (32 query heads on 2 KV heads of 128) and
+#: olmo-hybrid-7b's (30 x 128, multi-head: pool rows of 3,840 lanes).
 KERNEL_SHAPES = {
     "images": (256, 224, 224, 3),
     "logits": (256, 1000),
@@ -439,6 +440,7 @@ KERNEL_SHAPES = {
     "sp_s_local": 1024,
     "paged_mha": (20, 20, 64),   # (heads, kv_heads, head_dim)
     "paged_gqa": (32, 2, 128),
+    "paged_mha_wide": (30, 30, 128),
     "paged_slots": 24,
     "paged_table": 64,           # pages a slot's table names, 16 tokens each
 }
@@ -583,6 +585,7 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
          grads(causal_ref), 2 * BF16_TOL),
         ("paged_attention_mha_bf16", *paged_case(*shapes["paged_mha"])),
         ("paged_attention_gqa_bf16", *paged_case(*shapes["paged_gqa"])),
+        ("paged_attention_mha_30x128_bf16", *paged_case(*shapes["paged_mha_wide"])),
         (f"ring_flash_sp{n}",
          lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
          sp_args, causal_ref, BF16_TOL),

@@ -36,6 +36,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from dmlc_tpu.models.seeded_tree import SeededTreeModule
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk
 
 HIGHEST = jax.lax.Precision.HIGHEST
@@ -155,10 +156,6 @@ def param_shapes(cfg: NemotronHConfig) -> dict:
     return tree
 
 
-def _is_shape(node: Any) -> bool:
-    return isinstance(node, tuple)
-
-
 def _leaf_mean_std(path: str, depth: int) -> tuple[float, float]:
     """Seed init, sized so activations stay O(1) through the stack (a
     served configuration brings its own table: the benchmark's is in its
@@ -182,30 +179,14 @@ def _leaf_mean_std(path: str, depth: int) -> tuple[float, float]:
     return 0.0, 0.02
 
 
-class NemotronHModule:
-    """What the registry's ``init_params`` needs of a module: ``init(rng,
-    tokens) -> {"params": tree}``. Plain functions over the tree do the
-    rest, so there is no flax module to keep in step with them."""
+class NemotronHModule(SeededTreeModule):
+    """The family's parameter tree as the registry's ``init_params`` draws it."""
 
     def __init__(self, config: NemotronHConfig, dtype: Any = jnp.float32) -> None:
+        depth = len(config.layer_kinds)
+        super().__init__(param_shapes(config), lambda path: _leaf_mean_std(path, depth),
+                         vocab=config.vocab_size, max_len=config.max_len, dtype=dtype)
         self.config = config
-        self.dtype = dtype
-        self.vocab = config.vocab_size
-        self.max_len = config.max_len
-
-    def init(self, rng: Any, tokens: Any = None) -> dict:
-        del tokens  # the tree is sized by the config, not by an example
-        depth = len(self.config.layer_kinds)
-
-        def build(node: Any, path: str, key: Any) -> Any:
-            if _is_shape(node):
-                mean, std = _leaf_mean_std(path, depth)
-                return (mean + std * jax.random.normal(key, node, jnp.float32)).astype(self.dtype)
-            keys = jax.random.split(key, len(node))
-            return {name: build(child, f"{path}/{name}", k)
-                    for (name, child), k in zip(sorted(node.items()), keys)}
-
-        return {"params": build(param_shapes(self.config), "", rng)}
 
 
 # ---------------------------------------------------------------------------

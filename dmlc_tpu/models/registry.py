@@ -289,3 +289,10 @@ for _spec in [
 from dmlc_tpu.models.nemotron_h import NEMOTRON_H_TINY, register_nemotron_h  # noqa: E402
 
 register_nemotron_h("nemotron_h_tiny", NEMOTRON_H_TINY)
+
+# The Olmo-Hybrid family's CPU-test preset (gated delta-rule linear attention
+# 3:1 with full attention, two residual branches a layer); real sizes are
+# registered by whoever serves them (``models/olmo_hybrid.register_olmo_hybrid``).
+from dmlc_tpu.models.olmo_hybrid import OLMO_HYBRID_TINY, register_olmo_hybrid  # noqa: E402
+
+register_olmo_hybrid("olmo_hybrid_tiny", OLMO_HYBRID_TINY)

@@ -132,13 +132,18 @@ def run(engine, prompts, n_steps):
     return np.stack(out)
 
 
-GEOMETRIES = pytest.mark.parametrize("heads,kv_heads,head_dim", [
+_GEOMETRIES = [
     (4, 4, 8),     # multi-head, a 32-wide row
     (4, 2, 16),    # grouped-query
     (6, 2, 24),    # grouped-query, a 48-wide row
     (2, 1, 64),    # one KV head
     (5, 5, 40),    # 200 wide: over one tile, no multiple of 128
-])
+]
+GEOMETRIES = pytest.mark.parametrize("heads,kv_heads,head_dim", _GEOMETRIES)
+#: The kernel alone (no engine, so no weights of that width) also at a served
+#: model's row: 30 heads x 128 = 3,840 lanes, 30 query rows padded to 32.
+KERNEL_GEOMETRIES = pytest.mark.parametrize(
+    "heads,kv_heads,head_dim", _GEOMETRIES + [(30, 30, 128)])
 
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["take", "pallas"])
@@ -255,7 +260,7 @@ def chunked(request, monkeypatch):
     monkeypatch.setattr(ragged_decode, "_CHUNK_TOKENS", request.param)
 
 
-@GEOMETRIES
+@KERNEL_GEOMETRIES
 def test_fused_attention_is_the_gathered_views(chunked, heads, kv_heads, head_dim):
     # 1, exactly a page, a page + 3, a slot at its full table; the second layer.
     *case, num_pages = pool_case(heads, kv_heads, head_dim,
@@ -280,7 +285,7 @@ def test_fused_attention_on_bfloat16_pools_keeps_float32_sums(chunked, heads, kv
     np.testing.assert_allclose(got, want, atol=1e-6, rtol=2 ** -8)
 
 
-@GEOMETRIES
+@KERNEL_GEOMETRIES
 def test_fused_attention_reads_nothing_a_slot_does_not_hold(chunked, heads, kv_heads, head_dim):
     """Every page no slot holds (scratch among them) and the tail of every
     slot's last page filled with NaN: the output is finite and unchanged."""

@@ -8,11 +8,11 @@ runs ``nemotron_h_tiny`` (pattern ``ME*E``, 8 experts top 2, 2 held) in
 float32 with seeded weights.
 """
 
-import importlib.util
-from pathlib import Path
 
 import numpy as np
+import plain_reference
 import pytest
+from plain_reference import flat_of
 
 pytest.importorskip("jax")
 import jax  # noqa: E402
@@ -39,15 +39,7 @@ VOCAB = CFG.vocab_size
 LOGIT_ATOL = 5e-5
 
 
-def _load_reference():
-    path = Path(__file__).resolve().parents[1] / "benchmark" / "reference" / "nemotron_h.py"
-    spec = importlib.util.spec_from_file_location("bench_reference_nemotron_h", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-REF = _load_reference()
+REF = plain_reference.load("nemotron_h")
 
 
 def ref_cfg(cfg=CFG, held=None) -> dict:
@@ -62,17 +54,6 @@ def ref_cfg(cfg=CFG, held=None) -> dict:
     out["published"] = {"n_routed_experts": cfg.n_routed_experts}
     out["deployment"] = {"experts_held": [first, count]}
     return out
-
-
-def flat_of(variables) -> dict:
-    def walk(tree, prefix=""):
-        for key, value in tree.items():
-            path = f"{prefix}/{key}" if prefix else key
-            if isinstance(value, dict):
-                yield from walk(value, path)
-            else:
-                yield path, value
-    return dict(walk(variables))
 
 
 @pytest.fixture(scope="module")

@@ -10,6 +10,12 @@ finding 2) shows as temporaries of several pools. Nothing runs and nothing
 here is a time. Every test of this kind lives in this one file, and the
 topology is described inside a fixture: one process at a time may load the
 TPU's library.
+
+The second test is the hybrid family's geometry (``models/olmo_hybrid`` at its
+published widths, one period of four layers): pool rows of 30 heads x 128 =
+3,840 lanes, three times gpt2-large's, through the same attention kernel at
+its 256-position chunk, beside a float32 matrix state of 2.2 MB a slot a
+layer that both programs must also update in place.
 """
 
 import pytest
@@ -34,24 +40,30 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
-def test_step_and_prefill_update_the_pools_in_place(one_chip, monkeypatch):
+@pytest.fixture
+def compiled_for_the_chip(monkeypatch):
+    """The attention kernel lowers through Mosaic, as it does on the chip, and
+    the persistent cache is off: a compile for a described chip is written to
+    it and cannot be read back without the chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
-    from dmlc_tpu.models import registry
     from dmlc_tpu.ops import ragged_decode
 
-    # The attention kernel must lower through Mosaic, as it does on the chip.
     monkeypatch.setattr(ragged_decode, "interpret_mode", lambda: False)
-    # A compile for a described chip is written to the persistent cache and
-    # cannot be read back without the chip: keep it out.
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", cache_was_on)
+    compilation_cache.reset_cache()
+
+
+def test_step_and_prefill_update_the_pools_in_place(one_chip, compiled_for_the_chip):
+    from dmlc_tpu.models import registry
+
     try:
         memory = chip_smoke.pool_memory(GEOMETRY, sharding=one_chip, use_pallas=True)
     finally:
-        jax.config.update("jax_enable_compilation_cache", cache_was_on)
-        compilation_cache.reset_cache()
         registry._REGISTRY.pop("pool_geometry_lm", None)
     pool = 2 * 16384 * 16 * 1280 * 2
     assert memory["pool_bytes"] == pool
@@ -64,3 +76,49 @@ def test_step_and_prefill_update_the_pools_in_place(one_chip, monkeypatch):
     # (the two gathered bfloat16 views alone were that much).
     g = GEOMETRY
     assert memory["step"]["temp_bytes"] < g["max_slots"] * g["max_len"] * g["hidden"] * 4
+
+
+def test_hybrid_state_and_3840_lane_pools_update_in_place(one_chip, compiled_for_the_chip):
+    import jax.numpy as jnp
+
+    from dmlc_tpu.generate.engine import GenerationEngine
+    from dmlc_tpu.models import olmo_hybrid as oh
+    from dmlc_tpu.models import registry
+    from dmlc_tpu.ops import ragged_decode
+
+    # The published widths, one period; a small vocabulary (the head is not the point).
+    config = oh.OlmoHybridConfig(
+        vocab_size=512, hidden_size=3840, intermediate_size=11008,
+        layer_types=(oh.LINEAR, oh.LINEAR, oh.LINEAR, oh.FULL),
+        num_attention_heads=30, num_key_value_heads=30,
+        linear_num_key_heads=30, linear_num_value_heads=30,
+        linear_key_head_dim=96, linear_value_head_dim=192, linear_conv_kernel_dim=4,
+        max_len=2048)
+    slots, num_pages, dtype = 32, 4096, jnp.bfloat16
+    spec = oh.register_olmo_hybrid("hybrid_geometry_lm", config)
+    try:
+        engine = GenerationEngine(spec.name, variables={}, dtype=dtype, max_slots=slots,
+                                  page_size=16, num_pages=2, max_prefill=1536, use_pallas=True)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, dtype),
+            jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=dtype)[1]))
+        pool = jax.ShapeDtypeStruct((num_pages, 16, 3840), dtype)   # one K/V layer
+        args = chip_smoke.abstract_program_args(engine, variables=variables, pool=pool,
+                                                sharding=one_chip)
+        pool_bytes = num_pages * 16 * 3840 * 2
+        # S is [slots, 30, 192, 96] float32 on tiles of 128 lanes: 96 lanes take 128.
+        state_bytes = 3 * slots * (30 * 192 * 128 * 4 + 3 * 11520 * 2)
+        assert engine.state.nbytes == 3 * slots * (30 * 192 * 96 * 4 + 3 * 11520 * 2)
+        for name, program in (("step", engine._step), ("prefill", engine._prefill)):
+            compiled = program.lower(*args[name]).compile()
+            memory = compiled.memory_analysis()
+            assert memory.alias_size_in_bytes >= 2 * pool_bytes + state_bytes, name
+            # No copy of a pool or of the state: a step keeps a twentieth of a pool,
+            # a prefill less than a pool beside its dense attention's float32 scores.
+            scores = 30 * 1536 * 1536 * 4 if name == "prefill" else 0
+            assert memory.temp_size_in_bytes < pool_bytes + scores, (name, memory.temp_size_in_bytes)
+            assert (chip_smoke.MOSAIC_CALL in compiled.as_text()) == (name == "step")
+    finally:
+        registry._REGISTRY.pop(spec.name, None)
+    # gpt2-large's rows and these share one chunk: 256 positions of K and V in flight.
+    assert ragged_decode._CHUNK_TOKENS == 256
