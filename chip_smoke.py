@@ -313,6 +313,58 @@ def generate_phase(node, *, model: str, prompts, max_new) -> dict:
     }
 
 
+def admission_matches_serial(batched, serial, requests, steps: int = 4) -> list:
+    """ONE ``batched.admit(requests)`` (one run of the prefill program, one
+    blocking read) against as many serial ``serial.join``s on a twin engine:
+    the same first tokens, then after the admission and after each of
+    ``steps`` decode steps the same pools and recurrent state bit for bit, the
+    same host registers, the same tokens (and logits, where the engines keep
+    them). Raises AssertionError on the first difference; returns the first
+    tokens. ``tests/batched_admission.py`` runs it on every model family."""
+    import jax
+    import numpy as np
+
+    def same(what, got, want):
+        if not np.array_equal(np.asarray(got), np.asarray(want)):
+            raise AssertionError(f"one admission of {len(requests)}: {what} differs from serial joins")
+
+    firsts = batched.admit(requests)
+    same("first tokens", firsts, [
+        serial.join(r.slot, r.prompt, temperature=r.temperature, seed=r.seed) for r in requests])
+    for step in range(steps + 1):
+        for name in ("lengths", "active", "temps", "seeds", "last_tokens", "tokens_out", "_joins"):
+            same(f"{name} at step {step}", getattr(batched, name), getattr(serial, name))
+        device = [jax.tree_util.tree_leaves((e._k_state, e._v_state, e._r_state))
+                  for e in (batched, serial)]
+        for i, (got, want) in enumerate(zip(*device, strict=True)):
+            same(f"device array {i} at step {step}", got, want)
+        if step == steps:
+            break
+        for engine in (batched, serial):
+            for r in requests:
+                engine.ensure_capacity(r.slot)
+        same(f"tokens of step {step}", batched.step(), serial.step())
+        if batched.return_logits:
+            same(f"logits of step {step}", batched.last_logits, serial.last_logits)
+    same("compiled programs", *(sorted(e.jit_cache_sizes().items()) for e in (batched, serial)))
+    if batched.jit_cache_sizes() != {"step": 1, "prefill": 1}:
+        raise AssertionError(f"programs recompiled: {batched.jit_cache_sizes()}")
+    return firsts
+
+
+def batched_admission(cfg, *, model: str, prompts) -> dict:
+    """``admission_matches_serial`` on two engines built like the served one."""
+    from dmlc_tpu.generate.engine import Admission, GenerationEngine
+
+    batched, serial = (
+        GenerationEngine(model, max_slots=cfg.gen_max_slots, page_size=cfg.gen_page_size,
+                         num_pages=cfg.gen_num_pages, max_prefill=cfg.gen_max_prefill)
+        for _ in range(2))
+    firsts = admission_matches_serial(
+        batched, serial, [Admission(slot, p) for slot, p in enumerate(prompts)])
+    return {"prompts": len(prompts), "first_tokens": firsts, "steps_compared": 4}
+
+
 def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -> dict:
     """``{"step": args, "prefill": args}``: the shapes of what the engine
     hands its two programs (no live buffer is touched — the pools are
@@ -337,9 +389,11 @@ def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -
         "step": (variables, k_state, v_state, r_state, abstract(engine.last_tokens),
                  abstract(engine.lengths), abstract(engine.active), abstract(table),
                  abstract(engine.seeds), abstract(engine.temps)),
-        "prefill": (variables, abstract(np.zeros((1, engine.max_prefill), np.int32)),
-                    scalar(np.int32), k_state, v_state, r_state, abstract(table[0]),
-                    scalar(np.int32), scalar(np.uint32), scalar(np.float32)),
+        "prefill": (variables,
+                    abstract(np.zeros((engine.max_slots, engine.max_prefill), np.int32)),
+                    abstract(engine.lengths), k_state, v_state, r_state, abstract(table),
+                    abstract(engine.lengths), abstract(engine.seeds), abstract(engine.temps),
+                    scalar(np.int32)),
     }
 
 
@@ -364,7 +418,9 @@ def pool_memory(geometry: dict, *, sharding=None, use_pallas: bool | None = None
     handed) and read each program's ``memory_analysis()``. Both must alias
     the two donated pools to their outputs and keep temporaries under the
     size of ONE pool: a layout the compiler re-lays around the writes shows
-    as temporaries of several pools (PERF.md, PR 24 finding 2). A check of
+    as temporaries of several pools (PERF.md, PR 24 finding 2), and so would
+    a pool that the prefill's loop over the admitted prompts (``loop``: the
+    program holds a ``while``) carried as a copy. A check of
     the chip's compiler (or of a described chip's, tests/test_tpu_compile.py):
     the CPU backend widens a bfloat16 pool around a scatter and would fail it."""
     import math
@@ -403,9 +459,10 @@ def pool_memory(geometry: dict, *, sharding=None, use_pallas: bool | None = None
     for name, program in (("step", engine._step), ("prefill", engine._prefill)):
         compiled = program.lower(*args[name]).compile()
         memory = compiled.memory_analysis()
+        text = compiled.as_text()
         out[name] = {"temp_bytes": int(memory.temp_size_in_bytes),
                      "alias_bytes": int(memory.alias_size_in_bytes),
-                     "mosaic": MOSAIC_CALL in compiled.as_text()}
+                     "mosaic": MOSAIC_CALL in text, "loop": " while(" in text}
         say(f"pool_memory {name}: {out[name]} (one pool {pool_bytes})")
         if memory.alias_size_in_bytes < 2 * pool_bytes:
             raise AssertionError(
@@ -759,6 +816,8 @@ def main() -> int:
             prompts = [rng.integers(0, 1024, n).tolist() for n in lengths]
             run("generate", lambda: {
                 **generate_phase(node, model=GEN_MODEL, prompts=prompts, max_new=max_new),
+                "batched_admission": batched_admission(
+                    node.config, model=GEN_MODEL, prompts=prompts[:3]),
                 "pool_memory": pool_memory(POOL_GEOMETRY)})
 
             run("kernels", kernels_phase, devices)

@@ -33,9 +33,11 @@ substrate the RPC worker (generate/worker.py) exposes as
 Tracing: every decode step runs under a ``gen/step`` span bound to the
 OLDEST resident slot's submit-time trace context, so a request's timeline
 shows the steps that produced its tokens parented under its
-``rpc/job.generate`` span (trace smoke asserts this); ``gen/prefill`` spans
-bind the joining request's own context, and so does its ``gen/wait`` (submit
-to the start of the prefill). The decode thread feeds the device, so its time
+``rpc/job.generate`` span (trace smoke asserts this); a ``gen/prefill`` span
+is ONE run of the prefill program for every request the loop turn admits
+(``prompts``, ``prompt_tokens``), bound to the oldest of them, and each of
+them has its own ``gen/wait`` under its own context (submit to the start of
+that run). The decode thread feeds the device, so its time
 is TILED by leaf spans (docs/OBSERVABILITY.md §1): ``gen/idle`` (waiting for
 work), ``gen/admit`` (admission bookkeeping either side of a prefill),
 ``gen/prefill``, ``gen/retire`` (the resident sweep, page growth),
@@ -56,6 +58,7 @@ from dmlc_tpu.cluster import deadline as deadline_mod
 from dmlc_tpu.cluster import tenant as tenant_mod
 from dmlc_tpu.cluster import tracectx
 from dmlc_tpu.cluster.rpc import Overloaded
+from dmlc_tpu.generate.engine import Admission
 from dmlc_tpu.generate.kvcache import PagePoolExhausted
 from dmlc_tpu.utils import tracing
 from dmlc_tpu.utils.metrics import LatencyStats
@@ -463,36 +466,47 @@ class SlotScheduler:
                 self._resident = []
 
     def _admit_pending(self) -> None:
-        """Move waiting requests into free engine slots (between steps).
+        """Move the waiting requests that find a free engine slot into the
+        batch (between steps): ONE run of the prefill program and one
+        blocking read for all of them, first in first out.
 
-        The head request stays IN ``_pending`` until it lands in
-        ``_resident``: submit-time admission counts both lists, and a
-        request invisible to that count during its prefill would let a
-        third request slip past a full slot table."""
-        while True:
-            with tracer.span("gen/admit", cpu=True):
-                req = self._next_admissible()
-            if req is None:
-                return
-            try:
-                with tracectx.bind(req.trace_ctx):
-                    if req.wait_t0 is not None:
+        A request stays IN ``_pending`` until it lands in ``_resident``:
+        submit-time admission counts both lists, and a request invisible to
+        that count during the prefill would let another slip past a full
+        slot table."""
+        with tracer.span("gen/admit", cpu=True):
+            batch = self._admissible()
+            for req in batch:
+                if req.wait_t0 is not None:
+                    with tracectx.bind(req.trace_ctx):
                         tracer.record("gen/wait", max(0.0, tracer.now() - req.wait_t0))
-                    with tracer.span("gen/prefill", cpu=True, slot=req.slot,
-                                     prompt=len(req.prompt),
-                                     prompt_tokens=len(req.prompt)) as span:
-                        first = self.engine.join(
-                            req.slot, req.prompt,
-                            temperature=req.temperature, pages=req.pages,
-                            seed=req.seed,
-                        )
-                        self._count_work(span, self.engine.prefill_attrs)
-            except Exception as e:
-                # A bad request (or a prefill failure) fails ITS stream,
-                # never the resident batch. Pages go back wherever they
-                # are: bound to the slot (join got past bind) or still the
-                # submit-time reservation.
-                log.exception("prefill failed for %s", req.stream.request_id)
+        if not batch:
+            return
+        firsts: list[int | Exception]
+        try:
+            # Bound to the oldest admitted request's trace, as gen/step is
+            # to the oldest resident's.
+            with tracectx.bind(batch[0].trace_ctx):
+                with tracer.span("gen/prefill", cpu=True) as span:
+                    firsts = self.engine.admit([
+                        Admission(req.slot, req.prompt, req.temperature, req.pages, req.seed)
+                        for req in batch])
+                    ran = [req for req, first in zip(batch, firsts)
+                           if not isinstance(first, Exception)]
+                    span.set(prompts=len(ran),
+                             prompt_tokens=sum(len(req.prompt) for req in ran))
+                    self._count_work(span, self.engine.prefill_attrs)
+        except Exception as e:
+            # The run failed: that fails the streams of ITS batch, never
+            # the resident one.
+            log.exception("prefill run of %d requests failed", len(batch))
+            firsts = [e] * len(batch)
+        for req, first in zip(batch, firsts):
+            if isinstance(first, Exception):
+                # A bad request fails ITS stream. Pages go back wherever
+                # they are: bound to the slot (the engine got past its
+                # check) or still the submit-time reservation.
+                log.error("prefill failed for %s: %s", req.stream.request_id, first)
                 with tracer.span("gen/admit", cpu=True):
                     self._unpend(req)
                     if (self.engine.cache_mode == "paged"
@@ -500,7 +514,7 @@ class SlotScheduler:
                         self.engine.release_reservation(req.pages)
                     self.engine.release(req.slot)
                     self._ledger_release(req)
-                    req.stream.finish(f"{type(e).__name__}: {e}")
+                    req.stream.finish(f"{type(first).__name__}: {first}")
                 continue
             with tracer.span("gen/admit", cpu=True):
                 self._seat(req)
@@ -509,24 +523,27 @@ class SlotScheduler:
                 if req.eos_id is not None and first == req.eos_id:
                     self._exit(req, "eos")
 
-    def _next_admissible(self) -> _Slot | None:
-        """The head waiting request with a free slot assigned, or None when
-        nobody waits or no slot is free. A request that expired or was
-        cancelled while waiting (the router migrated it away, or the client
-        gave up) is finished here: a prefill now would be dead work."""
-        while True:
-            free = self.engine.free_slots()
-            with self._cv:
-                if not self._pending or not free:
-                    return None
-                req = self._pending[0]
+    def _admissible(self) -> list[_Slot]:
+        """The waiting requests, oldest first, that each get a free slot
+        (assigned here); empty when nobody waits or no slot is free. A
+        request that expired or was cancelled while waiting (the router
+        migrated it away, or the client gave up) is finished here: a
+        prefill now would be dead work."""
+        free = self.engine.free_slots()
+        with self._cv:
+            waiting = list(self._pending)
+        batch: list[_Slot] = []
+        for req in waiting:
+            if len(batch) == len(free):
+                break
             if req.deadline is not None and req.deadline.expired():
                 self._drop_waiting(req, "deadline: expired before a slot freed")
             elif req.stream.cancelled:
                 self._drop_waiting(req, "cancelled: before a slot freed")
             else:
-                req.slot = free[0]
-                return req
+                req.slot = free[len(batch)]
+                batch.append(req)
+        return batch
 
     def _drop_waiting(self, req: _Slot, error: str) -> None:
         self._unpend(req)

@@ -66,6 +66,8 @@ def test_serve_and_generate_phases_at_tiny_size(tmp_path):
         assert generated["use_pallas"] is False
         engine = node._gen_backends["lm_small"]._scheduler.engine
         assert chip_smoke.MOSAIC_CALL not in chip_smoke.lowered_step_text(engine)
+        admitted = chip_smoke.batched_admission(node.config, model="lm_small", prompts=prompts)
+        assert admitted["prompts"] == 3 and admitted["steps_compared"] == 4
     finally:
         stop_local_cluster(nodes)
 

@@ -19,7 +19,8 @@ pytest.importorskip("jax")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
-from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
+import batched_admission  # noqa: E402
+from dmlc_tpu.generate.engine import Admission, GenerationEngine  # noqa: E402
 from dmlc_tpu.generate.kvcache import (  # noqa: E402
     SCRATCH_PAGE,
     PageAllocator,
@@ -195,6 +196,61 @@ class TestRecompileFree:
         # churn must never rebuild them (H1's regression class).
         assert eng.cache is cache_obj
         assert eng.cache.allocator is allocator_obj
+
+
+# ---------------------------------------------------------------------------
+# one run of the prefill program for every request of a loop turn
+# ---------------------------------------------------------------------------
+
+
+class TestBatchedAdmission:
+    @pytest.mark.parametrize("k,temperature", batched_admission.CASES)
+    @pytest.mark.parametrize("cache", ["paged", "contiguous"])
+    def test_one_admission_of_k_is_k_serial_joins(self, lm, cache, k, temperature):
+        batched_admission.assert_batch_matches_serial(
+            lambda: make_engine(lm[1], cache=cache), VOCAB, k, temperature)
+
+    def test_one_prefill_program_after_runs_of_every_size(self, lm):
+        eng = make_engine(lm[1])
+        eng.warmup()
+        rng = np.random.default_rng(9)
+        for n in (1, 3, 2, 4, 1):
+            firsts = eng.admit([Admission(slot, rng.integers(0, VOCAB, size=2 + slot + n))
+                                for slot in range(n)])
+            assert all(isinstance(t, int) and 0 <= t < VOCAB for t in firsts)
+            for slot in range(n):
+                eng.ensure_capacity(slot)
+            eng.step()
+            for slot in range(n):
+                eng.release(slot)
+        assert eng.jit_cache_sizes() == {"step": 1, "prefill": 1}
+        assert eng.pages_free == eng.cache.allocator.pages_total
+
+    def test_a_refused_request_touches_nothing_and_the_others_run(self, lm):
+        eng, alone = make_engine(lm[1]), make_engine(lm[1])
+        good = [np.arange(5, dtype=np.int32), np.arange(3, 12, dtype=np.int32)]
+        eng.join(3, good[0])
+        reserved = eng.reserve(4)
+        free = eng.pages_free
+        got = eng.admit([
+            Admission(0, good[0]),
+            Admission(1, np.zeros(eng.max_prefill + 1, np.int32)),    # too long
+            Admission(3, good[1]),                                    # a resident's slot
+            Admission(2, [], pages=reserved),                         # empty, with a reservation
+            Admission(0, good[1]),                                    # a slot this call took
+            Admission(2, good[1]),
+        ])
+        assert [type(r) for r in got] == [int, ValueError, ValueError, ValueError, ValueError, int]
+        assert "max_prefill" in str(got[1]) and "already active" in str(got[2])
+        assert got[0] == alone.join(0, good[0]) and got[5] == alone.join(2, good[1])
+        assert list(eng.active) == [True, False, True, True]
+        # The refused ones bound no page; the reservation is still its caller's.
+        assert not eng.cache.slot_pages(1)
+        assert eng.pages_free == free - 1 - 2        # 5 + 1 tokens, 9 + 1 tokens
+        eng.release_reservation(reserved)
+        with pytest.raises(ValueError, match="non-empty"):
+            eng.join(1, [])
+        assert eng.admit([]) == [] and eng.prefill_attrs == {}
 
 
 # ---------------------------------------------------------------------------

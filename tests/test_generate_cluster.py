@@ -226,6 +226,128 @@ class TestOverloadContract:
             sched.stop()
 
 
+class TestBatchedAdmission:
+    """Every request a loop turn admits goes through ONE ``engine.admit``."""
+
+    @staticmethod
+    def spy_on_admit(eng, during=None):
+        """Record each ``admit`` call's prompts; ``during`` runs on the
+        decode thread while the batch is neither waiting nor seated."""
+        calls, real = [], eng.admit
+
+        def admit(batch):
+            calls.append([list(a.prompt) for a in batch])
+            if during is not None:
+                during(batch)
+            return real(batch)
+
+        eng.admit = admit
+        return calls
+
+    def test_a_turn_admits_everyone_waiting_in_one_run(self, variables):
+        eng = make_engine(variables, max_slots=4)
+        calls = self.spy_on_admit(eng)
+        sched = SlotScheduler(eng, max_waiting=8, autostart=False)
+        prompts = [[1, 2, 3], [4, 5, 6, 7, 8], [9], [10, 11], [12, 13, 14], [15, 16]]
+        try:
+            streams = [sched.submit(p, max_new_tokens=3 + i) for i, p in enumerate(prompts)]
+            sched.start()
+            outs = [s.result(timeout=60) for s in streams]
+        finally:
+            sched.stop()
+        # First in, first out: four slots take the first four in one call;
+        # the last two come as slots free, in their order.
+        assert calls[0] == prompts[:4]
+        assert [p for call in calls[1:] for p in call] == prompts[4:]
+        assert outs == [reference_tokens(variables, p, 3 + i) for i, p in enumerate(prompts)]
+        assert eng.jit_cache_sizes() == {"step": 1, "prefill": 1}
+        assert eng.pages_free == eng.cache.allocator.pages_total
+
+    def test_requests_count_as_pending_until_seated(self, variables):
+        """Submit-time admission must see a batch that is in its prefill:
+        a full slot table stays full."""
+        eng = make_engine(variables, max_slots=2)
+        seen = []
+
+        def during(batch):
+            seen.append((len(sched._pending), len(sched._resident)))
+            with pytest.raises(Overloaded, match="slot table full"):
+                sched.submit([7, 7], max_new_tokens=2)
+
+        self.spy_on_admit(eng, during)
+        sched = SlotScheduler(eng, max_waiting=0, autostart=False)
+        try:
+            streams = [sched.submit([1, 2, 3], max_new_tokens=2) for _ in range(2)]
+            sched.start()
+            for s in streams:
+                s.result(timeout=60)
+        finally:
+            sched.stop()
+        assert seen == [(2, 0)] and sched.sheds == 1
+
+    def test_cancelled_or_expired_head_is_dropped_not_prefilled(self, variables):
+        eng = make_engine(variables, max_slots=4)
+        calls = self.spy_on_admit(eng)
+        sched = SlotScheduler(eng, max_waiting=8, autostart=False)
+        try:
+            gone = sched.submit([1, 2, 3], max_new_tokens=4)
+            late = sched.submit([4, 5, 6], max_new_tokens=4, deadline=Deadline(0.0))
+            kept = sched.submit([7, 8, 9], max_new_tokens=4)
+            gone.cancel()
+            sched.start()
+            assert kept.result(timeout=60) == reference_tokens(variables, [7, 8, 9], 4)
+            with pytest.raises(DeadlineExceeded):
+                late.result(timeout=60)
+            assert gone.wait(60) and gone.error.startswith("cancelled:")
+        finally:
+            sched.stop()
+        assert calls == [[[7, 8, 9]]]
+        assert eng.pages_free == eng.cache.allocator.pages_total
+
+    def test_a_bad_request_in_a_batch_fails_alone(self, variables):
+        eng = make_engine(variables, max_slots=4)
+        sched = SlotScheduler(eng, max_waiting=8, autostart=False)
+        try:
+            streams = [sched.submit(p, max_new_tokens=4) for p in ([1, 2], [3, 4, 5], [6])]
+            sched._pending[1].prompt = []      # what submit would have refused
+            sched.start()
+            assert streams[0].result(timeout=60) == reference_tokens(variables, [1, 2], 4)
+            assert streams[2].result(timeout=60) == reference_tokens(variables, [6], 4)
+            assert streams[1].wait(60) and streams[1].error.startswith("ValueError:")
+            assert streams[1].tokens() == []
+        finally:
+            sched.stop()
+        assert eng.pages_free == eng.cache.allocator.pages_total
+        assert not sched.ledger.summary() or all(
+            t["active"] == 0 for t in sched.ledger.summary().values())
+
+    def test_a_failed_run_fails_its_batch_and_leaves_the_residents(self, variables):
+        eng = make_engine(variables, max_slots=4)
+        sched = SlotScheduler(eng, max_waiting=8)
+        real = eng._prefill
+        try:
+            resident = sched.submit([1, 2, 3], max_new_tokens=40)
+            while not resident.tokens():
+                time.sleep(0.002)
+
+            def broken(*args):
+                raise RuntimeError("device said no")
+
+            eng._prefill = broken
+            batch = [sched.submit([4, 5], max_new_tokens=4),
+                     sched.submit([6, 7, 8], max_new_tokens=4)]
+            for s in batch:
+                assert s.wait(60) and s.error.startswith("RuntimeError: device said no")
+            eng._prefill = real
+            assert resident.result(timeout=60) == reference_tokens(variables, [1, 2, 3], 40)
+            # The engine is whole: the next request is served.
+            after = sched.submit([9, 9], max_new_tokens=3)
+            assert after.result(timeout=60) == reference_tokens(variables, [9, 9], 3)
+        finally:
+            sched.stop()
+        assert eng.pages_free == eng.cache.allocator.pages_total
+
+
 class TestExactlyOnceStreaming:
     """The chunk-poll protocol over the sim fabric."""
 

@@ -18,6 +18,7 @@ pytest.importorskip("jax")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+import batched_admission  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
 from dmlc_tpu.generate.worker import GenerationBackend  # noqa: E402
 from dmlc_tpu.models import nemotron_h as nh  # noqa: E402
@@ -256,6 +257,15 @@ class TestStateSlots:
         assert attrs["state_slots"] == 2
         assert attrs["state_bytes"] == 2 * engine.state.bytes_per_slot
         assert 0 <= attrs["experts_hit"] <= 2 and attrs["expert_rows_max"] <= 2
+
+
+class TestBatchedAdmission:
+    @pytest.mark.parametrize("k,temperature", batched_admission.CASES)
+    def test_one_admission_of_k_is_k_serial_joins(self, variables, k, temperature):
+        """Pages AND state slots: each prompt's row of the run leaves its
+        slot's recurrent state as the prompt alone left it."""
+        batched_admission.assert_batch_matches_serial(
+            lambda: make_engine(variables), VOCAB, k, temperature)
 
 
 class TestMigration:

@@ -20,6 +20,7 @@ pytest.importorskip("jax")
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 
+import batched_admission  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
 from dmlc_tpu.generate.worker import GenerationBackend  # noqa: E402
 from dmlc_tpu.models import olmo_hybrid as oh  # noqa: E402
@@ -363,6 +364,15 @@ class TestStateSlots:
             text = program.lower(*args[name]).as_text(debug_info=True)
             for scope in ("deltanet", "attn", "mlp"):
                 assert f"/{scope}/" in text, (name, scope)
+
+
+class TestBatchedAdmission:
+    @pytest.mark.parametrize("k,temperature", batched_admission.CASES)
+    def test_one_admission_of_k_is_k_serial_joins(self, variables, k, temperature):
+        """Pages AND state slots: each prompt's row of the run leaves its
+        slot's recurrent state as the prompt alone left it."""
+        batched_admission.assert_batch_matches_serial(
+            lambda: make_engine(variables), VOCAB, k, temperature)
 
 
 class TestMigration:

@@ -12,10 +12,14 @@ topology is described inside a fixture: one process at a time may load the
 TPU's library.
 
 The second test is the hybrid family's geometry (``models/olmo_hybrid`` at its
-published widths, one period of four layers): pool rows of 30 heads x 128 =
+published widths, two periods of four layers): pool rows of 30 heads x 128 =
 3,840 lanes, three times gpt2-large's, through the same attention kernel at
 its 256-position chunk, beside a float32 matrix state of 2.2 MB a slot a
-layer that both programs must also update in place.
+layer that both programs must also update in place. Its prefill runs the
+family's chunk scans INSIDE the engine's loop over the admitted prompts: the
+temporaries stay what the batch-1 program kept (501 MB at the two periods here,
+620 at the cell's four against that program's 619; 3,772 before the family was
+handed a state of one slot and the loop's body a depth-first order).
 """
 
 import pytest
@@ -47,9 +51,11 @@ def compiled_for_the_chip(monkeypatch):
     it and cannot be read back without the chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
+    from dmlc_tpu.generate import engine
     from dmlc_tpu.ops import ragged_decode
 
     monkeypatch.setattr(ragged_decode, "interpret_mode", lambda: False)
+    monkeypatch.setattr(engine, "_compiles_for_tpu", lambda: True)
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
@@ -71,6 +77,9 @@ def test_step_and_prefill_update_the_pools_in_place(one_chip, compiled_for_the_c
         assert memory[program]["alias_bytes"] >= 2 * pool
         assert memory[program]["temp_bytes"] < pool
     assert memory["step"]["mosaic"] and not memory["prefill"]["mosaic"]
+    # The prefill is the loop over a turn's admitted prompts (this family has no
+    # loop of its own): pools through a loop's carry, still the donated buffers.
+    assert memory["prefill"]["loop"] and not memory["step"]["loop"]
     # The attention reads the pool's pages and keeps no padded view of them:
     # the step's temporaries stay under ONE float32 unfolding of such a view
     # (the two gathered bfloat16 views alone were that much).
@@ -86,15 +95,17 @@ def test_hybrid_state_and_3840_lane_pools_update_in_place(one_chip, compiled_for
     from dmlc_tpu.models import registry
     from dmlc_tpu.ops import ragged_decode
 
-    # The published widths, one period; a small vocabulary (the head is not the point).
+    # The published widths, two periods (deep enough that an order of the loop's
+    # body that lets layers' temporaries overlap shows: 847 MB against 501); a
+    # small vocabulary (the head is not the point).
     config = oh.OlmoHybridConfig(
         vocab_size=512, hidden_size=3840, intermediate_size=11008,
-        layer_types=(oh.LINEAR, oh.LINEAR, oh.LINEAR, oh.FULL),
+        layer_types=(oh.LINEAR, oh.LINEAR, oh.LINEAR, oh.FULL) * 2,
         num_attention_heads=30, num_key_value_heads=30,
         linear_num_key_heads=30, linear_num_value_heads=30,
         linear_key_head_dim=96, linear_value_head_dim=192, linear_conv_kernel_dim=4,
         max_len=2048)
-    slots, num_pages, dtype = 32, 4096, jnp.bfloat16
+    slots, num_pages, dtype = 32, 2048, jnp.bfloat16
     spec = oh.register_olmo_hybrid("hybrid_geometry_lm", config)
     try:
         engine = GenerationEngine(spec.name, variables={}, dtype=dtype, max_slots=slots,
@@ -102,13 +113,13 @@ def test_hybrid_state_and_3840_lane_pools_update_in_place(one_chip, compiled_for
         variables = jax.tree_util.tree_map(
             lambda a: jax.ShapeDtypeStruct(a.shape, dtype),
             jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=dtype)[1]))
-        pool = jax.ShapeDtypeStruct((num_pages, 16, 3840), dtype)   # one K/V layer
+        pool = jax.ShapeDtypeStruct((2 * num_pages, 16, 3840), dtype)   # two K/V layers
         args = chip_smoke.abstract_program_args(engine, variables=variables, pool=pool,
                                                 sharding=one_chip)
-        pool_bytes = num_pages * 16 * 3840 * 2
+        pool_bytes = 2 * num_pages * 16 * 3840 * 2
         # S is [slots, 30, 192, 96] float32 on tiles of 128 lanes: 96 lanes take 128.
-        state_bytes = 3 * slots * (30 * 192 * 128 * 4 + 3 * 11520 * 2)
-        assert engine.state.nbytes == 3 * slots * (30 * 192 * 96 * 4 + 3 * 11520 * 2)
+        state_bytes = 6 * slots * (30 * 192 * 128 * 4 + 3 * 11520 * 2)
+        assert engine.state.nbytes == 6 * slots * (30 * 192 * 96 * 4 + 3 * 11520 * 2)
         for name, program in (("step", engine._step), ("prefill", engine._prefill)):
             compiled = program.lower(*args[name]).compile()
             memory = compiled.memory_analysis()
