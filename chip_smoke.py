@@ -18,6 +18,11 @@ main path once, through the entry points a user would call:
   and the prefill compiled at gpt2-large's cache geometry from abstract
   arguments, which must alias both KV pools and keep their temporaries under
   the size of one pool (``pool_memory``).
+- **family**: a model family that keeps positions and recurrent state of its
+  own (``models/lfm2_moe``: rotary positions, conv windows, gated experts) at
+  a small size with the attention kernel's real head width: prefill, then
+  decode steps with slots at DIFFERENT lengths in one step, every row's
+  logits against the benchmark's plain reference's full forward.
 - **kernels**: every ``pl.pallas_call`` site compiled (never interpreted)
   once at a serving/training shape and checked against its XLA reference.
 - **multichip**: on a host with several chips, the engine's dp mesh, per-
@@ -365,6 +370,89 @@ def batched_admission(cfg, *, model: str, prompts) -> dict:
     return {"prompts": len(prompts), "first_tokens": firsts, "steps_compared": 4}
 
 
+def plain_reference(stem: str):
+    """``benchmark/reference/<stem>.py`` loaded by path: the plain float32
+    math that decides ``correct`` on the chip (``tests/plain_reference.py``
+    hands the tier-1 tests this same copy)."""
+    import importlib.util
+
+    path = Path(__file__).resolve().parent / "benchmark" / "reference" / f"{stem}.py"
+    spec = importlib.util.spec_from_file_location(f"bench_reference_{stem}", path)
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+def flat_of(variables) -> dict:
+    """A variables tree as the flat ``{path: leaf}`` dict a reference reads."""
+    def walk(tree, prefix=""):
+        for key, value in tree.items():
+            path = f"{prefix}/{key}" if prefix else key
+            if isinstance(value, dict):
+                yield from walk(value, path)
+            else:
+                yield path, value
+    return dict(walk(variables))
+
+
+def family_phase(config=None, *, lengths=(5, 40, 97), steps: int = 4) -> dict:
+    """``models/lfm2_moe`` through the engine against its plain reference
+    (``benchmark/reference/lfm2_moe.py``, one full forward over prompt + served
+    tokens, float32 at ``highest``): float32 weights from a seed, prompts of
+    different ``lengths`` prefilled into slots that then decode TOGETHER, so
+    one step turns each row's query and key at that row's own position. Every
+    row's logits of every step must lie within a bfloat16 near-tie of the
+    reference's (the chip's matmuls round their inputs to bfloat16)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from dmlc_tpu.generate.engine import GenerationEngine
+    from dmlc_tpu.models import lfm2_moe as lf
+    from dmlc_tpu.models import registry
+
+    if config is None:   # the kernel's served head width (64), everything else small
+        config = lf.Lfm2MoeConfig(
+            vocab_size=512, hidden_size=256, intermediate_size=512, moe_intermediate_size=128,
+            layer_types=(lf.CONV, lf.CONV, lf.FULL, lf.CONV, lf.CONV, lf.CONV), num_dense_layers=2,
+            num_experts=8, num_experts_per_tok=2, num_attention_heads=4, num_key_value_heads=2,
+            max_len=256)
+    reference = plain_reference("lfm2_moe")
+    spec = lf.register_lfm2_moe("chip_smoke_lfm2_moe", config)
+    try:
+        _, variables = spec.init_params(jax.random.PRNGKey(7), dtype=jnp.float32)
+        engine = GenerationEngine(spec.name, variables=variables, max_slots=len(lengths) + 1,
+                                  page_size=16, num_pages=64, max_prefill=max(lengths),
+                                  return_logits=True)
+        rng = np.random.default_rng(3)
+        prompts = [rng.integers(0, config.vocab_size, n).astype(np.int32) for n in lengths]
+        served = [[engine.join(slot + 1, p)] for slot, p in enumerate(prompts)]
+        ref_cfg = {k: getattr(config, k) for k in config.__dataclass_fields__ if k != "max_len"}
+        flat = flat_of(variables)
+        worst = 0.0
+        for _ in range(steps):
+            for slot in range(len(prompts)):
+                engine.ensure_capacity(slot + 1)
+            out = engine.step()
+            for slot, prompt in enumerate(prompts):
+                seq = np.concatenate([prompt, np.asarray(served[slot], np.int32)])
+                want = np.asarray(reference.logits_at(
+                    ref_cfg, flat, jnp.asarray(seq[None]), jnp.asarray([[len(seq) - 1]]))[0, 0])
+                got = np.asarray(engine.last_logits[slot + 1])
+                gap = float(np.abs(got - want).max() / np.abs(want).max())
+                if not gap <= BF16_TOL:
+                    raise AssertionError(
+                        f"family: slot {slot + 1} at position {len(seq) - 1}: logits "
+                        f"{gap:.3e} of the reference's largest away (bound {BF16_TOL:.3e})")
+                worst = max(worst, gap)
+                served[slot].append(int(out[slot + 1]))
+        return {"model": "lfm2_moe", "lengths": list(lengths), "steps": steps,
+                "rows_checked": steps * len(lengths), "worst_rel_err": float(f"{worst:.3e}"),
+                "use_pallas": bool(engine.use_pallas)}
+    finally:
+        registry._REGISTRY.pop(spec.name, None)
+
+
 def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -> dict:
     """``{"step": args, "prefill": args}``: the shapes of what the engine
     hands its two programs (no live buffer is touched — the pools are
@@ -485,8 +573,9 @@ MOSAIC_CALL = "tpu_custom_call"
 #: vision kernels, a training-grade bf16 Dh=128 attention on both sides of
 #: the resident/streamed K/V switch, and the fused decode attention at the
 #: forms the cells run: gpt2-large's heads (20 x 64, multi-head),
-#: nemotron3-super's (32 query heads on 2 KV heads of 128) and
-#: olmo-hybrid-7b's (30 x 128, multi-head: pool rows of 3,840 lanes).
+#: nemotron3-super's (32 query heads on 2 KV heads of 128),
+#: olmo-hybrid-7b's (30 x 128, multi-head: pool rows of 3,840 lanes) and
+#: lfm2-8b-a1b's (32 query heads on 8 KV heads of 64: rows of 512 lanes).
 KERNEL_SHAPES = {
     "images": (256, 224, 224, 3),
     "logits": (256, 1000),
@@ -498,6 +587,7 @@ KERNEL_SHAPES = {
     "paged_mha": (20, 20, 64),   # (heads, kv_heads, head_dim)
     "paged_gqa": (32, 2, 128),
     "paged_mha_wide": (30, 30, 128),
+    "paged_gqa_64": (32, 8, 64),
     "paged_slots": 24,
     "paged_table": 64,           # pages a slot's table names, 16 tokens each
 }
@@ -574,7 +664,10 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
     from dmlc_tpu.parallel.ring_attention import ring_flash_attention
     from dmlc_tpu.parallel.ulysses import ulysses_attention
 
-    keys = iter(jax.random.split(jax.random.PRNGKey(7), 32))
+    # A second run of keys after the first 32, so a case added later leaves the
+    # earlier cases' inputs as they were.
+    keys = iter([*jax.random.split(jax.random.PRNGKey(7), 32),
+                 *jax.random.split(jax.random.PRNGKey(8), 8)])
     h, dh = shapes["attn_heads"], shapes["attn_dh"]
 
     def qkv(s, dtype=jnp.bfloat16, heads=h):
@@ -643,6 +736,7 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
         ("paged_attention_mha_bf16", *paged_case(*shapes["paged_mha"])),
         ("paged_attention_gqa_bf16", *paged_case(*shapes["paged_gqa"])),
         ("paged_attention_mha_30x128_bf16", *paged_case(*shapes["paged_mha_wide"])),
+        ("paged_attention_gqa_32on8x64_bf16", *paged_case(*shapes["paged_gqa_64"])),
         (f"ring_flash_sp{n}",
          lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
          sp_args, causal_ref, BF16_TOL),
@@ -820,6 +914,7 @@ def main() -> int:
                     node.config, model=GEN_MODEL, prompts=prompts[:3]),
                 "pool_memory": pool_memory(POOL_GEOMETRY)})
 
+            run("family", family_phase)
             run("kernels", kernels_phase, devices)
             interpreted = [k for k, v in phases["kernels"].items()
                            if isinstance(v, dict) and not v["mosaic"]]

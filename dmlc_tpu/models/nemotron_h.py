@@ -373,7 +373,7 @@ def latent_moe(p: Any, cfg: NemotronHConfig, u: Any, rows: Any) -> tuple[Any, An
     lat = u @ p["down"]["kernel"]
     routed, counts = held_experts_ffn(lat, p["experts"]["w1"], p["experts"]["w2"],
                                       idx, gates, cfg.held, rows,
-                                      n_experts=cfg.n_routed_experts)
+                                      n_experts=cfg.n_routed_experts, activation=_relu2)
     shared = _relu2(u @ p["shared"]["w1"]["kernel"]) @ p["shared"]["w2"]["kernel"]
     return routed.astype(u.dtype) @ p["up"]["kernel"] + shared, counts
 

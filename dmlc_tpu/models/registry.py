@@ -296,3 +296,10 @@ register_nemotron_h("nemotron_h_tiny", NEMOTRON_H_TINY)
 from dmlc_tpu.models.olmo_hybrid import OLMO_HYBRID_TINY, register_olmo_hybrid  # noqa: E402
 
 register_olmo_hybrid("olmo_hybrid_tiny", OLMO_HYBRID_TINY)
+
+# The LFM2-MoE family's CPU-test preset (gated short convolutions 3:1 with
+# rotary grouped-query attention, gated experts after two dense layers); real
+# sizes are registered by whoever serves them (``models/lfm2_moe.register_lfm2_moe``).
+from dmlc_tpu.models.lfm2_moe import LFM2_MOE_TINY, register_lfm2_moe  # noqa: E402
+
+register_lfm2_moe("lfm2_moe_tiny", LFM2_MOE_TINY)

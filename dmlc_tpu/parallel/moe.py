@@ -5,8 +5,10 @@
   ALL experts in float32, keeps the gate normalisation over every chosen
   expert, and computes the part of the result its own experts give. No
   capacity, no dropped token, nothing that stands in for the absent chips or
-  their exchange: on one chip the layer runs without it. ``models/nemotron_h``
-  serves through it.
+  their exchange: on one chip the layer runs without it. The expert's own
+  form is its caller's to state (``activation``): ``models/nemotron_h``
+  serves ``relu(x W1)^2 W2`` through it, ``models/lfm2_moe`` the gated
+  ``(silu(x W1) * x W3) W2`` with ``W1 | W3`` side by side in one kernel.
 - the training-time GShard layer the multi-chip dry run shards over an ``ep``
   axis (``MoEMlp``; capacity routing that drops overflow tokens; no served
   path reaches it).
@@ -190,29 +192,34 @@ def shard_moe_params(mesh: Mesh, variables):
 
 
 def route_sigmoid_topk(u, router_kernel, score_bias, top_k: int, *, scaling: float = 1.0,
-                       normalize: bool = True):
+                       normalize: bool = True, eps: float = 1e-20):
     """Sigmoid router over every expert of the model, in float32 whatever
     the activations' type. ``u`` [T, D]; ``router_kernel`` [D, E];
     ``score_bias`` [E] (the score-correction bias: it picks, it does not
     weigh). Returns (idx [T, k] int32, gates [T, k] float32): the ``top_k``
     experts of largest ``s + bias``, each weighed by its own ``s``,
-    normalised over ALL chosen experts (held here or not) and scaled."""
+    normalised over ALL chosen experts (held here or not; ``eps`` is what the
+    family adds to that sum) and scaled."""
     scores = jax.nn.sigmoid(jnp.dot(
         u.astype(jnp.float32), router_kernel.astype(jnp.float32),
         precision=jax.lax.Precision.HIGHEST))
     _, idx = jax.lax.top_k(scores + score_bias.astype(jnp.float32), top_k)
     gates = jnp.take_along_axis(scores, idx, axis=-1)
     if normalize:
-        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + 1e-20)
+        gates = gates / (jnp.sum(gates, axis=-1, keepdims=True) + eps)
     return idx.astype(jnp.int32), gates * scaling
 
 
 def held_experts_ffn(x, w1, w2, idx, gates, held: tuple[int, int], rows=None, *,
-                     n_experts: int, dense: bool | None = None):
-    """``sum over chosen e that are held of g_e * relu(x W1_e)^2 W2_e``.
+                     n_experts: int, activation, dense: bool | None = None):
+    """``sum over chosen e that are held of g_e * activation(x W1_e) W2_e``.
 
-    ``x`` [T, d]; ``w1`` [count, d, f], ``w2`` [count, f, d]: experts
-    ``first .. first + count - 1`` of the layer's ``n_experts``; ``idx``/
+    ``x`` [T, d]; ``w1`` [count, d, f1], ``w2`` [count, f, d]: experts
+    ``first .. first + count - 1`` of the layer's ``n_experts``;
+    ``activation`` maps the first product ``[.., f1]`` (float32) to the
+    second's operand ``[.., f]`` and is the expert's form, stated by the
+    family: ``relu(h)^2`` with ``f1 = f``, or for a gated expert whose kernel
+    holds ``W1 | W3``, ``silu(h[:f]) * h[f:]`` with ``f1 = 2 f``; ``idx``/
     ``gates`` [T, k] from the router over all experts; ``rows`` [T] bool
     marks rows that hold a token (padding and empty slots route nowhere).
     Exact at any routing skew, with no capacity, by one of two forms chosen
@@ -250,14 +257,14 @@ def held_experts_ffn(x, w1, w2, idx, gates, held: tuple[int, int], rows=None, *,
         # the converts into the dot: the weights are still read as they are stored).
         f32 = jnp.float32
         h = jnp.einsum("td,edf->etf", x.astype(f32), w1.astype(f32))
-        h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
+        h = activation(h).astype(x.dtype)
         y = jnp.einsum("etf,efd->etd", h.astype(f32), w2.astype(f32))
         return jnp.einsum("etd,te->td", y, weight), group_sizes
     key = key.reshape(t * k)
     order = jnp.argsort(key, stable=True)
     h = jax.lax.ragged_dot(x[order // k], w1, group_sizes,
                            preferred_element_type=jnp.float32)
-    h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
+    h = activation(h).astype(x.dtype)
     y = jax.lax.ragged_dot(h, w2, group_sizes, preferred_element_type=jnp.float32)
     # Rows past the last group are whatever the kernel left there: zeroed here.
     in_a_group = (key[order] < count)[:, None]

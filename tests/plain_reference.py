@@ -1,28 +1,11 @@
 """The benchmark's plain references, for the tier-1 tests that pin a model
 family to one: ``benchmark/reference/<stem>.py`` loaded by path (the same
 copy of the plain math decides ``correct`` on the chip), and a variables tree
-as the flat ``{path: leaf}`` dict a reference reads."""
+as the flat ``{path: leaf}`` dict a reference reads. Both are ``chip_smoke``'s,
+which compares a family with its reference on the chip too."""
 
-import importlib.util
 from pathlib import Path
 
+from chip_smoke import flat_of, plain_reference as load  # noqa: F401
+
 REPO = Path(__file__).resolve().parents[1]
-
-
-def load(stem: str):
-    path = REPO / "benchmark" / "reference" / f"{stem}.py"
-    spec = importlib.util.spec_from_file_location(f"bench_reference_{stem}", path)
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-def flat_of(variables) -> dict:
-    def walk(tree, prefix=""):
-        for key, value in tree.items():
-            path = f"{prefix}/{key}" if prefix else key
-            if isinstance(value, dict):
-                yield from walk(value, path)
-            else:
-                yield path, value
-    return dict(walk(variables))

@@ -72,6 +72,16 @@ def test_serve_and_generate_phases_at_tiny_size(tmp_path):
         stop_local_cluster(nodes)
 
 
+def test_family_phase_at_tiny_size():
+    """The rotary family against its plain reference, three slots at different
+    lengths in one step: float32 on the CPU, so far inside the chip's bound."""
+    from dmlc_tpu.models.lfm2_moe import LFM2_MOE_TINY
+
+    out = chip_smoke.family_phase(LFM2_MOE_TINY, lengths=(3, 17, 29), steps=3)
+    assert out["rows_checked"] == 9 and out["use_pallas"] is False
+    assert out["worst_rel_err"] < 1e-5
+
+
 def test_kernels_phase_at_tiny_shapes():
     """The parity harness itself, through the interpreter: every kernel
     case runs and matches its reference — and none claims Mosaic here."""
@@ -79,8 +89,8 @@ def test_kernels_phase_at_tiny_shapes():
         chip_smoke.KERNEL_SHAPES, images=(4, 32, 32, 3), logits=(16, 40),
         attn_heads=2, attn_dh=16, s_resident=128, s_streamed=256, sp_s_local=64,
         paged_mha=(4, 4, 8), paged_gqa=(4, 2, 16), paged_mha_wide=(6, 6, 16),
-        paged_slots=5, paged_table=3,
+        paged_gqa_64=(8, 2, 8), paged_slots=5, paged_table=3,
     )
     out = chip_smoke.kernels_phase(jax.devices()[:2], shapes)
-    assert len(out) == 10
+    assert len(out) == 11
     assert not any(case["mosaic"] for case in out.values())

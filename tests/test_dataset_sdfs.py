@@ -65,7 +65,7 @@ def test_four_node_sdfs_sharded_inference(tmp_path):
     """4 nodes, zero local corpora, tinynet engines: publish -> predict ->
     every shard served from SDFS-pulled images, full accuracy."""
     synset_path, seed_data = make_corpus(tmp_path, N_CLASSES)
-    base = random.randint(21000, 52000) // 10 * 10
+    base = random.randint(21000, 32000) // 10 * 10  # below the kernel's ephemeral range (32768+)
     leader_candidates = [f"127.0.0.1:{base + 1}"]
     nodes = []
     try:

@@ -140,10 +140,11 @@ _GEOMETRIES = [
     (5, 5, 40),    # 200 wide: over one tile, no multiple of 128
 ]
 GEOMETRIES = pytest.mark.parametrize("heads,kv_heads,head_dim", _GEOMETRIES)
-#: The kernel alone (no engine, so no weights of that width) also at a served
-#: model's row: 30 heads x 128 = 3,840 lanes, 30 query rows padded to 32.
+#: The kernel alone (no engine, so no weights of that width) also at two served
+#: models' rows: 30 heads x 128 = 3,840 lanes, 30 query rows padded to 32; and
+#: 32 query heads on 8 KV heads of 64, rows of 512 lanes, four query rows a head.
 KERNEL_GEOMETRIES = pytest.mark.parametrize(
-    "heads,kv_heads,head_dim", _GEOMETRIES + [(30, 30, 128)])
+    "heads,kv_heads,head_dim", _GEOMETRIES + [(30, 30, 128), (32, 8, 64)])
 
 
 @pytest.mark.parametrize("use_pallas", [False, True], ids=["take", "pallas"])

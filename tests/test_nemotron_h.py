@@ -378,7 +378,7 @@ class TestExpertLayer:
             idx = jnp.tile(jnp.asarray([[5, 1]], jnp.int32), (t, 1))       # 5 lives elsewhere
             gates = jnp.tile(jnp.asarray([[0.25, 0.75]], jnp.float32), (t, 1))
             routed, counts = held_experts_ffn(x, w1, w2, idx, gates, (0, 2), n_experts=8,
-                                              dense=dense)
+                                              activation=nh._relu2, dense=dense)
             want = 0.75 * np.square(np.maximum(np.asarray(x) @ np.asarray(w1[1]), 0)) @ np.asarray(w2[1])
             np.testing.assert_allclose(np.asarray(routed), want, atol=1e-4)
             assert counts.tolist() == [0, t]
@@ -389,7 +389,8 @@ class TestExpertLayer:
         lat = u @ p["down"]["kernel"]
         rows = jnp.arange(40) % 5 != 0
         outs = [held_experts_ffn(lat, p["experts"]["w1"], p["experts"]["w2"], idx, gates, (0, 2),
-                                 rows, n_experts=8, dense=dense) for dense in (False, True, None)]
+                                 rows, n_experts=8, activation=nh._relu2, dense=dense)
+                for dense in (False, True, None)]
         for routed, counts in outs[1:]:
             np.testing.assert_allclose(np.asarray(routed), np.asarray(outs[0][0]), atol=1e-6)
             assert counts.tolist() == outs[0][1].tolist()
@@ -397,11 +398,29 @@ class TestExpertLayer:
         # a prefill's 512 rows, or 4 rows that hit few experts: the grouped one.
         def form(t):
             fn = lambda x, i, g: held_experts_ffn(
-                x, p["experts"]["w1"], p["experts"]["w2"], i, g, (0, 2), n_experts=8)[0]
+                x, p["experts"]["w1"], p["experts"]["w2"], i, g, (0, 2), n_experts=8,
+                activation=nh._relu2)[0]
             return str(jax.make_jaxpr(fn)(lat[:1].repeat(t, 0), idx[:1].repeat(t, 0),
                                           gates[:1].repeat(t, 0)))
         assert "ragged_dot" not in form(40)
         assert "ragged_dot" in form(512) and "ragged_dot" in form(4)
+
+    @pytest.mark.parametrize("dense", [False, True], ids=["grouped", "dense"])
+    def test_relu_squared_through_the_callers_form_is_what_it_was(self, variables, dense):
+        """``held_experts_ffn`` takes the expert's form from its caller since a
+        second family serves gated experts through it; this family's form,
+        handed in, gives bit for bit what the function gave when it wrote
+        ``relu(x W1)^2`` itself (``_held_experts_ffn_before``: that text)."""
+        p, u = self._layer(variables)
+        idx, gates = route_sigmoid_topk(u, p["router"]["kernel"], p["router"]["bias"], 2, scaling=2.5)
+        lat = u @ p["down"]["kernel"]
+        rows = jnp.arange(40) % 5 != 0
+        args = (lat, p["experts"]["w1"], p["experts"]["w2"], idx, gates, (0, 2), rows)
+        got = held_experts_ffn(*args, n_experts=8, activation=nh._relu2, dense=dense)
+        want = _held_experts_ffn_before(*args, dense=dense)
+        assert np.abs(np.asarray(want[0])).max() > 1e-5
+        np.testing.assert_array_equal(np.asarray(got[0]), np.asarray(want[0]))
+        np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
 
     def test_the_router_normalises_over_all_chosen_experts(self, variables):
         p, u = self._layer(variables)
@@ -411,6 +430,34 @@ class TestExpertLayer:
         dense = np.asarray(REF.route(u, p, REF.sizes(ref_cfg())))
         picked = np.take_along_axis(dense, np.asarray(idx), axis=-1)
         np.testing.assert_allclose(picked, np.asarray(gates), rtol=1e-5)
+
+
+def _held_experts_ffn_before(x, w1, w2, idx, gates, held, rows, *, dense):
+    """``parallel/moe.held_experts_ffn`` as it stood while relu-squared was its
+    only form, kept here word for word as the pin of the test above."""
+    first, count = held
+    t, k = idx.shape
+    local = idx - first
+    mine = (local >= 0) & (local < count) & rows[:, None]
+    key = jnp.where(mine, local, count)
+    group_sizes = jnp.zeros(count + 1, jnp.int32).at[key.reshape(t * k)].add(1)[:count]
+    if dense:
+        weight = jnp.zeros((t, count + 1), jnp.float32).at[
+            jnp.arange(t)[:, None], key].add(gates)[:, :count]
+        f32 = jnp.float32
+        h = jnp.einsum("td,edf->etf", x.astype(f32), w1.astype(f32))
+        h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
+        y = jnp.einsum("etf,efd->etd", h.astype(f32), w2.astype(f32))
+        return jnp.einsum("etd,te->td", y, weight), group_sizes
+    key = key.reshape(t * k)
+    order = jnp.argsort(key, stable=True)
+    h = jax.lax.ragged_dot(x[order // k], w1, group_sizes, preferred_element_type=jnp.float32)
+    h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
+    y = jax.lax.ragged_dot(h, w2, group_sizes, preferred_element_type=jnp.float32)
+    in_a_group = (key[order] < count)[:, None]
+    y = jnp.where(in_a_group, y * gates.reshape(t * k)[order][:, None], 0.0)
+    back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))
+    return y[back].reshape(t, k, -1).sum(axis=1), group_sizes
 
 
 def rng_rows(n, d=CFG.hidden_size, seed=21):

@@ -20,6 +20,12 @@ family's chunk scans INSIDE the engine's loop over the admitted prompts: the
 temporaries stay what the batch-1 program kept (501 MB at the two periods here,
 620 at the cell's four against that program's 619; 3,772 before the family was
 handed a state of one slot and the loop's body a depth-first order).
+
+The third is ``models/lfm2_moe`` at the sizes of its cell (the configuration
+file itself: 12 layers, the whole vocabulary, 64 slots, 6,144 pages, prompts
+padded to 1,024): pool rows of 8 KV heads x 64 = 512 lanes under 32 query
+heads, conv windows of 8 KB a layer a slot, and an expert layer whose dense
+form keeps a float32 ``[32, 64, 3584]`` product beside 7 GB of experts.
 """
 
 import pytest
@@ -133,3 +139,54 @@ def test_hybrid_state_and_3840_lane_pools_update_in_place(one_chip, compiled_for
         registry._REGISTRY.pop(spec.name, None)
     # gpt2-large's rows and these share one chunk: 256 positions of K and V in flight.
     assert ragged_decode._CHUNK_TOKENS == 256
+
+
+def test_rotary_gated_expert_family_at_its_cells_sizes(one_chip, compiled_for_the_chip):
+    import json
+
+    import jax.numpy as jnp
+    import plain_reference
+
+    from dmlc_tpu.generate.engine import GenerationEngine
+    from dmlc_tpu.models import lfm2_moe as lf
+    from dmlc_tpu.models import registry
+
+    cfg = json.loads((plain_reference.REPO / "benchmark" / "configs" / "lfm2-8b-a1b.json").read_text())
+    cluster = cfg["cluster"]
+    config = lf.Lfm2MoeConfig.from_published(cfg, max_len=cfg["serving_positions"])
+    slots, num_pages, dtype = cluster["gen_max_slots"], cluster["gen_num_pages"], jnp.bfloat16
+    assert (slots, num_pages, cluster["gen_max_prefill"]) == (64, 6144, 1024)
+    spec = lf.register_lfm2_moe("lfm2_geometry_lm", config)
+    try:
+        engine = GenerationEngine(spec.name, variables={}, dtype=dtype, max_slots=slots,
+                                  page_size=cluster["gen_page_size"], num_pages=2,
+                                  max_prefill=cluster["gen_max_prefill"], use_pallas=True)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, dtype),
+            jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=dtype)[1]))
+        pool = jax.ShapeDtypeStruct((3 * num_pages, 16, 512), dtype)    # three K/V layers
+        args = chip_smoke.abstract_program_args(engine, variables=variables, pool=pool,
+                                                sharding=one_chip)
+        pool_bytes = 3 * num_pages * 16 * 512 * 2
+        state_bytes = 9 * slots * 2 * 2048 * 2
+        assert engine.state.nbytes == state_bytes
+        weights = 2 * 3_928_728_256
+        for name, program in (("step", engine._step), ("prefill", engine._prefill)):
+            compiled = program.lower(*args[name]).compile()
+            memory = compiled.memory_analysis()
+            # Both pools and every conv window updated where they are.
+            assert memory.alias_size_in_bytes == 2 * pool_bytes + state_bytes, name
+            # No copy of a pool: a step keeps the experts' float32 product and little
+            # else (16 MB), a prefill its 1,024 rows at the widest product and the
+            # dense attention's float32 scores (198 MB), beside 7.86 GB of weights.
+            assert memory.temp_size_in_bytes < pool_bytes, (name, memory.temp_size_in_bytes)
+            assert weights < memory.argument_size_in_bytes < weights + 2 * pool_bytes + 2 ** 24
+            text = compiled.as_text()
+            # The static shapes chose: the step's experts are the dense form's two
+            # batched products, the prefill's the grouped matmul (a Mosaic call of
+            # its own on the chip); the step's three Mosaic calls are the attention.
+            assert chip_smoke.MOSAIC_CALL in text
+            assert ("ragged-dot" in text) == (name == "prefill"), name
+            assert ("f32[32,64,3584]" in text) == (name == "step"), name
+    finally:
+        registry._REGISTRY.pop(spec.name, None)
