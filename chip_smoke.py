@@ -287,7 +287,7 @@ def generate_phase(node, *, model: str, prompts, max_new) -> dict:
                 f"{got[:1]}, want {max_new[i]} starting [{first}]")
         same += 1
         for j in range(1, len(got)):
-            ref.last_tokens[0] = got[j - 1]
+            ref.last_tokens = [got[j - 1]]
             ref.step()
             logits = ref.last_logits[0]
             gap = float(logits.max() - logits[got[j]]) / float(np.abs(logits).max())
@@ -474,14 +474,14 @@ def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -
     v_state = abstract(engine._v_state if pool is None else pool)
     r_state, table = abstract(engine._r_state), engine.cache.page_table
     return {
-        "step": (variables, k_state, v_state, r_state, abstract(engine.last_tokens),
+        "step": (variables, k_state, v_state, r_state, abstract(engine._tokens),
                  abstract(engine.lengths), abstract(engine.active), abstract(table),
                  abstract(engine.seeds), abstract(engine.temps)),
         "prefill": (variables,
                     abstract(np.zeros((engine.max_slots, engine.max_prefill), np.int32)),
                     abstract(engine.lengths), k_state, v_state, r_state, abstract(table),
                     abstract(engine.lengths), abstract(engine.seeds), abstract(engine.temps),
-                    scalar(np.int32)),
+                    scalar(np.int32), abstract(engine._tokens)),
     }
 
 

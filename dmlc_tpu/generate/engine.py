@@ -26,6 +26,19 @@ lint J2's regression class):
   over ``lengths[s]+1`` cached positions, MLP) + head + sampling (greedy
   at temperature 0, categorical otherwise, per-slot temperature).
 
+The token a step appends is the one thing it needs from the step before, and
+it never leaves the device: both programs take the LAST-TOKEN REGISTER
+(``[max_slots]`` int32) and return it updated (the step: ``where(active,
+sampled, tokens)``; the prefill: each admitted row's first token at its
+slot). Everything else a step is given (lengths, the active mask, the page
+table, seeds, temperatures) the host knows before any result, so each
+program has a half that dispatches and a half that reads
+(``dispatch_step`` / ``collect_step``, ``dispatch_admit`` /
+``collect_admit``), and the SlotScheduler's loop dispatches a turn's runs
+before it reads (the step of the turn before, then its own prefill run).
+``step()``, ``admit()`` and ``join()`` are
+the two halves in a row.
+
 The page pools are DONATED through both programs and live in the one layout
 both write and the gather reads (``[kv_layers * num_pages, page_size,
 kv_heads * head_dim]``, generate/kvcache.py): each program writes its rows
@@ -184,12 +197,36 @@ class Admission(NamedTuple):
     seed: int | None = None
 
 
+class StepRun(NamedTuple):
+    """A decode step the device has and the host has not read
+    (``dispatch_step`` -> ``collect_step``)."""
+
+    tokens: Any            # device [max_slots] int32: the register after the step
+    aux: Any               # device: the family's count arrays
+    logits: Any            # device [max_slots, vocab], or None without return_logits
+    active: np.ndarray     # the mask it ran with: the rows of ``tokens`` that mean something
+    t0: float              # perf_counter at dispatch
+
+
+class PrefillRun(NamedTuple):
+    """One run of the prefill program the host has not read
+    (``dispatch_admit`` -> ``collect_admit``)."""
+
+    results: list[int | Exception]  # per request: its slot, or what refused it before the run
+    tokens: Any                     # device register after the run; None when no request ran
+    counts: Any                     # device: the family's counts summed over the rows
+    prompt_tokens: int              # the real lengths of the prompts that ran, summed
+
+
 class GenerationEngine:
     """Continuous-batching decode driver for one registry LM.
 
     Host-side state (lengths, active flags, temperatures, the page table)
-    is NumPy; device state is the param tree and the KV pools. Mutating
-    methods (admit/join/step/release) must be serialized by the caller — the
+    is NumPy; device state is the param tree, the KV pools and the last-token
+    register, the one thing a step needs from the step before: the host never
+    has to read a result to dispatch the next program. Mutating methods
+    (admit/join/step and their dispatch/collect halves, release) must be
+    serialized by the caller — the
     SlotScheduler's decode thread is the only writer in production;
     ``reserve``/``release_reservation`` are thread-safe (the allocator has
     its own lock) so admission can run on RPC threads.
@@ -290,8 +327,15 @@ class GenerationEngine:
         self.temps = np.zeros(self.max_slots, np.float32)
         self.steps = 0
         self.tokens_out = 0
-        self.last_tokens = np.zeros(self.max_slots, np.int32)
+        # The last sampled token of every slot, on the device from here on
+        # (an operand of one kind for both programs' one compiled entry):
+        # the step appends it to the slot's cache and replaces it, the
+        # prefill puts an admitted row's first token in.
+        self._tokens = jnp.zeros(self.max_slots, jnp.int32)
         self.last_logits: np.ndarray | None = None
+        # End of the last blocking read of a step: the work hook is given
+        # intervals that do not overlap when steps are in flight.
+        self._t_collected = 0.0
         # Per-slot sampling seeds (position-seeded RNG, module docstring).
         # Default seeds derive deterministically from the engine seed and a
         # join counter; a caller-supplied seed (the router's migration path)
@@ -315,6 +359,7 @@ class GenerationEngine:
 
     def _build_step(self) -> Any:
         import jax
+        import jax.numpy as jnp
 
         family = self.family
         return_logits = self.return_logits
@@ -327,10 +372,12 @@ class GenerationEngine:
                 variables["params"], tokens, lengths, active, kv, r_state)
             # The token sampled here lands at sequence position ``lengths``
             # (pre-increment) — the position the key must be folded on.
-            nxt = _sample(logits, seeds, lengths, temps)
+            # The register keeps an inactive row's token: a slot admitted while
+            # this step is in flight has its first token there already.
+            tokens = jnp.where(active, _sample(logits, seeds, lengths, temps), tokens)
             if return_logits:
-                return kv.k_state, kv.v_state, r_state, nxt, aux, logits
-            return kv.k_state, kv.v_state, r_state, nxt, aux
+                return kv.k_state, kv.v_state, r_state, tokens, aux, logits
+            return kv.k_state, kv.v_state, r_state, tokens, aux
 
         return jax.jit(step, donate_argnums=(1, 2, 3))
 
@@ -342,16 +389,18 @@ class GenerationEngine:
 
         def prefill(variables: Any, tokens: Any, lengths: Any, k_state: Any,
                     v_state: Any, r_state: Any, dests: Any, slots: Any, seeds: Any,
-                    temps: Any, n: Any) -> Any:
+                    temps: Any, n: Any, last: Any) -> Any:
             """One row per admitted request, rows ``n`` and up unused:
             tokens [max_slots, s_pad]; lengths [max_slots] int32 (real prompt
             lengths); dests: page rows [max_slots, max_pages_per_slot] (paged)
             or slot indices [max_slots] (contiguous); slots [max_slots] int32,
-            the rows of the recurrent state the prompts overwrite; n [] int32.
+            the rows of the recurrent state the prompts overwrite; n [] int32;
+            last [max_slots] int32, the last-token register.
             The family's prefill runs once per row, at batch 1, on the pools
             and the state the row before left: they are the loop's carry and
-            stay the donated buffers. Returns the first tokens [max_slots]
-            and the family's counts summed over the rows."""
+            stay the donated buffers. Returns the register with each row's
+            first token at ``slots[i]`` and the family's counts summed over
+            the rows."""
 
             def prefill_row(i: Any, k_state: Any, v_state: Any, r_state: Any) -> Any:
                 length = lengths[i]
@@ -372,16 +421,15 @@ class GenerationEngine:
                 return kv.k_state, kv.v_state, r_state, nxt, aux
 
             def body(i: Any, carry: Any) -> Any:
-                *state, firsts, counts = carry
+                *state, last, counts = carry
                 *state, nxt, aux = prefill_row(i, *state)
-                return *state, firsts.at[i].set(nxt), jax.tree_util.tree_map(jnp.add, counts, aux)
+                return *state, last.at[slots[i]].set(nxt), jax.tree_util.tree_map(jnp.add, counts, aux)
 
             # The shapes of the family's counts, to start their sums from zero.
             aux = jax.eval_shape(
                 lambda *state: prefill_row(0, *state)[4], k_state, v_state, r_state)
             counts = jax.tree_util.tree_map(lambda a: jnp.zeros(a.shape, a.dtype), aux)
-            return jax.lax.fori_loop(
-                0, n, body, (k_state, v_state, r_state, jnp.zeros_like(slots), counts))
+            return jax.lax.fori_loop(0, n, body, (k_state, v_state, r_state, last, counts))
 
         # Inside a loop the TPU compiler's default memory scheduler orders a
         # deep family's layers so that their temporaries overlap (0.9 GB more
@@ -456,8 +504,16 @@ class GenerationEngine:
         admitted, and those that got past their check hold their pages
         bound to their slots. Token for token what serial ``join``s in the
         same order give (the sampling key is a function of seed and
-        position, never of the batch)."""
-        out: list[int | Exception] = []
+        position, never of the batch). ``dispatch_admit`` then
+        ``collect_admit``: the decode loop dispatches the turn's step between the two."""
+        return self.collect_admit(self.dispatch_admit(batch))
+
+    def dispatch_admit(self, batch: Sequence[Admission]) -> PrefillRun:
+        """The half of ``admit`` that does not wait: check and bind every
+        request, run the program, and seat the rows that ran (``lengths``,
+        ``active``, ``temps``, ``seeds``: a step dispatched next decodes
+        them, their first tokens being in the device's register)."""
+        results: list[int | Exception] = []
         rows: list[tuple[int, np.ndarray, float, int]] = []  # slot, prompt, temperature, seed
         for req in batch:
             try:
@@ -466,17 +522,17 @@ class GenerationEngine:
                     self.cache.bind(
                         req.slot, self.reserve(prompt.size) if req.pages is None else req.pages)
             except Exception as e:  # the verdict on THIS request: the caller fails its stream
-                out.append(e)
+                results.append(e)
                 continue
             seed = req.seed
             if seed is None:
                 seed = (self._base_seed * 1_000_003 + self._joins) % (1 << 31)
             self._joins += 1
-            out.append(len(rows))  # its row of the run, for the token below
+            results.append(int(req.slot))
             rows.append((int(req.slot), prompt, float(req.temperature), int(seed) & 0xFFFFFFFF))
         if not rows:
-            self.prefill_attrs = {}
-            return out
+            return PrefillRun(results, None, {}, 0)
+        # Operands made for this run alone: nothing the host changes later.
         tokens = np.zeros((self.max_slots, self.max_prefill), np.int32)
         lengths = np.zeros(self.max_slots, np.int32)
         slots = np.zeros(self.max_slots, np.int32)
@@ -486,24 +542,30 @@ class GenerationEngine:
             tokens[i, : prompt.size] = prompt
             lengths[i], slots[i], seeds[i], temps[i] = prompt.size, slot, seed, temp
         dests = self.cache.page_table[slots] if self.cache_mode == "paged" else slots
-        k_state, v_state, r_state, firsts, counts = self._prefill(
+        k_state, v_state, r_state, last, counts = self._prefill(
             self._variables, tokens, lengths, self._k_state, self._v_state, self._r_state,
-            dests, slots, seeds, temps, np.int32(len(rows)))
+            dests, slots, seeds, temps, np.int32(len(rows)), self._tokens)
         self._set_state(k_state, v_state, r_state)
-        # The one call of admit that blocks on the device; what is left of
-        # the caller's gen/prefill span is the host's part.
-        with tracer.span("gen/prefill_sync", cpu=True):
-            firsts = np.asarray(firsts)
-            counts = {name: np.asarray(a) for name, a in counts.items()}
-        self.prefill_attrs = self.family.work_attrs(counts, int(lengths[: len(rows)].sum()))
-        for i, (slot, prompt, temp, seed) in enumerate(rows):
+        self._tokens = last
+        for slot, prompt, temp, seed in rows:
             self.lengths[slot] = prompt.size
             self.active[slot] = True
             self.temps[slot] = temp
             self.seeds[slot] = seed
-            self.last_tokens[slot] = firsts[i]
         self.tokens_out += len(rows)
-        return [r if isinstance(r, Exception) else int(firsts[r]) for r in out]
+        return PrefillRun(results, last, counts, int(lengths.sum()))
+
+    def collect_admit(self, run: PrefillRun) -> list[int | Exception]:
+        """The half of ``admit`` that waits: the run's one blocking read.
+        What is left of the caller's gen/prefill spans is the host's part."""
+        if run.tokens is None:
+            self.prefill_attrs = {}
+            return run.results
+        with tracer.span("gen/prefill_sync", cpu=True):
+            last = np.asarray(run.tokens)
+            counts = {name: np.asarray(a) for name, a in run.counts.items()}
+        self.prefill_attrs = self.family.work_attrs(counts, run.prompt_tokens)
+        return [r if isinstance(r, Exception) else int(last[r]) for r in run.results]
 
     def _check(self, req: Admission, taken: set[int]) -> np.ndarray:
         """The request's prompt as an array, or the ValueError that refuses it."""
@@ -532,12 +594,23 @@ class GenerationEngine:
         """One decode step over every active slot (fixed batch shape).
         Appends the previous sampled token to each slot's cache and samples
         the next; returns the sampled token per slot ([max_slots], only
-        active rows meaningful). Host state advances for active slots."""
+        active rows meaningful). Host state advances for active slots.
+        ``dispatch_step`` then ``collect_step``: the decode loop dispatches
+        the next step between the two."""
+        return self.collect_step(self.dispatch_step())
+
+    def dispatch_step(self) -> StepRun:
+        """The half of ``step`` that does not wait: run the program on the
+        registers as the host knows them and advance them at once
+        (``lengths``, ``steps``, ``tokens_out`` need no result). The program
+        gets COPIES of the registers the host goes on changing: an operand
+        may be read after this returns."""
         import time
 
         t0 = time.perf_counter()
+        active = self.active.copy()
         table = (
-            self.cache.page_table
+            self.cache.page_table.copy()
             if self.cache_mode == "paged"
             else np.zeros((self.max_slots, 1), np.int32)
         )
@@ -546,47 +619,56 @@ class GenerationEngine:
             self._k_state,
             self._v_state,
             self._r_state,
-            self.last_tokens,
-            self.lengths,
-            self.active,
+            self._tokens,
+            self.lengths.copy(),
+            active,
             table,
-            self.seeds,
-            self.temps,
+            self.seeds.copy(),
+            self.temps.copy(),
         )
-        k_state, v_state, r_state, nxt, aux = out[:5]
+        k_state, v_state, r_state, tokens, aux = out[:5]
         self._set_state(k_state, v_state, r_state)
-        # The one place step blocks on the device; what is left of the
-        # caller's gen/step span is the host's part (uploads, dispatch,
-        # bookkeeping).
+        self._tokens = tokens
+        self.lengths[active] += 1
+        self.steps += 1
+        self.tokens_out += int(active.sum())
+        return StepRun(tokens, aux, out[5] if self.return_logits else None, active, t0)
+
+    def collect_step(self, run: StepRun) -> np.ndarray:
+        """The half of ``step`` that waits: the one place a step blocks on
+        the device; what is left of the caller's gen/step span is the
+        host's part (uploads, dispatch, bookkeeping)."""
+        import time
+
         with tracer.span("gen/step_sync", cpu=True):
-            if self.return_logits:
-                self.last_logits = np.asarray(out[5])
-            tokens = np.asarray(nxt)
-            aux = {name: np.asarray(a) for name, a in aux.items()}
-        n_active = int(self.active.sum())
+            if run.logits is not None:
+                self.last_logits = np.asarray(run.logits)
+            tokens = np.asarray(run.tokens)
+            aux = {name: np.asarray(a) for name, a in run.aux.items()}
+        n_active = int(run.active.sum())
         self.step_attrs = self.family.work_attrs(aux, n_active)
         if self.state.nbytes:
             self.step_attrs.update(
                 state_slots=n_active, state_bytes=n_active * self.state.bytes_per_slot)
-        self.lengths[self.active] += 1
-        self.last_tokens[self.active] = tokens[self.active]
-        self.steps += 1
-        self.tokens_out += n_active
+        now = time.perf_counter()
         if self.device_work is not None and n_active > 0:
-            # np.asarray(nxt) above materialized the step's results, so
-            # this wall is the step's real device+host latency.
-            self.device_work(self.model_name, n_active, time.perf_counter() - t0)
+            # The read above materialized the step's results. Alone, this is
+            # the step's device + host latency; with the next step already
+            # dispatched, the time since the read before: the pace of steps.
+            self.device_work(self.model_name, n_active, now - max(run.t0, self._t_collected))
+        self._t_collected = now
         return tokens
 
     def release(self, slot: int) -> list[int]:
         """Slot exit: recycle its pages, reset its registers. Returns the
-        freed page ids. The slot's recurrent state stays where it is, with
-        no device work: the next ``join`` of this slot overwrites it whole."""
+        freed page ids. The slot's recurrent state and its row of the token
+        register stay where they are, with no device work: the next ``join``
+        of this slot overwrites both. A step still in flight may compute the
+        slot's old row: it runs before anything dispatched after this."""
         self.active[slot] = False
         self.lengths[slot] = 0
         self.temps[slot] = 0.0
         self.seeds[slot] = 0
-        self.last_tokens[slot] = 0
         if self.cache_mode == "paged":
             return self.cache.release(slot)
         return []
@@ -600,6 +682,19 @@ class GenerationEngine:
             self.cache.v_pages = v_state
 
     # ---- observability / weights ----------------------------------------
+
+    @property
+    def last_tokens(self) -> np.ndarray:
+        """The register, read back (blocks on whatever is in flight). Set it
+        to force the token the next step appends (a reference fed a served
+        prefix)."""
+        return np.asarray(self._tokens)
+
+    @last_tokens.setter
+    def last_tokens(self, tokens: Any) -> None:
+        import jax.numpy as jnp
+
+        self._tokens = jnp.asarray(tokens, jnp.int32).reshape(self.max_slots)
 
     @property
     def slots_active(self) -> int:

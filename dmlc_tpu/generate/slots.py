@@ -30,17 +30,31 @@ retained until the consumer's cumulative ack — the exactly-once delivery
 substrate the RPC worker (generate/worker.py) exposes as
 ``job.generate_poll`` (wire format: docs/GENERATE.md).
 
-Tracing: every decode step runs under a ``gen/step`` span bound to the
-OLDEST resident slot's submit-time trace context, so a request's timeline
+The loop keeps one decode step IN FLIGHT (``SlotScheduler._turn``): a turn
+dispatches its prefill run and its step, reads the step of the turn before,
+and only then waits for its own prefill run, so the device has a step queued
+while the host reads, delivers and prepares. What the host knows without a
+result decides who is in a step (cancel, deadline, ``emitted + in flight >=
+max_new_tokens``, page growth); an ``eos`` is seen one step late and the row
+computed meanwhile is thrown away (``gen_tokens_discarded``).
+
+Tracing: a loop turn has ONE ``gen/step`` span, which covers the dispatch of
+its step and the read of the step before (child ``gen/step_sync``); its
+attributes (``slots``, ``ahead``, ``pages_bound``, ``tokens_resident``, the
+family's counts) are those of the step it READ. It is bound to the OLDEST
+resident slot's submit-time trace context, so a request's timeline
 shows the steps that produced its tokens parented under its
-``rpc/job.generate`` span (trace smoke asserts this); a ``gen/prefill`` span
-is ONE run of the prefill program for every request the loop turn admits
-(``prompts``, ``prompt_tokens``), bound to the oldest of them, and each of
-them has its own ``gen/wait`` under its own context (submit to the start of
-that run). The decode thread feeds the device, so its time
+``rpc/job.generate`` span (trace smoke asserts this). ONE run of the prefill
+program admits every request the loop turn admits, and has ONE ``gen/prefill``
+span: its READ, at the end of the turn that dispatched it (child
+``gen/prefill_sync``), with the run's attributes (``prompts``,
+``prompt_tokens``), bound to the oldest admitted request; the run's dispatch
+is part of ``gen/admit``. Each
+request has its own ``gen/wait`` under its own context (submit to the
+admission that dispatches its run). The decode thread feeds the device, so its time
 is TILED by leaf spans (docs/OBSERVABILITY.md §1): ``gen/idle`` (waiting for
-work), ``gen/admit`` (admission bookkeeping either side of a prefill),
-``gen/prefill``, ``gen/retire`` (the resident sweep, page growth),
+work), ``gen/admit`` (admission: who gets a slot, the prefill run's dispatch,
+seating), ``gen/prefill``, ``gen/retire`` (the resident sweep, page growth),
 ``gen/step``, ``gen/deliver`` (token pushes, exits) — an idle gap of the chip
 always has an owner on this thread.
 """
@@ -186,8 +200,8 @@ class _Slot:
 
     __slots__ = (
         "stream", "prompt", "max_new_tokens", "temperature", "eos_id",
-        "deadline", "trace_ctx", "pages", "emitted", "slot", "submitted_t",
-        "tenant", "seed", "wait_t0",
+        "deadline", "trace_ctx", "pages", "emitted", "in_flight", "slot",
+        "submitted_t", "tenant", "seed", "wait_t0",
     )
 
     def __init__(self, stream: GenStream, prompt: list[int],
@@ -204,6 +218,9 @@ class _Slot:
         self.trace_ctx = trace_ctx
         self.pages = pages
         self.emitted = 0
+        # Tokens the device has been asked for and the loop has not read: a
+        # prefill run's first token, a row of a step.
+        self.in_flight = 0
         self.slot = -1
         self.submitted_t = submitted_t
         self.tenant = tenant
@@ -213,9 +230,27 @@ class _Slot:
         self.wait_t0 = tracer.now() if tracer.enabled else None
 
 
+class _Run:
+    """One program run the device has and the loop has not read: the
+    engine's handle, who sat in each slot WHEN it was dispatched (the only
+    requests its rows may go to), the span attributes that describe it, and
+    the trace its spans are bound to."""
+
+    __slots__ = ("handle", "seats", "attrs", "trace_ctx")
+
+    def __init__(self, handle: Any, seats: list[tuple[int, _Slot]],
+                 attrs: dict[str, Any], trace_ctx: Any) -> None:
+        self.handle = handle
+        self.seats = seats
+        self.attrs = attrs
+        self.trace_ctx = trace_ctx
+
+
 class SlotScheduler:
     """Continuous-batching loop: admit between steps, step while anyone is
-    resident, shed at the door when the slot table / page pool is full."""
+    resident, shed at the door when the slot table / page pool is full. The
+    loop keeps one decode step in flight: it reads a step's tokens a turn
+    after it dispatched it (``_turn``)."""
 
     def __init__(
         self,
@@ -270,6 +305,14 @@ class SlotScheduler:
         self._closed = False
         # Owned exclusively by the decode thread after admission.
         self._resident: list[_Slot] = []
+        # Dispatched and unread (decode thread only): a prefill run from its
+        # turn's admission to that turn's end; a step from its turn to the
+        # next one's read. While the newer step is dispatched and the older
+        # read, the turn holds the newer itself, everyone in it resident.
+        self._prefill_in_flight: _Run | None = None
+        self._step_in_flight: _Run | None = None
+        self.steps_ahead = 0
+        self.tokens_discarded = 0
         self.requests = 0
         self.sheds = 0
         self.evictions = 0
@@ -427,12 +470,16 @@ class SlotScheduler:
         with tracing.lane(lane_name):
             self._loop_body()
 
+    def _idle(self) -> bool:
+        """Nothing to admit, step, read or shut down (under ``_cv``)."""
+        return not (self._pending or self._resident or self._step_in_flight or self._closed)
+
     def _loop_body(self) -> None:
         while True:
             with self._cv:
-                if not self._pending and not self._resident and not self._closed:
+                if self._idle():
                     with tracer.span("gen/idle", cpu=True):
-                        while not self._pending and not self._resident and not self._closed:
+                        while self._idle():
                             self._cv.wait()
                 if self._closed:
                     drained = self._pending
@@ -444,31 +491,99 @@ class SlotScheduler:
                     self.engine.release_reservation(s.pages)
                     self._ledger_release(s)
                     s.stream.finish("overloaded: scheduler stopped")
-                for s in self._resident:
-                    self.engine.release(s.slot)
-                    self._ledger_release(s)
-                    s.stream.finish("overloaded: scheduler stopped")
-                self._resident = []
+                try:
+                    # What the device still holds (a step: a prefill run is read
+                    # in its own turn) is read and delivered first: a request
+                    # whose last token was in flight ends whole.
+                    self._collect_step()
+                except Exception:
+                    log.exception("reading the step in flight at stop failed")
+                self._fail_everyone("overloaded: scheduler stopped")
                 return
             try:
-                self._admit_pending()
-                self._retire_and_step()
+                self._turn()
             except Exception:
                 # A crashed decode loop must fail every resident request
                 # visibly, not hang their streams forever.
                 log.exception("decode loop error; failing resident slots")
-                for s in self._resident:
-                    try:
-                        self.engine.release(s.slot)
-                    except Exception:  # dmlc-lint: disable=E1 -- best-effort cleanup mid-failure; the stream error below is the observable verdict
-                        pass
-                    s.stream.finish("RpcError: generation engine failed")
-                self._resident = []
+                self._fail_everyone("RpcError: generation engine failed")
+
+    def _fail_everyone(self, error: str) -> None:
+        """End every resident's stream, and the stream of whoever left its
+        slot with tokens still in flight; nothing stays in flight (a run
+        nobody has read is waited for, best effort, and thrown away)."""
+        flying = [(run, collect) for run, collect in (
+            (self._prefill_in_flight, self.engine.collect_admit),
+            (self._step_in_flight, self.engine.collect_step)) if run is not None]
+        self._prefill_in_flight = self._step_in_flight = None
+        for run, collect in flying:
+            try:
+                collect(run.handle)
+            except Exception:  # dmlc-lint: disable=E1 -- best-effort cleanup mid-failure; the stream error below is the observable verdict
+                pass
+        for s in list(self._resident):
+            try:
+                self.engine.release(s.slot)
+            except Exception:  # dmlc-lint: disable=E1 -- best-effort cleanup mid-failure; the stream error below is the observable verdict
+                pass
+            self._ledger_release(s)
+            s.stream.finish(error)
+        self._resident = []
+        for run, _ in flying:
+            for _, req in run.seats:
+                req.stream.finish(error)
+
+    def _turn(self) -> None:
+        """One loop turn: dispatch this turn's runs, THEN read, so that the
+        device has a step queued while the host reads, delivers and prepares
+        the next turn:
+
+            admit (dispatch prefill run P_t, seat its requests) -> retire by
+            what the host knows -> dispatch step S_t -> read S_(t-1), deliver
+            its tokens -> read P_t, deliver its first tokens.
+
+        A step is read a turn late; a prefill run in its own turn, last, with
+        S_t queued behind it: a first token does not wait a turn, and when
+        the read returns the host has S_t's device time to prepare the next
+        turn.
+
+        ORDERING INVARIANT the loop relies on: the pools, the recurrent
+        state and the token register are touched by ONE in-order stream of
+        two donating programs, each taking what the one before returned. So
+        a slot may be released, its pages recycled and the slot re-admitted
+        while a step that still computes its old row is in flight: the
+        prefill dispatched later runs later and overwrites what that row
+        wrote. What must not cross is a RESULT: a run's row goes only to
+        the request that sat in the slot when the run was dispatched
+        (``_Run.seats``) and has not left since (``_hand_out``)."""
+        self._admit_pending()
+        with tracer.span("gen/retire", cpu=True):
+            self._retire()
+        if self._resident:
+            due = self._step_in_flight
+            trace_ctx = min(self._resident, key=lambda r: r.submitted_t).trace_ctx
+            t0 = self.clock()
+            with tracectx.bind(trace_ctx):
+                with tracer.span("gen/step", cpu=True) as span:
+                    run = self._dispatch_step(trace_ctx, ahead=due is not None)
+                    # A step that fails here fails every resident (the caller's
+                    # crash path), as a step that failed did.
+                    values = self._read_step(due, span)
+                    self._step_in_flight = run
+            self._deliver_step(due, values, self.clock() - t0)
+        else:
+            self._collect_step()  # a busy period's last turn dispatches nothing
+        self._collect_prefill()
 
     def _admit_pending(self) -> None:
         """Move the waiting requests that find a free engine slot into the
-        batch (between steps): ONE run of the prefill program and one
-        blocking read for all of them, first in first out.
+        batch (between steps): ONE run of the prefill program for all of
+        them, first in first out, dispatched and not waited for: they are
+        seated at once, their first tokens in the device's register and in
+        flight (the step dispatched next decodes them), and the run is read
+        at the end of the turn (``_collect_prefill``). The dispatch is
+        admission's own time, under ``gen/admit``: a run's ``gen/prefill``
+        span is its read.
 
         A request stays IN ``_pending`` until it lands in ``_resident``:
         submit-time admission counts both lists, and a request invisible to
@@ -476,52 +591,49 @@ class SlotScheduler:
         slot table."""
         with tracer.span("gen/admit", cpu=True):
             batch = self._admissible()
+            if not batch:
+                return
             for req in batch:
                 if req.wait_t0 is not None:
                     with tracectx.bind(req.trace_ctx):
                         tracer.record("gen/wait", max(0.0, tracer.now() - req.wait_t0))
-        if not batch:
-            return
-        firsts: list[int | Exception]
-        try:
-            # Bound to the oldest admitted request's trace, as gen/step is
-            # to the oldest resident's.
-            with tracectx.bind(batch[0].trace_ctx):
-                with tracer.span("gen/prefill", cpu=True) as span:
-                    firsts = self.engine.admit([
-                        Admission(req.slot, req.prompt, req.temperature, req.pages, req.seed)
-                        for req in batch])
-                    ran = [req for req, first in zip(batch, firsts)
-                           if not isinstance(first, Exception)]
-                    span.set(prompts=len(ran),
-                             prompt_tokens=sum(len(req.prompt) for req in ran))
-                    self._count_work(span, self.engine.prefill_attrs)
-        except Exception as e:
-            # The run failed: that fails the streams of ITS batch, never
-            # the resident one.
-            log.exception("prefill run of %d requests failed", len(batch))
-            firsts = [e] * len(batch)
-        for req, first in zip(batch, firsts):
-            if isinstance(first, Exception):
-                # A bad request fails ITS stream. Pages go back wherever
-                # they are: bound to the slot (the engine got past its
-                # check) or still the submit-time reservation.
-                log.error("prefill failed for %s: %s", req.stream.request_id, first)
-                with tracer.span("gen/admit", cpu=True):
+            handle: Any = None
+            results: list[int | Exception]
+            try:
+                handle = self.engine.dispatch_admit([
+                    Admission(req.slot, req.prompt, req.temperature, req.pages, req.seed)
+                    for req in batch])
+                results = handle.results
+            except Exception as e:
+                # The run failed: that fails the streams of ITS batch, never
+                # the resident one.
+                log.exception("prefill run of %d requests failed", len(batch))
+                results = [e] * len(batch)
+            seats = []
+            for req, result in zip(batch, results):
+                if isinstance(result, Exception):
+                    # A bad request fails ITS stream. Pages go back wherever
+                    # they are: bound to the slot (the engine got past its
+                    # check) or still the submit-time reservation.
+                    log.error("prefill failed for %s: %s", req.stream.request_id, result)
                     self._unpend(req)
                     if (self.engine.cache_mode == "paged"
                             and not self.engine.cache.slot_pages(req.slot)):
                         self.engine.release_reservation(req.pages)
                     self.engine.release(req.slot)
                     self._ledger_release(req)
-                    req.stream.finish(f"{type(first).__name__}: {first}")
-                continue
-            with tracer.span("gen/admit", cpu=True):
+                    req.stream.finish(f"{type(result).__name__}: {result}")
+                    continue
                 self._seat(req)
-            with tracer.span("gen/deliver", cpu=True):
-                self._deliver(req, first)
-                if req.eos_id is not None and first == req.eos_id:
-                    self._exit(req, "eos")
+                req.in_flight = 1
+                seats.append((req.slot, req))
+            if not seats:
+                return
+            attrs = {"prompts": len(seats),
+                     "prompt_tokens": sum(len(req.prompt) for _, req in seats)}
+            # Read under the oldest admitted request's trace, as gen/step is
+            # under the oldest resident's.
+            self._prefill_in_flight = _Run(handle, seats, attrs, batch[0].trace_ctx)
 
     def _admissible(self) -> list[_Slot]:
         """The waiting requests, oldest first, that each get a free slot
@@ -607,38 +719,103 @@ class SlotScheduler:
                    error=f"overloaded: evicted mid-decode ({why})",
                    counted=False)
 
-    def _retire_and_step(self) -> None:
-        # Between-step housekeeping: expired deadlines out, page growth
-        # secured, THEN one fixed-shape step for whoever remains.
-        with tracer.span("gen/retire", cpu=True):
-            self._retire()
-        if not self._resident:
+    def _collect_prefill(self) -> None:
+        """Read the prefill run in flight, if any (``gen/prefill``, which
+        carries the run's attributes) and deliver its first tokens. A run
+        that fails here fails the streams of ITS batch, never the residents."""
+        run, self._prefill_in_flight = self._prefill_in_flight, None
+        if run is None:
             return
-        oldest = min(self._resident, key=lambda r: r.submitted_t)
-        attrs = {"slots": len(self._resident)}
+        with tracectx.bind(run.trace_ctx):
+            with tracer.span("gen/prefill", cpu=True) as span:
+                try:
+                    firsts = self.engine.collect_admit(run.handle)
+                except Exception as e:
+                    log.exception("prefill run of %d requests failed", len(run.seats))
+                    for _, req in run.seats:
+                        req.in_flight -= 1
+                        if not req.stream.done:
+                            self._exit(req, "prefill_failed", counted=False,
+                                       error=f"{type(e).__name__}: {e}")
+                    return
+                span.set(**run.attrs)
+                self._count_work(span, self.engine.prefill_attrs)
+        values = {slot: first for slot, first in zip(run.handle.results, firsts)
+                  if not isinstance(first, Exception)}
+        with tracer.span("gen/deliver", cpu=True):
+            self._hand_out(run, values)
+
+    def _collect_step(self) -> None:
+        """Read and deliver the step in flight and dispatch none: a turn
+        with nobody resident, ``stop()``."""
+        due = self._step_in_flight
+        if due is None:
+            return
+        t0 = self.clock()
+        with tracectx.bind(due.trace_ctx):
+            with tracer.span("gen/step", cpu=True) as span:
+                values = self._read_step(due, span)
+                self._step_in_flight = None
+        self._deliver_step(due, values, self.clock() - t0)
+
+    def _dispatch_step(self, trace_ctx: Any, ahead: bool) -> _Run:
+        """One fixed-shape step for whoever is resident, not waited for."""
+        attrs = {"slots": len(self._resident), "ahead": int(ahead)}
         if tracer.enabled:
             # Pages handed out (bound to slots + reserved by waiting
             # requests) against the tokens that sit in them, measured where
             # the pages are handed out. A resident's cache holds its prompt
-            # and all but the newest of the tokens it was sent.
+            # and all but the newest of the tokens it was sent or is due.
             attrs["pages_bound"] = self._page_total - self.engine.pages_free
             attrs["tokens_resident"] = sum(
-                len(r.prompt) + r.emitted - 1 for r in self._resident)
-        t0 = self.clock()
-        with tracectx.bind(oldest.trace_ctx):
-            with tracer.span("gen/step", cpu=True, **attrs) as span:
-                tokens = self.engine.step()
-                self._count_work(span, self.engine.step_attrs)
-        elapsed = max(0.0, self.clock() - t0)
+                len(r.prompt) + r.emitted + r.in_flight - 1 for r in self._resident)
+        handle = self.engine.dispatch_step()
+        for req in self._resident:
+            req.in_flight += 1
+        if ahead:
+            self.steps_ahead += 1
+            if self.metrics is not None:
+                self.metrics.inc("gen_steps_ahead")
+        return _Run(handle, [(r.slot, r) for r in self._resident], attrs, trace_ctx)
+
+    def _read_step(self, due: _Run | None, span: Any) -> Any:
+        """The due step's one blocking read: its tokens by slot (None when
+        nothing is due: a busy period's first turn). The span that reads a
+        step is the span that describes it."""
+        if due is None:
+            return None
+        values = self.engine.collect_step(due.handle)
+        span.set(**due.attrs)
+        self._count_work(span, self.engine.step_attrs)
+        return values
+
+    def _deliver_step(self, due: _Run | None, values: Any, elapsed: float) -> None:
+        if due is None:
+            return
         with tracer.span("gen/deliver", cpu=True):
-            self.step_stats.record(elapsed)
+            self.step_stats.record(max(0.0, elapsed))
             if self.profile is not None:
                 self.profile(elapsed)
-            for req in list(self._resident):
-                tok = int(tokens[req.slot])
-                self._deliver(req, tok)
-                if req.eos_id is not None and tok == req.eos_id:
-                    self._exit(req, "eos")
+            self._hand_out(due, values)
+
+    def _hand_out(self, run: _Run, values: Any) -> None:
+        """A run's rows to the requests that sat in its slots when it was
+        dispatched and are still here: a request that left meanwhile (eos
+        seen a step late, cancel, deadline, eviction) gets nothing, whoever
+        holds its slot now."""
+        for slot, req in run.seats:
+            req.in_flight -= 1
+            if req.stream.done:
+                self.tokens_discarded += 1
+                if self.metrics is not None:
+                    self.metrics.inc("gen_tokens_discarded")
+                continue
+            tok = int(values[slot])
+            self._deliver(req, tok)
+            if req.eos_id is not None and tok == req.eos_id:
+                self._exit(req, "eos")
+            elif req.emitted >= req.max_new_tokens:
+                self._exit(req, "max_tokens")
 
     def _count_work(self, span: Any, attrs: dict) -> None:
         """What the engine says its last program run did (expert pairs,
@@ -665,8 +842,10 @@ class SlotScheduler:
                 self._exit(req, "deadline",
                            error="deadline: generation exceeded its budget")
                 continue
-            if req.emitted >= req.max_new_tokens:
-                self._exit(req, "max_tokens")
+            if req.emitted + req.in_flight >= req.max_new_tokens:
+                # Every token it is due is in flight: the slot is free for
+                # the next request now, the stream ends when the last lands.
+                self._vacate(req, "max_tokens", req.max_new_tokens)
                 continue
             try:
                 self.engine.ensure_capacity(req.slot)
@@ -693,18 +872,27 @@ class SlotScheduler:
             self._t_first_token = now
         self._t_last_token = now
 
-    def _exit(self, req: _Slot, reason: str, error: str | None = None,
-              counted: bool = True) -> None:
+    def _vacate(self, req: _Slot, reason: str, emitted: int) -> None:
+        """The request's slot and pages go back to the engine; its stream
+        stays open for the tokens still in flight."""
         freed = self.engine.release(req.slot)
         with self._cv:  # submit reads len(_resident) for admission
             self._resident.remove(req)
             self.ledger.release(req.tenant)
-        if counted:
-            self.completions += 1
         if self.flight is not None:
             self.flight.note("slot_exit", slot=req.slot, reason=reason,
-                             step=self.engine.steps, emitted=req.emitted,
+                             step=self.engine.steps, emitted=emitted,
                              pages_freed=len(freed))
+        req.slot = -1
+
+    def _exit(self, req: _Slot, reason: str, error: str | None = None,
+              counted: bool = True) -> None:
+        """The request is over: out of its slot if it still holds one, its
+        stream finished. Rows of runs in flight find the stream done."""
+        if req.slot >= 0:
+            self._vacate(req, reason, req.emitted)
+        if counted:
+            self.completions += 1
         req.stream.finish(error)
 
     # ---- observability / lifecycle ---------------------------------------
@@ -738,6 +926,8 @@ class SlotScheduler:
             "page_budget": self.page_budget,
             **({"tenants": tenants} if tenants else {}),
             "steps": self.engine.steps,
+            "steps_ahead": self.steps_ahead,
+            "tokens_discarded": self.tokens_discarded,
             "step_ms_p50": round(self.step_stats.percentile(50) * 1e3, 3)
             if len(self.step_stats) else None,
             "step_ms_p99": round(self.step_stats.percentile(99) * 1e3, 3)

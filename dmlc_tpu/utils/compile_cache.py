@@ -72,7 +72,10 @@ def _count_entries(root: str | None) -> int:
     if root is None:
         return 0
     try:
-        return sum(1 for p in Path(root).iterdir() if p.is_file())
+        # One directory read, no stat per entry: this runs inside every
+        # metrics scrape, beside threads that want the interpreter.
+        with os.scandir(root) as entries:
+            return sum(1 for entry in entries if entry.is_file())
     except OSError:
         return 0
 
@@ -94,8 +97,8 @@ def export_metrics(registry) -> None:
     (utils/metrics.py): ``jax_cache_hits`` / ``jax_cache_misses`` /
     ``jax_cache_writes`` / ``jax_cache_entries``. Gauges read live, so one
     registration at node build covers the process lifetime."""
-    registry.gauge("jax_cache_hits", lambda: counters()["hits"])
-    registry.gauge("jax_cache_misses", lambda: counters()["misses"])
+    registry.gauge("jax_cache_hits", lambda: _COUNTS["hits"])  # no directory read for these two
+    registry.gauge("jax_cache_misses", lambda: _COUNTS["misses"])
     registry.gauge("jax_cache_writes", lambda: counters()["writes"])
     registry.gauge("jax_cache_entries", lambda: counters()["entries"])
 

@@ -461,6 +461,36 @@ class TestCompileCachePlacement:
         want = cc._REPO_ROOT / ".jax_cache" / f"cpu-{cc.machine_fingerprint()}"
         assert configured == reported == str(want)
 
+    def test_a_metrics_scrape_reads_the_directory_twice_and_stats_nothing(
+            self, monkeypatch, tmp_path):
+        """The four gauges run inside every ``obs.metrics`` scrape, beside the
+        decode thread: a stat per entry (2,800 system calls a scrape at 700
+        entries) held a 2 s scrape over its budget on the chip's host."""
+        import os
+        from pathlib import Path
+
+        from dmlc_tpu.utils import compile_cache as cc
+        from dmlc_tpu.utils.metrics import Registry
+
+        for i in range(5):
+            (tmp_path / f"entry{i}").write_bytes(b"x")
+        (tmp_path / "a_directory").mkdir()
+        monkeypatch.setattr(cc, "cache_dir", lambda: str(tmp_path))
+        reads = []
+        real_scandir = os.scandir
+        monkeypatch.setattr(cc.os, "scandir", lambda p: (reads.append(p), real_scandir(p))[1])
+
+        def no_stat(self, *a, **kw):
+            raise AssertionError(f"a stat of {self}")
+
+        monkeypatch.setattr(Path, "stat", no_stat)
+        registry = Registry()
+        cc.export_metrics(registry)
+        gauges = registry.snapshot()["gauges"]
+        assert gauges["jax_cache_entries"] == 5
+        assert {"jax_cache_hits", "jax_cache_misses", "jax_cache_writes"} <= set(gauges)
+        assert len(reads) == 2  # entries and writes; hits and misses read no directory
+
     def test_cluster_node_build_enables_it(self, monkeypatch, tmp_path):
         from dmlc_tpu.cluster.node import ClusterNode
         from dmlc_tpu.utils import compile_cache as cc

@@ -227,21 +227,22 @@ class TestOverloadContract:
 
 
 class TestBatchedAdmission:
-    """Every request a loop turn admits goes through ONE ``engine.admit``."""
+    """Every request a loop turn admits goes through ONE run of the prefill
+    program (``engine.dispatch_admit``: the loop reads the run a turn later)."""
 
     @staticmethod
     def spy_on_admit(eng, during=None):
-        """Record each ``admit`` call's prompts; ``during`` runs on the
+        """Record each ``dispatch_admit`` call's prompts; ``during`` runs on the
         decode thread while the batch is neither waiting nor seated."""
-        calls, real = [], eng.admit
+        calls, real = [], eng.dispatch_admit
 
-        def admit(batch):
+        def dispatch_admit(batch):
             calls.append([list(a.prompt) for a in batch])
             if during is not None:
                 during(batch)
             return real(batch)
 
-        eng.admit = admit
+        eng.dispatch_admit = dispatch_admit
         return calls
 
     def test_a_turn_admits_everyone_waiting_in_one_run(self, variables):

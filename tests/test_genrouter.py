@@ -240,7 +240,11 @@ class TestSeededResume:
         and migrated by the router ends token-identical to the unkilled
         single-slot reference — the tentpole, end to end on the real
         RNG."""
-        prompt, seed, n = [2, 7, 1], 99 + SEED_BASE, 8
+        # 24 tokens: the crash has to land mid-decode, and the loop delivers a
+        # short stream whole between two 10 ms polls (the session is then done
+        # and there is nothing to migrate); prompt + delivered stays under the
+        # members' max_prefill of 32 wherever it lands.
+        prompt, seed, n = [2, 7, 1], 99 + SEED_BASE, 24
         ref = reference_sampled(variables, prompt, n, seed)
         net = SimRpcNetwork()
         alive = {"m0", "m1"}

@@ -331,22 +331,26 @@ def lm_engine():
 def gen_spans(lm_engine):
     """Six requests staged before the decode thread starts (four slots, so
     two wait holding their reservations), each submitted under a root span
-    of its own; the engine's own state is written down inside every step."""
+    of its own; the engine's own state is written down as every step is
+    dispatched (the loop reads a step a turn after it dispatched it)."""
     eng = lm_engine
     rng = np.random.default_rng(7)
     reqs = [(rng.integers(0, SPEC.num_outputs, size=int(rng.integers(3, 12))).tolist(),
              int(rng.integers(3, 9))) for _ in range(N_REQUESTS)]
     state_at_step = []
-    real_step = eng.step
+    real_step = eng.dispatch_step
 
-    def step():
+    def dispatch_step():
         alloc = eng.cache.allocator
         state_at_step.append((alloc.pages_total - alloc.pages_free,
                               int(eng.lengths[eng.active].sum()), int(eng.active.sum())))
-        time.sleep(0.01)  # a step the size of the tracer's own cost would test the tracer
+        # A step the size of the tracer's own cost would test the tracer; and under
+        # the suite's six workers the host work between spans slows several times
+        # while a sleep does not: long enough that the tiling pin reads the loop.
+        time.sleep(0.02)
         return real_step()
 
-    eng.step = step
+    eng.dispatch_step = dispatch_step
     try:
         with traced_scenario():
             sched = SlotScheduler(eng, max_waiting=N_REQUESTS, autostart=False)
@@ -359,7 +363,7 @@ def gen_spans(lm_engine):
             sched.stop()
             spans = wire(tracer.events_wire())
     finally:
-        del eng.step
+        del eng.dispatch_step
     assert [len(o) for o in outs] == [n for _, n in reqs]
     return {"all": spans, "reqs": reqs, "state_at_step": state_at_step}
 
@@ -368,18 +372,27 @@ def named(spans, name):
     return [s for s in spans["all"] if s["name"] == name]
 
 
+def reads(spans, name):
+    """The ``gen/step`` / ``gen/prefill`` spans that READ a run, oldest first:
+    they carry the run's attributes (a span that only dispatches has none)."""
+    attr = "slots" if name == "gen/step" else "prompts"
+    return sorted((s for s in named(spans, name) if attr in s["attrs"]), key=lambda s: s["t0"])
+
+
 def test_gen_wait_once_per_request_under_its_trace(gen_spans):
     roots = {r["attrs"]["i"]: r for r in named(gen_spans, "test/request")}
     waits = named(gen_spans, "gen/wait")
     assert len(roots) == N_REQUESTS and len(waits) == N_REQUESTS
     assert sorted(w["trace"] for w in waits) == sorted(r["trace"] for r in roots.values())
-    runs = named(gen_spans, "gen/prefill")
+    admits = named(gen_spans, "gen/admit")
     for w in waits:
         root = next(r for r in roots.values() if r["trace"] == w["trace"])
         assert w["parent"] == root["span"]
-        # from submit (inside the root span) to the start of the prefill run that took it
-        assert root["t0"] - CLOCK_SLACK <= w["t0"] <= root["t1"] + CLOCK_SLACK
-        assert min(abs(w["t1"] - p["t0"]) for p in runs) < 0.005
+        # from submit (inside the root span) to the admission that dispatches its prefill run;
+        # ``tracer.record`` dates a span back from ITS clock read, a few calls after the loop
+        # took the duration: a loaded host can put a preemption between the two (1 ms of room)
+        assert root["t0"] - CLOCK_SLACK <= w["t0"] <= root["t1"] + 1e-3
+        assert any(a["t0"] - CLOCK_SLACK <= w["t1"] <= a["t1"] + CLOCK_SLACK for a in admits)
     # the two that found no slot waited for an exit: longer than any of the first four
     by_len = sorted(w["dur"] for w in waits)
     assert by_len[-2] > by_len[3]
@@ -388,8 +401,18 @@ def test_gen_wait_once_per_request_under_its_trace(gen_spans):
 def test_gen_prefill_is_one_span_per_run_of_the_program(gen_spans):
     """Four staged requests find the four slots free: ONE run admits them,
     under the oldest one's trace; the two that waited come in later runs.
-    Every run counts its requests and their tokens."""
-    runs = sorted(named(gen_spans, "gen/prefill"), key=lambda p: p["t0"])
+    A run's span is its READ, at the end of the turn whose admission
+    dispatched it (under ``gen/admit``), and counts the run's requests and
+    their tokens."""
+    runs = reads(gen_spans, "gen/prefill")
+    assert len(runs) == len(named(gen_spans, "gen/prefill"))
+    steps = sorted(named(gen_spans, "gen/step"), key=lambda s: s["t0"])
+    waits = named(gen_spans, "gen/wait")
+    for read in runs:
+        sent = max(w["t1"] for w in waits if w["trace"] == read["trace"])
+        # between the two the loop dispatched ONE step, its own turn's: the
+        # device has it to do while the host waits for the run
+        assert sum(sent <= s["t0"] and s["t1"] <= read["t0"] + CLOCK_SLACK for s in steps) == 1
     roots = {r["attrs"]["i"]: r for r in named(gen_spans, "test/request")}
     lens = [len(prompt) for prompt, _ in gen_spans["reqs"]]
     assert runs[0]["attrs"]["prompts"] == 4
@@ -415,9 +438,11 @@ def test_loop_thread_spans_tile_admission_to_exit(gen_spans):
 @pytest.mark.parametrize("child,parent", [("gen/step_sync", "gen/step"),
                                           ("gen/prefill_sync", "gen/prefill")])
 def test_sync_span_is_the_child_that_blocks(gen_spans, child, parent):
-    parents = {s["span"]: s for s in named(gen_spans, parent)}
+    """One blocking read a run, under the span that reads the run."""
+    parents = {s["span"]: s for s in reads(gen_spans, parent)}
     kids = named(gen_spans, child)
     assert len(kids) == len(parents) > 0
+    assert sorted(k["parent"] for k in kids) == sorted(parents)
     for k in kids:
         p = parents[k["parent"]]
         assert p["t0"] - CLOCK_SLACK <= k["t0"] and k["t1"] <= p["t1"] + CLOCK_SLACK
@@ -425,12 +450,25 @@ def test_sync_span_is_the_child_that_blocks(gen_spans, child, parent):
 
 
 def test_gen_step_carries_the_allocators_and_engines_state(gen_spans):
-    steps = sorted(named(gen_spans, "gen/step"), key=lambda s: s["t0"])
+    """The span that reads a step carries what the allocator and the engine
+    held when THAT step was dispatched, a turn earlier."""
+    steps = reads(gen_spans, "gen/step")
     seen = [(s["attrs"]["pages_bound"], s["attrs"]["tokens_resident"], s["attrs"]["slots"])
             for s in steps]
     assert seen == gen_spans["state_at_step"]
     # while two requests waited, their reserved pages were counted as bound
     assert max(p for p, _, _ in seen) > 0 and all(p * 8 >= t for p, t, _ in seen)
+
+
+def test_gen_step_says_whether_it_left_with_the_step_before_unread(gen_spans):
+    """One busy period: its first turn dispatches a step and reads none, its
+    last reads one and dispatches none, and every step but the first left
+    while the one before it was in flight."""
+    spans = sorted(named(gen_spans, "gen/step"), key=lambda s: s["t0"])
+    assert "ahead" not in spans[0]["attrs"] and "slots" not in spans[0]["attrs"]
+    steps = reads(gen_spans, "gen/step")
+    assert len(steps) == len(spans) - 1 == len(gen_spans["state_at_step"])
+    assert [s["attrs"]["ahead"] for s in steps] == [0] + [1] * (len(steps) - 1)
 
 
 @pytest.mark.parametrize("name", LOOP_TOP[1:] + ("gen/step_sync", "gen/prefill_sync"))

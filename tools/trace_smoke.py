@@ -258,9 +258,11 @@ def main() -> int:
         )
         return 1
     # Feeding-thread contract (docs/OBSERVABILITY.md §1): the blocking read
-    # of every step is a gen/step_sync CHILD of its gen/step, every prefill
-    # has its gen/prefill_sync, and every generate trace that reached a
-    # prefill shows how long the request waited for it (gen/wait).
+    # of every step is a gen/step_sync CHILD of the gen/step that read it
+    # (a busy period's first turn dispatches a step and reads none: no
+    # ``slots``, no child), every prefill run has its gen/prefill_sync, and
+    # every generate trace that reached a prefill shows how long the request
+    # waited for it (gen/wait).
     for child, parent in (("gen/step_sync", "gen/step"),
                           ("gen/prefill_sync", "gen/prefill")):
         parents = [e for e in events if e["name"] == parent]
@@ -268,7 +270,8 @@ def main() -> int:
         for e in events:
             if e["name"] == child:  # the engine's warm-up syncs too, under no step
                 kids[e["args"].get("parent")] = kids.get(e["args"].get("parent"), 0) + 1
-        childless = [e for e in parents if kids.get(e["args"]["span"]) != 1]
+        childless = [e for e in parents if kids.get(e["args"]["span"], 0)
+                     != (parent == "gen/prefill" or "slots" in e["args"])]
         if not parents or childless:
             print(
                 f"trace smoke FAILED: of {len(parents)} {parent} span(s) "
