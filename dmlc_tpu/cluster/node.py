@@ -1435,6 +1435,14 @@ class ClusterNode:
         then survives member death, drain, and leader failover
         (docs/GENERATE.md §Routing) — with member-direct dialing as the
         fallback for routerless fleets."""
+        # The client helper's spans (cli/generate, cli/first_token) are this
+        # node's: unlaned, every co-hosted node reports them and the merged
+        # trace holds each once per node, at offsets a few microseconds apart.
+        with tracing.lane(self.lane):
+            return self._generate(model, prompt, max_new_tokens, temperature, seed)
+
+    def _generate(self, model: str, prompt: list[int], max_new_tokens: int,
+                  temperature: float, seed: int | None) -> dict:
         from dmlc_tpu.cluster.rpc import RpcError, RpcUnreachable
         from dmlc_tpu.generate import worker as gen_worker
 

@@ -206,6 +206,7 @@ class StepRun(NamedTuple):
     logits: Any            # device [max_slots, vocab], or None without return_logits
     active: np.ndarray     # the mask it ran with: the rows of ``tokens`` that mean something
     t0: float              # perf_counter at dispatch
+    seq: int               # ``steps`` at dispatch: joins gen/step_call to gen/step_sync
 
 
 class PrefillRun(NamedTuple):
@@ -216,6 +217,7 @@ class PrefillRun(NamedTuple):
     tokens: Any                     # device register after the run; None when no request ran
     counts: Any                     # device: the family's counts summed over the rows
     prompt_tokens: int              # the real lengths of the prompts that ran, summed
+    run: int                        # ``prefill_runs`` at dispatch: joins the call to its sync
 
 
 class GenerationEngine:
@@ -327,6 +329,9 @@ class GenerationEngine:
         self.temps = np.zeros(self.max_slots, np.float32)
         self.steps = 0
         self.tokens_out = 0
+        # Runs of the prefill program, the warm-up's included (never reset:
+        # the number that joins a run's dispatch span to its read's).
+        self.prefill_runs = 0
         # The last sampled token of every slot, on the device from here on
         # (an operand of one kind for both programs' one compiled entry):
         # the step appends it to the slot's cache and replaces it, the
@@ -515,36 +520,42 @@ class GenerationEngine:
         them, their first tokens being in the device's register)."""
         results: list[int | Exception] = []
         rows: list[tuple[int, np.ndarray, float, int]] = []  # slot, prompt, temperature, seed
-        for req in batch:
-            try:
-                prompt = self._check(req, taken={slot for slot, *_ in rows})
-                if self.cache_mode == "paged":
-                    self.cache.bind(
-                        req.slot, self.reserve(prompt.size) if req.pages is None else req.pages)
-            except Exception as e:  # the verdict on THIS request: the caller fails its stream
-                results.append(e)
-                continue
-            seed = req.seed
-            if seed is None:
-                seed = (self._base_seed * 1_000_003 + self._joins) % (1 << 31)
-            self._joins += 1
-            results.append(int(req.slot))
-            rows.append((int(req.slot), prompt, float(req.temperature), int(seed) & 0xFFFFFFFF))
-        if not rows:
-            return PrefillRun(results, None, {}, 0)
-        # Operands made for this run alone: nothing the host changes later.
-        tokens = np.zeros((self.max_slots, self.max_prefill), np.int32)
-        lengths = np.zeros(self.max_slots, np.int32)
-        slots = np.zeros(self.max_slots, np.int32)
-        seeds = np.zeros(self.max_slots, np.uint32)
-        temps = np.zeros(self.max_slots, np.float32)
-        for i, (slot, prompt, temp, seed) in enumerate(rows):
-            tokens[i, : prompt.size] = prompt
-            lengths[i], slots[i], seeds[i], temps[i] = prompt.size, slot, seed, temp
-        dests = self.cache.page_table[slots] if self.cache_mode == "paged" else slots
-        k_state, v_state, r_state, last, counts = self._prefill(
-            self._variables, tokens, lengths, self._k_state, self._v_state, self._r_state,
-            dests, slots, seeds, temps, np.int32(len(rows)), self._tokens)
+        with tracer.span("gen/prefill_operands", cpu=True) as span:
+            for req in batch:
+                try:
+                    prompt = self._check(req, taken={slot for slot, *_ in rows})
+                    if self.cache_mode == "paged":
+                        self.cache.bind(
+                            req.slot, self.reserve(prompt.size) if req.pages is None else req.pages)
+                except Exception as e:  # the verdict on THIS request: the caller fails its stream
+                    results.append(e)
+                    continue
+                seed = req.seed
+                if seed is None:
+                    seed = (self._base_seed * 1_000_003 + self._joins) % (1 << 31)
+                self._joins += 1
+                results.append(int(req.slot))
+                rows.append((int(req.slot), prompt, float(req.temperature), int(seed) & 0xFFFFFFFF))
+            span.set(prompts=len(rows))
+            if not rows:
+                return PrefillRun(results, None, {}, 0, self.prefill_runs)
+            # Operands made for this run alone: nothing the host changes later.
+            tokens = np.zeros((self.max_slots, self.max_prefill), np.int32)
+            lengths = np.zeros(self.max_slots, np.int32)
+            slots = np.zeros(self.max_slots, np.int32)
+            seeds = np.zeros(self.max_slots, np.uint32)
+            temps = np.zeros(self.max_slots, np.float32)
+            for i, (slot, prompt, temp, seed) in enumerate(rows):
+                tokens[i, : prompt.size] = prompt
+                lengths[i], slots[i], seeds[i], temps[i] = prompt.size, slot, seed, temp
+            dests = self.cache.page_table[slots] if self.cache_mode == "paged" else slots
+        run = self.prefill_runs
+        self.prefill_runs += 1
+        # The call alone: the runtime's uploads and dispatch, and whatever it waits for.
+        with tracer.span("gen/prefill_call", cpu=True, run=run, prompts=len(rows)):
+            k_state, v_state, r_state, last, counts = self._prefill(
+                self._variables, tokens, lengths, self._k_state, self._v_state, self._r_state,
+                dests, slots, seeds, temps, np.int32(len(rows)), self._tokens)
         self._set_state(k_state, v_state, r_state)
         self._tokens = last
         for slot, prompt, temp, seed in rows:
@@ -553,15 +564,18 @@ class GenerationEngine:
             self.temps[slot] = temp
             self.seeds[slot] = seed
         self.tokens_out += len(rows)
-        return PrefillRun(results, last, counts, int(lengths.sum()))
+        return PrefillRun(results, last, counts, int(lengths.sum()), run)
 
     def collect_admit(self, run: PrefillRun) -> list[int | Exception]:
-        """The half of ``admit`` that waits: the run's one blocking read.
-        What is left of the caller's gen/prefill spans is the host's part."""
+        """The half of ``admit`` that waits: the run's one blocking read
+        (``gen/prefill_sync``, joined to the run's ``gen/prefill_call`` by
+        ``run``). What is left of the caller's gen/prefill span is the
+        read's bookkeeping (the family's counts into attributes); the run's
+        host part is ``dispatch_admit``'s two spans."""
         if run.tokens is None:
             self.prefill_attrs = {}
             return run.results
-        with tracer.span("gen/prefill_sync", cpu=True):
+        with tracer.span("gen/prefill_sync", cpu=True, run=run.run):
             last = np.asarray(run.tokens)
             counts = {name: np.asarray(a) for name, a in run.counts.items()}
         self.prefill_attrs = self.family.work_attrs(counts, run.prompt_tokens)
@@ -608,39 +622,47 @@ class GenerationEngine:
         import time
 
         t0 = time.perf_counter()
-        active = self.active.copy()
-        table = (
-            self.cache.page_table.copy()
-            if self.cache_mode == "paged"
-            else np.zeros((self.max_slots, 1), np.int32)
-        )
-        out = self._step(
-            self._variables,
-            self._k_state,
-            self._v_state,
-            self._r_state,
-            self._tokens,
-            self.lengths.copy(),
-            active,
-            table,
-            self.seeds.copy(),
-            self.temps.copy(),
-        )
+        # Python and NumPy only: blocks on nothing.
+        with tracer.span("gen/step_operands", cpu=True):
+            active = self.active.copy()
+            table = (
+                self.cache.page_table.copy()
+                if self.cache_mode == "paged"
+                else np.zeros((self.max_slots, 1), np.int32)
+            )
+            lengths, seeds, temps = self.lengths.copy(), self.seeds.copy(), self.temps.copy()
+        # The call alone: the runtime's uploads and dispatch, and whatever it waits for.
+        seq = self.steps
+        with tracer.span("gen/step_call", cpu=True, seq=seq):
+            out = self._step(
+                self._variables,
+                self._k_state,
+                self._v_state,
+                self._r_state,
+                self._tokens,
+                lengths,
+                active,
+                table,
+                seeds,
+                temps,
+            )
         k_state, v_state, r_state, tokens, aux = out[:5]
         self._set_state(k_state, v_state, r_state)
         self._tokens = tokens
         self.lengths[active] += 1
         self.steps += 1
         self.tokens_out += int(active.sum())
-        return StepRun(tokens, aux, out[5] if self.return_logits else None, active, t0)
+        return StepRun(tokens, aux, out[5] if self.return_logits else None, active, t0, seq)
 
     def collect_step(self, run: StepRun) -> np.ndarray:
         """The half of ``step`` that waits: the one place a step blocks on
-        the device; what is left of the caller's gen/step span is the
-        host's part (uploads, dispatch, bookkeeping)."""
+        the device (``gen/step_sync``, joined to the step's ``gen/step_call``
+        by ``seq``). With ``dispatch_step``'s two spans beside it, what is
+        left of the caller's gen/step span is bookkeeping (the family's
+        counts into attributes, the work hook)."""
         import time
 
-        with tracer.span("gen/step_sync", cpu=True):
+        with tracer.span("gen/step_sync", cpu=True, seq=run.seq):
             if run.logits is not None:
                 self.last_logits = np.asarray(run.logits)
             tokens = np.asarray(run.tokens)
@@ -648,8 +670,7 @@ class GenerationEngine:
         n_active = int(run.active.sum())
         self.step_attrs = self.family.work_attrs(aux, n_active)
         if self.state.nbytes:
-            self.step_attrs.update(
-                state_slots=n_active, state_bytes=n_active * self.state.bytes_per_slot)
+            self.step_attrs.update(state_bytes=n_active * self.state.bytes_per_slot)
         now = time.perf_counter()
         if self.device_work is not None and n_active > 0:
             # The read above materialized the step's results. Alone, this is

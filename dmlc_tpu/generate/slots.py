@@ -39,23 +39,29 @@ max_new_tokens``, page growth); an ``eos`` is seen one step late and the row
 computed meanwhile is thrown away (``gen_tokens_discarded``).
 
 Tracing: a loop turn has ONE ``gen/step`` span, which covers the dispatch of
-its step and the read of the step before (child ``gen/step_sync``); its
-attributes (``slots``, ``ahead``, ``pages_bound``, ``tokens_resident``, the
-family's counts) are those of the step it READ. It is bound to the OLDEST
+its step (children ``gen/step_operands``, the register copies, and
+``gen/step_call``, the jitted call alone: ``GenerationEngine.dispatch_step``)
+and the read of the step before (child ``gen/step_sync``); its attributes
+(``slots``, ``ahead``, ``pages_bound``, ``tokens_resident``, the family's
+counts) are those of the step it READ, and ``seq`` on the call and on the sync
+joins a step's dispatch to its read a turn later. It is bound to the OLDEST
 resident slot's submit-time trace context, so a request's timeline
 shows the steps that produced its tokens parented under its
 ``rpc/job.generate`` span (trace smoke asserts this). ONE run of the prefill
-program admits every request the loop turn admits, and has ONE ``gen/prefill``
-span: its READ, at the end of the turn that dispatched it (child
-``gen/prefill_sync``), with the run's attributes (``prompts``,
-``prompt_tokens``), bound to the oldest admitted request; the run's dispatch
-is part of ``gen/admit``. Each
-request has its own ``gen/wait`` under its own context (submit to the
-admission that dispatches its run). The decode thread feeds the device, so its time
+program admits every request the loop turn admits: its dispatch is two
+children of ``gen/admit`` (``gen/prefill_operands``: checks, page binding,
+operand arrays; ``gen/prefill_call``: the jitted call alone), and it has ONE
+``gen/prefill`` span: its READ, at the end of the turn that dispatched it
+(child ``gen/prefill_sync``, joined to the call by ``run``), with the run's
+attribute ``prompts``, bound to the oldest admitted request. Each request has
+two records under its own context: ``gen/wait`` (submit to the admission that
+dispatches its run) and ``gen/first`` (from there to the push of its first
+token), which abut. The decode thread feeds the device, so its time
 is TILED by leaf spans (docs/OBSERVABILITY.md §1): ``gen/idle`` (waiting for
-work), ``gen/admit`` (admission: who gets a slot, the prefill run's dispatch,
-seating), ``gen/prefill``, ``gen/retire`` (the resident sweep, page growth),
-``gen/step``, ``gen/deliver`` (token pushes, exits) — an idle gap of the chip
+work), ``gen/retire`` (the resident sweep, page growth), ``gen/deliver``
+(token pushes, exits), the four dispatch leaves and the two syncs; what is
+left as the SELF time of ``gen/admit`` (who gets a slot, seating),
+``gen/step`` and ``gen/prefill`` is bookkeeping — an idle gap of the chip
 always has an owner on this thread.
 """
 
@@ -201,7 +207,7 @@ class _Slot:
     __slots__ = (
         "stream", "prompt", "max_new_tokens", "temperature", "eos_id",
         "deadline", "trace_ctx", "pages", "emitted", "in_flight", "slot",
-        "submitted_t", "tenant", "seed", "wait_t0",
+        "submitted_t", "tenant", "seed", "wait_t0", "first_t0",
     )
 
     def __init__(self, stream: GenStream, prompt: list[int],
@@ -228,6 +234,9 @@ class _Slot:
         # Submit instant on the TRACER's clock (gen/wait is a span, so it
         # lives on the timebase spans live on, not the injectable clock).
         self.wait_t0 = tracer.now() if tracer.enabled else None
+        # Where gen/wait ended and gen/first starts (set at admission, only
+        # while the tracer is on; None again once the first token is pushed).
+        self.first_t0: float | None = None
 
 
 class _Run:
@@ -581,9 +590,9 @@ class SlotScheduler:
         them, first in first out, dispatched and not waited for: they are
         seated at once, their first tokens in the device's register and in
         flight (the step dispatched next decodes them), and the run is read
-        at the end of the turn (``_collect_prefill``). The dispatch is
-        admission's own time, under ``gen/admit``: a run's ``gen/prefill``
-        span is its read.
+        at the end of the turn (``_collect_prefill``). The dispatch is two
+        children of ``gen/admit`` (``gen/prefill_operands``,
+        ``gen/prefill_call``): a run's ``gen/prefill`` span is its read.
 
         A request stays IN ``_pending`` until it lands in ``_resident``:
         submit-time admission counts both lists, and a request invisible to
@@ -595,8 +604,9 @@ class SlotScheduler:
                 return
             for req in batch:
                 if req.wait_t0 is not None:
+                    req.first_t0 = tracer.now()
                     with tracectx.bind(req.trace_ctx):
-                        tracer.record("gen/wait", max(0.0, tracer.now() - req.wait_t0))
+                        tracer.record("gen/wait", max(0.0, req.first_t0 - req.wait_t0))
             handle: Any = None
             results: list[int | Exception]
             try:
@@ -629,8 +639,7 @@ class SlotScheduler:
                 seats.append((req.slot, req))
             if not seats:
                 return
-            attrs = {"prompts": len(seats),
-                     "prompt_tokens": sum(len(req.prompt) for _, req in seats)}
+            attrs = {"prompts": len(seats)}
             # Read under the oldest admitted request's trace, as gen/step is
             # under the oldest resident's.
             self._prefill_in_flight = _Run(handle, seats, attrs, batch[0].trace_ctx)
@@ -864,6 +873,11 @@ class SlotScheduler:
         req.emitted += 1
         req.stream.step_gen = self.engine.steps
         req.stream.push([token])
+        if req.first_t0 is not None:
+            # The request's first token, pushed: the leg that gen/wait started.
+            with tracectx.bind(req.trace_ctx):
+                tracer.record("gen/first", max(0.0, tracer.now() - req.first_t0))
+            req.first_t0 = None
         self.tokens_streamed += 1
         if self.metrics is not None:
             self.metrics.inc("gen_tokens")

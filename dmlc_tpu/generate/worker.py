@@ -348,6 +348,7 @@ def generate_stream(
     if seed is not None:
         payload["seed"] = int(seed)
     with tracer.span("cli/generate", model=model):
+        t_call = tracer.now() if tracer.enabled else None
         reply = rpc.call(addr, "job.generate", payload, timeout=poll_timeout)
         gen_id = reply["gen_id"]
         acked = 0
@@ -363,6 +364,10 @@ def generate_stream(
                 acked = seq
                 advanced = True
                 for t in toks:
+                    if t_call is not None:
+                        # The first token in the client's hands, under the request's trace.
+                        tracer.record("cli/first_token", tracer.now() - t_call)
+                        t_call = None
                     yield int(t)
             if r.get("done") and not r.get("chunks"):
                 if r.get("error"):

@@ -254,7 +254,6 @@ class TestStateSlots:
         engine.step()
         attrs = engine.step_attrs
         assert attrs["expert_pairs"] + attrs["expert_pairs_absent"] == 2 * 2 * 2
-        assert attrs["state_slots"] == 2
         assert attrs["state_bytes"] == 2 * engine.state.bytes_per_slot
         assert 0 <= attrs["experts_hit"] <= 2 and attrs["expert_rows_max"] <= 2
 

@@ -353,7 +353,7 @@ class TestStateSlots:
         assert attrs["linear_layers"] == 6
         assert attrs["state_bytes_touched"] == 2 * 2 * per_slot     # two slots, read and written
         assert attrs["kv_tokens_read"] == (10 + 1) + (4 + 1)
-        assert attrs["state_slots"] == 2 and attrs["state_bytes"] == 2 * per_slot
+        assert attrs["state_bytes"] == 2 * per_slot
 
     def test_named_scopes_are_in_both_programs(self, variables):
         import chip_smoke
