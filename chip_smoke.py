@@ -448,9 +448,40 @@ def family_phase(config=None, *, lengths=(5, 40, 97), steps: int = 4) -> dict:
                 served[slot].append(int(out[slot + 1]))
         return {"model": "lfm2_moe", "lengths": list(lengths), "steps": steps,
                 "rows_checked": steps * len(lengths), "worst_rel_err": float(f"{worst:.3e}"),
-                "use_pallas": bool(engine.use_pallas)}
+                "use_pallas": bool(engine.use_pallas),
+                "replaced_arrays_donated": replaced_arrays_donated(engine, free_slot=0)}
     finally:
         registry._REGISTRY.pop(spec.name, None)
+
+
+def replaced_arrays_donated(engine, *, free_slot: int) -> int:
+    """One run of each program by its halves: every array the call replaced
+    (both pools, each leaf of the recurrent state) is DONATED, so the engine,
+    which keeps them until its decode thread has time to let them go, keeps
+    no device memory with them, and it took every one. Returns how many
+    arrays a run replaces."""
+    import jax
+    import numpy as np
+
+    from dmlc_tpu.generate.engine import Admission
+
+    for dispatch, collect, args in (
+            (engine.dispatch_admit, engine.collect_admit, ([Admission(free_slot, [1, 2, 3])],)),
+            (engine.dispatch_step, engine.collect_step, ())):
+        for slot in np.flatnonzero(engine.active):
+            engine.ensure_capacity(int(slot))
+        engine.release_replaced()
+        state = jax.tree_util.tree_leaves((engine._k_state, engine._v_state, engine._r_state))
+        run = dispatch(*args)
+        kept = [a.shape for a in state if not a.is_deleted()]
+        if kept or len(engine._replaced) != len(state):
+            raise AssertionError(
+                f"family: a call replaced {len(state)} arrays, {len(kept)} of them not donated "
+                f"{kept}; the engine kept {len(engine._replaced)}")
+        collect(run)
+    engine.release(free_slot)
+    engine.release_replaced()
+    return len(state)
 
 
 def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -> dict:

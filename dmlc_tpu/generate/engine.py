@@ -45,7 +45,15 @@ kv_heads * head_dim]``, generate/kvcache.py): each program writes its rows
 into the donated buffers and aliases them to its outputs, so exactly one
 generation of the cache exists in device memory, no program keeps a
 pool-sized temporary, and a call costs what it writes and gathers whatever
-the pool's size (both take it from the pool they are handed).
+the pool's size (both take it from the pool they are handed). What a call
+leaves behind on the host is the Python objects of its donated inputs: they
+hold no device memory, but letting one go hands the interpreter to another
+thread and the decode thread has to win it back (measured on the chip: 5 us
+an array alone in the process, 0.65 ms among 64 polling clients, whatever
+the device does meanwhile). So no dispatch half lets any go: they wait in
+``_replaced``, and a collect half lets them go while its run's result is
+not ready yet, when the thread would wait anyway, and otherwise only what
+the bounded stock cannot keep (``_release``, the span ``gen/release``).
 
 Sampling is **per-slot position-seeded**: the categorical draw for the
 token at sequence position ``p`` of a request seeded ``s`` uses the key
@@ -77,6 +85,7 @@ baseline the 2x continuous-batching pin measures against.
 
 from __future__ import annotations
 
+from collections import deque
 from collections.abc import Sequence
 from typing import Any, NamedTuple
 
@@ -318,6 +327,12 @@ class GenerationEngine:
         # rows a step rewrites; empty for a family that has none. Donated
         # through both programs like the pools.
         self.state = SlotState(self.family.state_shapes(self.max_slots), self.max_slots)
+        # The arrays the two programs' calls replaced (both pools and every
+        # leaf of the recurrent state, donated to the call: they hold no
+        # device memory), oldest first, until ``_release`` lets them go.
+        self._replaced: deque[Any] = deque()
+        self._replaced_max = _REPLACED_RUNS * len(
+            jax.tree_util.tree_leaves((self._k_state, self._v_state, self.state.arrays)))
         # What the last step / prefill did, for the caller's span
         # (``gen/step`` / ``gen/prefill``): counts fetched with the tokens.
         self.step_attrs: dict[str, Any] = {}
@@ -517,7 +532,8 @@ class GenerationEngine:
         """The half of ``admit`` that does not wait: check and bind every
         request, run the program, and seat the rows that ran (``lengths``,
         ``active``, ``temps``, ``seeds``: a step dispatched next decodes
-        them, their first tokens being in the device's register)."""
+        them, their first tokens being in the device's register). The arrays
+        the call replaced are let go by a later ``collect_*``, none here."""
         results: list[int | Exception] = []
         rows: list[tuple[int, np.ndarray, float, int]] = []  # slot, prompt, temperature, seed
         with tracer.span("gen/prefill_operands", cpu=True) as span:
@@ -569,12 +585,15 @@ class GenerationEngine:
     def collect_admit(self, run: PrefillRun) -> list[int | Exception]:
         """The half of ``admit`` that waits: the run's one blocking read
         (``gen/prefill_sync``, joined to the run's ``gen/prefill_call`` by
-        ``run``). What is left of the caller's gen/prefill span is the
-        read's bookkeeping (the family's counts into attributes); the run's
-        host part is ``dispatch_admit``'s two spans."""
+        ``run``), after ``gen/release``, which lets replaced arrays go for
+        as long as the run's result is not ready. What is left of the
+        caller's gen/prefill span is the read's bookkeeping (the family's
+        counts into attributes); the run's host part is ``dispatch_admit``'s
+        two spans."""
         if run.tokens is None:
             self.prefill_attrs = {}
             return run.results
+        self._release(run.tokens, run=run.run)
         with tracer.span("gen/prefill_sync", cpu=True, run=run.run):
             last = np.asarray(run.tokens)
             counts = {name: np.asarray(a) for name, a in run.counts.items()}
@@ -618,7 +637,8 @@ class GenerationEngine:
         registers as the host knows them and advance them at once
         (``lengths``, ``steps``, ``tokens_out`` need no result). The program
         gets COPIES of the registers the host goes on changing: an operand
-        may be read after this returns."""
+        may be read after this returns. The arrays the call replaced are let
+        go by a later ``collect_*``, none here."""
         import time
 
         t0 = time.perf_counter()
@@ -657,11 +677,13 @@ class GenerationEngine:
     def collect_step(self, run: StepRun) -> np.ndarray:
         """The half of ``step`` that waits: the one place a step blocks on
         the device (``gen/step_sync``, joined to the step's ``gen/step_call``
-        by ``seq``). With ``dispatch_step``'s two spans beside it, what is
-        left of the caller's gen/step span is bookkeeping (the family's
-        counts into attributes, the work hook)."""
+        by ``seq``), after ``gen/release``, which lets replaced arrays go for
+        as long as the step's result is not ready. With ``dispatch_step``'s
+        two spans beside them, what is left of the caller's gen/step span is
+        bookkeeping (the family's counts into attributes, the work hook)."""
         import time
 
+        self._release(run.tokens, seq=run.seq)
         with tracer.span("gen/step_sync", cpu=True, seq=run.seq):
             if run.logits is not None:
                 self.last_logits = np.asarray(run.logits)
@@ -695,12 +717,50 @@ class GenerationEngine:
         return []
 
     def _set_state(self, k_state: Any, v_state: Any, r_state: Any) -> None:
+        """A program's outputs become the engine's pools and recurrent state
+        (whoever reads them sees these, never a replaced one). The arrays
+        they replace were donated to the call just made: those join
+        ``_replaced``, to be let go when the thread has time (``_release``).
+        One that was NOT donated still owns its memory and is dropped here,
+        at once, as it always was."""
+        import jax
+
+        replaced = jax.tree_util.tree_leaves((self._k_state, self._v_state, self.state.arrays))
+        self._replaced.extend(a for a in replaced if a.is_deleted())
         self._k_state = k_state
         self._v_state = v_state
         self.state.arrays = r_state
         if self.cache_mode == "paged":
             self.cache.k_pages = k_state
             self.cache.v_pages = v_state
+
+    def _release(self, result: Any, **number: int) -> None:
+        """The one place replaced arrays are let go while the engine serves
+        (``gen/release``, once per program run, before the run's blocking
+        read and numbered like it). Letting one go costs the decode thread
+        the interpreter and the wait to get it back, so: oldest first, for
+        as long as ``result`` (the run's tokens, still on the device) is not
+        ready, the time the thread would spend waiting in the read anyway
+        (``waiting`` of the ``arrays`` the span counts); beyond that only
+        what ``_replaced`` holds over its bound, ``_REPLACED_RUNS`` runs'
+        worth. In a turn the device sets the pace of, every array goes for
+        free; where the host does, the stock fills during steps and empties
+        into the next wait for a prefill run."""
+        let_go = waiting = 0
+        with tracer.span("gen/release", cpu=True, **number) as span:
+            while self._replaced:
+                wait = not result.is_ready()
+                if not wait and len(self._replaced) <= self._replaced_max:
+                    break
+                self._replaced.popleft()
+                let_go += 1
+                waiting += wait
+            span.set(arrays=let_go, waiting=waiting)
+
+    def release_replaced(self) -> None:
+        """Let every replaced array go now: the engine stops serving (the
+        loop's ``stop()`` and its failure path), and nothing stays held."""
+        self._replaced.clear()
 
     # ---- observability / weights ----------------------------------------
 
@@ -777,6 +837,11 @@ class GenerationEngine:
         if self.cache_mode == "paged":
             out["pages"] = self.cache.allocator.summary()
         return out
+
+
+#: How many program runs' worth of replaced arrays ``_replaced`` may hold
+#: (a wait for a prefill run takes in up to seven steps' worth: p90 on the chip).
+_REPLACED_RUNS = 8
 
 
 def _compiles_for_tpu() -> bool:

@@ -41,7 +41,8 @@ computed meanwhile is thrown away (``gen_tokens_discarded``).
 Tracing: a loop turn has ONE ``gen/step`` span, which covers the dispatch of
 its step (children ``gen/step_operands``, the register copies, and
 ``gen/step_call``, the jitted call alone: ``GenerationEngine.dispatch_step``)
-and the read of the step before (child ``gen/step_sync``); its attributes
+and the read of the step before (children ``gen/release``, then
+``gen/step_sync``); its attributes
 (``slots``, ``ahead``, ``pages_bound``, ``tokens_resident``, the family's
 counts) are those of the step it READ, and ``seq`` on the call and on the sync
 joins a step's dispatch to its read a turn later. It is bound to the OLDEST
@@ -52,17 +53,29 @@ program admits every request the loop turn admits: its dispatch is two
 children of ``gen/admit`` (``gen/prefill_operands``: checks, page binding,
 operand arrays; ``gen/prefill_call``: the jitted call alone), and it has ONE
 ``gen/prefill`` span: its READ, at the end of the turn that dispatched it
-(child ``gen/prefill_sync``, joined to the call by ``run``), with the run's
-attribute ``prompts``, bound to the oldest admitted request. Each request has
+(children ``gen/release`` and ``gen/prefill_sync``, joined to the call by
+``run``), with the run's attribute ``prompts``, bound to the oldest admitted
+request. Each request has
 two records under its own context: ``gen/wait`` (submit to the admission that
 dispatches its run) and ``gen/first`` (from there to the push of its first
 token), which abut. The decode thread feeds the device, so its time
 is TILED by leaf spans (docs/OBSERVABILITY.md §1): ``gen/idle`` (waiting for
 work), ``gen/retire`` (the resident sweep, page growth), ``gen/deliver``
-(token pushes, exits), the four dispatch leaves and the two syncs; what is
+(token pushes, exits), the four dispatch leaves, ``gen/release`` and the two
+syncs; what is
 left as the SELF time of ``gen/admit`` (who gets a slot, seating),
 ``gen/step`` and ``gen/prefill`` is bookkeeping — an idle gap of the chip
 always has an owner on this thread.
+
+The arrays a program's call replaced (the donated pools and recurrent state:
+husks that hold no device memory) are let go by NO dispatch half: each costs
+the decode thread the interpreter and the wait to win it back among the
+polling clients. They wait in the engine (``GenerationEngine._replaced``) and
+go in ONE place, the leaf ``gen/release`` before a run's sync (once per
+program run, numbered ``seq`` / ``run`` like the call and the sync): for as
+long as the run's result is not ready (``waiting`` of its ``arrays``: time the
+thread would wait in the read anyway), beyond that only what the engine's
+bounded stock cannot keep. ``_fail_everyone``, and so ``stop()``, empties it.
 """
 
 from __future__ import annotations
@@ -520,7 +533,8 @@ class SlotScheduler:
     def _fail_everyone(self, error: str) -> None:
         """End every resident's stream, and the stream of whoever left its
         slot with tokens still in flight; nothing stays in flight (a run
-        nobody has read is waited for, best effort, and thrown away)."""
+        nobody has read is waited for, best effort, and thrown away) and the
+        engine keeps none of the arrays its calls replaced."""
         flying = [(run, collect) for run, collect in (
             (self._prefill_in_flight, self.engine.collect_admit),
             (self._step_in_flight, self.engine.collect_step)) if run is not None]
@@ -541,6 +555,7 @@ class SlotScheduler:
         for run, _ in flying:
             for _, req in run.seats:
                 req.stream.finish(error)
+        self.engine.release_replaced()
 
     def _turn(self) -> None:
         """One loop turn: dispatch this turn's runs, THEN read, so that the

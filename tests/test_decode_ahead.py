@@ -11,11 +11,18 @@ step t-1, with the last-token register on the device.
 - order: step t is dispatched before step t-1 is read, and everything in
   flight is read before ``gen/idle`` and in ``stop()``;
 - a run that fails when it is read fails the streams it failed when it was
-  read at once (a step: every resident; a prefill run: its batch).
+  read at once (a step: every resident; a prefill run: its batch);
+- the arrays a program's call replaced (the donated pools and recurrent
+  state) are let go by no dispatch half: the engine keeps them, lets them go
+  before a read for as long as the run's result is not ready, never holds
+  more than its bound, and holds none after ``stop()``, ``_fail_everyone`` or
+  a read that raised.
 """
 
+import gc
 import threading
 import time
+import weakref
 
 import numpy as np
 import pytest
@@ -360,3 +367,183 @@ def test_step_and_admit_are_their_halves_in_a_row():
     for e in (engine, twin):
         e.release(1)
         assert e.jit_cache_sizes() == {"step": 1, "prefill": 1}
+
+
+# ---------------------------------------------------------------------------
+# the arrays a call replaced: kept by the engine, let go where the thread waits
+# ---------------------------------------------------------------------------
+
+
+def device_state(engine):
+    """The pools and every leaf of the recurrent state, as the engine holds them now."""
+    return jax.tree_util.tree_leaves((engine._k_state, engine._v_state, engine._r_state))
+
+
+class Result:
+    """A run's tokens on the device, for ``_release``: not ready for the first ``polls`` asks."""
+
+    def __init__(self, polls=0):
+        self.polls = polls
+
+    def is_ready(self):
+        self.polls -= 1
+        return self.polls < 0
+
+
+def always_ready(engine):
+    """Every collect of ``engine`` finds its run's result ready, as where the
+    host sets the pace: only the bound lets replaced arrays go."""
+    real = engine._release
+    engine._release = lambda result, **number: real(Result(), **number)
+
+
+@pytest.mark.parametrize("program", ("prefill", "step"))
+@pytest.mark.parametrize("cache", ("paged", "contiguous"))
+@pytest.mark.parametrize("model", ("lm_small", "nemotron_h_tiny"))
+def test_a_dispatch_lets_no_replaced_array_go(model, cache, program):
+    """When a dispatch half returns, what the engine held before the call is
+    donated (it holds no memory) and still referenced: by the engine's stock,
+    last in; the engine's state is the call's outputs."""
+    engine, _ = engines_of(model, cache)
+    if program == "step":
+        engine.join(0, [3, 1, 4])
+        engine.ensure_capacity(0)
+    before = device_state(engine)
+    assert (len(before) > 2) == (model != "lm_small")  # a hybrid keeps recurrent state beside the pools
+    refs = [weakref.ref(a) for a in before]
+    run = (engine.dispatch_step() if program == "step"
+           else engine.dispatch_admit([Admission(0, [3, 1, 4])]))
+    assert all(a.is_deleted() for a in before)
+    kept = list(engine._replaced)[-len(before):]
+    assert len(kept) == len(before) and all(held is was for held, was in zip(kept, before))
+    now = device_state(engine)
+    assert not any(a.is_deleted() for a in now) and not any(
+        new is old for new in now for old in before)
+    if cache == "paged":  # whoever reads the cache's pools sees the engine's
+        assert engine.cache.k_pages is engine._k_state and engine.cache.v_pages is engine._v_state
+    del before, kept
+    gc.collect()
+    assert all(r() is not None for r in refs)
+    (engine.collect_step if program == "step" else engine.collect_admit)(run)
+    assert len(engine._replaced) <= engine._replaced_max
+    engine.release(0)
+    engine.release_replaced()
+    gc.collect()
+    assert not engine._replaced and all(r() is None for r in refs)
+
+
+@pytest.mark.parametrize("stock,polls,left", [
+    (5, 0, 5),      # the result is ready and the stock within its bound: nothing goes
+    (5, 3, 2),      # not ready for three asks: three go, the oldest
+    (5, 9, 0),      # a wait longer than the stock: all of it, then the read
+    (40, 0, 16),    # ready, over the bound: down to the bound, no further
+    (40, 30, 10),   # over the bound AND a wait: the wait takes what it can
+])
+def test_release_lets_go_while_the_result_is_not_ready_and_beyond_that_the_excess(stock, polls, left):
+    engine = small_engine()
+    assert engine._replaced_max == 16  # eight runs' worth of two pools
+    husks = [object() for _ in range(stock)]
+    engine._replaced.extend(husks)
+    engine._release(Result(polls), seq=0)
+    assert list(engine._replaced) == husks[stock - left:]
+
+
+def test_the_stock_of_replaced_arrays_is_bounded_and_holds_husks_only():
+    engine = GenerationEngine("nemotron_h_tiny", variables=variables_of("nemotron_h_tiny"),
+                              max_slots=4, page_size=PAGE, num_pages=64, max_prefill=16)
+    always_ready(engine)
+    engine.join(0, [3, 1, 4])
+    for _ in range(3 * 8):
+        engine.ensure_capacity(0)
+        engine.step()
+        assert len(engine._replaced) <= engine._replaced_max == 8 * 4
+    assert len(engine._replaced) == engine._replaced_max
+    assert all(a.is_deleted() for a in engine._replaced)
+
+
+def test_a_replaced_array_that_was_not_donated_is_not_kept():
+    """Keeping an array that still owns its memory would keep a second copy of a pool."""
+    engine = small_engine()
+    live = device_state(engine)
+    engine._set_state(*(a + 0 for a in live), engine._r_state)
+    assert not engine._replaced and not any(a.is_deleted() for a in live)
+
+
+def test_a_run_that_admits_nobody_replaces_nothing():
+    engine, _ = engines_of("lm_small", "paged")
+    before, stock = device_state(engine), len(engine._replaced)
+    run = engine.dispatch_admit([Admission(0, [])])
+    assert run.tokens is None
+    (refused,) = engine.collect_admit(run)
+    assert isinstance(refused, ValueError) and len(engine._replaced) == stock
+    assert all(now is was for now, was in zip(device_state(engine), before))
+
+
+class Unreadable:
+    """A run's tokens whose read raises: the device said no."""
+
+    def is_ready(self):
+        return True
+
+    def __array__(self, *args, **kwargs):
+        raise RuntimeError("device said no")
+
+
+@pytest.mark.parametrize("how", ("stop", "fail_everyone", "fail_everyone_mid_turn",
+                                 "step_read_raises", "prefill_read_raises"))
+@pytest.mark.parametrize("model", ("lm_small", "nemotron_h_tiny"))
+def test_no_replaced_array_outlives_the_serving(model, how):
+    """However the loop ends, the engine keeps none of the arrays its calls
+    replaced. The turns are called by hand and every result is found ready,
+    so what the engine holds at the end is known."""
+    engine = GenerationEngine(model, variables=variables_of(model), max_slots=4, page_size=PAGE,
+                              num_pages=64, max_prefill=16)
+    always_ready(engine)
+    sched = SlotScheduler(engine, max_waiting=8, autostart=False)
+    streams = [sched.submit([1, 2, 3 + i], max_new_tokens=40) for i in range(2)]
+    refs = []
+
+    def held():
+        """The engine's stock is not empty; remember what is in it."""
+        refs.extend(weakref.ref(a) for a in engine._replaced)
+        return len(engine._replaced)
+
+    if how == "fail_everyone_mid_turn":
+        sched._admit_pending()  # the prefill run dispatched and not read
+        assert sched._prefill_in_flight is not None and held()
+        sched._fail_everyone("RpcError: generation engine failed")
+    elif how == "prefill_read_raises":
+        real = engine.collect_admit
+        engine.collect_admit = lambda run: real(run._replace(tokens=Unreadable()))
+        sched._turn()  # admits, dispatches a step, fails at the run's read: the batch's streams
+        assert all(s.done and s.error == "RuntimeError: device said no" for s in streams)
+        assert held()
+        sched._fail_everyone("RpcError: generation engine failed")  # the step still in flight
+    else:
+        sched._turn()
+        sched._turn()
+        assert sched._step_in_flight is not None and held()
+        if how == "stop":
+            sched._closed = True
+            sched.start()
+            sched.stop()
+        elif how == "fail_everyone":
+            sched._fail_everyone("RpcError: generation engine failed")
+        else:
+            real = engine.collect_step
+            engine.collect_step = lambda run: real(run._replace(tokens=Unreadable()))
+            try:
+                sched._turn()  # dispatches a step, then fails at the read of the one before
+            except RuntimeError:
+                held()
+            else:
+                raise AssertionError("the read did not raise")
+            sched._fail_everyone("RpcError: generation engine failed")  # as the loop does
+    assert all(s.done and s.error for s in streams)
+    assert not any(in_flight(sched)) and not engine.active.any()
+    gc.collect()
+    assert refs and not engine._replaced and all(r() is None for r in refs)
+    # what the engine holds is whole: the next request is served from it
+    assert not any(a.is_deleted() for a in device_state(engine))
+    vars(engine).pop("collect_step", None), vars(engine).pop("collect_admit", None)
+    assert len(engine.admit([Admission(0, [9, 9])])) == 1 and engine.step().shape == (4,)

@@ -467,7 +467,7 @@ def test_gen_step_says_whether_it_left_with_the_step_before_unread(gen_spans):
     assert [s["attrs"]["ahead"] for s in steps] == [0] + [1] * (len(steps) - 1)
 
 
-@pytest.mark.parametrize("name", LOOP_TOP[1:] + ("gen/step_sync", "gen/prefill_sync"))
+@pytest.mark.parametrize("name", LOOP_TOP[1:] + ("gen/step_sync", "gen/prefill_sync", "gen/release"))
 def test_loop_spans_carry_cpu_time(gen_spans, name):
     spans = named(gen_spans, name)
     assert spans and all(0.0 <= s["attrs"]["cpu_s"] for s in spans)
@@ -503,7 +503,7 @@ RUN_LEAVES = (("gen/step_operands", "gen/step"), ("gen/step_call", "gen/step"),
               ("gen/prefill_operands", "gen/admit"), ("gen/prefill_call", "gen/admit"))
 #: What the decode thread can be doing, one leaf each; a parent's self time is the rest.
 LOOP_LEAVES = tuple(leaf for leaf, _ in RUN_LEAVES) + (
-    "gen/step_sync", "gen/prefill_sync", "gen/retire", "gen/deliver", "gen/idle")
+    "gen/step_sync", "gen/prefill_sync", "gen/release", "gen/retire", "gen/deliver", "gen/idle")
 LOOP_PARENTS = ("gen/step", "gen/admit", "gen/prefill")
 
 
@@ -530,6 +530,33 @@ def test_run_leaf_once_per_program_run_under_its_loop_span(gen_spans, leaf, pare
     if parent == "gen/admit":
         assert sorted(s["attrs"]["prompts"] for s in leaves) == sorted(
             p["attrs"]["prompts"] for p in reads(gen_spans, "gen/prefill"))
+
+
+@pytest.mark.parametrize("parent,sync,key", [("gen/step", "gen/step_sync", "seq"),
+                                             ("gen/prefill", "gen/prefill_sync", "run")])
+def test_gen_release_once_per_program_run_before_its_read(gen_spans, parent, sync, key):
+    """The arrays the programs' calls replaced are let go in ONE leaf,
+    ``gen/release``, under the loop span that reads a run, BEFORE the run's
+    blocking read (for as long as the result is not ready: ``waiting`` of the
+    ``arrays`` it let go), numbered like the run's call and sync."""
+    releases = [s for s in named(gen_spans, "gen/release") if key in s["attrs"]]
+    syncs = {s["attrs"][key]: s for s in named(gen_spans, sync)}
+    assert len(releases) == len(syncs) > 0
+    assert sorted(r["attrs"][key] for r in releases) == sorted(syncs)
+    parents = {s["span"]: s for s in reads(gen_spans, parent)}
+    for r in releases:
+        p, read = parents[r["parent"]], syncs[r["attrs"][key]]
+        assert p["tid"] == r["tid"] and p["trace"] == r["trace"]
+        assert p["t0"] - CLOCK_SLACK <= r["t0"] and r["t1"] <= p["t1"] + CLOCK_SLACK
+        assert read["parent"] == r["parent"] and r["t1"] <= read["t0"] + CLOCK_SLACK
+        assert 0 <= r["attrs"]["waiting"] <= r["attrs"]["arrays"] and r["attrs"]["cpu_s"] >= 0.0
+        assert ("seq" in r["attrs"]) != ("run" in r["attrs"])
+    # one release a program run, whichever program: as many as there were calls
+    calls = len(named(gen_spans, "gen/step_call")) + len(named(gen_spans, "gen/prefill_call"))
+    assert len(named(gen_spans, "gen/release")) == calls
+    # ``lm_small``: two pools a call, and none is kept beyond the engine's bound of eight runs' worth
+    let_go = sum(s["attrs"]["arrays"] for s in named(gen_spans, "gen/release"))
+    assert 2 * calls - 16 <= let_go <= 2 * calls + 16
 
 
 def uncovered(parent, kids):
@@ -737,7 +764,7 @@ def test_disabled_tracer_records_nothing_and_reads_no_thread_clock(
         be, synsets = backend
         assert len(be(synsets)) == len(synsets)
     elif path == "generate":
-        # the four run leaves are the shared no-op span; gen/wait and gen/first keep no instant
+        # the run leaves (gen/release too) are the shared no-op span; gen/wait and gen/first keep no instant
         monkeypatch.setattr(tracer, "now", no_instant)
         sched = SlotScheduler(lm_engine, max_waiting=2)
         try:
