@@ -456,7 +456,7 @@ def family_phase(config=None, *, lengths=(5, 40, 97), steps: int = 4) -> dict:
 
 def replaced_arrays_donated(engine, *, free_slot: int) -> int:
     """One run of each program by its halves: every array the call replaced
-    (both pools, each leaf of the recurrent state) is DONATED, so the engine,
+    (the pools, two or a latent family's one, and each leaf of the recurrent state) is DONATED, so the engine,
     which keeps them until its decode thread has time to let them go, keeps
     no device memory with them, and it took every one. Returns how many
     arrays a run replaces."""
@@ -502,7 +502,9 @@ def abstract_program_args(engine, *, variables=None, pool=None, sharding=None) -
 
     variables = abstract(engine._variables if variables is None else variables)
     k_state = abstract(engine._k_state if pool is None else pool)
-    v_state = abstract(engine._v_state if pool is None else pool)
+    # A latent family keeps one pool: its programs' second pool is None.
+    v_state = None if engine._v_state is None else abstract(
+        engine._v_state if pool is None else pool)
     r_state, table = abstract(engine._r_state), engine.cache.page_table
     return {
         "step": (variables, k_state, v_state, r_state, abstract(engine._tokens),
@@ -534,7 +536,8 @@ def pool_memory(geometry: dict, *, sharding=None, use_pallas: bool | None = None
     """Compile the generation engine's step and prefill at ``geometry`` from
     abstract arguments (no weight is drawn and no pool of that size is
     allocated: the programs take the pool's size from the pool they are
-    handed) and read each program's ``memory_analysis()``. Both must alias
+    handed) and read each program's ``memory_analysis()`` (``program_memory``,
+    which a latent family's ONE pool goes through as well). Both must alias
     the two donated pools to their outputs and keep temporaries under the
     size of ONE pool: a layout the compiler re-lays around the writes shows
     as temporaries of several pools (PERF.md, PR 24 finding 2), and so would
@@ -542,8 +545,6 @@ def pool_memory(geometry: dict, *, sharding=None, use_pallas: bool | None = None
     program holds a ``while``) carried as a copy. A check of
     the chip's compiler (or of a described chip's, tests/test_tpu_compile.py):
     the CPU backend widens a bfloat16 pool around a scatter and would fail it."""
-    import math
-
     import jax
     import jax.numpy as jnp
 
@@ -572,24 +573,36 @@ def pool_memory(geometry: dict, *, sharding=None, use_pallas: bool | None = None
         jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=dtype)[1]))
     pool = jax.ShapeDtypeStruct(
         (g["layers"] * g["num_pages"], g["page_size"], g["hidden"]), dtype)
+    return program_memory(engine, variables, pool, sharding=sharding, what=f"gen at {g}")
+
+
+def program_memory(engine, variables, pool, *, sharding=None, what: str = "gen") -> dict:
+    """``pool_memory``'s check for ANY engine: compile its step and prefill
+    from abstract ``variables`` and an abstract ``pool`` and hold them to the
+    pools the engine keeps, two (K and V) or a latent family's ONE: every one
+    aliased to its output, temporaries under the size of one."""
+    import math
+
+    pools = 1 if engine._v_state is None else 2
     pool_bytes = math.prod(pool.shape) * pool.dtype.itemsize
     args = abstract_program_args(engine, variables=variables, pool=pool, sharding=sharding)
-    out: dict = {"pool_bytes": pool_bytes}
+    out: dict = {"pool_bytes": pool_bytes, "pools": pools}
     for name, program in (("step", engine._step), ("prefill", engine._prefill)):
         compiled = program.lower(*args[name]).compile()
         memory = compiled.memory_analysis()
         text = compiled.as_text()
         out[name] = {"temp_bytes": int(memory.temp_size_in_bytes),
                      "alias_bytes": int(memory.alias_size_in_bytes),
+                     "argument_bytes": int(memory.argument_size_in_bytes),
                      "mosaic": MOSAIC_CALL in text, "loop": " while(" in text}
-        say(f"pool_memory {name}: {out[name]} (one pool {pool_bytes})")
-        if memory.alias_size_in_bytes < 2 * pool_bytes:
+        say(f"pool_memory {name}: {out[name]} ({pools} pool(s) of {pool_bytes})")
+        if memory.alias_size_in_bytes < pools * pool_bytes:
             raise AssertionError(
-                f"gen {name} at {g}: {memory.alias_size_in_bytes} bytes aliased, "
-                f"two pools are {2 * pool_bytes}: a donated pool is not updated in place")
+                f"{what} {name}: {memory.alias_size_in_bytes} bytes aliased, "
+                f"{pools} pool(s) are {pools * pool_bytes}: a donated pool is not updated in place")
         if memory.temp_size_in_bytes >= pool_bytes:
             raise AssertionError(
-                f"gen {name} at {g}: temporaries of {memory.temp_size_in_bytes} bytes "
+                f"{what} {name}: temporaries of {memory.temp_size_in_bytes} bytes "
                 f"reach one pool ({pool_bytes}): the program copies a pool")
     return out
 
@@ -606,7 +619,9 @@ MOSAIC_CALL = "tpu_custom_call"
 #: forms the cells run: gpt2-large's heads (20 x 64, multi-head),
 #: nemotron3-super's (32 query heads on 2 KV heads of 128),
 #: olmo-hybrid-7b's (30 x 128, multi-head: pool rows of 3,840 lanes) and
-#: lfm2-8b-a1b's (32 query heads on 8 KV heads of 64: rows of 512 lanes).
+#: lfm2-8b-a1b's (32 query heads on 8 KV heads of 64: rows of 512 lanes), and
+#: its latent form at kanana-2-30b-a3b's (32 heads against one row of 640
+#: lanes a position, the weighted sum over the first 512).
 KERNEL_SHAPES = {
     "images": (256, 224, 224, 3),
     "logits": (256, 1000),
@@ -619,6 +634,7 @@ KERNEL_SHAPES = {
     "paged_gqa": (32, 2, 128),
     "paged_mha_wide": (30, 30, 128),
     "paged_gqa_64": (32, 8, 64),
+    "paged_latent": (32, 640, 512),   # (heads, lanes of a stored row, value lanes)
     "paged_slots": 24,
     "paged_table": 64,           # pages a slot's table names, 16 tokens each
 }
@@ -688,7 +704,10 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
     from dmlc_tpu.ops import preprocess as pp
     from dmlc_tpu.ops.ragged_decode import (
         gather_kv_pages,
+        gather_latent_pages,
+        latent_decode_attention,
         paged_decode_attention,
+        paged_latent_decode_attention,
         ragged_decode_attention,
     )
     from dmlc_tpu.parallel.mesh import make_mesh
@@ -719,20 +738,26 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
     images = jax.random.randint(next(keys), shapes["images"], 0, 256, jnp.int32).astype(jnp.uint8)
     logits = jax.random.normal(next(keys), shapes["logits"], jnp.float32) * 3.0
 
-    def paged_case(heads, kv_heads, head_dim, page_size=16):
-        """bfloat16 pools of two layers (the second is read), float32 queries
-        so that the float32 sums show in the result; lengths of 1, a page,
-        a page + 3, a full table, and whatever the key draws."""
+    def pooled(n_pools, width, q_shape, page_size):
+        """(queries, pools, table, lengths, pages a layer): bfloat16 pools of
+        two layers (the second is read), float32 queries so that the float32
+        sums show in the result; lengths of 1, a page, a page + 3, a full
+        table, and whatever the key draws."""
         slots, per_slot = shapes["paged_slots"], shapes["paged_table"]
         n_pages = slots * per_slot + 1
-        pools = [jax.random.normal(next(keys), (2 * n_pages, page_size, kv_heads * head_dim),
-                                   jnp.bfloat16) for _ in range(2)]
+        pools = [jax.random.normal(next(keys), (2 * n_pages, page_size, width), jnp.bfloat16)
+                 for _ in range(n_pools)]
         table = 1 + jax.random.permutation(next(keys), n_pages - 1)[: slots * per_slot]
         lengths = jax.random.randint(next(keys), (slots,), 1, per_slot * page_size + 1)
         lengths = lengths.at[:4].set(
             jnp.asarray([1, page_size, page_size + 3, per_slot * page_size]))
-        q = jax.random.normal(next(keys), (slots, heads, head_dim), jnp.float32)
-        args = (q, *pools, table.reshape(slots, per_slot).astype(jnp.int32), lengths)
+        q = jax.random.normal(next(keys), (slots, *q_shape), jnp.float32)
+        return q, pools, table.reshape(slots, per_slot).astype(jnp.int32), lengths, n_pages
+
+    def paged_case(heads, kv_heads, head_dim, page_size=16):
+        q, pools, table, lengths, n_pages = pooled(2, kv_heads * head_dim, (heads, head_dim),
+                                                   page_size)
+        args = (q, *pools, table, lengths)
 
         def fused(q, k_pool, v_pool, table, lengths):
             return paged_decode_attention(q, k_pool, v_pool, table, lengths,
@@ -743,6 +768,24 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
                       for pool in (k_pool, v_pool))
             with jax.default_matmul_precision("highest"):
                 return ragged_decode_attention(q, ks, vs, lengths)
+
+        return fused, args, reference, 1e-4
+
+    def latent_case(heads, width, value_lanes, page_size=16):
+        """``paged_case`` for the latent form: ONE pool whose rows are keys
+        and values at once, queries of the rows' width, the scale of a
+        192-lane head."""
+        q, (pool,), table, lengths, n_pages = pooled(1, width, (heads, width), page_size)
+        args = (q, pool, table, lengths)
+        how = {"value_lanes": value_lanes, "scale": 192 ** -0.5}
+
+        def fused(q, pool, table, lengths):
+            return paged_latent_decode_attention(q, pool, table, lengths, first_row=n_pages, **how)
+
+        def reference(q, pool, table, lengths):
+            rows = gather_latent_pages(pool, table, first_row=n_pages)
+            with jax.default_matmul_precision("highest"):
+                return latent_decode_attention(q, rows, lengths, **how)
 
         return fused, args, reference, 1e-4
 
@@ -768,6 +811,7 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
         ("paged_attention_gqa_bf16", *paged_case(*shapes["paged_gqa"])),
         ("paged_attention_mha_30x128_bf16", *paged_case(*shapes["paged_mha_wide"])),
         ("paged_attention_gqa_32on8x64_bf16", *paged_case(*shapes["paged_gqa_64"])),
+        ("paged_latent_attention_32x640_bf16", *latent_case(*shapes["paged_latent"])),
         (f"ring_flash_sp{n}",
          lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
          sp_args, causal_ref, BF16_TOL),

@@ -22,9 +22,10 @@ lint J2's regression class):
   per request. ``admit`` is the one entry point; ``join`` is ``admit`` with
   a list of one.
 - ``_step``: one token per slot ([max_slots]) — embed + per-layer
-  (write K/V into pages at position ``lengths[s]``, ragged paged attention
-  over ``lengths[s]+1`` cached positions, MLP) + head + sampling (greedy
-  at temperature 0, categorical otherwise, per-slot temperature).
+  (write the position's cache row(s) into pages at position ``lengths[s]``,
+  ragged paged attention over ``lengths[s]+1`` cached positions, MLP) +
+  head + sampling (greedy at temperature 0, categorical otherwise, per-slot
+  temperature).
 
 The token a step appends is the one thing it needs from the step before, and
 it never leaves the device: both programs take the LAST-TOKEN REGISTER
@@ -40,8 +41,13 @@ before it reads (the step of the turn before, then its own prefill run).
 the two halves in a row.
 
 The page pools are DONATED through both programs and live in the one layout
-both write and the gather reads (``[kv_layers * num_pages, page_size,
-kv_heads * head_dim]``, generate/kvcache.py): each program writes its rows
+both write and the attention reads (``[kv_layers * num_pages, page_size,
+row]``, generate/kvcache.py; what a row holds is the family's to say: K and
+V per KV head, ``kv_heads * head_dim`` lanes in each of two pools, or ONE
+latent row shared by every head, ``latent_row`` lanes in one pool and no V
+pool: the second pool is then ``None`` wherever two are named below, and
+nothing is allocated, carried, donated or released for it): each program
+writes its rows
 into the donated buffers and aliases them to its outputs, so exactly one
 generation of the cache exists in device memory, no program keeps a
 pool-sized temporary, and a call costs what it writes and gathers whatever
@@ -68,19 +74,22 @@ continuation equals the uninterrupted run token for token.
 A model FAMILY owns its math; the engine owns batching, pages, state slots
 and sampling. ``spec.decode_family(dtype)`` hands the engine an object with
 two pure functions over explicit state, ``prefill`` (a padded prompt) and
-``decode`` (one token per slot), the size of its KV (``kv_layers``,
-``kv_heads``, ``head_dim``: only layers that HAVE K/V take pages) and the
+``decode`` (one token per slot), the size of its cache (``kv_layers``: only
+layers that HAVE one take pages; ``kv_heads`` and ``head_dim`` for K and V
+per head, or ``latent_row`` for a latent row) and the
 shapes of whatever state it keeps beside the pages (``state_shapes``: a
 state-space layer's conv window and SSM state; none for the GPT-2 family).
 Inside a traced program the family reaches the cache through two calls the
-engine provides, ``kv.write_prefill`` and ``kv.write_attend``; it never sees
-pages, tables or slots. ``models/lm.TransformerFamily`` is
+engine provides, ``kv.write_prefill`` and ``kv.write_attend`` (a latent
+family: ``kv.write_prefill_latent`` and ``kv.write_attend_latent``); it
+never sees pages, tables or slots. ``models/lm.TransformerFamily`` is
 ``SPTransformerLM`` parameter-for-parameter (decode logits match the full-
 sequence ``lm.apply`` within float tolerance: the paged-KV correctness
 pin); ``models/nemotron_h.NemotronHFamily`` is the hybrid stack.
 ``cache="contiguous"`` swaps the paged gather for a dense per-slot cache
-with identical math: the parity reference for the paged path, and the
-baseline the 2x continuous-batching pin measures against.
+with identical math, for every family, the latent one included: the parity
+reference for the paged path, and the baseline the 2x continuous-batching
+pin measures against.
 """
 
 from __future__ import annotations
@@ -108,6 +117,7 @@ class _StepKV:
         self._paged = engine.cache_mode == "paged"
         self._use_pallas = engine.use_pallas
         self._kv_heads = engine.kv_heads
+        self._latent_row = engine.latent_row
         self._lengths, self._page_table = lengths, page_table
         if self._paged:
             # Destination of this step's K/V: the page covering position
@@ -150,6 +160,38 @@ class _StepKV:
             ks, vs = self.k_state[layer], self.v_state[layer]  # [B, S_max, KV, Dh]
         return ragged_decode_attention(q, ks, vs, self._kv_lengths)
 
+    def write_attend_latent(self, layer: int, q: Any, row: Any, *, value_lanes: int,
+                            scale: float) -> Any:
+        """The latent family's form: ``row`` [B, R] is this step's ONE cached
+        row a slot (``R <= latent_row``: lanes past R are stored as zeros),
+        ``q`` [B, H, R] the heads' queries against a row as stored. Scores
+        over all R lanes times ``scale``, the weighted sum over the first
+        ``value_lanes`` of THE SAME rows -> [B, H, value_lanes]."""
+        import jax.numpy as jnp
+
+        from dmlc_tpu.ops.ragged_decode import (
+            gather_latent_pages,
+            latent_decode_attention,
+            paged_latent_decode_attention,
+        )
+
+        pad = self._latent_row - row.shape[-1]
+        row = jnp.pad(row, ((0, 0), (0, pad)))
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, pad)))
+        if self._paged:
+            first = layer * self._num_pages
+            self.k_state = self.k_state.at[first + self._dest_page, self._dest_off].set(row)
+            if self._use_pallas:
+                return paged_latent_decode_attention(
+                    q, self.k_state, self._page_table, self._kv_lengths, first_row=first,
+                    value_lanes=value_lanes, scale=scale)
+            rows = gather_latent_pages(self.k_state, self._page_table, first_row=first)
+        else:
+            self.k_state = self.k_state.at[layer, self._batch, self._lengths].set(row)
+            rows = self.k_state[layer]                               # [B, S_max, latent_row]
+        return latent_decode_attention(q, rows, self._kv_lengths, value_lanes=value_lanes,
+                                       scale=scale)
+
 
 class _PrefillKV:
     """The cache as a family's ``prefill`` sees it: ``write_prefill`` puts
@@ -166,6 +208,7 @@ class _PrefillKV:
         self._paged = engine.cache_mode == "paged"
         self._dest = dest
         self._s_pad = engine.max_prefill
+        self._latent_row = engine.latent_row
         if self._paged:
             self._page_size = page_size = engine.cache.page_size
             self._num_pages = k_state.shape[0] // engine.kv_layers
@@ -174,7 +217,7 @@ class _PrefillKV:
                 first_pos < length, dest[: first_pos.shape[0]], SCRATCH_PAGE)
 
     def _pages(self, x: Any) -> Any:
-        """[S, KV, Dh] -> [pages, page_size, KV * Dh], the last page padded."""
+        """[S, KV, Dh] (or [S, row]) -> [pages, page_size, KV * Dh], the last page padded."""
         import jax.numpy as jnp
 
         n_pages = self._dest_page.shape[0]
@@ -193,6 +236,19 @@ class _PrefillKV:
             # never exposes; later decode steps overwrite them.
             self.k_state = self.k_state.at[layer, self._dest, :self._s_pad].set(k)
             self.v_state = self.v_state.at[layer, self._dest, :self._s_pad].set(v)
+
+    def write_prefill_latent(self, layer: int, rows: Any) -> None:
+        """rows: [S, R] of the padded prompt, ONE row a position (``R <=
+        latent_row``: lanes past R are stored as zeros), whole pages as
+        ``write_prefill`` writes them."""
+        import jax.numpy as jnp
+
+        rows = jnp.pad(rows, ((0, 0), (0, self._latent_row - rows.shape[-1])))
+        if self._paged:
+            dest = layer * self._num_pages + self._dest_page
+            self.k_state = self.k_state.at[dest].set(self._pages(rows))
+        else:
+            self.k_state = self.k_state.at[layer, self._dest, :self._s_pad].set(rows)
 
 
 class Admission(NamedTuple):
@@ -287,10 +343,14 @@ class GenerationEngine:
         self._variables = jax.device_put(variables)
         self.vocab = int(self.family.vocab)
         self.max_len = int(self.family.max_len)
-        # Only the model's attention layers have K/V: layers without take no pages.
+        # Only the model's attention layers have a cache: layers without take no
+        # pages. What a cached position holds is the family's to say: K and V per
+        # KV head, or one latent row (``latent_row`` lanes) and then no V pool.
         self.kv_layers = int(self.family.kv_layers)
-        self.kv_heads = int(self.family.kv_heads)
-        self.head_dim = int(self.family.head_dim)
+        self.latent_row = getattr(self.family, "latent_row", None)
+        latent = self.latent_row is not None
+        self.kv_heads = None if latent else int(self.family.kv_heads)
+        self.head_dim = None if latent else int(self.family.head_dim)
         self.max_slots = int(max_slots)
         self.max_prefill = min(int(max_prefill), self.max_len)
         if use_pallas is None:
@@ -310,6 +370,7 @@ class GenerationEngine:
                 max_slots=self.max_slots,
                 max_pages_per_slot=max_pages_per_slot,
                 dtype=self.dtype,
+                latent_row=self.latent_row,
             )
             self.max_tokens = min(self.max_len, self.cache.max_tokens_per_slot)
             self._k_state = self.cache.k_pages
@@ -317,19 +378,17 @@ class GenerationEngine:
         else:
             self.cache = None
             self.max_tokens = self.max_len
-            shape = (
-                self.kv_layers, self.max_slots, self.max_tokens,
-                self.kv_heads, self.head_dim,
-            )
+            shape = (self.kv_layers, self.max_slots, self.max_tokens) + (
+                (self.latent_row,) if latent else (self.kv_heads, self.head_dim))
             self._k_state = jnp.zeros(shape, self.dtype)
-            self._v_state = jnp.zeros(shape, self.dtype)
+            self._v_state = None if latent else jnp.zeros(shape, self.dtype)
         # The second kind of per-slot state (kvcache.SlotState): fixed-size
         # rows a step rewrites; empty for a family that has none. Donated
         # through both programs like the pools.
         self.state = SlotState(self.family.state_shapes(self.max_slots), self.max_slots)
-        # The arrays the two programs' calls replaced (both pools and every
-        # leaf of the recurrent state, donated to the call: they hold no
-        # device memory), oldest first, until ``_release`` lets them go.
+        # The arrays the two programs' calls replaced (the pools, one or two,
+        # and every leaf of the recurrent state, donated to the call: they
+        # hold no device memory), oldest first, until ``_release`` lets them go.
         self._replaced: deque[Any] = deque()
         self._replaced_max = _REPLACED_RUNS * len(
             jax.tree_util.tree_leaves((self._k_state, self._v_state, self.state.arrays)))
@@ -795,8 +854,9 @@ class GenerationEngine:
         return self.cache.pages_free if self.cache_mode == "paged" else 0
 
     def resident_bytes(self) -> int:
-        """Analytic device residency of this engine: weights pytree + both
-        KV pools (paged or contiguous) + the recurrent state of every slot —
+        """Analytic device residency of this engine: weights pytree + the
+        cache's pools (paged or contiguous; two, or a latent family's one) +
+        the recurrent state of every slot —
         the per-model attribution behind the ``resident_bytes_<model>``
         gauge (docs/OBSERVABILITY.md §8)."""
         from dmlc_tpu.cluster.devicemon import pytree_nbytes

@@ -10,12 +10,20 @@ is a FLEET of pages shared by whatever mix of requests is resident.
 
 Layout (docs/GENERATE.md):
 
-- ``k_pages`` / ``v_pages``: [num_layers * num_pages, page_size, H * Dh]
-  device arrays: layer ``l``'s page ``p`` is row ``l * num_pages + p``, and
-  a page is ``page_size`` rows of every KV head side by side. That is the
-  one layout both programs write and the gather reads, so the donated
-  pools are updated in place: heads folded into the last axis fill the
-  chip's tiles (a trailing [H, Dh] such as 20 x 64 pads to 32 x 128, and
+- What a cached position's row holds is the model family's to say. A family
+  that caches K and V per KV head (``models/lm``, ``nemotron_h``,
+  ``olmo_hybrid``, ``lfm2_moe``) gets two pools, ``k_pages`` / ``v_pages``:
+  [num_layers * num_pages, page_size, KV * Dh]; a page is ``page_size`` rows
+  of every KV head side by side. A family that caches ONE latent row a
+  position, shared by all its heads (``models/deepseek_v3``: the compressed
+  K/V and the turned rotary key, ``latent_row`` lanes), gets one pool,
+  ``k_pages``: [num_layers * num_pages, page_size, latent_row], and
+  ``v_pages`` is ``None``: no second pool is allocated, carried, donated or
+  released.
+- Either way layer ``l``'s page ``p`` is row ``l * num_pages + p``. That is
+  the one layout both programs write and the attention reads, so the
+  donated pools are updated in place: heads folded into the last axis fill
+  the chip's tiles (a trailing [H, Dh] such as 20 x 64 pads to 32 x 128, and
   the compiler then re-lays the WHOLE pool around every write), and layers
   folded into the page axis mean no layer is ever sliced out. One page id
   spans EVERY layer — allocating a page grants page_size token positions
@@ -134,7 +142,9 @@ class PageAllocator:
 
 
 class PagedKVCache:
-    """Device page pools + the host-side slot table over one allocator.
+    """Device page pools + the host-side slot table over one allocator:
+    a K and a V pool of ``num_heads * head_dim`` lanes a row, or, given
+    ``latent_row``, the one pool of a family that caches a latent row.
 
     Construction is the expensive part (it allocates the whole pool in
     device memory) and happens ONCE per engine — never per request or per
@@ -153,6 +163,7 @@ class PagedKVCache:
         max_slots: int,
         max_pages_per_slot: int,
         dtype: Any = None,
+        latent_row: int | None = None,
     ) -> None:
         import jax.numpy as jnp
 
@@ -162,13 +173,13 @@ class PagedKVCache:
         self.max_pages_per_slot = int(max_pages_per_slot)
         self.dtype = dtype if dtype is not None else jnp.float32
         self.allocator = PageAllocator(num_pages, page_size)
-        shape = (self.num_layers * int(num_pages), self.page_size,
-                 int(num_heads) * int(head_dim))
+        row = int(num_heads) * int(head_dim) if latent_row is None else int(latent_row)
+        shape = (self.num_layers * int(num_pages), self.page_size, row)
         # The pools live on the engine's device; both jitted programs donate
         # them and write the new rows into the same buffers, so exactly one
         # generation of the pool exists at a time.
         self.k_pages = jnp.zeros(shape, self.dtype)
-        self.v_pages = jnp.zeros(shape, self.dtype)
+        self.v_pages = jnp.zeros(shape, self.dtype) if latent_row is None else None
         # Host-owned table/lengths; rows default to the scratch page.
         self.page_table = np.full(
             (self.max_slots, self.max_pages_per_slot), SCRATCH_PAGE, np.int32

@@ -303,3 +303,11 @@ register_olmo_hybrid("olmo_hybrid_tiny", OLMO_HYBRID_TINY)
 from dmlc_tpu.models.lfm2_moe import LFM2_MOE_TINY, register_lfm2_moe  # noqa: E402
 
 register_lfm2_moe("lfm2_moe_tiny", LFM2_MOE_TINY)
+
+# The DeepSeek-V3 family's CPU-test preset (latent attention over one cached
+# row a position, a dense layer, then gated experts beside shared ones, a
+# quarter of them held); real sizes are registered by whoever serves them
+# (``models/deepseek_v3.register_deepseek_v3``).
+from dmlc_tpu.models.deepseek_v3 import DEEPSEEK_V3_TINY, register_deepseek_v3  # noqa: E402
+
+register_deepseek_v3("deepseek_v3_tiny", DEEPSEEK_V3_TINY)

@@ -17,6 +17,13 @@ one row axis.
   softmax over the chunks in float32. No padded view of the cache exists and
   no page is unfolded to heads. Interpreter mode off-TPU keeps tests hermetic
   (same seam as the flash kernels).
+- ``paged_latent_decode_attention`` — the same pipeline for a family that
+  caches ONE latent row a position, shared by all its heads
+  (``models/deepseek_v3``; generate/kvcache.py, "Layout"): one pool, each
+  held page DMA'd once, the heads' queries against the chunk as stored (a
+  true ``[H, row] x [row, chunk]`` product), the weighted sum over the first
+  ``value_lanes`` lanes of the same buffer. Its reference, and what the
+  engine runs off-TPU: ``gather_latent_pages`` + ``latent_decode_attention``.
 - ``gather_kv_pages`` + ``ragged_decode_attention`` — the reference the kernel
   is pinned against, and what the engine runs off-TPU: ``jnp.take`` over the
   row axis assembles the padded ``[B, S_max]`` view, scores are computed
@@ -57,6 +64,60 @@ def _bf16_terms(x):
         terms.append(term)
         rest = rest - term.astype(jnp.float32)
     return terms
+
+
+def _fold(stacked, rows: int):
+    """A product whose left operand was bfloat16 terms stacked on rows (three
+    of them, or the one of an operand that is stored in bfloat16)."""
+    if stacked.shape[0] == rows:
+        return stacked
+    return stacked[:rows] + stacked[rows:2 * rows] + stacked[2 * rows:]
+
+
+def _page_pipeline(table_ref, len_ref, first_ref, pools, sem, *, slots: int, max_pages: int,
+                   page_size: int, chunk_pages: int):
+    """The DMA pipeline both kernels share: a slot's pages leave the pool(s) a
+    chunk at a time into one of two VMEM buffers. ``pools``: (pool ref in HBM,
+    its ``[2, chunk, width]`` buffer) for each pool a page is read from: K and
+    V, or the one latent pool. Returns ``pages_of(b)`` (pages that hold slot
+    b's positions) and ``advance(b, c, chunks, buf)``, which puts the NEXT
+    chunk in flight into the other buffer (this slot's, or the next slot's
+    first) and waits for chunk c of slot b in ``buf``; ``start`` begins the
+    very first."""
+
+    def pages_of(b):
+        return (len_ref[b] + page_size - 1) // page_size
+
+    def page_copies(b, c, buf, act):
+        """``act`` (start, or wait for) the copies of the pages of chunk c of
+        slot b into buffer ``buf``. Every copy moves one page of one row
+        width, so a buffer's copies share its semaphore: a wait takes one
+        page's worth of it, whichever copy finished."""
+        count = jnp.minimum(pages_of(b) - c * chunk_pages, chunk_pages)
+
+        def body(j, carry):
+            row = first_ref[0] + table_ref[b * max_pages + c * chunk_pages + j]
+            for pool_ref, buf_ref in pools:
+                act(pltpu.make_async_copy(
+                    pool_ref.at[row], buf_ref.at[buf, pl.ds(j * page_size, page_size)],
+                    sem.at[buf]))
+            return carry
+
+        jax.lax.fori_loop(0, count, body, 0)
+
+    def start(b, c, buf):
+        page_copies(b, c, buf, lambda copy: copy.start())
+
+    def advance(b, c, chunks, buf):
+        more = c + 1 < chunks
+
+        @pl.when(more | (b + 1 < slots))
+        def _():
+            start(jnp.where(more, b, b + 1), jnp.where(more, c + 1, 0), 1 - buf)
+
+        page_copies(b, c, buf, lambda copy: copy.wait())
+
+    return pages_of, start, advance
 
 
 def paged_decode_attention(q, k_pool, v_pool, page_table, kv_lengths, *, first_row, kv_heads: int):
@@ -104,31 +165,9 @@ def _paged_decode_attention(q, k_pool, v_pool, page_table, kv_lengths, first_row
     qs = qs.reshape(slots, width) if group == 1 else qs.reshape(slots * heads, head_dim)
 
     def kernel(table_ref, len_ref, first_ref, q_ref, k_ref, v_ref, out_ref, k_buf, v_buf, sem):
-        def pages_of(b):
-            return (len_ref[b] + page_size - 1) // page_size
-
-        def page_copies(b, c, buf, act):
-            """``act`` (start, or wait for) the K and V copies of the pages of
-            chunk c of slot b into buffer ``buf``. Every copy moves one page of
-            one row width, so a buffer's copies share its semaphore: a wait
-            takes one page's worth of it, whichever copy finished."""
-            count = jnp.minimum(pages_of(b) - c * chunk_pages, chunk_pages)
-
-            def body(j, carry):
-                row = first_ref[0] + table_ref[b * max_pages + c * chunk_pages + j]
-                for pool_ref, buf_ref in ((k_ref, k_buf), (v_ref, v_buf)):
-                    act(pltpu.make_async_copy(
-                        pool_ref.at[row], buf_ref.at[buf, pl.ds(j * page_size, page_size)],
-                        sem.at[buf]))
-                return carry
-
-            jax.lax.fori_loop(0, count, body, 0)
-
-        def start(b, c, buf):
-            page_copies(b, c, buf, lambda copy: copy.start())
-
-        def wait(b, c, buf):
-            page_copies(b, c, buf, lambda copy: copy.wait())
+        pages_of, start, advance = _page_pipeline(
+            table_ref, len_ref, first_ref, ((k_ref, k_buf), (v_ref, v_buf)), sem, slots=slots,
+            max_pages=max_pages, page_size=page_size, chunk_pages=chunk_pages)
 
         row_head = jax.lax.broadcasted_iota(jnp.int32, (rows, width), 0) // group
         own_lanes = row_head == jax.lax.broadcasted_iota(jnp.int32, (rows, width), 1) // head_dim
@@ -146,7 +185,7 @@ def _paged_decode_attention(q, k_pool, v_pool, page_table, kv_lengths, first_row
             return jnp.concatenate(_bf16_terms(jnp.where(own_lanes, spread, 0.0)), axis=0)
 
         def fold(stacked):
-            return stacked[:rows] + stacked[rows:2 * rows] + stacked[2 * rows:]
+            return _fold(stacked, rows)
 
         def slot(b, buf):
             length = len_ref[b]
@@ -155,13 +194,7 @@ def _paged_decode_attention(q, k_pool, v_pool, page_table, kv_lengths, first_row
 
             def attend(c, carry):
                 buf, m, l, acc = carry
-                more = c + 1 < chunks
-
-                @pl.when(more | (b + 1 < slots))
-                def _():
-                    start(jnp.where(more, b, b + 1), jnp.where(more, c + 1, 0), 1 - buf)
-
-                wait(b, c, buf)
+                advance(b, c, chunks, buf)
                 base = c * chunk
 
                 # Rows past the length hold whatever the buffer or the page's
@@ -222,6 +255,135 @@ def _paged_decode_attention(q, k_pool, v_pool, page_table, kv_lengths, first_row
     )(page_table.reshape(-1).astype(jnp.int32), kv_lengths.astype(jnp.int32),
       jnp.asarray(first_row, jnp.int32).reshape(1), qs, k_pool, v_pool)
     return out.reshape(q.shape).astype(q.dtype)
+
+
+def paged_latent_decode_attention(q, pool, page_table, kv_lengths, *, first_row,
+                                  value_lanes: int, scale: float):
+    """One decode step of attention over ONE latent row a cached position,
+    shared by all heads, straight from its pool (absorbed latent attention:
+    the heads' queries arrive already multiplied into the latent's lanes).
+
+    ``q``: [B, H, row]; ``pool``: [rows, page_size, row] as it lives in device
+    memory; ``page_table``, ``kv_lengths`` (every one >= 1), ``first_row`` as
+    ``paged_decode_attention`` takes them, and the same pipeline: only a
+    slot's own pages leave the pool, EACH ONCE (keys and values are the same
+    rows), a chunk at a time into one of two VMEM buffers with the next in
+    flight. Per chunk ``scores[H, T] = (q @ rows.T) * scale`` over all ``row``
+    lanes, a true product with the chunk as stored, and ``acc[H, value_lanes]
+    += p @ rows[:, :value_lanes]``, the weighted sum over the first
+    ``value_lanes`` lanes of the same buffer. Scores, softmax (online over
+    the chunks) and the weighted sum are float32 (``_bf16_terms``); the scale
+    multiplies the float32 SCORES, so a query stored in bfloat16 is its own
+    one term and its product with a bfloat16 pool is exact in one pass.
+    -> [B, H, value_lanes] in q's dtype."""
+    _, page_size, _ = pool.shape
+    chunk_pages = max(1, min(_CHUNK_TOKENS // page_size, page_table.shape[1]))
+    return _paged_latent_decode_attention(
+        q, pool, page_table, kv_lengths, first_row, value_lanes=value_lanes, scale=float(scale),
+        chunk_pages=chunk_pages, interpret=interpret_mode())
+
+
+@functools.partial(jax.jit, static_argnames=("value_lanes", "scale", "chunk_pages", "interpret"))
+def _paged_latent_decode_attention(q, pool, page_table, kv_lengths, first_row, *,
+                                   value_lanes: int, scale: float, chunk_pages: int,
+                                   interpret: bool):
+    slots, heads, width = q.shape
+    _, page_size, _ = pool.shape
+    max_pages = page_table.shape[1]
+    chunk = chunk_pages * page_size
+    rows = -(-heads // 16) * 16  # query rows, padded to whole bfloat16 tiles
+
+    def kernel(table_ref, len_ref, first_ref, q_ref, pool_ref, out_ref, row_buf, sem):
+        pages_of, start, advance = _page_pipeline(
+            table_ref, len_ref, first_ref, ((pool_ref, row_buf),), sem, slots=slots,
+            max_pages=max_pages, page_size=page_size, chunk_pages=chunk_pages)
+
+        def slot(b, buf):
+            length = len_ref[b]
+            chunks = (pages_of(b) + chunk_pages - 1) // chunk_pages
+            mine = q_ref[pl.ds(pl.multiple_of(b * heads, heads), heads), :]
+            if rows > heads:
+                mine = jnp.concatenate(
+                    [mine, jnp.zeros((rows - heads, width), mine.dtype)], axis=0)
+            q_terms = jnp.concatenate(_bf16_terms(mine), axis=0)       # [1 or 3 * rows, width]
+
+            def attend(c, carry):
+                buf, m, l, acc = carry
+                advance(b, c, chunks, buf)
+                base = c * chunk
+
+                # Rows past the length hold whatever the buffer or the page's
+                # tail held: p is 0 there, and 0 * NaN is not.
+                @pl.when(base + chunk > length)
+                def _():
+                    held = base + jax.lax.broadcasted_iota(jnp.int32, (chunk, 1), 0) < length
+                    row_buf[buf] = jnp.where(held, row_buf[buf], jnp.zeros((), row_buf.dtype))
+
+                latent = row_buf[buf]                                  # [chunk, width]
+                scores = scale * sum(
+                    _fold(jax.lax.dot_general(q_terms, k, (((1,), (1,)), ((), ())),
+                                              preferred_element_type=jnp.float32), rows)
+                    for k in _bf16_terms(latent))
+                held = base + jax.lax.broadcasted_iota(jnp.int32, (1, chunk), 1) < length
+                scores = jnp.where(held, scores, -jnp.inf)
+                m_new = jnp.maximum(m, jnp.max(scores, axis=1, keepdims=True))
+                alpha = jnp.exp(m - m_new)
+                p = jnp.exp(scores - m_new)
+                p_terms = jnp.concatenate(_bf16_terms(p), axis=0)
+                weighted = sum(
+                    _fold(jnp.dot(p_terms, v, preferred_element_type=jnp.float32), rows)
+                    for v in _bf16_terms(latent[:, :value_lanes]))
+                return (1 - buf, m_new, alpha * l + jnp.sum(p, axis=1, keepdims=True),
+                        alpha * acc + weighted)
+
+            buf, _, l, acc = jax.lax.fori_loop(0, chunks, attend, (
+                buf, jnp.full((rows, 1), -jnp.inf, jnp.float32),
+                jnp.zeros((rows, 1), jnp.float32), jnp.zeros((rows, value_lanes), jnp.float32)))
+            out_ref[pl.ds(pl.multiple_of(b * heads, heads), heads), :] = (acc / l)[:heads]
+            return buf
+
+        start(0, 0, 0)
+        jax.lax.fori_loop(0, slots, slot, 0)
+
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(),
+        in_specs=[pl.BlockSpec(memory_space=pltpu.VMEM), pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec(memory_space=pltpu.VMEM),
+        scratch_shapes=[pltpu.VMEM((2, chunk, width), pool.dtype),
+                        pltpu.SemaphoreType.DMA((2,))],
+    )
+    out = pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((slots * heads, value_lanes), jnp.float32),
+        interpret=interpret,
+    )(page_table.reshape(-1).astype(jnp.int32), kv_lengths.astype(jnp.int32),
+      jnp.asarray(first_row, jnp.int32).reshape(1), q.reshape(slots * heads, width), pool)
+    return out.reshape(slots, heads, value_lanes).astype(q.dtype)
+
+
+def gather_latent_pages(pool, page_table, *, first_row=0):
+    """The per-slot contiguous view of a latent pool ``[rows, page_size, row]``:
+    row b's sequence is its pages in table order -> [B, max_pages * page_size,
+    row] (``gather_kv_pages`` without heads to unfold)."""
+    b, max_pages = page_table.shape
+    _, page_size, width = pool.shape
+    rows = page_table.reshape(b * max_pages).astype(jnp.int32) + first_row
+    return jnp.take(pool, rows, axis=0).reshape(b, max_pages * page_size, width)
+
+
+def latent_decode_attention(q, rows, kv_lengths, *, value_lanes: int, scale: float):
+    """Dense latent attention, the latent kernel's pin: ``q`` [B, H, row]
+    against ``rows`` [B, S_max, row] (one row a position for all heads), slot
+    b over positions [0, kv_lengths[b]); scores over all lanes times
+    ``scale``, softmax in float32, the weighted sum over the rows' first
+    ``value_lanes`` lanes -> [B, H, value_lanes] in q's dtype."""
+    rows = rows.astype(jnp.float32)
+    scores = jnp.einsum("bhr,bsr->bhs", q.astype(jnp.float32) * scale, rows)
+    mask = jnp.arange(rows.shape[1])[None, None, :] < kv_lengths[:, None, None]
+    probs = jax.nn.softmax(jnp.where(mask, scores, -jnp.inf), axis=-1)
+    return jnp.einsum("bhs,bsv->bhv", probs, rows[..., :value_lanes]).astype(q.dtype)
 
 
 def gather_kv_pages(pool, page_table, kv_heads: int, *, first_row=0):

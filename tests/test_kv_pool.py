@@ -11,7 +11,11 @@ by the step and the prefill and read where they live by the attention.
 - a prefill touches the slot's page run and the scratch page, nothing else;
 - the names the benchmark reads (``engine.cache.k_pages``, ``engine._k_state``)
   answer, and deleting them frees every pool buffer;
-- a step and a prefill consume the pool they are handed (one generation).
+- a step and a prefill consume the pool they are handed (one generation);
+- a family that caches ONE latent row a position (``models/deepseek_v3``)
+  gets one pool and no second one, and its form of the fused kernel
+  (``paged_latent_decode_attention``) gives what the gathered rows and the
+  dense latent attention give, under the same poisons.
 
 Counts and values only; nothing here is a speed.
 """
@@ -320,3 +324,143 @@ def test_fused_attention_with_inactive_slots_beside_full_ones(chunked, heads, kv
         atol=2e-6, rtol=1e-5)
     scratch_v = np.asarray(v_pool[num_pages + SCRATCH_PAGE, 0]).reshape(kv_heads, head_dim)
     np.testing.assert_allclose(got[1], np.repeat(scratch_v, heads // kv_heads, axis=0), atol=1e-6)
+
+
+# ---------------------------------------------------------------------------
+# the latent layout: one pool, and the kernel's latent form
+# ---------------------------------------------------------------------------
+
+
+def latent_engine(**kw):
+    kw = {"max_slots": 3, "page_size": PAGE, "num_pages": 24, "max_prefill": 16, **kw}
+    return GenerationEngine("deepseek_v3_tiny", **kw)
+
+
+def test_a_latent_family_gets_one_pool_and_no_second_one():
+    engine = latent_engine(dtype=jnp.bfloat16)
+    engine.join(0, np.arange(5, dtype=np.int32))
+    engine.step()
+    assert engine.cache.v_pages is None and engine._v_state is None
+    assert engine.cache.k_pages.dtype == jnp.bfloat16
+    assert engine.cache.k_pages.shape == (4 * 24, PAGE, 128)     # four layers, 40 values on 128 lanes
+
+    def live_pools():  # this test's engine is the only bfloat16 one of this shape
+        return [a for a in jax.live_arrays()
+                if a.shape == (4 * 24, PAGE, 128) and a.dtype == jnp.bfloat16]
+
+    assert len(live_pools()) == 1  # one generation of ONE pool
+    for name in ("_k_state", "_v_state"):  # as benchlib.system.free_pools does
+        pool = getattr(engine, name, None)
+        if pool is not None:
+            pool.delete()
+    assert engine.cache.k_pages.is_deleted() and not live_pools()
+
+
+@pytest.mark.parametrize("program", ["step", "prefill"])
+def test_each_program_consumes_the_one_latent_pool(program):
+    probe = jnp.zeros((8,))
+    jax.jit(lambda x: x + 1, donate_argnums=0)(probe)
+    if not probe.is_deleted():
+        pytest.skip("this backend does not honour donation")
+    engine = latent_engine()
+    engine.join(0, np.arange(6, dtype=np.int32))
+    handed = engine._k_state
+    if program == "step":
+        engine.step()
+    else:
+        engine.join(1, np.arange(3, dtype=np.int32))
+    assert handed.is_deleted() and not engine._k_state.is_deleted()
+    assert engine.cache.k_pages is engine._k_state and engine._v_state is None
+    # The stock of replaced arrays is bounded at one array a run, not two.
+    assert len(engine._replaced) <= 2 and engine._replaced_max == 8
+
+
+@pytest.mark.parametrize("cache", ["paged", "contiguous"])
+def test_a_latent_prefill_writes_whole_rows_and_pads_with_zeros(cache):
+    engine = latent_engine(cache=cache)
+    engine.join(1, np.arange(PAGE + 3, dtype=np.int32))
+    if cache == "paged":
+        first, second = engine.cache.slot_pages(1)[:2]
+        rows = np.concatenate([np.asarray(engine._k_state[first]), np.asarray(engine._k_state[second])])
+        untouched = np.ones(24, bool)
+        untouched[[SCRATCH_PAGE, *engine.cache.slot_pages(1)]] = False
+        assert not np.asarray(engine._k_state[:24])[untouched].any()
+    else:
+        rows = np.asarray(engine._k_state[0, 1, :2 * PAGE])
+    assert np.abs(rows[:PAGE + 3, :40]).min() > 0 and not rows[:, 40:].any()
+
+
+LATENT = pytest.mark.parametrize("heads,width,value_lanes", [(4, 128, 32), (32, 128, 64), (6, 256, 128)],
+                                 ids=["padded_rows", "two_tiles_of_rows", "ragged_rows"])
+
+
+def latent_case(heads, width, value_lanes, lengths, *, dtype=jnp.float32, seed=0):
+    q, pool, _, table, lengths, num_pages = pool_case(heads, 1, width, lengths, dtype=dtype, seed=seed)
+    return q, pool, table, lengths, num_pages, {"value_lanes": value_lanes, "scale": 0.21}
+
+
+def fused_latent(q, pool, table, lengths, first_row, how):
+    return np.asarray(ragged_decode.paged_latent_decode_attention(
+        q, pool, table, lengths, first_row=first_row, **how), np.float32)
+
+
+def gathered_latent(q, pool, table, lengths, first_row, how):
+    rows = ragged_decode.gather_latent_pages(pool, table, first_row=first_row)
+    return np.asarray(ragged_decode.latent_decode_attention(q, rows, lengths, **how), np.float32)
+
+
+@LATENT
+def test_latent_kernel_is_the_gathered_rows(chunked, heads, width, value_lanes):
+    lengths = [1, PAGE, PAGE + 3, FUSED_PAGES * PAGE, 10]
+    q, pool, table, lengths, num_pages, how = latent_case(heads, width, value_lanes, lengths)
+    for first_row in (0, num_pages):
+        got = fused_latent(q, pool, table, lengths, first_row, how)
+        assert got.shape == (5, heads, value_lanes)
+        np.testing.assert_allclose(got, gathered_latent(q, pool, table, lengths, first_row, how),
+                                   atol=2e-6, rtol=1e-5)
+    assert np.abs(got).max() > 0.1
+
+
+def test_latent_kernel_on_a_bfloat16_pool_keeps_float32_sums(chunked):
+    lengths = [FUSED_PAGES * PAGE, 7, PAGE]
+    q, pool, table, lengths, num_pages, how = latent_case(4, 128, 32, lengths, dtype=jnp.bfloat16)
+    q = q.astype(jnp.float32) + 1e-3          # queries the bfloat16 grid does not hold
+    got = fused_latent(q, pool, table, lengths, num_pages, how)
+    want = gathered_latent(q, pool, table, lengths, num_pages, how)
+    np.testing.assert_allclose(got, want, atol=2e-6, rtol=1e-5)
+    rounded = gathered_latent(q.astype(jnp.bfloat16).astype(jnp.float32), pool, table, lengths,
+                              num_pages, how)
+    assert np.abs(rounded - want).max() > 20 * np.abs(got - want).max()
+    # Queries STORED in bfloat16 (what a bfloat16 engine hands the kernel) are one
+    # term: the same float32 sums over them, only the result is rounded to bfloat16.
+    low = q.astype(jnp.bfloat16)
+    got = fused_latent(low, pool, table, lengths, num_pages, how)
+    want = gathered_latent(low.astype(jnp.float32), pool, table, lengths, num_pages, how)
+    assert np.abs(got - want).max() <= 2.0 ** -8 * np.abs(want).max()
+
+
+@LATENT
+def test_latent_kernel_reads_nothing_a_slot_does_not_hold(chunked, heads, width, value_lanes):
+    lengths = [1, PAGE + 3, FUSED_PAGES * PAGE, 10]
+    q, pool, table, lengths, num_pages, how = latent_case(heads, width, value_lanes, lengths)
+    clean = fused_latent(q, pool, table, lengths, 0, how)
+    poison = np.ones((LAYERS * num_pages, PAGE), bool)
+    for slot, length in enumerate(np.asarray(lengths)):
+        for j in range(-(-length // PAGE)):
+            poison[int(table[slot, j]), :min(PAGE, length - j * PAGE)] = False
+    poisoned = fused_latent(q, jnp.where(jnp.asarray(poison)[:, :, None], jnp.nan, pool), table,
+                            lengths, 0, how)
+    assert np.isfinite(poisoned).all()
+    np.testing.assert_array_equal(poisoned, clean)
+
+
+def test_latent_kernel_with_inactive_slots_beside_full_ones(chunked):
+    full = FUSED_PAGES * PAGE
+    q, pool, table, lengths, num_pages, how = latent_case(4, 128, 32, [full, 1, full, 1])
+    table = table.at[jnp.asarray([1, 3])].set(SCRATCH_PAGE)
+    got = fused_latent(q, pool, table, lengths, num_pages, how)
+    np.testing.assert_allclose(got, gathered_latent(q, pool, table, lengths, num_pages, how),
+                               atol=2e-6, rtol=1e-5)
+    # One position attended: the result is that row's value lanes, for every head.
+    scratch = np.asarray(pool[num_pages + SCRATCH_PAGE, 0, :32])
+    np.testing.assert_allclose(got[1], np.broadcast_to(scratch, (4, 32)), atol=1e-6)

@@ -26,6 +26,17 @@ file itself: 12 layers, the whole vocabulary, 64 slots, 6,144 pages, prompts
 padded to 1,024): pool rows of 8 KV heads x 64 = 512 lanes under 32 query
 heads, conv windows of 8 KB a layer a slot, and an expert layer whose dense
 form keeps a float32 ``[32, 64, 3584]`` product beside 7 GB of experts.
+
+The fourth is ``models/deepseek_v3`` at the sizes of ITS cell (24 layers, the
+whole vocabulary, 64 slots, 12,288 pages, prompts padded to 2,048): ONE pool of
+640-lane rows (6.04 GB) aliased, no second pool anywhere in either program,
+the latent kernel through Mosaic 24 times a step, and beside 6.31 GB of
+weights temporaries of 46 MB a step and 411 MB a prefill. A 576-lane row is
+refused by the chip's compiler (and would occupy 640 lanes all the same).
+
+The last pins the K/V form of the fused attention kernel at the accepted
+cells' shapes to what the parent of PR 37 lowered it to: the Mosaic module
+itself, read back from the custom call without its source locations.
 """
 
 import pytest
@@ -34,6 +45,7 @@ pytest.importorskip("jax")
 import jax  # noqa: E402
 
 import chip_smoke  # noqa: E402
+import lowered_programs  # noqa: E402
 
 GEOMETRY = {**chip_smoke.POOL_GEOMETRY, "layers": 2, "vocab": 512, "num_pages": 16384}
 
@@ -190,3 +202,97 @@ def test_rotary_gated_expert_family_at_its_cells_sizes(one_chip, compiled_for_th
             assert ("f32[32,64,3584]" in text) == (name == "step"), name
     finally:
         registry._REGISTRY.pop(spec.name, None)
+
+
+
+def _latent_engine(name, cfg, row_lanes=None):
+    import jax.numpy as jnp
+
+    from dmlc_tpu.generate.engine import GenerationEngine
+    from dmlc_tpu.models import deepseek_v3 as ds
+
+    cluster = cfg["cluster"]
+    config = ds.DeepseekV3Config.from_published(
+        cfg, n_routed_experts=cfg["published"]["n_routed_experts"],
+        experts_held=cfg["deployment"]["experts_held"], max_len=cfg["serving_positions"])
+    spec = ds.register_deepseek_v3(name, config)
+    engine = GenerationEngine(spec.name, variables={}, dtype=jnp.bfloat16,
+                              max_slots=cluster["gen_max_slots"], page_size=cluster["gen_page_size"],
+                              num_pages=2, max_prefill=cluster["gen_max_prefill"], use_pallas=True)
+    variables = jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, jnp.bfloat16),
+        jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=jnp.bfloat16)[1]))
+    return engine, variables
+
+
+def test_latent_family_at_its_cells_sizes_keeps_one_pool(one_chip, compiled_for_the_chip):
+    import json
+
+    import jax.numpy as jnp
+    import plain_reference
+
+    from dmlc_tpu.models import registry
+
+    cfg = json.loads(
+        (plain_reference.REPO / "benchmark" / "configs" / "kanana-2-30b-a3b.json").read_text())
+    cluster = cfg["cluster"]
+    assert (cluster["gen_max_slots"], cluster["gen_num_pages"], cluster["gen_max_prefill"]) == (
+        64, 12288, 2048)
+    try:
+        engine, variables = _latent_engine("latent_geometry_lm", cfg)
+        assert engine.latent_row == 640 and engine._v_state is None and engine.state.nbytes == 0
+        pool = jax.ShapeDtypeStruct((24 * cluster["gen_num_pages"], 16, 640), jnp.bfloat16)
+        memory = chip_smoke.program_memory(engine, variables, pool, sharding=one_chip)
+    finally:
+        registry._REGISTRY.pop("latent_geometry_lm", None)
+    pool_bytes = 24 * 12288 * 16 * 640 * 2
+    weights = 2 * 3_155_018_624
+    assert memory["pools"] == 1 and memory["pool_bytes"] == pool_bytes == 6_039_797_760
+    for name in ("step", "prefill"):
+        # ONE pool aliased and nothing else of its size: a second pool would show
+        # in the aliased bytes, in the arguments, or as a temporary.
+        assert memory[name]["alias_bytes"] == pool_bytes, name
+        assert weights + pool_bytes < memory[name]["argument_bytes"] < weights + pool_bytes + 2 ** 24
+    # A step keeps the held experts' float32 product and the kernel's operands
+    # (46 MB); a prefill its 2,048 rows at the widest product and a block of 512
+    # query rows of float32 scores, not the whole square (411 MB, not 1.5 GB).
+    assert memory["step"]["temp_bytes"] < 2 ** 26
+    assert memory["prefill"]["temp_bytes"] < 2 ** 29
+    assert memory["step"]["mosaic"] and memory["prefill"]["loop"] and not memory["step"]["loop"]
+
+
+def test_a_576_lane_row_is_refused_by_the_chips_compiler(one_chip, compiled_for_the_chip, monkeypatch):
+    """Why a cached row is stored on 640 lanes (PERF.md finding PR 37.1): the
+    chip tiles the pool's trailing axis by 128 lanes (the error names the
+    pool as ``x640`` in memory already) and the kernel's page copy may not
+    cut a tile."""
+    import jax.numpy as jnp
+
+    from dmlc_tpu.ops.ragged_decode import paged_latent_decode_attention
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    def lower(width):
+        fn = jax.jit(lambda q, pool, table, lengths: paged_latent_decode_attention(
+            q, pool, table, lengths, first_row=0, value_lanes=512, scale=192 ** -0.5))
+        return fn.lower(abstract((8, 32, width), jnp.bfloat16),
+                        abstract((64, 16, width), jnp.bfloat16), abstract((8, 4), jnp.int32),
+                        abstract((8,), jnp.int32))
+
+    assert chip_smoke.MOSAIC_CALL in lower(640).as_text()
+    with pytest.raises(Exception, match=r"aligned to tiling \(128\), but is 576"):
+        lower(576).compile()
+
+
+#: The Mosaic module of ``paged_decode_attention`` at the accepted cells' shapes,
+#: without source locations: SHA-256 (16 hex digits) on the parent of PR 37.
+MOSAIC_PINS = {"docs": "9c0c9ce281e4e025", "answers": "dbd2a4a3d7bbee20",
+               "briefs": "4fa738e6a100b6fd", "replies": "8f407f86b5c3f216"}
+
+
+@pytest.mark.parametrize("case", sorted(lowered_programs.KERNEL_CASES))
+def test_the_kv_kernel_lowers_to_the_parents_mosaic_module(one_chip, compiled_for_the_chip, case):
+    module = lowered_programs.mosaic_module(lowered_programs.kernel_text_for_tpu(case, one_chip))
+    assert "tpu.enqueue_dma" in module or "dma" in module
+    assert lowered_programs.sha(module) == MOSAIC_PINS[case]
