@@ -91,6 +91,67 @@ class BatchResult:
     device_seconds: float
 
 
+class ShardDecodes:
+    """A shard's batch decodes on the persistent stage pool, handed out in
+    batch order with at most ``prefetch`` started and not yet taken
+    (``InferenceEngine.start_decodes``; ``run_paths_stream`` takes them).
+
+    Each decode runs in a copy of ``ctx``, the context of the span open
+    where the handle was made, so ``host/decode`` keeps the shard's trace
+    and lane on a pool thread (docs/OBSERVABILITY.md §1). A decode that
+    raises keeps its exception in its future: it surfaces where the shard
+    waits for that batch, in the shard's own reply."""
+
+    def __init__(self, engine: "InferenceEngine", paths: Sequence[str], workers: int | None,
+                 prefetch: int, decode_source) -> None:
+        self.paths = paths
+        self.starts = list(range(0, len(paths), engine.batch_size))
+        self.prefetch = max(1, int(prefetch))
+        self.futs: collections.deque = collections.deque()
+        self.submitted = 0  # batches handed to the pool so far
+        self.ctx = contextvars.copy_context() if tracer.enabled else None
+        self._engine = engine
+        self._workers = workers
+        self._decode_source = decode_source
+
+    def _decode(self, s: int):
+        engine = self._engine
+        chunk = self.paths[s : s + engine.batch_size]
+        t0 = time.perf_counter()
+        with tracer.span("host/decode", n=len(chunk)):
+            if self._decode_source is not None:
+                batch = self._decode_source(chunk, engine.input_size)
+            else:
+                batch = pp.load_batch(chunk, size=engine.input_size, workers=self._workers)
+        if len(chunk) < engine.batch_size:
+            pad = np.zeros((engine.batch_size - len(chunk), *batch.shape[1:]), batch.dtype)
+            batch = np.concatenate([batch, pad])
+        # Statistic only: the interval is the ``host/decode`` span above.
+        with engine._ingest_lock:
+            engine._ingest["decode"].record(time.perf_counter() - t0)
+        return len(chunk), batch
+
+    def submit(self, leaf: str = "ingest/decode_submit") -> None:
+        """Start batches until ``prefetch`` are outstanding. A leaf of its
+        own: a pool thread that starts decoding may take the interpreter
+        from this one before ``submit()`` returns."""
+        pool = _stage_pool()
+        with tracer.span(leaf, cpu=True):
+            while self.submitted < len(self.starts) and len(self.futs) < self.prefetch:
+                self.futs.append(_submit_in(self.ctx, pool, self._decode,
+                                            self.starts[self.submitted]))
+                self.submitted += 1
+
+    def ready(self) -> int:
+        """Started batches whose decode has ended."""
+        return sum(f.done() for f in self.futs)
+
+    def cancel(self) -> None:
+        """Drop the batches not yet taken (a shard that will not run)."""
+        for f in self.futs:
+            f.cancel()
+
+
 class InferenceEngine:
     """One model, one mesh, one compiled program."""
 
@@ -372,6 +433,22 @@ class InferenceEngine:
             batch = pp.load_batch(paths, size=self.input_size, workers=workers)
         return self.run_batch(batch)
 
+    def start_decodes(
+        self,
+        paths: Sequence[str],
+        workers: int | None = None,
+        prefetch: int = 2,
+        decode_source=None,
+        leaf: str = "ingest/decode_submit",
+    ) -> ShardDecodes:
+        """Start a shard's first ``prefetch`` batch decodes on the stage
+        pool, under the span open here, and hand back what
+        ``run_paths_stream(paths, started=...)`` takes. ``leaf`` names the
+        submit's span."""
+        decodes = ShardDecodes(self, paths, workers, prefetch, decode_source)
+        decodes.submit(leaf)
+        return decodes
+
     @hot_path
     def run_paths_stream(
         self,
@@ -379,6 +456,7 @@ class InferenceEngine:
         workers: int | None = None,
         prefetch: int = 2,
         decode_source=None,
+        started: ShardDecodes | None = None,
     ) -> BatchResult:
         """Decode overlapped with h2d transfer and device compute (SURVEY §7
         hard part b) — the three-stage ingest pipeline (docs/INGEST.md).
@@ -406,49 +484,27 @@ class InferenceEngine:
         (cluster/decodetier.py) plugs in: the prefetch stage still runs on
         the persistent stage pool and the staging ring/donation path below
         is untouched; only where the pixels come from changes.
+
+        ``started`` (optional) is this shard's decodes as
+        ``start_decodes(paths, ...)`` already began them, e.g. while another
+        shard held the engine; ``workers``, ``prefetch`` and
+        ``decode_source`` are then the handle's own.
         """
         if not paths:
             raise ValueError("empty path list")
-        starts = list(range(0, len(paths), self.batch_size))
-        prefetch = max(1, int(prefetch))
-        pool = _stage_pool()
-
-        def decode(s: int):
-            chunk = paths[s : s + self.batch_size]
-            t0 = time.perf_counter()
-            with tracer.span("host/decode", n=len(chunk)):
-                if decode_source is not None:
-                    batch = decode_source(chunk, self.input_size)
-                else:
-                    batch = pp.load_batch(chunk, size=self.input_size, workers=workers)
-            if len(chunk) < self.batch_size:
-                pad = np.zeros(
-                    (self.batch_size - len(chunk), *batch.shape[1:]), batch.dtype
-                )
-                batch = np.concatenate([batch, pad])
-            # Statistic only: the interval is the ``host/decode`` span above.
-            with self._ingest_lock:
-                self._ingest["decode"].record(time.perf_counter() - t0)
-            return len(chunk), batch
-
         t_all = time.perf_counter()
+        if started is None:
+            # Decodes run under the span open HERE (the shard's engine/run),
+            # not under whichever leaf span is open when one is submitted.
+            decodes = self.start_decodes(paths, workers, prefetch, decode_source)
+        else:
+            if started.paths is not paths:
+                raise ValueError("started decodes are another shard's")
+            decodes = started
+            # The batches submitted from here on run under this span.
+            decodes.ctx = contextvars.copy_context() if tracer.enabled else None
+        starts, futs = decodes.starts, decodes.futs
         outs: list[tuple[int, Any]] = []
-        futs: collections.deque = collections.deque()
-        next_i = 0
-        # Decodes run under the span open HERE (the shard's engine/run), not
-        # under whichever leaf span happens to be open when one is submitted.
-        shard_ctx = contextvars.copy_context() if tracer.enabled else None
-
-        def submit_decodes() -> None:
-            # A span of its own: a pool thread that starts decoding may take
-            # the interpreter from this one before submit() returns.
-            nonlocal next_i
-            with tracer.span("ingest/decode_submit", cpu=True):
-                while next_i < len(starts) and len(futs) < prefetch:
-                    futs.append(_submit_in(shard_ctx, pool, decode, starts[next_i]))
-                    next_i += 1
-
-        submit_decodes()
         staged: collections.deque = collections.deque()
         inflight: collections.deque = collections.deque()
         for _ in starts:
@@ -460,8 +516,8 @@ class InferenceEngine:
                 fut = futs.popleft()
                 with tracer.span("ingest/decode_wait", cpu=True, ready=fut.done()):
                     n, batch = fut.result()
-                if next_i < len(starts):
-                    submit_decodes()
+                if decodes.submitted < len(starts):
+                    decodes.submit()
                 t0 = time.perf_counter()
                 buf = jax.device_put(batch, self._data_sharding)
                 self._record_stage("stage", time.perf_counter() - t0, batch=int(n))

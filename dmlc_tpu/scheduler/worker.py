@@ -443,10 +443,20 @@ class EngineBackend:
 
     Loads lazily on first shard (JAX import + compile are heavy; tests that
     never dispatch to a real model shouldn't pay), then serves every shard
-    with one batched device execution. A lock serializes shards per engine —
-    the device pipeline is already saturated by one batch stream; the
-    reference serialized with a model mutex too (services.rs:493).
+    with one batched device execution. ``_lock`` serializes what needs the
+    engine, one shard at a time: the wait for its decoded batches, staging,
+    dispatch, the sync and the collect (the reference serialized with a
+    model mutex too, services.rs:493). It does not serialize the host's
+    part before that: ONE shard waiting for the lock (the "ahead" slot,
+    ``_AHEAD``) resolves its paths and starts its first batches' decodes
+    while another holds the engine, so the decode pool works through the
+    holder's staging and syncs instead of idling there (ROADMAP B3).
     """
+
+    #: Shards that may resolve and decode while another holds the engine.
+    #: One keeps host memory at the holder's shard plus one, and keeps a
+    #: queue of shards from decoding many ways at once beside the holder.
+    _AHEAD = 1
 
     def __init__(
         self,
@@ -488,6 +498,7 @@ class EngineBackend:
         self.dtype = dtype
         self._engine = None
         self._lock = threading.Lock()
+        self._ahead = threading.Semaphore(self._AHEAD)
         # Gang decode staging: slice-content -> decoded uint8 batch, keyed
         # by the synset tuple itself so a requeued shard's stage is still
         # valid and no leader-coordinated token is needed. Bounded LRU —
@@ -531,34 +542,55 @@ class EngineBackend:
 
     def __call__(self, synsets: Sequence[str]) -> list[int]:
         # Spans (docs/OBSERVABILITY.md §1): this thread feeds the device, so
-        # between taking the lock and the reply every instant lies under one
-        # leaf span — an idle chip always has an owner here.
+        # from taking the ahead slot to the reply every instant lies under
+        # one leaf span — an idle chip always has an owner here.
+        n = len(synsets)
+        batches = -(-n // self.batch_size)
+        decode_source = self.decode_tier.decode_paths if self.decode_tier is not None else None
+        started = None
         t_wait = time.perf_counter()
-        # dmlc-lint: disable=A2 -- the engine lock serializes shards per engine BY DESIGN (the reference's model mutex, services.rs:493); the future wait it reaches in run_paths_stream is the decode/execute pipeline INSIDE one shard, not a foreign dependency
-        with self._lock:
-            tracer.record("engine/lock_wait", time.perf_counter() - t_wait)
-            with tracer.span("engine/run", cpu=True, n=len(synsets),
-                             batches=-(-len(synsets) // self.batch_size)):
-                engine = self._ensure_engine()
-                with tracer.span("engine/resolve_paths", cpu=True, n=len(synsets)) as span:
+        self._ahead.acquire()
+        ahead_held = True
+        try:
+            tracer.record("engine/ahead_wait", time.perf_counter() - t_wait)
+            with tracer.span("engine/ahead", cpu=True, n=n, batches=batches):
+                with tracer.span("engine/resolve_paths", cpu=True, n=n) as span:
                     paths = _resolve_paths(self.image_source, self.data_dir, synsets, span)
-                if len(paths) <= self.batch_size:
-                    result = engine.run_paths(paths)
-                else:
-                    # Multi-batch shard: decode batch i+1 while the device runs
-                    # batch i (SURVEY §7 hard part b). With the fleet decode
-                    # tier wired, that prefetch decode fans out across peers'
-                    # idle decode lanes instead of only the local stage pool.
-                    result = engine.run_paths_stream(
-                        paths,
-                        decode_source=(
-                            self.decode_tier.decode_paths
-                            if self.decode_tier is not None
-                            else None
-                        ),
-                    )
-                with tracer.span("engine/collect", cpu=True):
-                    return [int(x) for x in result.top1_index]
+                engine = self._engine  # None until the first shard builds it
+                if engine is not None and len(paths) > self.batch_size:
+                    started = engine.start_decodes(
+                        paths, decode_source=decode_source, leaf="engine/ahead_submit")
+            t_wait = time.perf_counter()
+            # dmlc-lint: disable=A2 -- the engine lock serializes shards per engine BY DESIGN (the reference's model mutex, services.rs:493); the future wait it reaches in run_paths_stream is the decode/execute pipeline INSIDE one shard, not a foreign dependency
+            with self._lock:
+                self._ahead.release()
+                ahead_held = False
+                tracer.record("engine/lock_wait", time.perf_counter() - t_wait)
+                with tracer.span(
+                    "engine/run", cpu=True, n=n, batches=batches,
+                    ahead=started.submitted if started else 0,
+                    ready=started.ready() if started else 0,
+                ):
+                    engine = self._ensure_engine()
+                    if len(paths) <= self.batch_size:
+                        result = engine.run_paths(paths)
+                    else:
+                        # Multi-batch shard: decode batch i+1 while the device
+                        # runs batch i (SURVEY §7 hard part b). With the fleet
+                        # decode tier wired, that prefetch decode fans out
+                        # across peers' idle decode lanes instead of only the
+                        # local stage pool.
+                        result = engine.run_paths_stream(
+                            paths, decode_source=decode_source, started=started)
+                    with tracer.span("engine/collect", cpu=True):
+                        return [int(x) for x in result.top1_index]
+        except BaseException:
+            if started is not None:
+                started.cancel()
+            raise
+        finally:
+            if ahead_held:
+                self._ahead.release()
 
     def decode_gang(self, synsets: Sequence[str], rank: int, world: int) -> bool:
         """Decode this rank's slice of an UPCOMING gang shard into the

@@ -38,9 +38,13 @@ BATCH = 8  # the CPU test mesh is dp=8
 N_IMAGES = 32  # 4 batches a shard: the stream path
 
 #: What the engine-holding thread does, one leaf span each (they tile engine/run).
-PREDICT_LEAVES = ("engine/resolve_paths", "ingest/decode_submit", "ingest/decode_wait", "ingest/stage",
+PREDICT_LEAVES = ("ingest/decode_submit", "ingest/decode_wait", "ingest/stage",
                   "ingest/dispatch", "device/sync_wait", "ingest/collect",
                   "engine/collect")
+#: What a shard does in the ahead slot, before it takes the engine (they tile engine/ahead).
+AHEAD_LEAVES = ("engine/resolve_paths", "engine/ahead_submit")
+#: Every leaf of a shard on its RPC thread, from the wait for the ahead slot to the reply.
+SHARD_LEAVES = ("engine/ahead_wait",) + AHEAD_LEAVES + ("engine/lock_wait",) + PREDICT_LEAVES
 #: The decode thread's top-level spans (they tile first admission .. last exit).
 LOOP_TOP = ("gen/idle", "gen/admit", "gen/prefill", "gen/retire", "gen/step", "gen/deliver")
 
@@ -162,14 +166,15 @@ def predict_spans(backend):
     return {"all": spans, "by_trace": by_trace, "ingest_summary": summary}
 
 
-@pytest.mark.parametrize("name", ("engine/lock_wait", "engine/run", "host/decode") + PREDICT_LEAVES)
+@pytest.mark.parametrize("name", ("engine/ahead", "engine/run", "host/decode") + SHARD_LEAVES)
 def test_predict_span_in_every_shard(predict_spans, name):
     for spans in predict_spans["by_trace"].values():
         assert any(s["name"] == name for s in spans), (name, sorted({s["name"] for s in spans}))
 
 
 def test_predict_lock_wait_sees_the_other_shard(predict_spans):
-    """One shard takes the lock at once; the other waits out the first's whole hold."""
+    """One shard takes the lock at once; the other, ahead of it, waits out
+    the first's whole hold less its own ahead work (a few lookups)."""
     waits, runs = [], []
     for spans in predict_spans["by_trace"].values():
         waits += [s["dur"] for s in spans if s["name"] == "engine/lock_wait"]
@@ -194,43 +199,94 @@ def test_predict_leaves_tile_engine_run(predict_spans):
                    for s in leaves)
 
 
-def test_predict_decode_keeps_the_shards_trace(predict_spans):
-    """host/decode runs on the stage pool and is still the shard's: its
-    trace id, engine/run as parent, another thread."""
+def test_predict_leaves_tile_the_shard_on_its_thread(predict_spans):
+    """The tiling rule covers the ahead span too: from the wait for the ahead
+    slot to the reply, the shard's leaves on its RPC thread cover >= 95% and
+    no two of them overlap."""
     for spans in predict_spans["by_trace"].values():
         run = next(s for s in spans if s["name"] == "engine/run")
+        wait = next(s for s in spans if s["name"] == "engine/ahead_wait")
+        leaves = [s for s in spans if s["name"] in SHARD_LEAVES and s["tid"] == run["tid"]]
+        total, overlap = covered(leaves, wait["t0"], run["t1"])
+        assert overlap < CLOCK_SLACK
+        assert total >= 0.95 * (run["t1"] - wait["t0"]), (total, run["t1"] - wait["t0"])
+
+
+def test_predict_decode_keeps_the_shards_trace(predict_spans):
+    """host/decode runs on the stage pool and is still the shard's: its
+    trace id, another thread, and as parent the span it was started under:
+    engine/ahead for the batches started before the lock, engine/run for
+    the rest."""
+    for spans in predict_spans["by_trace"].values():
+        run = next(s for s in spans if s["name"] == "engine/run")
+        ahead = next(s for s in spans if s["name"] == "engine/ahead")
         decodes = [s for s in spans if s["name"] == "host/decode"]
         assert len(decodes) == N_IMAGES // BATCH
-        assert all(d["parent"] == run["span"] and d["tid"] != run["tid"] for d in decodes)
+        assert all(d["tid"] != run["tid"] for d in decodes)
+        assert sorted(d["parent"] for d in decodes) == sorted(
+            [ahead["span"]] * run["attrs"]["ahead"]
+            + [run["span"]] * (N_IMAGES // BATCH - run["attrs"]["ahead"]))
     # and none is left a root of its own trace
     assert not [s for s in predict_spans["all"]
                 if s["name"] == "host/decode" and s["parent"] is None]
 
 
 def test_predict_parent_edges(predict_spans):
+    """ahead_wait, ahead, lock_wait, run: four children of the shard's span,
+    in that order; the lookup and the ahead submit lie under engine/ahead,
+    the rest under engine/run."""
     for spans in predict_spans["by_trace"].values():
         root = next(s for s in spans if s["name"] == "test/shard")
-        run = next(s for s in spans if s["name"] == "engine/run")
-        wait = next(s for s in spans if s["name"] == "engine/lock_wait")
-        assert run["parent"] == root["span"] and wait["parent"] == root["span"]
-        assert wait["t1"] <= run["t0"] + CLOCK_SLACK
+        order = [next(s for s in spans if s["name"] == name)
+                 for name in ("engine/ahead_wait", "engine/ahead", "engine/lock_wait", "engine/run")]
+        assert all(s["parent"] == root["span"] for s in order)
+        assert all(a["t1"] <= b["t0"] + CLOCK_SLACK for a, b in zip(order, order[1:]))
+        ahead, run = order[1], order[3]
+        assert ahead["attrs"]["n"] == N_IMAGES and ahead["attrs"]["batches"] == N_IMAGES // BATCH
         assert run["attrs"]["n"] == N_IMAGES and run["attrs"]["batches"] == N_IMAGES // BATCH
         for s in spans:
             if s["name"] in PREDICT_LEAVES:
                 assert s["parent"] == run["span"], s["name"]
+            if s["name"] in AHEAD_LEAVES:
+                assert s["parent"] == ahead["span"], s["name"]
         waits = [s for s in spans if s["name"] == "ingest/decode_wait"]
         assert len(waits) == N_IMAGES // BATCH
         assert all(isinstance(w["attrs"]["ready"], bool) for w in waits)
 
 
+def test_predict_run_counts_the_batches_started_ahead(predict_spans):
+    """engine/run carries ``ahead``, the batches whose decode started before
+    the lock (the first ``prefetch`` = 2 of four, whether or not another
+    shard held the engine), and ``ready``, those already decoded when the
+    lock was taken. The shard that waited out the other's hold finds both
+    of its batches decoded (each decode sleeps 50 ms, the hold is longer)."""
+    runs = sorted((next(s for s in spans if s["name"] == "engine/run")
+                   for spans in predict_spans["by_trace"].values()), key=lambda s: s["t0"])
+    assert [r["attrs"]["ahead"] for r in runs] == [2, 2]
+    assert all(0 <= r["attrs"]["ready"] <= 2 for r in runs)
+    assert runs[1]["attrs"]["ready"] == 2
+
+
+@pytest.mark.parametrize("name", ("engine/ahead_wait", "engine/ahead", "engine/resolve_paths"))
+def test_predict_ahead_spans_keep_the_shards_trace(predict_spans, name):
+    """The spans a shard opens before it holds the engine are the shard's:
+    its trace id and thread, one each."""
+    for trace, spans in predict_spans["by_trace"].items():
+        run = next(s for s in spans if s["name"] == "engine/run")
+        (mine,) = [s for s in predict_spans["all"] if s["name"] == name and s["trace"] == trace]
+        assert mine["tid"] == run["tid"]
+    assert len([s for s in predict_spans["all"] if s["name"] == name]) == 2
+
+
 def test_predict_resolve_paths_counts_its_misses(predict_spans):
-    """engine/resolve_paths opens under engine/run on every shard with n and
-    misses; the fixture's first shard listed every directory, so these two
-    list none."""
+    """engine/resolve_paths opens under engine/ahead on every shard with n
+    and misses; the fixture's first shard listed every directory, so these
+    two list none."""
     for spans in predict_spans["by_trace"].values():
         run = next(s for s in spans if s["name"] == "engine/run")
+        ahead = next(s for s in spans if s["name"] == "engine/ahead")
         (resolve,) = [s for s in spans if s["name"] == "engine/resolve_paths"]
-        assert resolve["parent"] == run["span"] and resolve["tid"] == run["tid"]
+        assert resolve["parent"] == ahead["span"] and resolve["tid"] == run["tid"]
         assert resolve["attrs"]["n"] == N_IMAGES and resolve["attrs"]["misses"] == 0
 
 
@@ -246,9 +302,9 @@ def test_resolve_paths_lists_a_directory_once(backend, tracing_on, tmp_path, mon
         with tracer.span("test/shard"):
             be(synsets + synsets[:BATCH])
     spans = wire(tracer.events_wire())
-    run = next(s for s in spans if s["name"] == "engine/run")
+    ahead = next(s for s in spans if s["name"] == "engine/ahead")
     (resolve,) = [s for s in spans if s["name"] == "engine/resolve_paths"]
-    assert resolve["parent"] == run["span"]
+    assert resolve["parent"] == ahead["span"]
     assert resolve["attrs"]["n"] == N_IMAGES + BATCH and resolve["attrs"]["misses"] == misses
 
 
@@ -281,8 +337,8 @@ def test_predict_cpu_time_on_engine_spans(predict_spans):
         run = next(s for s in spans if s["name"] == "engine/run")
         assert 0.0 <= run["attrs"]["cpu_s"] < run["dur"]
         for s in spans:
-            if s["name"] in ("engine/resolve_paths", "ingest/decode_wait",
-                             "ingest/collect", "engine/collect"):
+            if s["name"] in ("engine/ahead", "engine/resolve_paths", "engine/ahead_submit",
+                             "ingest/decode_wait", "ingest/collect", "engine/collect"):
                 assert s["attrs"]["cpu_s"] >= 0.0
 
 
@@ -293,7 +349,9 @@ def test_ingest_decode_is_a_statistic_not_a_span(predict_spans):
 
 
 def test_one_batch_shard_is_not_dark(backend, tracing_on, monkeypatch):
-    """A shard of one batch takes run_paths: decode, forward, collect."""
+    """A shard of one batch takes run_paths: decode, forward, collect. Its
+    lookup moves ahead of the lock like any shard's; its decode does not
+    (run_paths decodes inline), so engine/run says ``ahead`` = 0."""
     be, synsets = backend
     real_load = pp.load_batch
     monkeypatch.setattr(pp, "load_batch",
@@ -302,10 +360,12 @@ def test_one_batch_shard_is_not_dark(backend, tracing_on, monkeypatch):
         be(synsets[:BATCH - 1])
     spans = wire(tracer.events_wire())
     run = next(s for s in spans if s["name"] == "engine/run")
+    ahead = next(s for s in spans if s["name"] == "engine/ahead")
+    assert {s["name"] for s in spans if s["parent"] == ahead["span"]} == {"engine/resolve_paths"}
+    assert run["attrs"]["ahead"] == run["attrs"]["ready"] == 0
     leaves = [s for s in spans if s["parent"] == run["span"]]
     assert {s["name"] for s in leaves} == {
-        "engine/resolve_paths", "host/decode", "ingest/stage", "device/forward",
-        "ingest/collect", "engine/collect"}
+        "host/decode", "ingest/stage", "device/forward", "ingest/collect", "engine/collect"}
     total, overlap = covered(leaves, run["t0"], run["t1"])
     assert overlap < CLOCK_SLACK and total >= 0.9 * run["dur"]
 

@@ -102,10 +102,12 @@ def test_nested_hops_share_one_trace_with_parent_links():
 
 def test_member_predict_children_and_pool_work_stay_in_the_callers_trace(tmp_path):
     """Below rpc/job.predict on a real EngineBackend (docs/OBSERVABILITY.md
-    §1, feeding threads): the wait at the engine lock and engine/run are the
-    handler's children, and the decode handed to the stage pool keeps the
-    caller's trace, engine/run as parent, and the member's lane — it used
-    to be the root of a trace of its own, with no lane."""
+    §1, feeding threads): the wait for the ahead slot, the ahead work, the
+    wait at the engine lock and engine/run are the handler's children, and
+    the decodes handed to the stage pool keep the caller's trace, the span
+    they were started under as parent (engine/ahead: a two-batch shard
+    starts both before the lock), and the member's lane — they used to be
+    the roots of traces of their own, with no lane."""
     from dmlc_tpu.scheduler.worker import EngineBackend, PredictWorker
     from dmlc_tpu.utils import corpus
     import tiny_model  # noqa: F401  (registers "tinynet")
@@ -124,17 +126,20 @@ def test_member_predict_children_and_pool_work_stay_in_the_callers_trace(tmp_pat
     events = tracer.events_wire()
     assert len({e["trace"] for e in events}) == 1
     one = {e["name"]: e for e in events}
-    rpc, run = one["rpc/job.predict"], one["engine/run"]
+    rpc, ahead, run = one["rpc/job.predict"], one["engine/ahead"], one["engine/run"]
     assert rpc["parent"] == one["scheduler/dispatch"]["span"]
-    assert one["engine/lock_wait"]["parent"] == rpc["span"] and run["parent"] == rpc["span"]
-    for leaf in ("engine/resolve_paths", "ingest/decode_submit", "ingest/decode_wait",
-                 "ingest/stage", "ingest/dispatch", "device/sync_wait", "ingest/collect",
-                 "engine/collect"):
+    for child in ("engine/ahead_wait", "engine/ahead", "engine/lock_wait", "engine/run"):
+        assert one[child]["parent"] == rpc["span"], child
+    for leaf in ("engine/resolve_paths", "engine/ahead_submit"):
+        assert one[leaf]["parent"] == ahead["span"] and one[leaf]["lane"] == "member", leaf
+    for leaf in ("ingest/decode_wait", "ingest/stage", "ingest/dispatch", "device/sync_wait",
+                 "ingest/collect", "engine/collect"):
         assert one[leaf]["parent"] == run["span"] and one[leaf]["lane"] == "member", leaf
+    assert run["attrs"]["ahead"] == 2
     decodes = [e for e in events if e["name"] == "host/decode"]
     assert len(decodes) == 2  # 16 images, batches of 8: the stream path
     for d in decodes:
-        assert d["parent"] == run["span"] and d["lane"] == "member" and d["tid"] != run["tid"]
+        assert d["parent"] == ahead["span"] and d["lane"] == "member" and d["tid"] != run["tid"]
 
 
 def test_every_frame_carries_the_same_trace_id():
