@@ -119,6 +119,15 @@ def step_fixed_bytes(cfg: dict, experts_hit: float) -> float:
     return float(BF16) * (dense + z["n_moe"] * experts_hit * expert_params(z))
 
 
+def moe_step_bytes(cfg: dict, experts_hit: float) -> float:
+    """What the routed expert layers of one decode step must read: each held
+    expert that got a row once (``experts_hit`` a layer) and the router with
+    its bias, every expert layer. The shared experts are not routed and are
+    left out."""
+    z = sizes(cfg)
+    return float(BF16) * z["n_moe"] * (experts_hit * expert_params(z) + router_params(z))
+
+
 def step_bytes(cfg: dict, contexts, experts_hit: float) -> float:
     """One decode step for the residents whose cached lengths are ``contexts``."""
     return step_fixed_bytes(cfg, experts_hit) + latent_bytes_per_token(cfg) * float(sum(contexts))

@@ -58,15 +58,17 @@ def test_lm_token_altered_where_it_is_produced_is_not_correct(judge, monkeypatch
     system.import_program()
     from dmlc_tpu.generate import engine as engine_module
 
-    sound_step = engine_module.GenerationEngine.step
+    # The loop calls the halves (``dispatch_step`` / ``collect_step``), never
+    # ``step``: the fault goes where the loop reads a step's tokens (PERF.md
+    # finding PR 34.6).
+    sound = engine_module.GenerationEngine.collect_step
 
-    def altered(self):
-        tokens = sound_step(self)
-        tokens = tokens.copy()
+    def altered(self, run):
+        tokens = sound(self, run).copy()
         tokens[0] = (int(tokens[0]) + 1) % self.vocab    # slot 0 streams a token the model did not pick
         return tokens
 
-    monkeypatch.setattr(engine_module.GenerationEngine, "step", altered)
+    monkeypatch.setattr(engine_module.GenerationEngine, "collect_step", altered)
     result = drive("lm_tiny.json", "lm_tiny_traffic.json", "lm", seed=5, seconds=1.5)
     mean = result["checks"]["logit_gap_mean"]
     assert mean["value"] > mean["limit"], mean     # the committed number is the one that fails

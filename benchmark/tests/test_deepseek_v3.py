@@ -35,8 +35,18 @@ PUBLISHED = {
     "topk_method": "noaux_tc", "v_head_dim": 128, "vocab_size": 128256,
 }
 
-#: The per-layer entries PR 37 listed (the manifest had room for three: PERF.md finding PR 37.6).
-LISTED = ("step_mfu.longform", "step_hbm_roofline.longform", "mla_attention_hbm_roofline.longform")
+#: The per-layer entries that list this cell alone: PR 37's three (the manifest had room for
+#: three: PERF.md finding PR 37.6) and PR 39's four.
+LISTED = ("step_mfu.longform", "step_hbm_roofline.longform", "mla_attention_hbm_roofline.longform",
+          "mla_attention_us_per_call", "latent_mb_read_per_step", "moe_ms_per_step.longform",
+          "moe_hbm_roofline.longform")
+#: Folded quantities (one entry, several cells) that PR 39 gave this cell.
+FOLDED = ("step_ms_p50", "slots_active_mean", "ttft_ms_p50", "prefill_prompts_mean", "step_ahead_mean",
+          "idle_in_step_host_pct", "tokens_resident_mean", "expert_pairs_per_step", "experts_hit_mean",
+          "expert_rows_max_mean", "device_idle_pct", "hbm_peak_gb", "step_operands_ms", "step_call_ms",
+          "prefill_operands_ms", "prefill_call_ms", "idle_in_step_call_pct", "idle_in_prefill_dispatch_pct",
+          "prefill_run_ms_p50", "first_token_in_ms_p50", "first_token_run_ms_p50", "first_token_out_ms_p50",
+          "step_call_cpu_s_mean", "prefill_call_cpu_s_mean", "release_ms")
 
 
 def test_discovery_finds_the_cell_and_everything_it_names():
@@ -68,6 +78,9 @@ def test_discovery_finds_the_cell_and_everything_it_names():
             assert kernels[spec["args"]["kernel"]].EVENTS
     # Every entry the cell reports has its file, and every `.longform` file is listed.
     assert set(entries) <= set(specs)
+    assert set(FOLDED) <= set(entries) and all(len(specs[name]["workloads"]) > 1 for name in FOLDED)
+    # No recurrent state, and a client's gaps say nothing at a 100 ms poll (PERF.md finding PR 39).
+    assert not {"state_mb_mean", "token_gap_ms_p50"} & set(entries)
     assert {name for name in specs if name.endswith(".longform")} <= set(entries)
     # Void since PR 34 (PERF.md Open question 18): not brought to a new cell.
     assert not {"prefill_host_ms.longform", "idle_in_prefill_host_pct.longform"} & set(specs)
@@ -136,6 +149,9 @@ def test_parameter_totals_and_a_steps_bytes_and_operations():
     assert counts.step_bytes(cut, lengths, 16) == pytest.approx(
         counts.step_fixed_bytes(cut, 16) + 64 * 1730 * 27_648)
     assert counts.step_fixed_bytes(cut, 16) - counts.step_fixed_bytes(cut, 15) == 23 * 2 * 4_718_592
+    # The routed layers alone: each hit held expert and the router, 23 layers (PR 37.5: 3.11 GB at 14.27).
+    assert counts.moe_step_bytes(cut, 14.0) == 2 * 23 * (14 * 4_718_592 + 262_272)
+    assert round(counts.moe_step_bytes(cut, 14.27) / 1e9, 2) == 3.11
     # 2 FLOPs a parameter that multiplies, the absorbed form: W_uk and W_uv a head at a time.
     attn = 2048 * 6144 + 2048 * 576 + 2 * 32 * 128 * 512 + 4096 * 2048
     active = 24 * attn + 37_748_736 + 23 * (2048 * 128 + 9_437_184) + 2048 * 128256
@@ -181,7 +197,8 @@ def reader_ctx(tr, records, spans=(), config=None):
 
 NEW_READERS = [("deepseek_v3_step_mfu", {"pattern": "step"}),
                ("deepseek_v3_step_hbm_roofline", {"pattern": "step"}),
-               ("mla_attention_hbm_roofline", {"kernel": "mla_decode_attention"})]
+               ("mla_attention_hbm_roofline", {"kernel": "mla_decode_attention"}),
+               ("deepseek_v3_moe_hbm_roofline", {"kernel": "deepseek_moe_mixer", "pattern": "step"})]
 
 
 @pytest.mark.parametrize("reader,args", NEW_READERS)
@@ -228,6 +245,27 @@ def test_new_step_readers_on_a_recorded_excerpt(recorded):
     assert events
     assert manifest.plugin("readers", "mla_attention_hbm_roofline").read(ctx, kernel="stand_in") == (
         pytest.approx(100.0 * 27_648 * 64 * 1730 / 819e9 / seconds))
+    # The routed layers' share over the same events: the step spans' mean hit, once a step run.
+    moe = manifest.plugin("readers", "deepseek_v3_moe_hbm_roofline").read(
+        ctx, kernel="stand_in", pattern="jit_step")
+    assert moe == pytest.approx(100.0 * counts.moe_step_bytes(ctx.config, 15.0) / 819e9 / seconds)
+
+
+def test_the_expert_layers_events_are_the_decode_steps_alone():
+    moe = re.compile(manifest.plugin("kernels", "deepseek_moe_mixer").EVENTS)
+    decode = ["%fusion.7 = f32[16,64,1536]{2,1,0} fusion(bf16[64,2048]{1,0} %x, bf16[16,2048,1536]{2,1,0} %w13)",
+              "%fusion.8 = bf16[64,2048]{1,0} fusion(f32[16,64,768]{2,1,0} %h, f32[64,17]{1,0} %g)",
+              "%scatter.2 = f32[64,17]{1,0} scatter(f32[64,17]{1,0} %z, s32[64,6]{1,0} %i)",
+              "%sort.1 = (f32[64,128]{1,0}, s32[64,128]{1,0}) sort(f32[64,128]{1,0} %s, s32[64,128]{1,0} %j)",
+              "%convolution.4 = f32[64,128]{1,0} convolution(bf16[64,2048]{1,0} %x, bf16[2048,128]{1,0} %r)"]
+    other = ["%ragged-dot-none = f32[12288,1536]{1,0:T(8,128)S(1)} custom-call(s32[1]{0} %n, "
+             "bf16[16,2048,1536]{2,1,0} %w13), custom_call_target=\"tpu_custom_call\"",
+             "%while.3 = (s32[], bf16[294912,16,640]{2,1,0}, f32[16,64,1536]{2,1,0}) while(s32[] %n)",
+             "%fusion.9 = bf16[64,6720]{1,0} fusion(bf16[64,2048]{1,0} %x, bf16[2048,6720]{1,0} %wq)",
+             "%fusion.10 = f32[64,128]{1,0} fusion(bf16[64,32,128]{2,1,0} %q)",
+             "%fusion.11 = bf16[64,640]{1,0} fusion(bf16[64,2048]{1,0} %x)"]
+    assert all(moe.search(name) for name in decode)
+    assert not any(moe.search(name) for name in other)
 
 
 def test_the_kernels_events_match_its_name_and_nothing_else():

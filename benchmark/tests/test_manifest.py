@@ -1,5 +1,6 @@
 """BENCHMARK.json against the contract's limits, and against the files it names."""
 
+import json
 import re
 
 import pytest
@@ -9,6 +10,8 @@ from benchlib import manifest
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.\-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.\-]{1,16}$")
 SOURCES = {"device_trace", "program_span", "program_counter", "host_clock"}
+#: The most ``per_layer`` entries the driver takes.
+MAX_PER_LAYER = 128
 
 
 @pytest.fixture(scope="module")
@@ -97,3 +100,23 @@ def test_roofline_and_mfu_names(m):
         base = p["name"].split(".")[0]
         if base.endswith("_roofline") or "mfu" in base:
             assert p["unit"] == "%" and p["better"] == "higher" and p["source"] == "device_trace"
+
+
+def test_per_layer_holds_one_entry_a_quantity_and_one_file_an_entry(m):
+    """The manifest does not fill again by copies (PERF.md finding PR 39): at
+    most 128 entries, no two that read the same thing the same way (one entry
+    lists every cell it is read in), every entry one file and every file one
+    entry, the file named by the metric."""
+    assert len(m["per_layer"]) <= MAX_PER_LAYER
+    paths = sorted((manifest.BENCH / "metrics").glob("*.json"))
+    assert [p.stem for p in paths] == [manifest.read_json(p)["name"] for p in paths]
+    specs = manifest.metric_files()
+    assert len(specs) == len(paths)
+    assert sorted(p["name"] for p in m["per_layer"]) == sorted(specs)
+    seen = {}
+    for p in m["per_layer"]:
+        spec = specs[p["name"]]
+        key = (spec["reader"], json.dumps(spec.get("args", {}), sort_keys=True),
+               p["unit"], p["better"], p["source"], p["layer"], p["moves"])
+        assert key not in seen, (p["name"], "reads what", seen.get(key), "reads")
+        seen[key] = p["name"]

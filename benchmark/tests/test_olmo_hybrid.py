@@ -47,7 +47,13 @@ def test_discovery_finds_the_cell_and_everything_it_names():
     assert [e["name"] for e in manifest.wanted(m, CELL, trace=False)] == ["tokens_per_s", "setup_s"]
     specs, readers, kernels = manifest.metric_files(), manifest.plugins("readers"), manifest.plugins("kernels")
     wanted = manifest.wanted(m, CELL, trace=True)
-    assert len(wanted) == 19 and all(e["name"].endswith(".briefs") for e in wanted)
+    # Names, not a count: folded quantities list this cell beside others (PERF.md finding PR 39).
+    names = {e["name"] for e in wanted}
+    assert {"step_mfu.briefs", "step_hbm_roofline.briefs", "deltanet_ms_per_step.briefs",
+            "deltanet_ms_per_prefill.briefs", "deltanet_hbm_roofline.briefs",
+            "paged_attention_us_per_call", "paged_attention_hbm_roofline.briefs",
+            "state_mb_mean", "tokens_resident_mean", "step_ms_p50", "device_idle_pct"} <= names
+    assert {n for n in specs if n.endswith(".briefs")} <= names
     for entry in wanted:
         spec = specs[entry["name"]]
         assert {k: spec[k] for k in entry} == entry
@@ -132,7 +138,7 @@ def reader_ctx(tr, records, config=None):
 NEW_READERS = [("olmo_hybrid_step_mfu", {"pattern": "step"}),
                ("olmo_hybrid_step_hbm_roofline", {"pattern": "step"}),
                ("deltanet_hbm_roofline", {"kernel": "deltanet_mixer", "pattern": "step"}),
-               ("olmo_hybrid_paged_attention_hbm_roofline", {"kernel": "paged_attention"})]
+               ("olmo_hybrid_paged_attention_hbm_roofline", {"kernel": "paged_decode_attention"})]
 
 
 @pytest.mark.parametrize("reader,args", NEW_READERS)
@@ -166,12 +172,12 @@ def test_new_step_readers_on_a_recorded_excerpt(recorded):
     roof = manifest.plugin("readers", "olmo_hybrid_step_hbm_roofline").read(ctx, pattern="jit_step")
     assert roof == pytest.approx(100.0 * counts.step_bytes(ctx.config, [1150] * 32) / 819e9 / step)
     attn = manifest.plugin("readers", "olmo_hybrid_paged_attention_hbm_roofline")
-    seconds, events = recorded.op_seconds(ctx.kernels["paged_attention"].EVENTS)
+    seconds, events = recorded.op_seconds(ctx.kernels["paged_decode_attention"].EVENTS)
     if events:   # the excerpt predates the fused kernel: nothing to read, not 0
-        assert attn.read(ctx, kernel="paged_attention") == pytest.approx(
+        assert attn.read(ctx, kernel="paged_decode_attention") == pytest.approx(
             100.0 * 61440 * 32 * 1150 / 819e9 / seconds)
     else:
-        assert attn.read(ctx, kernel="paged_attention") is None
+        assert attn.read(ctx, kernel="paged_decode_attention") is None
 
 
 def test_event_patterns_tell_the_delta_rule_from_attention_and_the_mlp():
