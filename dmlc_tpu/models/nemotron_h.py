@@ -14,14 +14,10 @@ reaches the family through ``NemotronHFamily.prefill`` / ``.decode``.
 State a slot carries between steps (docs/GENERATE.md, "Two kinds of
 per-slot state"): per ``*`` layer its K/V in pages (the engine's pools);
 per ``M`` layer the last ``conv_kernel - 1`` pre-activation ``xBC`` rows and
-the SSM state ``h`` ``[heads, head_dim, state]`` in float32.
-
-Padded prefill is exact for the recurrence: padding sits at the END, and
-``Delta`` is forced to 0 at padded positions, where ``h_t = exp(0) h_{t-1} +
-0`` carries ``h`` unchanged, so the state after the padded scan IS the state
-after position ``length - 1``; the conv window is rows ``length-3 ..
-length-1``. Prefill runs the chunked form of the scan (``chunk_size``),
-decode the one-step recurrence; tests pin both to the sequential definition.
+the SSM state ``h`` ``[heads, head_dim, state]`` in float32, one array each.
+The Mamba-2 mixer itself, prefill (chunked scan) and decode (one-step
+recurrence), is ``models/mamba2``'s, which ``models/granite_hybrid`` runs
+too; this config hands it ``mamba``.
 
 The multi-token-prediction module of the published model is not built: it
 adds nothing to next-token logits and the slot scheduler yields one token a
@@ -36,10 +32,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from dmlc_tpu.models.mamba2 import Mamba2Dims, mamba_decode, mamba_prefill
+from dmlc_tpu.models.mamba2 import ssd_chunked  # noqa: F401  (the family's scan, as tests reach it)
 from dmlc_tpu.models.seeded_tree import SeededTreeModule
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk
-
-HIGHEST = jax.lax.Precision.HIGHEST
 
 
 @dataclass(frozen=True)
@@ -101,12 +97,10 @@ class NemotronHConfig:
         return self.experts_held if self.experts_held is not None else (0, self.n_routed_experts)
 
     @property
-    def mamba_inner(self) -> int:
-        return self.mamba_num_heads * self.mamba_head_dim
-
-    @property
-    def conv_dim(self) -> int:
-        return self.mamba_inner + 2 * self.n_groups * self.ssm_state_size
+    def mamba(self) -> Mamba2Dims:
+        """What ``models/mamba2`` reads of this config."""
+        return Mamba2Dims(self.mamba_num_heads, self.mamba_head_dim, self.n_groups,
+                          self.ssm_state_size, self.conv_kernel, self.chunk_size, self.norm_eps)
 
     def layers_of(self, kind: str) -> list[int]:
         return [i for i, k in enumerate(self.layer_kinds) if k == kind]
@@ -121,7 +115,8 @@ def param_shapes(cfg: NemotronHConfig) -> dict:
     """Nested {name: shape} of the family's parameters. No bias anywhere
     but the conv's."""
     d, lat = cfg.hidden_size, cfg.moe_latent_size
-    inner, heads = cfg.mamba_inner, cfg.mamba_num_heads
+    dims = cfg.mamba
+    inner, heads = dims.inner, dims.num_heads
     q_dim = cfg.num_attention_heads * cfg.head_dim
     kv_dim = cfg.num_key_value_heads * cfg.head_dim
     held = cfg.held[1]
@@ -130,8 +125,8 @@ def param_shapes(cfg: NemotronHConfig) -> dict:
         layer: dict = {"norm": {"scale": (d,)}}
         if kind == "M":
             layer["mamba"] = {
-                "in_proj": {"kernel": (d, inner + cfg.conv_dim + heads)},   # [z | xBC | dt]
-                "conv": {"kernel": (cfg.conv_kernel, cfg.conv_dim), "bias": (cfg.conv_dim,)},
+                "in_proj": {"kernel": (d, inner + dims.conv_dim + heads)},   # [z | xBC | dt]
+                "conv": {"kernel": (cfg.conv_kernel, dims.conv_dim), "bias": (dims.conv_dim,)},
                 "dt_bias": (heads,), "A_log": (heads,), "D": (heads,),
                 "norm": {"scale": (inner,)},
                 "out_proj": {"kernel": (inner, d)},
@@ -202,134 +197,6 @@ def rms_norm(x: Any, scale: Any, eps: float) -> Any:
 
 def _relu2(x: Any) -> Any:
     return jnp.square(jax.nn.relu(x))
-
-
-def _gated_group_norm(y: Any, z: Any, scale: Any, groups: int, eps: float) -> Any:
-    """``RMSNorm over each of `groups` slices of ( y * silu(z) )``: the gate
-    is applied before the grouped norm. y, z: [..., inner] float32."""
-    g = y * jax.nn.silu(z)
-    shaped = g.reshape(*g.shape[:-1], groups, g.shape[-1] // groups)
-    shaped = shaped * jax.lax.rsqrt(jnp.mean(jnp.square(shaped), axis=-1, keepdims=True) + eps)
-    return shaped.reshape(g.shape) * scale.astype(jnp.float32)
-
-
-def _mamba_split(cfg: NemotronHConfig, zxbcdt: Any) -> tuple[Any, Any, Any]:
-    inner = cfg.mamba_inner
-    return (zxbcdt[..., :inner], zxbcdt[..., inner:inner + cfg.conv_dim],
-            zxbcdt[..., inner + cfg.conv_dim:])
-
-
-def _xbc_split(cfg: NemotronHConfig, xbc: Any) -> tuple[Any, Any, Any]:
-    """Activated ``xBC`` -> xs [..., H, P], B and C [..., G, N], float32."""
-    inner, gn = cfg.mamba_inner, cfg.n_groups * cfg.ssm_state_size
-    lead = xbc.shape[:-1]
-    xs = xbc[..., :inner].reshape(*lead, cfg.mamba_num_heads, cfg.mamba_head_dim)
-    b = xbc[..., inner:inner + gn].reshape(*lead, cfg.n_groups, cfg.ssm_state_size)
-    c = xbc[..., inner + gn:].reshape(*lead, cfg.n_groups, cfg.ssm_state_size)
-    return xs.astype(jnp.float32), b.astype(jnp.float32), c.astype(jnp.float32)
-
-
-def _dt_and_decay(p: Any, dt_raw: Any) -> tuple[Any, Any]:
-    """``Delta = softplus(dt + dt_bias)`` (no upper clamp) and ``A = -exp(A_log)``."""
-    dt = jax.nn.softplus(dt_raw.astype(jnp.float32) + p["dt_bias"].astype(jnp.float32))
-    return dt, -jnp.exp(p["A_log"].astype(jnp.float32))
-
-
-# ---------------------------------------------------------------------------
-# M: Mamba-2
-# ---------------------------------------------------------------------------
-
-
-def ssd_chunked(xs: Any, dt: Any, a: Any, b: Any, c: Any, chunk: int) -> tuple[Any, Any]:
-    """The chunked form of ``h_t = exp(dt_t a) h_{t-1} + dt_t xs_t (x) B_t``,
-    ``y_t = h_t C_t`` from ``h_{-1} = 0``. xs [S, H, P]; dt [S, H]; a [H];
-    b, c [S, G, N], all float32; S a multiple of ``chunk``. Returns
-    (y [S, H, P], h after the last position [H, P, N])."""
-    s, heads, p_dim = xs.shape
-    groups, n = b.shape[1], b.shape[2]
-    per = heads // groups
-    nc = s // chunk
-    xs = xs.reshape(nc, chunk, groups, per, p_dim)
-    dt = dt.reshape(nc, chunk, groups, per)
-    b = b.reshape(nc, chunk, groups, n)
-    c = c.reshape(nc, chunk, groups, n)
-    da = dt * a.reshape(groups, per)                          # [c, L, G, R], <= 0
-    cum = jnp.cumsum(da, axis=1)                              # inclusive
-    dtx = dt[..., None] * xs                                  # [c, L, G, R, P]
-    # Inside a chunk: position l reads s <= l with decay exp(cum_l - cum_s).
-    seg = cum[:, :, None] - cum[:, None, :]                   # [c, L(l), L(s), G, R]
-    causal = jnp.arange(chunk)[:, None] >= jnp.arange(chunk)[None, :]
-    decay = jnp.where(causal[None, :, :, None, None], jnp.exp(jnp.minimum(seg, 0.0)), 0.0)
-    cb = jnp.einsum("clgn,csgn->clsg", c, b, precision=HIGHEST)
-    y_diag = jnp.einsum("clsgr,csgrp->clgrp", cb[..., None] * decay, dtx, precision=HIGHEST)
-    # What each chunk adds to the state at its own end ...
-    to_end = jnp.exp(cum[:, -1:] - cum)                       # [c, L, G, R]
-    states = jnp.einsum("clgr,clgrp,clgn->cgrpn", to_end, dtx, b, precision=HIGHEST)
-    # ... carried across chunks by the one sequential part of the scan.
-    chunk_decay = jnp.exp(cum[:, -1])                         # [c, G, R]
-
-    def carry(h, inp):
-        st, dec = inp
-        return dec[..., None, None] * h + st, h
-
-    h0 = jnp.zeros((groups, per, p_dim, n), jnp.float32)
-    h_last, h_before = jax.lax.scan(carry, h0, (states, chunk_decay))
-    y_off = jnp.einsum("clgn,cgrpn,clgr->clgrp", c, h_before, jnp.exp(cum), precision=HIGHEST)
-    y = (y_diag + y_off).reshape(s, heads, p_dim)
-    return y, h_last.reshape(heads, p_dim, n)
-
-
-def mamba_prefill(p: Any, cfg: NemotronHConfig, u: Any, length: Any) -> tuple[Any, Any, Any]:
-    """One prompt, padded at the end. u: [S, D] (normed). Returns
-    (out [S, D], conv window [K-1, conv_dim] = the pre-activation xBC rows
-    ``length-K+1 .. length-1``, h after position ``length - 1`` [H, P, N])."""
-    s = u.shape[0]
-    k = cfg.conv_kernel
-    z, xbc, dt_raw = _mamba_split(cfg, u @ p["in_proj"]["kernel"])
-    padded = jnp.concatenate([jnp.zeros((k - 1, xbc.shape[1]), xbc.dtype), xbc], axis=0)
-    window = jax.lax.dynamic_slice_in_dim(padded, length, k - 1, axis=0)
-    w = p["conv"]["kernel"].astype(jnp.float32)
-    conv = sum(padded[j:j + s].astype(jnp.float32) * w[j] for j in range(k))
-    act = jax.nn.silu(conv + p["conv"]["bias"].astype(jnp.float32)).astype(u.dtype)
-    xs, b, c = _xbc_split(cfg, act)
-    dt, a = _dt_and_decay(p, dt_raw)
-    dt = jnp.where((jnp.arange(s) < length)[:, None], dt, 0.0)   # padding carries h unchanged
-    pad = -s % cfg.chunk_size
-    if pad:
-        xs, dt, b, c = (jnp.pad(t, [(0, pad)] + [(0, 0)] * (t.ndim - 1)) for t in (xs, dt, b, c))
-    y, h_last = ssd_chunked(xs, dt, a, b, c, cfg.chunk_size)
-    y = y[:s] + p["D"].astype(jnp.float32)[None, :, None] * xs[:s]
-    y = _gated_group_norm(y.reshape(s, -1), z.astype(jnp.float32), p["norm"]["scale"],
-                          cfg.n_groups, cfg.norm_eps)
-    return y.astype(u.dtype) @ p["out_proj"]["kernel"], window, h_last
-
-
-def mamba_decode(p: Any, cfg: NemotronHConfig, u: Any, window: Any, h: Any,
-                 active: Any) -> tuple[Any, Any, Any]:
-    """One token per slot. u: [B, D]; window [B, K-1, conv_dim]; h
-    [B, H, P, N] float32; rows with ``active`` false keep their state."""
-    bsz = u.shape[0]
-    z, xbc, dt_raw = _mamba_split(cfg, u @ p["in_proj"]["kernel"])
-    full = jnp.concatenate([window, xbc[:, None].astype(window.dtype)], axis=1)   # [B, K, C]
-    w = p["conv"]["kernel"].astype(jnp.float32)
-    conv = jnp.sum(full.astype(jnp.float32) * w[None], axis=1)
-    act = jax.nn.silu(conv + p["conv"]["bias"].astype(jnp.float32)).astype(u.dtype)
-    xs, b, c = _xbc_split(cfg, act)                                   # [B,H,P], [B,G,N]
-    dt, a = _dt_and_decay(p, dt_raw)                                  # [B,H], [H]
-    groups, per = cfg.n_groups, cfg.mamba_num_heads // cfg.n_groups
-    hg = h.reshape(bsz, groups, per, cfg.mamba_head_dim, cfg.ssm_state_size)
-    decay = jnp.exp(dt * a).reshape(bsz, groups, per, 1, 1)
-    dtx = (dt[..., None] * xs).reshape(bsz, groups, per, cfg.mamba_head_dim, 1)
-    h_new = decay * hg + dtx * b[:, :, None, None, :]
-    # Elementwise in float32 on purpose: a dot would round h to the MXU's inputs.
-    y = jnp.sum(h_new * c[:, :, None, None, :], axis=-1).reshape(bsz, cfg.mamba_num_heads, -1)
-    y = y + p["D"].astype(jnp.float32)[None, :, None] * xs
-    y = _gated_group_norm(y.reshape(bsz, -1), z.astype(jnp.float32), p["norm"]["scale"],
-                          cfg.n_groups, cfg.norm_eps)
-    keep = active[:, None, None]
-    return (y.astype(u.dtype) @ p["out_proj"]["kernel"],
-            jnp.where(keep, full[:, 1:], window),
-            jnp.where(keep[..., None], h_new.reshape(h.shape), h))
 
 
 # ---------------------------------------------------------------------------
@@ -406,7 +273,7 @@ class NemotronHFamily:
         cfg = self.config
         n = len(self._m_index)
         return {
-            "conv": [((max_slots, cfg.conv_kernel - 1, cfg.conv_dim), self.dtype)] * n,
+            "conv": [((max_slots, cfg.conv_kernel - 1, cfg.mamba.conv_dim), self.dtype)] * n,
             "ssm": [((max_slots, cfg.mamba_num_heads, cfg.mamba_head_dim,
                       cfg.ssm_state_size), jnp.float32)] * n,
         }
@@ -424,7 +291,7 @@ class NemotronHFamily:
             u = rms_norm(x, p["norm"]["scale"], cfg.norm_eps)
             if kind == "M":
                 with jax.named_scope("mamba"):
-                    out, window, h = mamba_prefill(p["mamba"], cfg, u, length)
+                    out, window, h = mamba_prefill(p["mamba"], cfg.mamba, u, length)
                 m = self._m_index[i]
                 conv[m] = conv[m].at[slot].set(window.astype(conv[m].dtype))
                 ssm[m] = ssm[m].at[slot].set(h)
@@ -456,7 +323,8 @@ class NemotronHFamily:
             if kind == "M":
                 m = self._m_index[i]
                 with jax.named_scope("mamba"):
-                    out, conv[m], ssm[m] = mamba_decode(p["mamba"], cfg, u, conv[m], ssm[m], active)
+                    out, conv[m], ssm[m] = mamba_decode(p["mamba"], cfg.mamba, u, conv[m], ssm[m],
+                                                       active)
             elif kind == "*":
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, u)

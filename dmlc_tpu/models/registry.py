@@ -311,3 +311,11 @@ register_lfm2_moe("lfm2_moe_tiny", LFM2_MOE_TINY)
 from dmlc_tpu.models.deepseek_v3 import DEEPSEEK_V3_TINY, register_deepseek_v3  # noqa: E402
 
 register_deepseek_v3("deepseek_v3_tiny", DEEPSEEK_V3_TINY)
+
+# The Granite-4.0-H family's CPU-test preset (Mamba-2 and grouped-query
+# attention, two branches a layer under muP multipliers, a tied head); real
+# sizes are registered by whoever serves them
+# (``models/granite_hybrid.register_granite_hybrid``).
+from dmlc_tpu.models.granite_hybrid import GRANITE_HYBRID_TINY, register_granite_hybrid  # noqa: E402
+
+register_granite_hybrid("granite_hybrid_tiny", GRANITE_HYBRID_TINY)

@@ -7,11 +7,12 @@ PARENT of a change that means to keep the programs)."""
 
 import hashlib
 
-#: The families behind the engine's seam before ``models/deepseek_v3``, each
-#: at its CPU preset: (registry name, slots) at two batch sizes, one of them
-#: a form of its own for the expert layer (``t k >= 2 n_experts`` or not).
+#: The families behind the engine's seam, each at its CPU preset: (registry
+#: name, slots) at two batch sizes, one of them a form of its own for the
+#: expert layer (``t k >= 2 n_experts`` or not).
 CASES = [(model, slots) for model in ("lm_small", "nemotron_h_tiny", "olmo_hybrid_tiny",
-                                      "lfm2_moe_tiny") for slots in (4, 24)]
+                                      "lfm2_moe_tiny", "deepseek_v3_tiny", "granite_hybrid_tiny")
+         for slots in (4, 24)]
 
 
 def program_texts(model: str, slots: int, use_pallas: bool) -> dict:

@@ -1,11 +1,13 @@
-"""The programs of the four families that ``models/deepseek_v3`` did not
-join are, byte for byte, what the parent of PR 37 lowered: the engine's step
+"""The engine's programs of every family, byte for byte, as pinned: the step
 and prefill of ``models/lm.TransformerFamily``, ``nemotron_h``, ``olmo_hybrid``
-and ``lfm2_moe`` at their CPU presets, at two batch sizes, through the gather
-path (``xla``) and through the fused attention kernel in the interpreter
-(``kernel``: the text then holds the kernel's whole traced body, the DMA
-pipeline the latent form now shares included). The digests are of
-``as_text()`` on the parent commit (``python tests/lowered_programs.py``
+and ``lfm2_moe`` as commit 37711bd lowered them, ``deepseek_v3`` as commit
+0f7afdd did, and ``granite_hybrid`` as it was added (the Mamba-2 mixer it
+shares with ``nemotron_h`` moved to ``models/mamba2`` with it, and
+``nemotron_h``'s pins held), at their CPU presets, at two batch sizes,
+through the gather path (``xla``) and through the fused attention kernel in
+the interpreter (``kernel``: the text then holds the kernel's whole traced
+body, the DMA pipeline the latent form shares included). The digests are of
+``as_text()`` on the commit named (``python tests/lowered_programs.py``
 there); a change that means to alter one of these programs prints the table
 anew and says so. The kernel's Mosaic module at the cells' own shapes is
 pinned in ``tests/test_tpu_compile.py`` (the one file that describes a chip).
@@ -17,7 +19,7 @@ pytest.importorskip("jax")
 
 import lowered_programs  # noqa: E402
 
-#: SHA-256 (16 hex digits) of ``as_text()``, parent of PR 37 (commit 37711bd).
+#: SHA-256 (16 hex digits) of ``as_text()``: commit 37711bd unless marked.
 PINS = {
     "xla": {
         "lm_small/4/step": "e8892ebec584d49e",
@@ -36,6 +38,16 @@ PINS = {
         "lfm2_moe_tiny/4/prefill": "e4dd3ec204eb7e42",
         "lfm2_moe_tiny/24/step": "60093742fadd884a",
         "lfm2_moe_tiny/24/prefill": "d7e9c7555e9be45c",
+        # commit 0f7afdd: the fifth family, unpinned before
+        "deepseek_v3_tiny/4/step": "3ba3d5ac434c15f5",
+        "deepseek_v3_tiny/4/prefill": "69f7a10ef36d20ae",
+        "deepseek_v3_tiny/24/step": "604500b85b7e4374",
+        "deepseek_v3_tiny/24/prefill": "0d8083f8212f667c",
+        # the sixth family, as added
+        "granite_hybrid_tiny/4/step": "d2189d67156d8ae1",
+        "granite_hybrid_tiny/4/prefill": "4d8dae9410dc2c6c",
+        "granite_hybrid_tiny/24/step": "11cb5678e045f061",
+        "granite_hybrid_tiny/24/prefill": "916a38c35b6bc648",
     },
     "kernel": {
         "lm_small/4/step": "10f1668281bb1e17",
@@ -54,6 +66,16 @@ PINS = {
         "lfm2_moe_tiny/4/prefill": "e4dd3ec204eb7e42",
         "lfm2_moe_tiny/24/step": "0b6142ed92de0fa9",
         "lfm2_moe_tiny/24/prefill": "d7e9c7555e9be45c",
+        # commit 0f7afdd: the fifth family, unpinned before
+        "deepseek_v3_tiny/4/step": "97d7a70ba93a4d96",
+        "deepseek_v3_tiny/4/prefill": "69f7a10ef36d20ae",
+        "deepseek_v3_tiny/24/step": "36ca592c09fd3b2c",
+        "deepseek_v3_tiny/24/prefill": "0d8083f8212f667c",
+        # the sixth family, as added
+        "granite_hybrid_tiny/4/step": "74c8b78cd03000f3",
+        "granite_hybrid_tiny/4/prefill": "4d8dae9410dc2c6c",
+        "granite_hybrid_tiny/24/step": "76b9242a32209cb0",
+        "granite_hybrid_tiny/24/prefill": "916a38c35b6bc648",
     },
 }
 

@@ -34,6 +34,14 @@ the latent kernel through Mosaic 24 times a step, and beside 6.31 GB of
 weights temporaries of 46 MB a step and 411 MB a prefill. A 576-lane row is
 refused by the chip's compiler (and would occupy 640 lanes all the same).
 
+The fifth is ``models/granite_hybrid`` at ITS cell's sizes (the whole model,
+40 layers, 64 slots, 10,752 pages, prompts padded to 2,048): the recurrent
+state of 36 mamba layers as ONE array per kind, ``[64, 36, 3, 4352]`` and
+``[64, 36, 64, 64, 128]`` float32 (4.89 GB), each layer's ``[:, m]`` written
+back in place: both arrays and both pools aliased, no copy of either array,
+a step's temporaries 50 MB and a prefill's 1.28 GB (its 32 heads' float32
+scores over 2,048 rows) beside 6.38 GB of weights.
+
 The last pins the K/V form of the fused attention kernel at the accepted
 cells' shapes to what the parent of PR 37 lowered it to: the Mosaic module
 itself, read back from the custom call without its source locations.
@@ -203,6 +211,54 @@ def test_rotary_gated_expert_family_at_its_cells_sizes(one_chip, compiled_for_th
     finally:
         registry._REGISTRY.pop(spec.name, None)
 
+
+def test_stacked_state_family_at_its_cells_sizes(one_chip, compiled_for_the_chip):
+    import json
+    import re
+
+    import jax.numpy as jnp
+    import plain_reference
+
+    from dmlc_tpu.generate.engine import GenerationEngine
+    from dmlc_tpu.models import granite_hybrid as gh
+    from dmlc_tpu.models import registry
+
+    cfg = json.loads(
+        (plain_reference.REPO / "benchmark" / "configs" / "granite-4-h-micro.json").read_text())
+    cluster = cfg["cluster"]
+    slots, num_pages, dtype = cluster["gen_max_slots"], cluster["gen_num_pages"], jnp.bfloat16
+    assert (slots, num_pages, cluster["gen_max_prefill"]) == (64, 10752, 2048)
+    config = gh.GraniteHybridConfig.from_published(cfg, max_len=cfg["serving_positions"])
+    spec = gh.register_granite_hybrid("granite_geometry_lm", config)
+    try:
+        engine = GenerationEngine(spec.name, variables={}, dtype=dtype, max_slots=slots,
+                                  page_size=cluster["gen_page_size"], num_pages=2,
+                                  max_prefill=cluster["gen_max_prefill"], use_pallas=True)
+        variables = jax.tree_util.tree_map(
+            lambda a: jax.ShapeDtypeStruct(a.shape, dtype),
+            jax.eval_shape(lambda: spec.init_params(jax.random.PRNGKey(0), dtype=dtype)[1]))
+        pool = jax.ShapeDtypeStruct((4 * num_pages, 16, 512), dtype)     # four K/V layers
+        args = chip_smoke.abstract_program_args(engine, variables=variables, pool=pool,
+                                                sharding=one_chip)
+        pool_bytes = 4 * num_pages * 16 * 512 * 2
+        state_bytes = slots * 36 * (64 * 64 * 128 * 4 + 3 * 4352 * 2)
+        assert engine.state.nbytes == state_bytes == 4_892_000_256
+        weights = 2 * 3_191_396_096
+        for name, program in (("step", engine._step), ("prefill", engine._prefill)):
+            compiled = program.lower(*args[name]).compile()
+            memory = compiled.memory_analysis()
+            # Both pools and both stacked arrays updated where they are.
+            assert memory.alias_size_in_bytes == 2 * pool_bytes + state_bytes, name
+            assert weights < memory.argument_size_in_bytes < weights + 2 * pool_bytes + state_bytes + 2 ** 24
+            text = compiled.as_text()
+            assert not re.search(r"copy(-start)?(\.\d+)? = [^ ]*\[64,36,", text), name
+            assert (chip_smoke.MOSAIC_CALL in text) == (name == "step"), name
+            # No copy of a state array: a step keeps 50 MB, a prefill its float32
+            # scores and its 2,048 rows at the widest product (1.28 GB).
+            limit = 2 ** 27 if name == "step" else 2 ** 31
+            assert memory.temp_size_in_bytes < limit, (name, memory.temp_size_in_bytes)
+    finally:
+        registry._REGISTRY.pop(spec.name, None)
 
 
 def _latent_engine(name, cfg, row_lanes=None):
