@@ -212,11 +212,35 @@ def test_the_pattern_tells_the_mixer_from_attention_the_mlp_and_the_prefill():
     assert not any(mixer.search(name) for name in theirs)
 
 
+def test_the_window_credits_each_token_where_it_arrives():
+    delivered_work = manifest.plugin("drivers", "granite_hybrid").delivered_work
+
+    def rec(prompt, token_t, error=None):
+        return {"prompt": [0] * prompt, "token_t": token_t, "error": error}
+
+    records = [
+        rec(100, [1.0, 2.0, 3.0]),            # prefilled before the window: 2 tokens inside
+        rec(200, [4.0, 5.0, 11.0, 12.0]),     # prefilled inside, ends after: 200 + 2
+        rec(300, [10.0]),                     # first token at the closing instant: 300 + 1
+        rec(400, [10.5]),                     # after the window: nothing
+        rec(500, [3.0, 4.0], error="RpcError: lost"),  # failed: nothing
+        rec(600, []),                         # nothing arrived
+    ]
+    assert delivered_work(records, 1.5, 10.0) == 2 + 202 + 301
+    # A window that holds every arrival credits each request whole, as drivers/lm does.
+    whole = [r for r in records if not r["error"] and r["token_t"]]
+    assert delivered_work(records, 0.0, 20.0) == sum(len(r["prompt"]) + len(r["token_t"]) for r in whole)
+
+
 def test_granite_hybrid_sound_run_is_correct_and_its_control_is_not():
     judge = load_run().judge
     result = drive("granite_hybrid_tiny.json", "lm_tiny_traffic.json", "granite_hybrid",
                    seed=2**31 + 9, seconds=1.5, control="fp8")
     assert result["failed"] == 0
+    # The rate is the work that arrived inside the window, whole requests or not.
+    window = result["readings"]["window"]
+    work = result["end_to_end"]["tokens_per_s"] * window.seconds
+    assert 0 < work <= sum(len(r["prompt"]) + len(r["token_t"]) for r in result["readings"]["records"])
     assert judge(result["checks"]), result["checks"]
     program, control = result["checks"]["logit_gap_mean"], result["control_checks"]["logit_gap_mean"]
     assert control["value"] > program["limit"] >= program["value"], (control, program)
