@@ -621,7 +621,9 @@ MOSAIC_CALL = "tpu_custom_call"
 #: olmo-hybrid-7b's (30 x 128, multi-head: pool rows of 3,840 lanes) and
 #: lfm2-8b-a1b's (32 query heads on 8 KV heads of 64: rows of 512 lanes), and
 #: its latent form at kanana-2-30b-a3b's (32 heads against one row of 640
-#: lanes a position, the weighted sum over the first 512).
+#: lanes a position, the weighted sum over the first 512); and the prefill's
+#: grouped expert matmul at nemotron3-super's expert widths (pair rows, k, n,
+#: experts), a quarter of the rows past the last group.
 KERNEL_SHAPES = {
     "images": (256, 224, 224, 3),
     "logits": (256, 1000),
@@ -637,6 +639,7 @@ KERNEL_SHAPES = {
     "paged_latent": (32, 640, 512),   # (heads, lanes of a stored row, value lanes)
     "paged_slots": 24,
     "paged_table": 64,           # pages a slot's table names, 16 tokens each
+    "grouped": (2048, 1024, 2688, 16),
 }
 
 
@@ -700,8 +703,11 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
     import jax
     import jax.numpy as jnp
 
+    import numpy as np
+
     from dmlc_tpu.ops import pallas_kernels as pk
     from dmlc_tpu.ops import preprocess as pp
+    from dmlc_tpu.ops.grouped_matmul import grouped_matmul
     from dmlc_tpu.ops.ragged_decode import (
         gather_kv_pages,
         gather_latent_pages,
@@ -789,6 +795,27 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
 
         return fused, args, reference, 1e-4
 
+    def grouped_case(m, k, n, count):
+        """Groups of random sizes over three quarters of the rows, the first
+        and the last empty; rows outside every group are zeroed on both sides
+        (the kernel leaves them as it found them)."""
+        sizes = np.random.default_rng(11).multinomial(3 * m // 4, np.ones(count - 2) / (count - 2))
+        sizes = jnp.asarray([0, *sizes, 0], jnp.int32)
+        k_lhs, k_rhs = jax.random.split(jax.random.PRNGKey(9))
+        args = (jax.random.normal(k_lhs, (m, k), jnp.bfloat16),
+                (jax.random.normal(k_rhs, (count, k, n), jnp.float32) * 0.05).astype(jnp.bfloat16),
+                sizes)
+        in_group = (jnp.arange(m) < 3 * m // 4)[:, None]
+
+        def fused(lhs, rhs, sizes):
+            return jnp.where(in_group, grouped_matmul(lhs, rhs, sizes), 0.0)
+
+        def reference(lhs, rhs, sizes):
+            return jnp.where(in_group, jax.lax.ragged_dot(
+                lhs, rhs, sizes, preferred_element_type=jnp.float32), 0.0)
+
+        return fused, args, reference, 1e-4
+
     n = len(devices)
     mesh = make_mesh({"sp": n}, devices=devices)
     sp_args = qkv(shapes["sp_s_local"] * n)
@@ -812,6 +839,7 @@ def kernels_phase(devices, shapes: dict = KERNEL_SHAPES) -> dict:
         ("paged_attention_mha_30x128_bf16", *paged_case(*shapes["paged_mha_wide"])),
         ("paged_attention_gqa_32on8x64_bf16", *paged_case(*shapes["paged_gqa_64"])),
         ("paged_latent_attention_32x640_bf16", *latent_case(*shapes["paged_latent"])),
+        ("grouped_expert_matmul_bf16", *grouped_case(*shapes["grouped"])),
         (f"ring_flash_sp{n}",
          lambda q, k, v: ring_flash_attention(q, k, v, mesh, causal=True),
          sp_args, causal_ref, BF16_TOL),

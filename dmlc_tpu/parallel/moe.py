@@ -38,6 +38,8 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from dmlc_tpu.ops.grouped_matmul import grouped_matmul
+
 
 def top1_routing(logits: jax.Array, capacity: int):
     """GShard-style top-1 routing with per-expert capacity.
@@ -227,10 +229,11 @@ def held_experts_ffn(x, w1, w2, idx, gates, held: tuple[int, int], rows=None, *,
     two to each other):
 
     - *grouped*: the token-expert pairs are sorted by expert and each held
-      expert multiplies just its own rows (``jax.lax.ragged_dot``: a grouped
-      matmul on the TPU); pairs of experts that live elsewhere sort last,
-      belong to no group and add nothing. Its cost follows the experts hit
-      and the row tiles: right for a prefill's hundreds of rows.
+      expert multiplies just its own rows (``ops/grouped_matmul``: a Pallas
+      grouped matmul that reads each hit expert's matrix from HBM once, in
+      whole-expert blocks); pairs of experts that live elsewhere sort last,
+      belong to no group and add nothing. Its cost follows the experts hit:
+      right for a prefill's hundreds of rows.
     - *dense*: every held expert multiplies every row and a ``[T, count]``
       gate matrix (0 where the expert was not chosen) weighs the results.
       It reads each expert once at full bandwidth whatever the routing:
@@ -262,10 +265,9 @@ def held_experts_ffn(x, w1, w2, idx, gates, held: tuple[int, int], rows=None, *,
         return jnp.einsum("etd,te->td", y, weight), group_sizes
     key = key.reshape(t * k)
     order = jnp.argsort(key, stable=True)
-    h = jax.lax.ragged_dot(x[order // k], w1, group_sizes,
-                           preferred_element_type=jnp.float32)
+    h = grouped_matmul(x[order // k], w1, group_sizes)
     h = activation(h).astype(x.dtype)
-    y = jax.lax.ragged_dot(h, w2, group_sizes, preferred_element_type=jnp.float32)
+    y = grouped_matmul(h, w2, group_sizes)
     # Rows past the last group are whatever the kernel left there: zeroed here.
     in_a_group = (key[order] < count)[:, None]
     y = jnp.where(in_a_group, y * gates.reshape(t * k)[order][:, None], 0.0)

@@ -90,7 +90,8 @@ def test_kernels_phase_at_tiny_shapes():
         attn_heads=2, attn_dh=16, s_resident=128, s_streamed=256, sp_s_local=64,
         paged_mha=(4, 4, 8), paged_gqa=(4, 2, 16), paged_mha_wide=(6, 6, 16),
         paged_gqa_64=(8, 2, 8), paged_latent=(4, 128, 32), paged_slots=5, paged_table=3,
+        grouped=(300, 64, 200, 6),
     )
     out = chip_smoke.kernels_phase(jax.devices()[:2], shapes)
-    assert len(out) == 12
+    assert len(out) == 13
     assert not any(case["mosaic"] for case in out.values())

@@ -386,8 +386,10 @@ class TestGatedExperts:
                                                   n_experts=experts, activation=lf.gated_expert)[0]
             return str(jax.make_jaxpr(fn)(jnp.zeros((t, 8)), jnp.zeros((t, k), jnp.int32),
                                           jnp.zeros((t, k))))
-        assert "ragged_dot" not in form(64, 4, 32)
-        assert "ragged_dot" in form(1024, 4, 32) and "ragged_dot" in form(8, 4, 32)
+        kernel = "_grouped_expert_matmul"
+        assert kernel not in form(64, 4, 32)
+        assert kernel in form(1024, 4, 32) and kernel in form(8, 4, 32)
+        assert "ragged_dot" not in form(1024, 4, 32)
 
     def test_a_config_without_the_expert_bias_is_served_as_a_zero_bias(self, variables):
         cfg = lf.Lfm2MoeConfig.from_published({**ref_cfg(), "use_expert_bias": False})

@@ -11,6 +11,14 @@ body, the DMA pipeline the latent form shares included). The digests are of
 there); a change that means to alter one of these programs prints the table
 anew and says so. The kernel's Mosaic module at the cells' own shapes is
 pinned in ``tests/test_tpu_compile.py`` (the one file that describes a chip).
+
+The three expert families' steps at 4 slots are the programs at these
+presets whose expert layer takes the grouped form (4 rows: ``t k < 2
+n_experts``); their pins are those of the grouped matmul kernel
+(``ops/grouped_matmul``) that replaced ``jax.lax.ragged_dot`` there. Every
+other pin, the prefills at 32 rows (the dense form) and every step at 24
+slots (the dense form, as every cell's 64-slot step) among them, is as the
+commits above lowered it.
 """
 
 import pytest
@@ -26,7 +34,7 @@ PINS = {
         "lm_small/4/prefill": "6cf512dbaf5b84a1",
         "lm_small/24/step": "31ce5fe05ba1eabf",
         "lm_small/24/prefill": "36c9f74a91abc48c",
-        "nemotron_h_tiny/4/step": "05e64486111f7148",
+        "nemotron_h_tiny/4/step": "abc490f3457fce56",  # grouped form: the grouped matmul kernel
         "nemotron_h_tiny/4/prefill": "023b045e0ca63ed5",
         "nemotron_h_tiny/24/step": "d48977557485aba7",
         "nemotron_h_tiny/24/prefill": "1e77c55890c38cb0",
@@ -34,12 +42,12 @@ PINS = {
         "olmo_hybrid_tiny/4/prefill": "ac8312944b0dac3e",
         "olmo_hybrid_tiny/24/step": "c1abd4b8d0b9ff16",
         "olmo_hybrid_tiny/24/prefill": "eb39254d1d789711",
-        "lfm2_moe_tiny/4/step": "8b1ba2499e8e30c2",
+        "lfm2_moe_tiny/4/step": "a1c4288c8b139e3d",  # grouped form: the grouped matmul kernel
         "lfm2_moe_tiny/4/prefill": "e4dd3ec204eb7e42",
         "lfm2_moe_tiny/24/step": "60093742fadd884a",
         "lfm2_moe_tiny/24/prefill": "d7e9c7555e9be45c",
         # commit 0f7afdd: the fifth family, unpinned before
-        "deepseek_v3_tiny/4/step": "3ba3d5ac434c15f5",
+        "deepseek_v3_tiny/4/step": "7ddca2f8aa8ca9df",  # grouped form: the grouped matmul kernel
         "deepseek_v3_tiny/4/prefill": "69f7a10ef36d20ae",
         "deepseek_v3_tiny/24/step": "604500b85b7e4374",
         "deepseek_v3_tiny/24/prefill": "0d8083f8212f667c",
@@ -54,7 +62,7 @@ PINS = {
         "lm_small/4/prefill": "6cf512dbaf5b84a1",
         "lm_small/24/step": "ee0c078b8b22922f",
         "lm_small/24/prefill": "36c9f74a91abc48c",
-        "nemotron_h_tiny/4/step": "82eb9d7a9695c905",
+        "nemotron_h_tiny/4/step": "f25502978303942d",  # grouped form: the grouped matmul kernel
         "nemotron_h_tiny/4/prefill": "023b045e0ca63ed5",
         "nemotron_h_tiny/24/step": "93f0cb9866177da3",
         "nemotron_h_tiny/24/prefill": "1e77c55890c38cb0",
@@ -62,12 +70,12 @@ PINS = {
         "olmo_hybrid_tiny/4/prefill": "ac8312944b0dac3e",
         "olmo_hybrid_tiny/24/step": "d3bd61916b6c0075",
         "olmo_hybrid_tiny/24/prefill": "eb39254d1d789711",
-        "lfm2_moe_tiny/4/step": "9e486a065753998e",
+        "lfm2_moe_tiny/4/step": "0f407e3475804745",  # grouped form: the grouped matmul kernel
         "lfm2_moe_tiny/4/prefill": "e4dd3ec204eb7e42",
         "lfm2_moe_tiny/24/step": "0b6142ed92de0fa9",
         "lfm2_moe_tiny/24/prefill": "d7e9c7555e9be45c",
         # commit 0f7afdd: the fifth family, unpinned before
-        "deepseek_v3_tiny/4/step": "97d7a70ba93a4d96",
+        "deepseek_v3_tiny/4/step": "4b5c9ce6b5a280eb",  # grouped form: the grouped matmul kernel
         "deepseek_v3_tiny/4/prefill": "69f7a10ef36d20ae",
         "deepseek_v3_tiny/24/step": "36ca592c09fd3b2c",
         "deepseek_v3_tiny/24/prefill": "0d8083f8212f667c",

@@ -24,6 +24,7 @@ from dmlc_tpu.generate.worker import GenerationBackend  # noqa: E402
 from dmlc_tpu.models import nemotron_h as nh  # noqa: E402
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 from dmlc_tpu.ops.ragged_decode import ragged_decode_attention  # noqa: E402
+from dmlc_tpu.ops.grouped_matmul import grouped_matmul  # noqa: E402
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk  # noqa: E402
 
 MODEL = "nemotron_h_tiny"
@@ -401,8 +402,10 @@ class TestExpertLayer:
                 activation=nh._relu2)[0]
             return str(jax.make_jaxpr(fn)(lat[:1].repeat(t, 0), idx[:1].repeat(t, 0),
                                           gates[:1].repeat(t, 0)))
-        assert "ragged_dot" not in form(40)
-        assert "ragged_dot" in form(512) and "ragged_dot" in form(4)
+        kernel = "_grouped_expert_matmul"
+        assert kernel not in form(40)
+        assert kernel in form(512) and kernel in form(4)
+        assert not any("ragged_dot" in form(t) for t in (4, 40, 512))
 
     @pytest.mark.parametrize("dense", [False, True], ids=["grouped", "dense"])
     def test_relu_squared_through_the_callers_form_is_what_it_was(self, variables, dense):
@@ -433,7 +436,11 @@ class TestExpertLayer:
 
 def _held_experts_ffn_before(x, w1, w2, idx, gates, held, rows, *, dense):
     """``parallel/moe.held_experts_ffn`` as it stood while relu-squared was its
-    only form, kept here word for word as the pin of the test above."""
+    only form, kept here word for word as the pin of the test above, but for
+    the grouped form's two products: they go through the grouped matmul the
+    program calls (``ops/grouped_matmul``), as ``jax.lax.ragged_dot`` did
+    when both were written (the test is about where the activation is
+    applied, not how the products are computed)."""
     first, count = held
     t, k = idx.shape
     local = idx - first
@@ -450,9 +457,9 @@ def _held_experts_ffn_before(x, w1, w2, idx, gates, held, rows, *, dense):
         return jnp.einsum("etd,te->td", y, weight), group_sizes
     key = key.reshape(t * k)
     order = jnp.argsort(key, stable=True)
-    h = jax.lax.ragged_dot(x[order // k], w1, group_sizes, preferred_element_type=jnp.float32)
+    h = grouped_matmul(x[order // k], w1, group_sizes)
     h = jnp.square(jax.nn.relu(h)).astype(x.dtype)
-    y = jax.lax.ragged_dot(h, w2, group_sizes, preferred_element_type=jnp.float32)
+    y = grouped_matmul(h, w2, group_sizes)
     in_a_group = (key[order] < count)[:, None]
     y = jnp.where(in_a_group, y * gates.reshape(t * k)[order][:, None], 0.0)
     back = jnp.zeros_like(order).at[order].set(jnp.arange(t * k, dtype=order.dtype))

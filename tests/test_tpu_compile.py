@@ -42,9 +42,16 @@ back in place: both arrays and both pools aliased, no copy of either array,
 a step's temporaries 50 MB and a prefill's 1.28 GB (its 32 heads' float32
 scores over 2,048 rows) beside 6.38 GB of weights.
 
-The last pins the K/V form of the fused attention kernel at the accepted
+The next pins the K/V form of the fused attention kernel at the accepted
 cells' shapes to what the parent of PR 37 lowered it to: the Mosaic module
 itself, read back from the custom call without its source locations.
+
+The last compiles the grouped expert matmul (``ops/grouped_matmul``) at the
+operand shapes of the three cells whose prefill runs it: every held expert's
+whole matrix in one block, blocks inside the VMEM limit the kernel sets, and
+no more temporaries than ``jax.lax.ragged_dot``'s program at the same shapes
+kept (the prefill programs of the two families compiled above at their cells'
+sizes are held to their parents' temporaries too).
 """
 
 import pytest
@@ -56,6 +63,10 @@ import chip_smoke  # noqa: E402
 import lowered_programs  # noqa: E402
 
 GEOMETRY = {**chip_smoke.POOL_GEOMETRY, "layers": 2, "vocab": 512, "num_pages": 16384}
+#: Temporaries of the two families' programs at their cells' sizes while the
+#: prefill's grouped products were ``jax.lax.ragged_dot`` (commit 3cc663f).
+PARENT_TEMP_BYTES = {"lfm2_moe": {"step": 16_398_848, "prefill": 197_594_624},
+                     "deepseek_v3": {"step": 45_589_504, "prefill": 410_936_832}}
 
 
 @pytest.fixture(scope="module")
@@ -72,15 +83,17 @@ def one_chip():
 
 @pytest.fixture
 def compiled_for_the_chip(monkeypatch):
-    """The attention kernel lowers through Mosaic, as it does on the chip, and
-    the persistent cache is off: a compile for a described chip is written to
-    it and cannot be read back without the chip."""
+    """The attention kernels and the grouped expert matmul lower through
+    Mosaic, as they do on the chip, and the persistent cache is off: a
+    compile for a described chip is written to it and cannot be read back
+    without the chip."""
     from jax.experimental.compilation_cache import compilation_cache
 
     from dmlc_tpu.generate import engine
-    from dmlc_tpu.ops import ragged_decode
+    from dmlc_tpu.ops import grouped_matmul, ragged_decode
 
     monkeypatch.setattr(ragged_decode, "interpret_mode", lambda: False)
+    monkeypatch.setattr(grouped_matmul, "interpret_mode", lambda: False)
     monkeypatch.setattr(engine, "_compiles_for_tpu", lambda: True)
     cache_was_on = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
@@ -200,13 +213,15 @@ def test_rotary_gated_expert_family_at_its_cells_sizes(one_chip, compiled_for_th
             # else (16 MB), a prefill its 1,024 rows at the widest product and the
             # dense attention's float32 scores (198 MB), beside 7.86 GB of weights.
             assert memory.temp_size_in_bytes < pool_bytes, (name, memory.temp_size_in_bytes)
+            assert memory.temp_size_in_bytes <= 1.1 * PARENT_TEMP_BYTES["lfm2_moe"][name], name
             assert weights < memory.argument_size_in_bytes < weights + 2 * pool_bytes + 2 ** 24
             text = compiled.as_text()
             # The static shapes chose: the step's experts are the dense form's two
             # batched products, the prefill's the grouped matmul (a Mosaic call of
             # its own on the chip); the step's three Mosaic calls are the attention.
             assert chip_smoke.MOSAIC_CALL in text
-            assert ("ragged-dot" in text) == (name == "prefill"), name
+            assert ("_grouped_expert_matmul" in text) == (name == "prefill"), name
+            assert "ragged-dot" not in text, name
             assert ("f32[32,64,3584]" in text) == (name == "step"), name
     finally:
         registry._REGISTRY.pop(spec.name, None)
@@ -314,6 +329,8 @@ def test_latent_family_at_its_cells_sizes_keeps_one_pool(one_chip, compiled_for_
     # query rows of float32 scores, not the whole square (411 MB, not 1.5 GB).
     assert memory["step"]["temp_bytes"] < 2 ** 26
     assert memory["prefill"]["temp_bytes"] < 2 ** 29
+    for name in ("step", "prefill"):
+        assert memory[name]["temp_bytes"] <= 1.1 * PARENT_TEMP_BYTES["deepseek_v3"][name], name
     assert memory["step"]["mosaic"] and memory["prefill"]["loop"] and not memory["step"]["loop"]
 
 
@@ -352,3 +369,40 @@ def test_the_kv_kernel_lowers_to_the_parents_mosaic_module(one_chip, compiled_fo
     module = lowered_programs.mosaic_module(lowered_programs.kernel_text_for_tpu(case, one_chip))
     assert "tpu.enqueue_dma" in module or "dma" in module
     assert lowered_programs.sha(module) == MOSAIC_PINS[case]
+
+
+#: The grouped products of the three cells whose prefill runs the grouped form:
+#: (pair rows, k, held experts, n), first product then second.
+GROUPED_SHAPES = {
+    "answers.w1": (11264, 1024, 128, 2688), "answers.w2": (11264, 2688, 128, 1024),
+    "replies.w13": (4096, 2048, 32, 3584), "replies.w2": (4096, 1792, 32, 2048),
+    "longform.w13": (12288, 2048, 16, 1536), "longform.w2": (12288, 768, 16, 2048),
+}
+
+
+@pytest.mark.parametrize("case", sorted(GROUPED_SHAPES))
+def test_the_grouped_matmul_compiles_at_the_cells_shapes(one_chip, compiled_for_the_chip, case):
+    import jax.numpy as jnp
+
+    from dmlc_tpu.ops import grouped_matmul as gm
+
+    m, k, count, n = GROUPED_SHAPES[case]
+
+    def abstract(shape, dtype):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    args = (abstract((m, k), jnp.bfloat16), abstract((count, k, n), jnp.bfloat16),
+            abstract((count,), jnp.int32))
+    blocks = gm.blocks(m, k, n, count)
+    # Each expert's whole matrix is one block, read once a call; the blocks fit
+    # the limit the kernel asks for, and the limit the chip's 128 MiB.
+    assert (blocks.tn, blocks.n_blocks, blocks.tm) == (n, 1, 128)
+    assert blocks.vmem_block_bytes <= blocks.vmem_limit_bytes <= 64 << 20
+    compiled = jax.jit(gm.grouped_matmul).lower(*args).compile()
+    text = compiled.as_text()
+    assert chip_smoke.MOSAIC_CALL in text and "_grouped_expert_matmul" in text
+    assert "ragged-dot" not in text
+    parent = jax.jit(lambda lhs, rhs, sizes: jax.lax.ragged_dot(
+        lhs, rhs, sizes, preferred_element_type=jnp.float32)).lower(*args).compile()
+    temp = compiled.memory_analysis().temp_size_in_bytes
+    assert temp <= 1.1 * parent.memory_analysis().temp_size_in_bytes, temp
