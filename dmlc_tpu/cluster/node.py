@@ -1063,6 +1063,7 @@ class ClusterNode:
         self.member_server.close()
         if self.leader_server is not None:
             self.leader_server.close()
+        self.rpc.close()
         self.gossip.close()
         if self.config.profile_persist:
             self.profiler.save(self.profile_path())

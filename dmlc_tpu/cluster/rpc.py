@@ -9,10 +9,10 @@ is a small synchronous RPC layer with two fabrics:
   threads. This is what the hermetic cluster tests run on (the
   fake-transport strategy the reference declared via its unused
   ``mockstream`` dev-dependency but never built, SURVEY.md §4).
-- ``TcpRpcServer`` / ``tcp_call`` — real length-prefixed msgpack frames over
-  TCP for deployment, one connection per call (control traffic is tiny; bulk
-  tensor bytes never ride this path — they go host->HBM via the staging
-  pipeline, and device-to-device over ICI via XLA collectives).
+- ``TcpRpcServer`` / ``TcpRpc`` — real length-prefixed msgpack frames over
+  TCP for deployment, on connections the client keeps and reuses across
+  calls (bulk tensor bytes never ride this path — they go host->HBM via the
+  staging pipeline, and device-to-device over ICI via XLA collectives).
 
 A "service" is just a dict of method-name -> callable(payload dict) -> reply
 dict. Method errors travel back as ``RpcError`` with the remote message.
@@ -463,7 +463,9 @@ def _recv_exact(sock: socket.socket, n: int) -> bytearray:
 
 
 class TcpRpcServer:
-    """Threaded TCP server hosting one method table.
+    """Threaded TCP server hosting one method table: one thread per
+    connection, serving its frames in order for as long as the client keeps
+    the connection (``TcpRpc`` reuses it across calls).
 
     ``metrics`` (utils/metrics.Counters, optional) counts the
     ``deadline_exceeded`` verdicts this server hands out (budget ran out on
@@ -494,6 +496,9 @@ class TcpRpcServer:
             # recorded in flight to any other endpoint are rejected here.
             auth.add_identity(self.address)
         self._stop = threading.Event()
+        # Live connections, so that close() can cut the ones clients keep.
+        self._conns: set[socket.socket] = set()
+        self._conns_lock = threading.Lock()
         self._thread = threading.Thread(target=self._accept_loop, daemon=True)
         self._thread.start()
 
@@ -506,6 +511,12 @@ class TcpRpcServer:
                 continue
             except OSError:
                 return
+            conn.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            with self._conns_lock:
+                if self._stop.is_set():
+                    conn.close()
+                    return
+                self._conns.add(conn)
             threading.Thread(target=self._serve_conn, args=(conn,), daemon=True).start()
 
     def _count(self, e: Exception) -> None:
@@ -518,8 +529,13 @@ class TcpRpcServer:
     def _serve_conn(self, conn: socket.socket) -> None:
         with conn:
             try:
+                fresh = True
                 while True:
                     req, peer = _recv_frame(conn, self.auth)
+                    # The rpc/<method> span of this frame says whether the
+                    # caller dialed for it (utils/tracing.traced).
+                    tracing.bind_fresh(fresh)
+                    fresh = False
                     # Replies are sealed for the AUTHENTICATED requester id,
                     # so a recorded reply cannot be replayed to anyone else.
                     try:
@@ -556,17 +572,47 @@ class TcpRpcServer:
                 # connection, never the server.
                 log.warning("closing connection after malformed frame", exc_info=True)
                 return
+            finally:
+                with self._conns_lock:
+                    self._conns.discard(conn)
 
     def close(self) -> None:
+        """Stop accepting and cut every live connection, as a crash would:
+        a client's kept connection reads EOF, and a handler still running
+        loses its reply."""
         self._stop.set()
         self.sock.close()
+        with self._conns_lock:
+            conns = list(self._conns)
+        for conn in conns:
+            try:
+                conn.shutdown(socket.SHUT_RDWR)
+            except OSError:
+                pass  # already closed by its own thread
         self._thread.join(timeout=1.0)
 
 
+#: Idle connections ``TcpRpc`` keeps per address. A call holds its socket
+#: alone, so the stack grows to the calls an address sees at once: 64
+#: covers the largest fleet of concurrent callers the deployments run
+#: (64 streaming clients polling one leader). Sockets returned above it
+#: are closed.
+_IDLE_PER_ADDR = 64
+
+
 class TcpRpc(Rpc):
-    """One connection per call. Control messages are small and infrequent
-    (heartbeats ride UDP, tensor bytes ride ICI/PCIe), so connection reuse
-    is not worth the failure-mode complexity here.
+    """Calls over kept connections. Each address has a stack of idle
+    connected sockets: a call takes the most recently returned one, or dials
+    when none is idle, and gives it back once a whole reply frame is read
+    (an ``ok: False`` reply included). Generation streams poll through two
+    hops many times a second, and a dial, an ``accept`` and a server thread
+    per call cost about a millisecond a call on a busy host, most of it
+    waiting for the interpreter, so connections are reused. Anything else that ends a call (a timeout, an ``OSError``, a
+    reply that fails authentication or will not parse) closes the socket:
+    a late reply must never be read as the next call's. A request is never
+    sent twice: a kept socket is checked before use (a peek that would
+    block means the peer is alive; EOF or stray bytes mean it is dropped),
+    and a failure after the send raises as it would on a fresh connection.
 
     With auth enabled, requests are sealed for the DIALED address, and the
     server only opens frames sealed for an address it registered — so keyed
@@ -575,13 +621,48 @@ class TcpRpc(Rpc):
     name, a second NIC). Every in-tree caller gets addresses from
     membership/config, which satisfies this by construction.
 
-    The call's budget is spent ONCE across the connect, send, and recv
-    phases: each phase's socket timeout is the time *remaining* from a
-    monotonic start, so a slow connect plus a slow reply can never stretch
-    one call to ~2x the stated bound."""
+    The call's budget is spent ONCE across the connect (when it dials),
+    send, and recv phases: each phase's socket timeout is the time
+    *remaining* from a monotonic start, so a slow connect plus a slow reply
+    can never stretch one call to ~2x the stated bound."""
 
     def __init__(self, auth: FrameAuth | None = None):
         self.auth = auth
+        self._idle: dict[str, list[socket.socket]] = {}
+        self._idle_lock = threading.Lock()
+
+    def _checkout(self, addr: str) -> socket.socket | None:
+        """The most recently returned live socket to ``addr``, or None."""
+        while True:
+            with self._idle_lock:
+                stack = self._idle.get(addr)
+                if not stack:
+                    return None
+                sock = stack.pop()
+            try:
+                sock.setblocking(False)
+                sock.recv(1, socket.MSG_PEEK)  # EOF or stray bytes: drop it
+            except BlockingIOError:
+                return sock  # nothing to read: the peer holds it open
+            except OSError:
+                pass  # reset by the peer: drop it
+            sock.close()
+
+    def _checkin(self, addr: str, sock: socket.socket) -> None:
+        with self._idle_lock:
+            stack = self._idle.setdefault(addr, [])
+            if len(stack) < _IDLE_PER_ADDR:
+                stack.append(sock)
+                return
+        sock.close()
+
+    def close(self) -> None:
+        """Close every idle connection (calls in flight keep theirs)."""
+        with self._idle_lock:
+            socks = [s for stack in self._idle.values() for s in stack]
+            self._idle.clear()
+        for sock in socks:
+            sock.close()
 
     def call(
         self,
@@ -601,7 +682,11 @@ class TcpRpc(Rpc):
             return budget - (_now() - start)
 
         try:
-            with socket.create_connection((host, int(port)), timeout=budget) as sock:
+            sock = self._checkout(addr)
+            if sock is None:
+                sock = socket.create_connection((host, int(port)), timeout=budget)
+                sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
+            try:
                 left = remaining()
                 if left <= 0:
                     raise RpcUnreachable(f"{addr}: connect consumed the whole budget")
@@ -626,12 +711,16 @@ class TcpRpc(Rpc):
                 # Replies are authenticated too: a spoofed leader cannot feed
                 # a keyed member forged directory state.
                 reply, _ = _recv_frame(sock, self.auth)
+            except BaseException:
+                sock.close()  # mid-call: what is left on it is not the next reply
+                raise
         except RpcUnreachable:
             raise
         except AuthError as e:
             raise RpcUnreachable(f"{addr}: reply failed authentication: {e}") from e
         except (OSError, ValueError) as e:
             raise RpcUnreachable(f"{addr}: {e}") from e
+        self._checkin(addr, sock)
         if not reply.get("ok"):
             raise remote_error(
                 reply.get("e", "remote error"),

@@ -319,6 +319,15 @@ class GenerateWorker:
 # ---------------------------------------------------------------------------
 
 
+#: How many times its own duration a poll keeps a client from polling again.
+#: Polls take the interpreter from the decode loop of the host that serves
+#: them, and a poll's duration says how busy that host is: a client that
+#: spends at most a ninth of its time polling backs off as the host fills.
+#: Without it, 64 streams polling every 50 ms over kept connections starve
+#: the decode loop (a dial and a thread per call used to slow them down).
+_POLL_BACKOFF = 8
+
+
 def generate_stream(
     rpc: Any,
     addr: str,
@@ -337,7 +346,10 @@ def generate_stream(
     dedup'd by seq and acked cumulatively, so a retried poll after a lost
     reply cannot duplicate or drop tokens. Raises the remote's typed error
     (Overloaded / DeadlineExceeded / RpcError) on failure. ``seed`` pins
-    the sampling RNG (temperature > 0) to a reproducible sequence."""
+    the sampling RNG (temperature > 0) to a reproducible sequence.
+    While the stream runs, a poll starts no sooner than ``poll_interval_s``
+    after the start of the last one, nor sooner than ``_POLL_BACKOFF`` times
+    the last one's own duration."""
     from dmlc_tpu.cluster.rpc import remote_error
 
     payload: dict[str, Any] = {
@@ -353,16 +365,17 @@ def generate_stream(
         gen_id = reply["gen_id"]
         acked = 0
         while True:
+            t_poll = time.monotonic()
             r = rpc.call(
                 addr, "job.generate_poll", {"gen_id": gen_id, "ack": acked},
                 timeout=poll_timeout,
             )
-            advanced = False
+            took = time.monotonic() - t_poll
+            due = t_poll + max(poll_interval_s, _POLL_BACKOFF * took)
             for seq, toks in sorted(r.get("chunks", [])):
                 if seq <= acked:
                     continue  # replayed chunk from a retried poll
                 acked = seq
-                advanced = True
                 for t in toks:
                     if t_call is not None:
                         # The first token in the client's hands, under the request's trace.
@@ -373,8 +386,9 @@ def generate_stream(
                 if r.get("error"):
                     raise remote_error(r["error"])
                 return
-            if not advanced and not r.get("done") and poll_interval_s > 0:
-                sleep(poll_interval_s)
+            wait = due - time.monotonic()
+            if wait > 0 and not r.get("done"):
+                sleep(wait)
 
 
 def generate(rpc: Any, addr: str, model: str, prompt: Iterable[int],

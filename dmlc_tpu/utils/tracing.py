@@ -454,6 +454,20 @@ def disable() -> None:
 # ---------------------------------------------------------------------------
 
 
+_fresh: contextvars.ContextVar[int | None] = contextvars.ContextVar(
+    "dmlc_rpc_fresh", default=None
+)
+
+
+def bind_fresh(fresh: bool) -> None:
+    """Say, on the thread that serves one TCP connection, whether the frame
+    it is about to dispatch is the first on that connection (the caller
+    dialed for it) or came over a kept one. ``traced`` copies it onto the
+    ``rpc/<method>`` span as attribute ``fresh`` (1 or 0); fabrics without
+    connections bind nothing and the span has no such attribute."""
+    _fresh.set(int(fresh))
+
+
 def traced(method_name: str, fn):
     """Wrap one RPC handler so it executes under a ``rpc/<method>`` span.
     The span parents onto the caller's wire context (which the serving
@@ -464,7 +478,9 @@ def traced(method_name: str, fn):
         return fn
 
     def handler(payload: dict, _fn=fn, _span_name=f"rpc/{method_name}") -> dict:
-        with tracer.span(_span_name):
+        fresh = _fresh.get()
+        span = tracer.span(_span_name) if fresh is None else tracer.span(_span_name, fresh=fresh)
+        with span:
             return _fn(payload)
 
     handler._dmlc_traced = True  # type: ignore[attr-defined]

@@ -39,6 +39,7 @@ from dmlc_tpu.generate.worker import (  # noqa: E402
     GenerateWorker,
     GenerationBackend,
     generate,
+    generate_stream,
 )
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 from dmlc_tpu.utils.metrics import Counters  # noqa: E402
@@ -395,6 +396,31 @@ class TestExactlyOnceStreaming:
             assert r3["chunks"] == [] and r3["done"] and not r3.get("error")
         finally:
             backend.stop()
+
+    @pytest.mark.parametrize("poll_s, least, most", [(0.0, 0.04, 0.05), (0.02, 0.13, 0.6)],
+                             ids=["interval", "backoff"])
+    def test_a_producing_stream_waits_between_polls(self, poll_s, least, most):
+        """Every poll brings a chunk, and the client still waits before the
+        next: out the rest of ``poll_interval_s`` after a quick poll, and
+        ``_POLL_BACKOFF`` times the poll's own time after a slow one. The poll
+        that brings ``done`` with the last chunk is acked at once."""
+        acks: list = []
+
+        def poll(p):
+            acks.append(p["ack"])
+            time.sleep(poll_s)
+            n = len(acks)
+            return {"chunks": [[n, [n]]] if n <= 3 else [], "done": n >= 3}
+
+        net = SimRpcNetwork()
+        net.serve("member", {"job.generate": lambda p: {"gen_id": "g"},
+                             "job.generate_poll": poll})
+        slept: list = []
+        toks = list(generate_stream(net.client("cli"), "member", "m", [1], max_new_tokens=3,
+                                    poll_interval_s=0.05, sleep=slept.append))
+        assert toks == [1, 2, 3]
+        assert acks == [0, 1, 2, 3]
+        assert len(slept) == 2 and all(least <= s <= most for s in slept), slept
 
     def test_seeded_join_leave_soak_exactly_once(self, variables):
         """Concurrent clients churning through the worker: every request's
