@@ -16,7 +16,7 @@ heads of ``qk_nope_head_dim + qk_rope_head_dim`` lanes, ``q_nope | q_rope``;
 ``c | k_rope = W_kva u`` (``kv_lora_rank | qk_rope_head_dim``; ``k_rope`` is
 ONE row for all heads); ``c <- RMSNorm(c)``; ``k_nope_h = W_uk_h c``, ``v_h =
 W_uv_h c``; ``q_rope`` and ``k_rope`` turned at the token's position (the
-tables and the pairing are ``models/lfm2_moe``'s: lane ``i`` of the rotary
+tables and the pairing are ``models/blocks``': lane ``i`` of the rotary
 lanes with lane ``i + d/2``; the published weights pair adjacent lanes and
 the published code moves them to this order in every forward, which a
 loader does once to the rotary columns of ``W_q`` and ``W_kva``: ``q . k``
@@ -58,9 +58,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dmlc_tpu.models.lfm2_moe import apply_rotary, gated_expert, gated_mlp, rotary_tables
-from dmlc_tpu.models.nemotron_h import rms_norm
-from dmlc_tpu.models.seeded_tree import SeededTreeModule
+from dmlc_tpu.models.blocks import (
+    apply_rotary, expert_attrs, expert_aux, gated_expert, gated_mlp, rms_norm, rotary_tables)
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk
 
 #: A cached row is stored on whole tiles of this many lanes (576 -> 640):
@@ -220,16 +219,6 @@ def _leaf_mean_std(path: str, depth: int, width: int) -> tuple[float, float]:
     return 0.0, 0.02
 
 
-class DeepseekV3Module(SeededTreeModule):
-    """The family's parameter tree as the registry's ``init_params`` draws it."""
-
-    def __init__(self, config: DeepseekV3Config, dtype: Any = jnp.float32) -> None:
-        depth, width = config.num_hidden_layers, config.hidden_size
-        super().__init__(param_shapes(config), lambda path: _leaf_mean_std(path, depth, width),
-                         vocab=config.vocab_size, max_len=config.max_len, dtype=dtype)
-        self.config = config
-
-
 # ---------------------------------------------------------------------------
 # latent attention
 # ---------------------------------------------------------------------------
@@ -380,7 +369,7 @@ class DeepseekV3Family:
                 out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
             x = self._ffn(p, i, x + out, rows, counts)
         logits = self._logits(params, jnp.take(x, length - 1, axis=0))
-        return logits, state, self._aux(counts)
+        return logits, state, expert_aux(counts)
 
     def decode(self, params: Any, tokens: Any, lengths: Any, active: Any, kv: Any,
                state: Any) -> tuple[Any, Any, Any]:
@@ -402,32 +391,21 @@ class DeepseekV3Family:
                 att = absorbed_values(p["attn"], attended)
                 out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
             x = self._ffn(p, i, x + out, active, counts)
-        aux = self._aux(counts)
+        aux = expert_aux(counts)
         aux["kv_tokens_read"] = jnp.sum(jnp.where(active, lengths + 1, 0))
         return self._logits(params, x), state, aux
-
-    @staticmethod
-    def _aux(counts: list) -> dict:
-        return {"expert_counts": jnp.stack(counts)} if counts else {}
 
     def work_attrs(self, aux: dict, rows: int) -> dict:
         """The attributes of ``gen/step`` (``aux`` of ``decode``; ``rows``
         active slots) and ``gen/prefill`` (the runs' counts summed; ``rows``
-        prompt tokens): the expert layers' work as ``models/nemotron_h``
-        names it (NumPy counts ``[expert layers, held]``: pairs whose expert
-        is held here, and ``expert_pairs_absent``, what the expert exchange
-        would carry), the cache's as the algorithm counts it (``latent_dim``
-        values a token a layer, whatever a row is stored as)."""
+        prompt tokens): the expert layers' work (``expert_attrs``; NumPy counts
+        ``[expert layers, held]``), the cache's as the algorithm counts it
+        (``latent_dim`` values a token a layer, whatever a row is stored as)."""
         cfg = self.config
         out = {"latent_layers": cfg.num_hidden_layers}
         counts = aux.get("expert_counts")
         if counts is not None:
-            pairs = int(counts.sum())
-            out.update(expert_pairs=pairs,
-                       expert_pairs_absent=(rows * cfg.num_experts_per_tok * cfg.expert_layers
-                                            - pairs),
-                       experts_hit=float((counts > 0).sum(axis=1).mean()),
-                       expert_rows_max=int(counts.max()))
+            out.update(expert_attrs(counts, rows, cfg.num_experts_per_tok, cfg.expert_layers))
         if "kv_tokens_read" in aux:
             read = int(aux["kv_tokens_read"])
             out.update(kv_tokens_read=read, latent_bytes_read=read * self.latent_bytes_per_token)
@@ -440,13 +418,10 @@ def register_deepseek_v3(name: str, config: DeepseekV3Config) -> Any:
     """Register ``config`` as a servable ``kind="lm"`` model called ``name``."""
     from dmlc_tpu.models import registry
 
-    spec = registry.ModelSpec(
-        name, lambda dtype=jnp.float32: DeepseekV3Module(config, dtype),
-        config.max_len, config.vocab_size, classifier=False, kind="lm",
-        num_heads=config.num_attention_heads,
-        family=lambda dtype: DeepseekV3Family(config, dtype))
-    registry.register(spec)
-    return spec
+    depth, width = config.num_hidden_layers, config.hidden_size
+    return registry.register_family(name, config, param_shapes(config),
+                                    lambda path: _leaf_mean_std(path, depth, width),
+                                    DeepseekV3Family)
 
 
 #: The CPU tests' preset: a dense layer, then three expert layers; four heads

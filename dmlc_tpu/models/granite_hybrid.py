@@ -48,10 +48,8 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dmlc_tpu.models.lfm2_moe import gated_mlp
+from dmlc_tpu.models.blocks import attend_decode, attend_prefill, gated_mlp, layer_index, rms_norm
 from dmlc_tpu.models.mamba2 import Mamba2Dims, mamba_decode, mamba_prefill
-from dmlc_tpu.models.nemotron_h import gqa_causal_attention, rms_norm
-from dmlc_tpu.models.seeded_tree import SeededTreeModule
 
 MAMBA, ATTENTION = "mamba", "attention"
 
@@ -150,7 +148,7 @@ class GraniteHybridConfig:
                           self.rms_norm_eps)
 
     def layers_of(self, kind: str) -> list[int]:
-        return [i for i, k in enumerate(self.layer_types) if k == kind]
+        return list(layer_index(self.layer_types, kind))
 
 
 # ---------------------------------------------------------------------------
@@ -218,16 +216,6 @@ def _leaf_mean_std(path: str, width: int) -> tuple[float, float]:
     return 0.0, 0.02
 
 
-class GraniteHybridModule(SeededTreeModule):
-    """The family's parameter tree as the registry's ``init_params`` draws it."""
-
-    def __init__(self, config: GraniteHybridConfig, dtype: Any = jnp.float32) -> None:
-        width = config.hidden_size
-        super().__init__(param_shapes(config), lambda path: _leaf_mean_std(path, width),
-                         vocab=config.vocab_size, max_len=config.max_len, dtype=dtype)
-        self.config = config
-
-
 # ---------------------------------------------------------------------------
 # the two functions the engine calls
 # ---------------------------------------------------------------------------
@@ -242,11 +230,11 @@ class GraniteHybridFamily:
         self.dtype = dtype
         self.vocab = config.vocab_size
         self.max_len = config.max_len
-        self.kv_layers = len(config.layers_of(ATTENTION))
+        self._kv_index = layer_index(config.layer_types, ATTENTION)
+        self._m_index = layer_index(config.layer_types, MAMBA)
+        self.kv_layers = len(self._kv_index)
         self.kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
-        self._kv_index = {layer: i for i, layer in enumerate(config.layers_of(ATTENTION))}
-        self._m_index = {layer: i for i, layer in enumerate(config.layers_of(MAMBA))}
 
     def state_shapes(self, max_slots: int) -> dict:
         """The recurrent state beside the pages: ONE array per kind, the
@@ -308,9 +296,7 @@ class GraniteHybridFamily:
             else:
                 with jax.named_scope("attn"):
                     q, k, v = self._qkv(p["attn"], u)
-                    kv.write_prefill(self._kv_index[i], k, v)
-                    att = gqa_causal_attention(q, k, v)
-                    out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    out = attend_prefill(kv, self._kv_index[i], q, k, v, p["attn"]["out"]["kernel"])
             x = self._mlp_branch(p, x + out * cfg.residual_multiplier)
         logits = self._logits(params, jnp.take(x, length - 1, axis=0))
         return logits, {"conv": conv, "ssm": ssm}, {}
@@ -335,8 +321,7 @@ class GraniteHybridFamily:
             else:
                 with jax.named_scope("attn"):
                     q, k, v = self._qkv(p["attn"], u)
-                    att = kv.write_attend(self._kv_index[i], q, k, v)
-                    out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    out = attend_decode(kv, self._kv_index[i], q, k, v, p["attn"]["out"]["kernel"])
             x = self._mlp_branch(p, x + out * cfg.residual_multiplier)
         return self._logits(params, x), {"conv": conv, "ssm": ssm}, {}
 
@@ -350,13 +335,9 @@ def register_granite_hybrid(name: str, config: GraniteHybridConfig) -> Any:
     """Register ``config`` as a servable ``kind="lm"`` model called ``name``."""
     from dmlc_tpu.models import registry
 
-    spec = registry.ModelSpec(
-        name, lambda dtype=jnp.float32: GraniteHybridModule(config, dtype),
-        config.max_len, config.vocab_size, classifier=False, kind="lm",
-        num_heads=config.num_attention_heads,
-        family=lambda dtype: GraniteHybridFamily(config, dtype))
-    registry.register(spec)
-    return spec
+    width = config.hidden_size
+    return registry.register_family(name, config, param_shapes(config),
+                                    lambda path: _leaf_mean_std(path, width), GraniteHybridFamily)
 
 
 #: The CPU tests' preset: both kinds of layer (4 mamba, 2 attention), ONE

@@ -53,8 +53,9 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dmlc_tpu.models.nemotron_h import gqa_causal_attention, rms_norm
-from dmlc_tpu.models.seeded_tree import SeededTreeModule
+from dmlc_tpu.models.blocks import (
+    apply_rotary, attend_decode, attend_prefill, expert_attrs, expert_aux, gated_expert, gated_mlp,
+    layer_index, rms_norm, rotary_tables)
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk
 
 CONV, FULL = "conv", "full_attention"
@@ -125,7 +126,7 @@ class Lfm2MoeConfig:
         return self.hidden_size // self.num_attention_heads
 
     def layers_of(self, kind: str) -> list[int]:
-        return [i for i, k in enumerate(self.layer_types) if k == kind]
+        return list(layer_index(self.layer_types, kind))
 
 
 # ---------------------------------------------------------------------------
@@ -200,41 +201,6 @@ def _leaf_mean_std(path: str, depth: int, width: int) -> tuple[float, float]:
     return 0.0, 0.02
 
 
-class Lfm2MoeModule(SeededTreeModule):
-    """The family's parameter tree as the registry's ``init_params`` draws it."""
-
-    def __init__(self, config: Lfm2MoeConfig, dtype: Any = jnp.float32) -> None:
-        depth, width = len(config.layer_types), config.hidden_size
-        super().__init__(param_shapes(config), lambda path: _leaf_mean_std(path, depth, width),
-                         vocab=config.vocab_size, max_len=config.max_len, dtype=dtype)
-        self.config = config
-
-
-# ---------------------------------------------------------------------------
-# rotary positions
-# ---------------------------------------------------------------------------
-
-
-def rotary_tables(positions: Any, head_dim: int, theta: float) -> tuple[Any, Any]:
-    """``positions`` [..] int -> (cos, sin) [.., head_dim / 2] float32: pair
-    ``i`` of a head turns by ``pos * theta^(-2i / head_dim)``."""
-    half = head_dim // 2
-    inv_freq = jnp.exp(jnp.arange(half, dtype=jnp.float32) * (-2.0 * math.log(theta) / head_dim))
-    angle = positions.astype(jnp.float32)[..., None] * inv_freq
-    return jnp.cos(angle), jnp.sin(angle)
-
-
-def apply_rotary(x: Any, cos: Any, sin: Any) -> Any:
-    """x [.., heads, head_dim]; cos, sin [.., head_dim / 2] (one row a
-    position). Lanes ``i`` and ``i + head_dim/2`` are one pair: ``(a, b) ->
-    (a cos - b sin, b cos + a sin)``, in float32, back in x's type."""
-    half = x.shape[-1] // 2
-    xf = x.astype(jnp.float32)
-    a, b = xf[..., :half], xf[..., half:]
-    cos, sin = cos[..., None, :], sin[..., None, :]
-    return jnp.concatenate([a * cos - b * sin, b * cos + a * sin], axis=-1).astype(x.dtype)
-
-
 # ---------------------------------------------------------------------------
 # conv: the gated short convolution
 # ---------------------------------------------------------------------------
@@ -272,7 +238,7 @@ def shortconv_decode(p: Any, cfg: Lfm2MoeConfig, u: Any, window: Any,
 
 
 # ---------------------------------------------------------------------------
-# full_attention (per-head query/key norm, rotary) and the two feed-forwards
+# full_attention (per-head query/key norm, rotary) and the gated experts
 # ---------------------------------------------------------------------------
 
 
@@ -288,19 +254,6 @@ def _qkv(p: Any, cfg: Lfm2MoeConfig, u: Any, cos: Any, sin: Any) -> tuple[Any, A
     q = apply_rotary(rms_norm(q, p["q_norm"]["scale"], cfg.norm_eps), cos, sin)
     k = apply_rotary(rms_norm(k, p["k_norm"]["scale"], cfg.norm_eps), cos, sin)
     return q, k, v
-
-
-def gated_mlp(p: Any, x: Any) -> Any:
-    both = x @ p["gate_up"]["kernel"]
-    width = both.shape[-1] // 2
-    return (jax.nn.silu(both[..., :width]) * both[..., width:]) @ p["down"]["kernel"]
-
-
-def gated_expert(h: Any) -> Any:
-    """The expert's form ``held_experts_ffn`` is handed: ``h = x (W_1 | W_3)``
-    -> ``silu(x W_1) * x W_3``."""
-    width = h.shape[-1] // 2
-    return jax.nn.silu(h[..., :width]) * h[..., width:]
 
 
 def gated_moe(p: Any, cfg: Lfm2MoeConfig, u: Any, rows: Any) -> tuple[Any, Any]:
@@ -332,11 +285,11 @@ class Lfm2MoeFamily:
         self.dtype = dtype
         self.vocab = config.vocab_size
         self.max_len = config.max_len
-        self.kv_layers = len(config.layers_of(FULL))
+        self._kv_index = layer_index(config.layer_types, FULL)
+        self._conv_index = layer_index(config.layer_types, CONV)
+        self.kv_layers = len(self._kv_index)
         self.kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
-        self._kv_index = {layer: i for i, layer in enumerate(config.layers_of(FULL))}
-        self._conv_index = {layer: i for i, layer in enumerate(config.layers_of(CONV))}
         self.expert_layers = len(config.layer_types) - config.num_dense_layers
         self.state_bytes_per_slot = sum(
             jnp.dtype(dtype).itemsize * math.prod(shape[1:])
@@ -387,12 +340,10 @@ class Lfm2MoeFamily:
             else:
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, u, cos, sin)
-                    kv.write_prefill(self._kv_index[i], k, v)
-                    att = gqa_causal_attention(q, k, v)
-                    out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    out = attend_prefill(kv, self._kv_index[i], q, k, v, p["attn"]["out"]["kernel"])
             x = self._ffn(p, i, x + out, rows, counts)
         logits = self._logits(params, jnp.take(x, length - 1, axis=0))
-        return logits, {"conv": conv}, self._aux(counts)
+        return logits, {"conv": conv}, expert_aux(counts)
 
     def decode(self, params: Any, tokens: Any, lengths: Any, active: Any, kv: Any,
                state: Any) -> tuple[Any, Any, Any]:
@@ -412,34 +363,24 @@ class Lfm2MoeFamily:
             else:
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, u, cos, sin)
-                    att = kv.write_attend(self._kv_index[i], q, k, v)
-                    out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    out = attend_decode(kv, self._kv_index[i], q, k, v, p["attn"]["out"]["kernel"])
             x = self._ffn(p, i, x + out, active, counts)
-        aux = self._aux(counts)
+        aux = expert_aux(counts)
         aux["kv_tokens_read"] = jnp.sum(jnp.where(active, lengths + 1, 0))
         return self._logits(params, x), {"conv": conv}, aux
-
-    @staticmethod
-    def _aux(counts: list) -> dict:
-        return {"expert_counts": jnp.stack(counts)} if counts else {}
 
     def work_attrs(self, aux: dict, rows: int) -> dict:
         """The attributes of ``gen/step`` (``aux`` of ``decode``; ``rows``
         active slots, each reading and writing its windows) and
         ``gen/prefill`` (the runs' counts summed; ``rows`` prompt tokens): the
-        expert layers' work as ``models/nemotron_h`` names it (NumPy counts
-        ``[expert layers, num_experts]``), the state's and the cache's as
-        ``models/olmo_hybrid`` does."""
+        expert layers' work (``expert_attrs``; NumPy counts ``[expert layers,
+        num_experts]``, every expert held here, so none absent), the state's
+        and the cache's as ``models/olmo_hybrid`` does."""
         out = {"conv_layers": len(self._conv_index)}
         counts = aux.get("expert_counts")
         if counts is not None:
-            pairs = int(counts.sum())
-            out.update(expert_pairs=pairs,
-                       # Every expert lives here: the exchange would carry none.
-                       expert_pairs_absent=(rows * self.config.num_experts_per_tok
-                                            * self.expert_layers - pairs),
-                       experts_hit=float((counts > 0).sum(axis=1).mean()),
-                       expert_rows_max=int(counts.max()))
+            out.update(expert_attrs(counts, rows, self.config.num_experts_per_tok,
+                                    self.expert_layers))
         if "kv_tokens_read" in aux:
             out.update(state_bytes_touched=2 * rows * self.state_bytes_per_slot,
                        kv_tokens_read=int(aux["kv_tokens_read"]))
@@ -452,13 +393,9 @@ def register_lfm2_moe(name: str, config: Lfm2MoeConfig) -> Any:
     """Register ``config`` as a servable ``kind="lm"`` model called ``name``."""
     from dmlc_tpu.models import registry
 
-    spec = registry.ModelSpec(
-        name, lambda dtype=jnp.float32: Lfm2MoeModule(config, dtype),
-        config.max_len, config.vocab_size, classifier=False, kind="lm",
-        num_heads=config.num_attention_heads,
-        family=lambda dtype: Lfm2MoeFamily(config, dtype))
-    registry.register(spec)
-    return spec
+    depth, width = len(config.layer_types), config.hidden_size
+    return registry.register_family(name, config, param_shapes(config),
+                                    lambda path: _leaf_mean_std(path, depth, width), Lfm2MoeFamily)
 
 
 #: The CPU tests' preset: two dense layers, then ``attn conv conv conv``

@@ -32,9 +32,10 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
+from dmlc_tpu.models.blocks import (
+    attend_decode, attend_prefill, expert_attrs, expert_aux, layer_index, rms_norm)
 from dmlc_tpu.models.mamba2 import Mamba2Dims, mamba_decode, mamba_prefill
 from dmlc_tpu.models.mamba2 import ssd_chunked  # noqa: F401  (the family's scan, as tests reach it)
-from dmlc_tpu.models.seeded_tree import SeededTreeModule
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk
 
 
@@ -103,7 +104,7 @@ class NemotronHConfig:
                           self.ssm_state_size, self.conv_kernel, self.chunk_size, self.norm_eps)
 
     def layers_of(self, kind: str) -> list[int]:
-        return [i for i, k in enumerate(self.layer_kinds) if k == kind]
+        return list(layer_index(self.layer_kinds, kind))
 
 
 # ---------------------------------------------------------------------------
@@ -174,31 +175,6 @@ def _leaf_mean_std(path: str, depth: int) -> tuple[float, float]:
     return 0.0, 0.02
 
 
-class NemotronHModule(SeededTreeModule):
-    """The family's parameter tree as the registry's ``init_params`` draws it."""
-
-    def __init__(self, config: NemotronHConfig, dtype: Any = jnp.float32) -> None:
-        depth = len(config.layer_kinds)
-        super().__init__(param_shapes(config), lambda path: _leaf_mean_std(path, depth),
-                         vocab=config.vocab_size, max_len=config.max_len, dtype=dtype)
-        self.config = config
-
-
-# ---------------------------------------------------------------------------
-# shared pieces
-# ---------------------------------------------------------------------------
-
-
-def rms_norm(x: Any, scale: Any, eps: float) -> Any:
-    xf = x.astype(jnp.float32)
-    y = xf * jax.lax.rsqrt(jnp.mean(jnp.square(xf), axis=-1, keepdims=True) + eps)
-    return (y * scale.astype(jnp.float32)).astype(x.dtype)
-
-
-def _relu2(x: Any) -> Any:
-    return jnp.square(jax.nn.relu(x))
-
-
 # ---------------------------------------------------------------------------
 # *: grouped-query attention, no positions
 # ---------------------------------------------------------------------------
@@ -212,23 +188,13 @@ def _qkv(p: Any, cfg: NemotronHConfig, u: Any) -> tuple[Any, Any, Any]:
     return q, k, v
 
 
-def gqa_causal_attention(q: Any, k: Any, v: Any) -> Any:
-    """Full causal attention of one sequence with query heads grouped onto
-    KV heads (no copies of K or V). q [S, H, Dh]; k, v [S, KV, Dh]. Scores
-    and softmax in float32 (``dense_attention``'s discipline)."""
-    s, heads, dh = q.shape
-    kv = k.shape[1]
-    qg = (q.astype(jnp.float32) * dh ** -0.5).reshape(s, kv, heads // kv, dh)
-    scores = jnp.einsum("qkgd,tkd->kgqt", qg, k.astype(jnp.float32))
-    mask = jnp.arange(s)[None, :] <= jnp.arange(s)[:, None]
-    probs = jax.nn.softmax(jnp.where(mask[None, None], scores, -jnp.inf), axis=-1)
-    out = jnp.einsum("kgqt,tkd->qkgd", probs, v.astype(jnp.float32))
-    return out.reshape(s, heads, dh).astype(q.dtype)
-
-
 # ---------------------------------------------------------------------------
 # E: LatentMoE
 # ---------------------------------------------------------------------------
+
+
+def _relu2(x: Any) -> Any:
+    return jnp.square(jax.nn.relu(x))
 
 
 def latent_moe(p: Any, cfg: NemotronHConfig, u: Any, rows: Any) -> tuple[Any, Any]:
@@ -259,11 +225,11 @@ class NemotronHFamily:
         self.dtype = dtype
         self.vocab = config.vocab_size
         self.max_len = config.max_len
-        self.kv_layers = len(config.layers_of("*"))
+        self._kv_index = layer_index(config.layer_kinds, "*")
+        self._m_index = layer_index(config.layer_kinds, "M")
+        self.kv_layers = len(self._kv_index)
         self.kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
-        self._kv_index = {layer: i for i, layer in enumerate(config.layers_of("*"))}
-        self._m_index = {layer: i for i, layer in enumerate(config.layers_of("M"))}
         self.expert_layers = len(config.layers_of("E"))
         self.experts_per_token = config.num_experts_per_tok
 
@@ -298,9 +264,7 @@ class NemotronHFamily:
             elif kind == "*":
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, u)
-                    kv.write_prefill(self._kv_index[i], k, v)
-                    att = gqa_causal_attention(q, k, v)
-                    out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    out = attend_prefill(kv, self._kv_index[i], q, k, v, p["attn"]["out"]["kernel"])
             else:
                 with jax.named_scope("moe"):
                     out, c = latent_moe(p["moe"], cfg, u, rows)
@@ -308,7 +272,7 @@ class NemotronHFamily:
             x = x + out
         last = jnp.take(x, length - 1, axis=0)
         logits = rms_norm(last, params["norm_f"]["scale"], cfg.norm_eps) @ params["head"]["kernel"]
-        return logits.astype(jnp.float32), {"conv": conv, "ssm": ssm}, self._aux(counts)
+        return logits.astype(jnp.float32), {"conv": conv, "ssm": ssm}, expert_aux(counts)
 
     def decode(self, params: Any, tokens: Any, lengths: Any, active: Any, kv: Any,
                state: Any) -> tuple[Any, Any, Any]:
@@ -328,46 +292,28 @@ class NemotronHFamily:
             elif kind == "*":
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, u)
-                    att = kv.write_attend(self._kv_index[i], q, k, v)
-                    out = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    out = attend_decode(kv, self._kv_index[i], q, k, v, p["attn"]["out"]["kernel"])
             else:
                 with jax.named_scope("moe"):
                     out, c = latent_moe(p["moe"], cfg, u, active)
                 counts.append(c)
             x = x + out
         logits = rms_norm(x, params["norm_f"]["scale"], cfg.norm_eps) @ params["head"]["kernel"]
-        return logits.astype(jnp.float32), {"conv": conv, "ssm": ssm}, self._aux(counts)
-
-    @staticmethod
-    def _aux(counts: list) -> dict:
-        return {"expert_counts": jnp.stack(counts)} if counts else {}
+        return logits.astype(jnp.float32), {"conv": conv, "ssm": ssm}, expert_aux(counts)
 
     def work_attrs(self, aux: dict, rows: int) -> dict:
-        """What one program run did in the expert layers, from the counts it
-        returned (NumPy, ``[E layers, held]``) and the rows that held a
-        token: the attributes of ``gen/step`` / ``gen/prefill``."""
-        counts = aux["expert_counts"]
-        pairs = int(counts.sum())
-        return {
-            "expert_pairs": pairs,
-            # Pairs whose expert lives on another chip: what the exchange would carry.
-            "expert_pairs_absent": rows * self.experts_per_token * self.expert_layers - pairs,
-            "experts_hit": float((counts > 0).sum(axis=1).mean()),
-            "expert_rows_max": int(counts.max()),
-        }
+        """The attributes of ``gen/step`` / ``gen/prefill``: the expert
+        layers' work (``[E layers, held]`` counts)."""
+        return expert_attrs(aux["expert_counts"], rows, self.experts_per_token, self.expert_layers)
 
 
 def register_nemotron_h(name: str, config: NemotronHConfig) -> Any:
     """Register ``config`` as a servable ``kind="lm"`` model called ``name``."""
     from dmlc_tpu.models import registry
 
-    spec = registry.ModelSpec(
-        name, lambda dtype=jnp.float32: NemotronHModule(config, dtype),
-        config.max_len, config.vocab_size, classifier=False, kind="lm",
-        num_heads=config.num_attention_heads,
-        family=lambda dtype: NemotronHFamily(config, dtype))
-    registry.register(spec)
-    return spec
+    depth = len(config.layer_kinds)
+    return registry.register_family(name, config, param_shapes(config),
+                                    lambda path: _leaf_mean_std(path, depth), NemotronHFamily)
 
 
 #: The CPU tests' preset (as ``lm_small`` is for the GPT-2 family): every

@@ -51,8 +51,7 @@ from typing import Any
 import jax
 import jax.numpy as jnp
 
-from dmlc_tpu.models.nemotron_h import gqa_causal_attention, rms_norm
-from dmlc_tpu.models.seeded_tree import SeededTreeModule
+from dmlc_tpu.models.blocks import attend_decode, attend_prefill, gated_mlp, layer_index, rms_norm
 
 HIGHEST = jax.lax.Precision.HIGHEST
 
@@ -129,7 +128,7 @@ class OlmoHybridConfig:
         return 2 * self.key_dim + self.value_dim
 
     def layers_of(self, kind: str) -> list[int]:
-        return [i for i, k in enumerate(self.layer_types) if k == kind]
+        return list(layer_index(self.layer_types, kind))
 
 
 # ---------------------------------------------------------------------------
@@ -197,16 +196,6 @@ def _leaf_mean_std(path: str, depth: int) -> tuple[float, float]:
     if path.endswith(("out/kernel", "down/kernel")):
         return 0.0, 0.02 / (2.0 * depth) ** 0.5
     return 0.0, 0.02
-
-
-class OlmoHybridModule(SeededTreeModule):
-    """The family's parameter tree as the registry's ``init_params`` draws it."""
-
-    def __init__(self, config: OlmoHybridConfig, dtype: Any = jnp.float32) -> None:
-        depth = len(config.layer_types)
-        super().__init__(param_shapes(config), lambda path: _leaf_mean_std(path, depth),
-                         vocab=config.vocab_size, max_len=config.max_len, dtype=dtype)
-        self.config = config
 
 
 # ---------------------------------------------------------------------------
@@ -381,7 +370,7 @@ def deltanet_decode(p: Any, cfg: OlmoHybridConfig, x: Any, window: Any, state: A
 
 
 # ---------------------------------------------------------------------------
-# full_attention (query/key norm, no positions) and the gated MLP
+# full_attention: query/key norm, no positions
 # ---------------------------------------------------------------------------
 
 
@@ -394,12 +383,6 @@ def _qkv(p: Any, cfg: OlmoHybridConfig, x: Any) -> tuple[Any, Any, Any]:
     return (q.reshape(*lead, cfg.num_attention_heads, cfg.head_dim),
             k.reshape(*lead, cfg.num_key_value_heads, cfg.head_dim),
             v.reshape(*lead, cfg.num_key_value_heads, cfg.head_dim))
-
-
-def gated_mlp(p: Any, cfg: OlmoHybridConfig, x: Any) -> Any:
-    both = x @ p["gate_up"]["kernel"]
-    width = cfg.intermediate_size
-    return (jax.nn.silu(both[..., :width]) * both[..., width:]) @ p["down"]["kernel"]
 
 
 # ---------------------------------------------------------------------------
@@ -416,11 +399,11 @@ class OlmoHybridFamily:
         self.dtype = dtype
         self.vocab = config.vocab_size
         self.max_len = config.max_len
-        self.kv_layers = len(config.layers_of(FULL))
+        self._kv_index = layer_index(config.layer_types, FULL)
+        self._linear_index = layer_index(config.layer_types, LINEAR)
+        self.kv_layers = len(self._kv_index)
         self.kv_heads = config.num_key_value_heads
         self.head_dim = config.head_dim
-        self._kv_index = {layer: i for i, layer in enumerate(config.layers_of(FULL))}
-        self._linear_index = {layer: i for i, layer in enumerate(config.layers_of(LINEAR))}
         self.state_bytes_per_slot = sum(
             jnp.dtype(dtype).itemsize * math.prod(shape[1:])
             for leaves in self.state_shapes(1).values() for shape, dtype in leaves)
@@ -441,7 +424,7 @@ class OlmoHybridFamily:
         cfg = self.config
         x = x + rms_norm(mixed, p["mixer_norm"]["scale"], cfg.rms_norm_eps)
         with jax.named_scope("mlp"):
-            return x + rms_norm(gated_mlp(p["mlp"], cfg, x), p["mlp_norm"]["scale"],
+            return x + rms_norm(gated_mlp(p["mlp"], x), p["mlp_norm"]["scale"],
                                 cfg.rms_norm_eps)
 
     def prefill(self, params: Any, tokens: Any, length: Any, slot: Any, kv: Any,
@@ -467,9 +450,8 @@ class OlmoHybridFamily:
             else:
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, x)
-                    kv.write_prefill(self._kv_index[i], k, v)
-                    att = gqa_causal_attention(q, k, v)
-                    mixed = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    mixed = attend_prefill(kv, self._kv_index[i], q, k, v,
+                                           p["attn"]["out"]["kernel"])
             x = self._branches(p, x, mixed)
         last = jnp.take(x, length - 1, axis=0)
         logits = rms_norm(last, params["norm_f"]["scale"], cfg.rms_norm_eps) @ params["head"]["kernel"]
@@ -491,8 +473,8 @@ class OlmoHybridFamily:
             else:
                 with jax.named_scope("attn"):
                     q, k, v = _qkv(p["attn"], cfg, x)
-                    att = kv.write_attend(self._kv_index[i], q, k, v)
-                    mixed = att.reshape(att.shape[0], -1) @ p["attn"]["out"]["kernel"]
+                    mixed = attend_decode(kv, self._kv_index[i], q, k, v,
+                                          p["attn"]["out"]["kernel"])
             x = self._branches(p, x, mixed)
         logits = rms_norm(x, params["norm_f"]["scale"], cfg.rms_norm_eps) @ params["head"]["kernel"]
         # No position term: ``lengths`` only says how much the attention read.
@@ -516,13 +498,9 @@ def register_olmo_hybrid(name: str, config: OlmoHybridConfig) -> Any:
     """Register ``config`` as a servable ``kind="lm"`` model called ``name``."""
     from dmlc_tpu.models import registry
 
-    spec = registry.ModelSpec(
-        name, lambda dtype=jnp.float32: OlmoHybridModule(config, dtype),
-        config.max_len, config.vocab_size, classifier=False, kind="lm",
-        num_heads=config.num_attention_heads,
-        family=lambda dtype: OlmoHybridFamily(config, dtype))
-    registry.register(spec)
-    return spec
+    depth = len(config.layer_types)
+    return registry.register_family(name, config, param_shapes(config),
+                                    lambda path: _leaf_mean_std(path, depth), OlmoHybridFamily)
 
 
 #: The CPU tests' preset: the published period ``L L L F`` twice, four heads

@@ -30,6 +30,7 @@ from dmlc_tpu.models.lm import (
     lm_wide,
 )
 from dmlc_tpu.models.resnet import resnet18, resnet34, resnet50
+from dmlc_tpu.models.seeded_tree import SeededTreeModule
 from dmlc_tpu.models.vit import vit_b16, vit_l14
 from dmlc_tpu.parallel.sharding import (
     REPLICATED_PARTITION_RULES,
@@ -245,6 +246,25 @@ def get_model(name: str) -> ModelSpec:
 
 def list_models() -> list[str]:
     return sorted(_REGISTRY)
+
+
+def register_family(name: str, config: Any, shapes: dict,
+                    leaf_mean_std: Callable[[str], tuple[float, float]],
+                    family_cls: Callable[[Any, Any], Any]) -> ModelSpec:
+    """Register a family's ``config`` as a servable ``kind="lm"`` model called
+    ``name``: its parameter tree ``shapes`` drawn from a seed (each leaf's
+    normal draw from ``leaf_mean_std(path)``), served through
+    ``family_cls(config, dtype)``."""
+
+    def build(dtype: Any) -> SeededTreeModule:
+        return SeededTreeModule(shapes, leaf_mean_std, vocab=config.vocab_size,
+                                max_len=config.max_len, dtype=dtype)
+
+    spec = ModelSpec(
+        name, build, config.max_len, config.vocab_size, classifier=False, kind="lm",
+        num_heads=config.num_attention_heads, family=lambda dtype: family_cls(config, dtype))
+    register(spec)
+    return spec
 
 
 for _spec in [

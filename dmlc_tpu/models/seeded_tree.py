@@ -1,5 +1,5 @@
 """A parameter tree drawn from a seed, for families that are plain functions
-over a tree (``models/nemotron_h``, ``models/olmo_hybrid``): what the
+over a tree (every family ``registry.register_family`` registers): what the
 registry's ``init_params`` needs of a module, ``init(rng, tokens) ->
 {"params": tree}``, and nothing else, so there is no flax module to keep in
 step with the functions.

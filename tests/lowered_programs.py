@@ -15,9 +15,9 @@ CASES = [(model, slots) for model in ("lm_small", "nemotron_h_tiny", "olmo_hybri
          for slots in (4, 24)]
 
 
-def program_texts(model: str, slots: int, use_pallas: bool) -> dict:
-    """``{"step": text, "prefill": text}`` of ``model``'s engine at ``slots``
-    slots, float32, pages of 8, prompts padded to 32."""
+def lowered(model: str, slots: int, use_pallas: bool) -> dict:
+    """``{"step": lowered, "prefill": lowered}`` of ``model``'s engine at
+    ``slots`` slots, float32, pages of 8, prompts padded to 32."""
     import jax
     import jax.numpy as jnp
 
@@ -31,8 +31,14 @@ def program_texts(model: str, slots: int, use_pallas: bool) -> dict:
     variables = jax.eval_shape(
         lambda: spec.init_params(jax.random.PRNGKey(0), dtype=jnp.float32)[1])
     args = chip_smoke.abstract_program_args(engine, variables=variables)
-    return {name: program.lower(*args[name]).as_text()
+    return {name: program.lower(*args[name])
             for name, program in (("step", engine._step), ("prefill", engine._prefill))}
+
+
+def program_texts(model: str, slots: int, use_pallas: bool) -> dict:
+    """``{"step": text, "prefill": text}``: ``lowered`` as text."""
+    return {name: program.as_text()
+            for name, program in lowered(model, slots, use_pallas).items()}
 
 
 #: The K/V form of the fused decode attention at the shapes the accepted cells

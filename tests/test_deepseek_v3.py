@@ -24,6 +24,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import batched_admission  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
+from dmlc_tpu.models import blocks  # noqa: E402
 from dmlc_tpu.models import deepseek_v3 as ds  # noqa: E402
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 from dmlc_tpu.ops import ragged_decode  # noqa: E402
@@ -208,7 +209,7 @@ class TestLatentAttention:
         p = variables["params"]["layer1"]["attn"]
         u = jnp.asarray(np.random.default_rng(seed).standard_normal((s, CFG.hidden_size)),
                         jnp.float32)
-        cos, sin = ds.rotary_tables(jnp.arange(s), CFG.qk_rope_head_dim, CFG.rope_theta)
+        cos, sin = blocks.rotary_tables(jnp.arange(s), CFG.qk_rope_head_dim, CFG.rope_theta)
         return p, ds.project(p, CFG, u, cos, sin)
 
     def test_absorbed_decode_equals_expanded_attention(self, variables):
@@ -244,7 +245,7 @@ class TestLatentAttention:
         assert engine._v_state is None and engine.latent_row == 128
         params = variables["params"]
         p = params["layer0"]
-        u = ds.rms_norm(params["embed"]["embedding"][prompt], p["attn_norm"]["scale"],
+        u = blocks.rms_norm(params["embed"]["embedding"][prompt], p["attn_norm"]["scale"],
                         CFG.rms_norm_eps)
         both = np.asarray(u @ p["attn"]["q_kva"]["kernel"], np.float64)
         q_lanes = CFG.num_attention_heads * CFG.qk_head_dim
@@ -326,11 +327,11 @@ class TestExperts:
             mine = slice(16 * rank, 16 * rank + 16)
             routed, counts = held_experts_ffn(
                 u, p["experts"]["w13"][mine], p["experts"]["w2"][mine], idx, gates,
-                (16 * rank, 16), rows, n_experts=128, activation=ds.gated_expert, dense=dense)
+                (16 * rank, 16), rows, n_experts=128, activation=blocks.gated_expert, dense=dense)
             parts.append(np.asarray(routed))
             pairs += int(counts.sum())
         assert pairs == 10 * 6
-        shared = np.asarray(ds.gated_mlp(p["shared"], u))
+        shared = np.asarray(blocks.gated_mlp(p["shared"], u))
         z = REF.sizes({**ref_cfg(cfg), "n_routed_experts": 128,
                        "deployment": {"experts_held": [0, 128]}})
         with jax.default_matmul_precision("highest"):

@@ -25,7 +25,7 @@ import batched_admission  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
 from dmlc_tpu.models import granite_hybrid as gh  # noqa: E402
 from dmlc_tpu.models import mamba2  # noqa: E402
-from dmlc_tpu.models.nemotron_h import gqa_causal_attention  # noqa: E402
+from dmlc_tpu.models.blocks import gqa_causal_attention  # noqa: E402
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 
 MODEL = "granite_hybrid_tiny"
@@ -350,16 +350,6 @@ class TestStateSlots:
         engine.join(2, prompt_of(4, seed=2))
         engine.step()
         assert engine.step_attrs == {"state_bytes": 2 * engine.state.bytes_per_slot}
-
-    def test_named_scopes_are_in_both_programs(self, variables):
-        import chip_smoke
-
-        engine = make_engine(variables)
-        args = chip_smoke.abstract_program_args(engine)
-        for name, program in (("step", engine._step), ("prefill", engine._prefill)):
-            text = program.lower(*args[name]).as_text(debug_info=True)
-            for scope in ("mamba", "attn", "mlp"):
-                assert f"/{scope}/" in text, (name, scope)
 
 
 class TestBatchedAdmission:

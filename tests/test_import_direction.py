@@ -35,23 +35,37 @@ IMPORTS = {
 }
 
 
+#: The model families behind the generation engine's seam: each imports its
+#: shared blocks from ``models/blocks`` (and ``mamba2``, ``seeded_tree``,
+#: ``parallel/moe``), never from another family.
+FAMILIES = ("nemotron_h", "olmo_hybrid", "lfm2_moe", "deepseek_v3", "granite_hybrid")
+
+
+def imported_modules(source: Path) -> set[str]:
+    """Every ``dmlc_tpu`` module ``source`` imports, dotted, inside functions too."""
+    found: set[str] = set()
+    for node in ast.walk(ast.parse(source.read_text())):
+        if isinstance(node, ast.Import):
+            modules = [alias.name for alias in node.names]
+        elif isinstance(node, ast.ImportFrom):
+            assert node.level == 0, f"{source}: relative import"
+            modules = [node.module or ""]
+            if node.module and node.module.split(".")[0] == "dmlc_tpu":
+                # ``from dmlc_tpu.models import registry`` imports the module too
+                modules += [f"{node.module}.{alias.name}" for alias in node.names]
+        else:
+            continue
+        found.update(m for m in modules if m.split(".")[0] == "dmlc_tpu")
+    return found
+
+
 def imported_packages(package: str) -> set[str]:
     found: set[str] = set()
     for source in (PACKAGE / package).rglob("*.py"):
-        for node in ast.walk(ast.parse(source.read_text())):
-            if isinstance(node, ast.Import):
-                modules = [alias.name for alias in node.names]
-            elif isinstance(node, ast.ImportFrom):
-                assert node.level == 0, f"{source}: relative import"
-                modules = [node.module or ""]
-                if node.module == "dmlc_tpu":
-                    modules = [f"dmlc_tpu.{alias.name}" for alias in node.names]
-            else:
-                continue
-            for module in modules:
-                parts = module.split(".")
-                if parts[0] == "dmlc_tpu" and len(parts) > 1:
-                    found.add(parts[1])
+        for module in imported_modules(source):
+            parts = module.split(".")
+            if len(parts) > 1:
+                found.add(parts[1])
     return found - {package}
 
 
@@ -62,6 +76,16 @@ def test_package_imports_no_more_than_recorded(package):
         f"dmlc_tpu/{package} now imports {sorted(new)}: a new edge in the "
         "package graph (ROADMAP D17); move the shared piece down instead"
     )
+
+
+def test_no_model_family_imports_another():
+    for family in FAMILIES:
+        imported = imported_modules(PACKAGE / "models" / f"{family}.py")
+        others = {f"dmlc_tpu.models.{f}" for f in FAMILIES if f != family}
+        assert not imported & others, (
+            f"dmlc_tpu/models/{family}.py imports {sorted(imported & others)}: a block more "
+            "than one family uses belongs in models/blocks (ROADMAP D22)"
+        )
 
 
 def test_every_package_is_recorded():

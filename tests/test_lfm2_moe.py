@@ -23,6 +23,7 @@ import jax.numpy as jnp  # noqa: E402
 
 import batched_admission  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
+from dmlc_tpu.models import blocks  # noqa: E402
 from dmlc_tpu.models import lfm2_moe as lf  # noqa: E402
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 from dmlc_tpu.parallel.moe import held_experts_ffn, route_sigmoid_topk  # noqa: E402
@@ -189,9 +190,9 @@ class TestRotary:
         heads, dh = 3, 16
         positions = np.array([0, 1, 7, 500, 1023, 1406])
         x = rng.normal(size=(len(positions), heads, dh))
-        cos, sin = lf.rotary_tables(jnp.asarray(positions), dh, theta)
+        cos, sin = blocks.rotary_tables(jnp.asarray(positions), dh, theta)
         assert cos.dtype == jnp.float32 and cos.shape == (len(positions), dh // 2)
-        got = np.asarray(lf.apply_rotary(jnp.asarray(x, jnp.float32), cos, sin))
+        got = np.asarray(blocks.apply_rotary(jnp.asarray(x, jnp.float32), cos, sin))
         z = x[..., :dh // 2] + 1j * x[..., dh // 2:]
         turn = np.exp(1j * positions[:, None] * theta ** (-2.0 * np.arange(dh // 2) / dh))
         want = z * turn[:, None, :]
@@ -208,9 +209,9 @@ class TestRotary:
         rng = np.random.default_rng(1)
         q, k = (jnp.asarray(rng.normal(size=(1, 1, 16)), jnp.float32) for _ in range(2))
         def score(m, n):
-            cm, sm = lf.rotary_tables(jnp.asarray([m]), 16, 1e6)
-            cn, sn = lf.rotary_tables(jnp.asarray([n]), 16, 1e6)
-            return float(jnp.sum(lf.apply_rotary(q, cm, sm) * lf.apply_rotary(k, cn, sn)))
+            cm, sm = blocks.rotary_tables(jnp.asarray([m]), 16, 1e6)
+            cn, sn = blocks.rotary_tables(jnp.asarray([n]), 16, 1e6)
+            return float(jnp.sum(blocks.apply_rotary(q, cm, sm) * blocks.apply_rotary(k, cn, sn)))
         assert abs(score(40, 33) - score(7, 0)) < 1e-4
         assert abs(score(40, 33) - score(40, 30)) > 1e-3
 
@@ -224,11 +225,12 @@ class TestRotary:
         x = params["embed"]["embedding"][prompt]
         for i in (0, 1):                                    # the two dense conv layers
             p = params[f"layer{i}"]
-            u = lf.rms_norm(x, p["operator_norm"]["scale"], CFG.norm_eps)
+            u = blocks.rms_norm(x, p["operator_norm"]["scale"], CFG.norm_eps)
             x = x + lf.shortconv_prefill(p["shortconv"], CFG, u, 9)[0]
-            x = x + lf.gated_mlp(p["mlp"], lf.rms_norm(x, p["ffn_norm"]["scale"], CFG.norm_eps))
+            u = blocks.rms_norm(x, p["ffn_norm"]["scale"], CFG.norm_eps)
+            x = x + blocks.gated_mlp(p["mlp"], u)
         p = params["layer2"]
-        u = lf.rms_norm(x, p["operator_norm"]["scale"], CFG.norm_eps)
+        u = blocks.rms_norm(x, p["operator_norm"]["scale"], CFG.norm_eps)
         k = (u @ p["attn"]["qkv"]["kernel"])[:, 64:96].reshape(9, 2, 16)
         k = np.asarray(k / jnp.sqrt(jnp.mean(k * k, axis=-1, keepdims=True) + CFG.norm_eps)
                        * p["attn"]["k_norm"]["scale"], np.float64)
@@ -271,7 +273,7 @@ class TestShortConv:
         engine.join(2, prompt)
         params = variables["params"]
         p = params["layer0"]
-        u = lf.rms_norm(params["embed"]["embedding"][prompt], p["operator_norm"]["scale"],
+        u = blocks.rms_norm(params["embed"]["embedding"][prompt], p["operator_norm"]["scale"],
                         CFG.norm_eps)
         bcu = np.asarray(u @ p["shortconv"]["in_proj"]["kernel"])
         z = np.concatenate([np.zeros((2, 64), np.float32), bcu[:, :64] * bcu[:, 128:]])
@@ -352,7 +354,7 @@ class TestGatedExperts:
         assert (np.sort(np.asarray(idx)) != np.sort(np.asarray(plain))).any()   # ... and picks
         rows = jnp.arange(40) % 5 != 0
         outs = [held_experts_ffn(u, p["experts"]["w13"], p["experts"]["w2"], idx, gates, (0, 8),
-                                 rows, n_experts=8, activation=lf.gated_expert, dense=dense)
+                                 rows, n_experts=8, activation=blocks.gated_expert, dense=dense)
                 for dense in (False, True, None)]
         want = self._loop(p, u, np.asarray(rows))
         assert np.abs(want).max() > 1e-4
@@ -383,7 +385,7 @@ class TestGatedExperts:
         def form(t, k, experts):
             w13, w2 = jnp.zeros((experts, 8, 16)), jnp.zeros((experts, 8, 8))
             fn = lambda x, i, g: held_experts_ffn(x, w13, w2, i, g, (0, experts),
-                                                  n_experts=experts, activation=lf.gated_expert)[0]
+                                                  n_experts=experts, activation=blocks.gated_expert)[0]
             return str(jax.make_jaxpr(fn)(jnp.zeros((t, 8)), jnp.zeros((t, k), jnp.int32),
                                           jnp.zeros((t, k))))
         kernel = "_grouped_expert_matmul"
@@ -480,16 +482,6 @@ class TestStateSlots:
         assert attrs["state_bytes_touched"] == 2 * 2 * per_slot     # two slots, read and written
         assert attrs["kv_tokens_read"] == (10 + 1) + (4 + 1)
         assert attrs["state_bytes"] == 2 * per_slot
-
-    def test_named_scopes_are_in_both_programs(self, variables):
-        import chip_smoke
-
-        engine = make_engine(variables)
-        args = chip_smoke.abstract_program_args(engine)
-        for name, program in (("step", engine._step), ("prefill", engine._prefill)):
-            text = program.lower(*args[name]).as_text(debug_info=True)
-            for scope in ("shortconv", "attn", "moe", "mlp"):
-                assert f"/{scope}/" in text, (name, scope)
 
 
 class TestBatchedAdmission:

@@ -96,6 +96,26 @@ def test_step_and_prefill_lower_to_what_the_parent_lowered(model, slots, path):
     assert got == {key: PINS[path][key] for key in got}
 
 
+#: The scopes each family's mixers and feed-forwards run under: the pins hash
+#: the text without them, and the benchmark's per-layer readers (and
+#: docs/OBSERVABILITY.md) name them. ``deepseek_v3`` has its own test beside
+#: its latent kernel's (``tests/test_deepseek_v3.py``).
+SCOPES = {
+    "nemotron_h_tiny": ("mamba", "attn", "moe"),
+    "olmo_hybrid_tiny": ("deltanet", "attn", "mlp"),
+    "lfm2_moe_tiny": ("shortconv", "attn", "moe", "mlp"),
+    "granite_hybrid_tiny": ("mamba", "attn", "mlp"),
+}
+
+
+@pytest.mark.parametrize("model", sorted(SCOPES))
+def test_named_scopes_are_in_both_programs(model):
+    for name, program in lowered_programs.lowered(model, 4, use_pallas=False).items():
+        text = program.as_text(debug_info=True)
+        for scope in SCOPES[model]:
+            assert f"/{scope}/" in text, (name, scope)
+
+
 def test_the_pins_cover_both_programs_of_every_case():
     want = {f"{model}/{slots}/{name}" for model, slots in lowered_programs.CASES
             for name in ("step", "prefill")}

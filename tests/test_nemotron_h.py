@@ -21,6 +21,7 @@ import jax.numpy as jnp  # noqa: E402
 import batched_admission  # noqa: E402
 from dmlc_tpu.generate.engine import GenerationEngine  # noqa: E402
 from dmlc_tpu.generate.worker import GenerationBackend  # noqa: E402
+from dmlc_tpu.models import blocks  # noqa: E402
 from dmlc_tpu.models import nemotron_h as nh  # noqa: E402
 from dmlc_tpu.models.registry import get_model  # noqa: E402
 from dmlc_tpu.ops.ragged_decode import ragged_decode_attention  # noqa: E402
@@ -322,7 +323,7 @@ def test_gqa_prefill_attention_matches_repeated_heads():
 
     rep = lambda a: jnp.repeat(a, heads // kv, axis=1).transpose(1, 0, 2)[None]
     want = dense_attention(q.transpose(1, 0, 2)[None], rep(k), rep(v), causal=True)[0]
-    got = nh.gqa_causal_attention(q, k, v)
+    got = blocks.gqa_causal_attention(q, k, v)
     np.testing.assert_allclose(np.asarray(got), np.asarray(want.transpose(1, 0, 2)), atol=1e-5)
 
 
@@ -342,7 +343,7 @@ class TestExpertLayer:
         full_cfg = nh.NemotronHConfig.from_published(
             {f: getattr(CFG, f) for f in CFG.__dataclass_fields__ if f != "layer_kinds"},
             experts_held=(0, 8))
-        module = nh.NemotronHModule(full_cfg, jnp.float32)
+        module = nh.register_nemotron_h("nemotron_h_all_experts_held", full_cfg).build(jnp.float32)
         p = module.init(jax.random.PRNGKey(11))["params"]["layer1"]["moe"]
         u = rng_rows(40)
         z = REF.sizes(ref_cfg(full_cfg))

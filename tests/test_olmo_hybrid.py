@@ -355,16 +355,6 @@ class TestStateSlots:
         assert attrs["kv_tokens_read"] == (10 + 1) + (4 + 1)
         assert attrs["state_bytes"] == 2 * per_slot
 
-    def test_named_scopes_are_in_both_programs(self, variables):
-        import chip_smoke
-
-        engine = make_engine(variables)
-        args = chip_smoke.abstract_program_args(engine)
-        for name, program in (("step", engine._step), ("prefill", engine._prefill)):
-            text = program.lower(*args[name]).as_text(debug_info=True)
-            for scope in ("deltanet", "attn", "mlp"):
-                assert f"/{scope}/" in text, (name, scope)
-
 
 class TestBatchedAdmission:
     @pytest.mark.parametrize("k,temperature", batched_admission.CASES)
